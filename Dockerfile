@@ -17,8 +17,15 @@
 #       --entrypoint python sbeacon-tpu -m sbeacon_tpu.parallel.dispatch \
 #       --data-root /data --port 5100 --host 0.0.0.0
 #
-# On TPU VMs, base this on the matching libtpu image instead and jax
-# picks the chips up automatically; CPU serving works as-is.
+# THIS IMAGE IS CPU-ONLY: it installs jax[cpu], pinned to the version
+# the code is written and checked against (JAX 0.9.0). It serves and
+# tests on the CPU backend; it cannot see a TPU. For a TPU VM, build
+# from a base that carries jax[tpu]==0.9.0 with its matching libtpu
+# (0.0.34) and run ONE server or worker process per host.
+# Compiled programs persist in /app/.jax_cache, which dies with the
+# container: set JAX_COMPILATION_CACHE_DIR to a directory on a mounted
+# volume (docker-compose.yml uses /data/jax-cache) to keep them across
+# restarts. Every program is kept there, whatever it took to compile.
 
 FROM python:3.12-slim
 
@@ -27,7 +34,7 @@ RUN apt-get update \
     && rm -rf /var/lib/apt/lists/*
 
 RUN pip install --no-cache-dir \
-    "jax[cpu]" numpy jsonschema cryptography
+    "jax[cpu]==0.9.0" numpy jsonschema cryptography
 
 WORKDIR /app
 COPY sbeacon_tpu ./sbeacon_tpu

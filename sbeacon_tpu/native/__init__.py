@@ -16,15 +16,17 @@ when the toolchain/library is missing so the Python path can take over.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
+import os
 import subprocess
 import threading
 from pathlib import Path
 
 log = logging.getLogger(__name__)
 
-_SRC = Path(__file__).parent / "src"
-_LIB_PATH = Path(__file__).parent / "_sbnative.so"
+_DIR = Path(__file__).parent
+_SRC = _DIR / "src"
 _SOURCES = [
     "bgzf.cpp",
     "scan.cpp",
@@ -32,6 +34,8 @@ _SOURCES = [
     "gt_planes.cpp",
     "tokenize.cpp",
 ]
+_HEADERS = ["thread_pool.hpp"]
+_CXXFLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 
 _lock = threading.Lock()
 _lib = None
@@ -42,32 +46,48 @@ class NativeUnavailable(RuntimeError):
     pass
 
 
-def _newest_source_mtime() -> float:
-    return max((_SRC / s).stat().st_mtime for s in _SOURCES)
+def source_hash() -> str:
+    """Identity of what the library is built FROM: every source and
+    header plus the compiler flags. The hash rides the library's file
+    name, so a ``.so`` that was copied in with the tree, or outlived an
+    edit to ``src/``, is simply never the file that gets loaded."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    for name in _SOURCES + _HEADERS:
+        h.update(name.encode())
+        h.update((_SRC / name).read_bytes())
+    return h.hexdigest()[:16]
+
+
+def lib_path() -> Path:
+    return _DIR / f"_sbnative.{source_hash()}.so"
 
 
 def build(force: bool = False) -> Path:
-    """Compile the shared library (cached by mtime)."""
-    if (
-        not force
-        and _LIB_PATH.exists()
-        and _LIB_PATH.stat().st_mtime >= _newest_source_mtime()
-    ):
-        return _LIB_PATH
+    """Compile the shared library for the CURRENT sources (reused only
+    when a library named by their hash already exists)."""
+    path = lib_path()
+    if not force and path.exists():
+        return path
+    # compile beside the target and rename: a concurrent loader never
+    # maps a half-written file
+    tmp = path.with_name(f"{path.name}.{os.getpid()}.tmp")
     cmd = [
         "g++",
-        "-O3",
-        "-std=c++17",
-        "-shared",
-        "-fPIC",
-        "-pthread",
+        *_CXXFLAGS,
         *[str(_SRC / s) for s in _SOURCES],
         "-lz",
         "-o",
-        str(_LIB_PATH),
+        str(tmp),
     ]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return _LIB_PATH
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, path)
+    finally:
+        tmp.unlink(missing_ok=True)
+    for old in _DIR.glob("_sbnative*.so"):
+        if old != path:
+            old.unlink(missing_ok=True)
+    return path
 
 
 def get_lib():
@@ -83,6 +103,11 @@ def get_lib():
             lib = ctypes.CDLL(str(path))
         except Exception as e:
             _build_failed = True
+            # every ingest from here on parses in pure Python, several
+            # times slower: counted, not just logged
+            from ..telemetry import record_device_fallback
+
+            record_device_fallback("native_build")
             log.warning("native library unavailable: %s", e)
             return None
         lib.sbn_inflate_range.argtypes = [
@@ -94,17 +119,16 @@ def get_lib():
             ctypes.POINTER(ctypes.c_uint64),
         ]
         lib.sbn_inflate_range.restype = ctypes.c_int
-        if hasattr(lib, "sbn_inflate_buffer"):
-            lib.sbn_inflate_buffer.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.c_uint64,
-                ctypes.c_uint64,
-                ctypes.c_uint64,
-                ctypes.c_int,
-                ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
-                ctypes.POINTER(ctypes.c_uint64),
-            ]
-            lib.sbn_inflate_buffer.restype = ctypes.c_int
+        lib.sbn_inflate_buffer.argtypes = [
+            ctypes.POINTER(ctypes.c_uint8),
+            ctypes.c_uint64,
+            ctypes.c_uint64,
+            ctypes.c_uint64,
+            ctypes.c_int,
+            ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
+            ctypes.POINTER(ctypes.c_uint64),
+        ]
+        lib.sbn_inflate_buffer.restype = ctypes.c_int
         lib.sbn_compress_bgzf.argtypes = [
             ctypes.POINTER(ctypes.c_uint8),
             ctypes.c_uint64,
@@ -204,37 +228,36 @@ def get_lib():
             ctypes.POINTER(ctypes.c_uint64),
         ]
         lib.sbn_gt_planes.restype = ctypes.c_int64
-        if hasattr(lib, "sbn_tokenize_planes"):
-            # uint64 params MUST be declared: the ctypes default of
-            # c_int silently truncates len/n_samples/words >= 2^32
-            # (a >=2 GiB decompressed slice would mis-parse with no
-            # error on the fused hot path)
-            u8pp_ = ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8))
-            u32pp_ = ctypes.POINTER(ctypes.POINTER(ctypes.c_uint32))
-            u64pp_ = ctypes.POINTER(ctypes.POINTER(ctypes.c_uint64))
-            i64pp_ = ctypes.POINTER(ctypes.POINTER(ctypes.c_int64))
-            u64p_ = ctypes.POINTER(ctypes.c_uint64)
-            lib.sbn_tokenize_planes.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.c_uint64,      # len
-                ctypes.c_uint64,      # n_samples
-                ctypes.c_uint64,      # words
-                i64pp_,               # pos
-                u32pp_, u32pp_,       # chrom off/len
-                u32pp_, u32pp_,       # ref off/len
-                u32pp_, u32pp_,       # vt off/len
-                i64pp_, u8pp_, u8pp_,  # an, has_an, has_ac
-                i64pp_,               # tok_total
-                u32pp_, u32pp_, u64pp_,  # alt off/len/start
-                i64pp_,               # ac_gt
-                i64pp_, u64pp_,       # ac, ac_start
-                u32pp_, u32pp_,       # g1, g2
-                u32pp_, u32pp_,       # t1, t2
-                i64pp_, u64p_,        # gt_over, n_gt_over
-                i64pp_, u64p_,        # tok_over, n_tok_over
-                u64p_, u64p_, u64p_,  # n_rec, n_alt, n_ac
-            ]
-            lib.sbn_tokenize_planes.restype = ctypes.c_int
+        # uint64 params MUST be declared: the ctypes default of
+        # c_int silently truncates len/n_samples/words >= 2^32
+        # (a >=2 GiB decompressed slice would mis-parse with no
+        # error on the fused hot path)
+        u8pp_ = ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8))
+        u32pp_ = ctypes.POINTER(ctypes.POINTER(ctypes.c_uint32))
+        u64pp_ = ctypes.POINTER(ctypes.POINTER(ctypes.c_uint64))
+        i64pp_ = ctypes.POINTER(ctypes.POINTER(ctypes.c_int64))
+        u64p_ = ctypes.POINTER(ctypes.c_uint64)
+        lib.sbn_tokenize_planes.argtypes = [
+            ctypes.POINTER(ctypes.c_uint8),
+            ctypes.c_uint64,      # len
+            ctypes.c_uint64,      # n_samples
+            ctypes.c_uint64,      # words
+            i64pp_,               # pos
+            u32pp_, u32pp_,       # chrom off/len
+            u32pp_, u32pp_,       # ref off/len
+            u32pp_, u32pp_,       # vt off/len
+            i64pp_, u8pp_, u8pp_,  # an, has_an, has_ac
+            i64pp_,               # tok_total
+            u32pp_, u32pp_, u64pp_,  # alt off/len/start
+            i64pp_,               # ac_gt
+            i64pp_, u64pp_,       # ac, ac_start
+            u32pp_, u32pp_,       # g1, g2
+            u32pp_, u32pp_,       # t1, t2
+            i64pp_, u64p_,        # gt_over, n_gt_over
+            i64pp_, u64p_,        # tok_over, n_tok_over
+            u64p_, u64p_, u64p_,  # n_rec, n_alt, n_ac
+        ]
+        lib.sbn_tokenize_planes.restype = ctypes.c_int
         _lib = lib
         return _lib
 
@@ -324,8 +347,6 @@ def inflate_buffer(
     lib = get_lib()
     if lib is None:
         raise NativeUnavailable("native library not built")
-    if not hasattr(lib, "sbn_inflate_buffer"):
-        raise NativeUnavailable("sbn_inflate_buffer missing (stale library)")
     import numpy as np
 
     # zero-copy in: the C side only reads the blob
@@ -572,8 +593,6 @@ def tokenize(text: bytes, n_samples: int) -> dict:
     lib = get_lib()
     if lib is None:
         raise NativeUnavailable("native library not built")
-    if not hasattr(lib, "sbn_tokenize"):
-        raise NativeUnavailable("sbn_tokenize missing (stale library)")
     u8p = ctypes.POINTER(ctypes.c_uint8)
     u32p = ctypes.POINTER(ctypes.c_uint32)
     u64p = ctypes.POINTER(ctypes.c_uint64)
@@ -649,8 +668,6 @@ def tokenize_planes(text: bytes, n_samples: int, words: int) -> dict:
     lib = get_lib()
     if lib is None:
         raise NativeUnavailable("native library not built")
-    if not hasattr(lib, "sbn_tokenize_planes"):
-        raise NativeUnavailable("sbn_tokenize_planes missing (stale library)")
     u8p = ctypes.POINTER(ctypes.c_uint8)
     u32p = ctypes.POINTER(ctypes.c_uint32)
     u64p = ctypes.POINTER(ctypes.c_uint64)

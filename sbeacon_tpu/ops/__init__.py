@@ -21,18 +21,17 @@ def make_device_index(
     real TPU backends, the XLA gather kernel elsewhere.
 
     The scattered kernel replaced the round-2 grouped Pallas kernel as
-    the serving default after measuring BOTH regimes on v5e: at
-    1000-Genomes scale (2e7 rows) sparse queries collapse the grouped
-    kernel's tile sharing (0.83M q/s vs 26.8M q/s scattered, 32x);
-    on small dense corpora the grouped kernel's device-only rate is
-    higher (~128M vs ~41M q/s) but end-to-end serving throughput is
-    equal-or-better for the gather path (and 3x on record granularity)
-    because transport dominates — see ROUND3_NOTES.md. Real corpora
-    are 2e7-scale, which decides the default. ``window`` only sizes
-    the XLA fallback index;
-    the scattered kernel applies the engine's window_cap per BATCH
-    (tier split in run_queries_scattered), so the index needs no
-    build-time window."""
+    the serving default: at 1000-Genomes scale (2e7 rows) sparse
+    queries leave one real query per 64-slot tile group, so the grouped
+    kernel gathered and evaluated a whole tile span per query. The
+    reasoning is in the module docstring of ``ops/scatter_kernel.py``;
+    the rates once quoted here were taken before PR 1 on a machine that
+    no longer exists and have not been re-measured (PERF.md). Real
+    corpora are 2e7-scale, which decides the default. ``window`` only
+    sizes the XLA fallback index; the scattered kernel applies the
+    engine's window_cap per BATCH (tier split in
+    run_queries_scattered), so the index needs no build-time
+    window."""
     import jax
 
     if jax.default_backend() == "tpu":

@@ -17,7 +17,7 @@ that combine in two implementations behind one call:
   shard_map does (the forced-host-device CI mesh included).
 
 Both run INSIDE a shard_map body; the caller picks the implementation
-at trace time (``jax.default_backend()``), never inside the program.
+at trace time (:func:`default_impl`), never inside the program.
 """
 
 from __future__ import annotations
@@ -28,6 +28,12 @@ import jax
 import jax.numpy as jnp
 
 
+def default_impl() -> str:
+    """The combine a mesh program traces with on this backend: the
+    Pallas ring on TPU, the portable ``all_gather`` everywhere else."""
+    return "pallas" if jax.default_backend() == "tpu" else "portable"
+
+
 def gather_partials_portable(x, axis: str):
     """Sum per-device partial blocks into a replicated block.
 
@@ -35,7 +41,7 @@ def gather_partials_portable(x, axis: str):
     else zeros). Uses ``all_gather`` + sum rather than ``psum`` so the
     gathered-axis layout mirrors the TPU ring pass (and the replication
     checker's view of both paths matches: neither is inferable, the
-    caller runs under ``check_rep=False``)."""
+    caller runs under ``check_vma=False``)."""
     g = jax.lax.all_gather(x, axis)  # [n_dev, ...]
     return jnp.sum(g, axis=0)
 
@@ -48,13 +54,24 @@ def _ring_step_kernel(x_ref, out_ref, send_sem, recv_sem, *, axis: str):
     me = jax.lax.axis_index(axis)
     n = jax.lax.axis_size(axis)
     right = jax.lax.rem(me + 1, n)
+    left = jax.lax.rem(me + n - 1, n)
+    mesh_id = pltpu.DeviceIdType.MESH
+    # both neighbours must be inside THIS step before anything lands in
+    # their output buffer: a device still in the previous step may have
+    # that memory live under another name
+    barrier = pltpu.get_barrier_semaphore()
+    for nb in (left, right):
+        pltpu.semaphore_signal(
+            barrier, inc=1, device_id=(nb,), device_id_type=mesh_id
+        )
+    pltpu.semaphore_wait(barrier, 2)
     copy = pltpu.make_async_remote_copy(
         src_ref=x_ref,
         dst_ref=out_ref,
         send_sem=send_sem,
         recv_sem=recv_sem,
         device_id=(right,),
-        device_id_type=pltpu.DeviceIdType.LOGICAL,
+        device_id_type=mesh_id,
     )
     copy.start()
     copy.wait()
@@ -70,9 +87,13 @@ def _ring_step_fn(axis: str, shape: tuple, dtype_name: str):
     return pl.pallas_call(
         functools.partial(_ring_step_kernel, axis=axis),
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
+        # the barrier semaphore is shared by the devices of one
+        # collective; every step of every ring pass uses the same one,
+        # in program order
+        compiler_params=pltpu.CompilerParams(collective_id=0),
     )
 
 
@@ -98,8 +119,8 @@ def gather_partials(x, axis: str, n_dev: int, *, impl: str = "portable"):
     """Dispatch on the implementation chosen at trace time.
 
     ``impl``: ``"pallas"`` (TPU remote-DMA ring) or ``"portable"``
-    (all_gather+sum). The caller decides from ``jax.default_backend()``
-    OUTSIDE the shard_map body — backend probing does not trace."""
+    (all_gather+sum). The caller decides OUTSIDE the shard_map body
+    (:func:`default_impl`) — backend probing does not trace."""
     if impl == "pallas":
         return gather_partials_tpu(x, axis, n_dev)
     return gather_partials_portable(x, axis)

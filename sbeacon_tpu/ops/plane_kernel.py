@@ -143,7 +143,10 @@ class PlaneDeviceIndex:
             self.gt2 = self.tok1 = self.tok2 = None
 
     def nbytes_hbm(self) -> int:
-        """HBM bytes including XLA's 128-lane minor-dim padding."""
+        """HBM bytes assuming XLA pads the minor dimension to 128
+        lanes — what the budget gate reserves. An upper bound: on the
+        v5e the chip smoke found the planes held at their unpadded
+        size (PERF.md, PR 21)."""
         w_pad = -(-self.n_words // 128) * 128
         per = self.n_rows * w_pad * 4
         return per * (4 if self.has_counts else 1)
@@ -292,8 +295,8 @@ def device_plane_probe(
     iters: int = 64,
 ) -> float:
     """Seconds per plane-stats call on-device, by the same two-chain
-    differencing the query kernels use (the backend's
-    block_until_ready returns early — see scatter_kernel)."""
+    differencing the query kernels use (see
+    ``scatter_kernel.device_time_probe``; bench-only)."""
     import time as _time
 
     R = len(rows)
@@ -338,7 +341,7 @@ def device_plane_probe(
         return best
 
     # auto-escalate the chain length until the signal CLEARS the
-    # transport-jitter floor (merely-positive deltas are noise — see
+    # jitter floor (merely-positive deltas are noise — see
     # scatter_kernel._probe_one_tier)
     floor_s = 0.020
     for k_iters in (iters, iters * 4, iters * 16, iters * 64):

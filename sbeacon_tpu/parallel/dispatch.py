@@ -83,6 +83,7 @@ from ..telemetry import (
     device_warmup_phase,
     new_span_id,
     publish_event,
+    record_device_fallback,
     request_context,
     sanitize_trace_id,
 )
@@ -1600,6 +1601,7 @@ class MeshDispatchTier:
             )
             return state
         except Exception:
+            record_device_fallback("mesh_tier_build")
             log.exception("mesh dispatch tier build failed; scatter serves")
             with self._lock:
                 self._skip_fp = fp
@@ -3517,7 +3519,10 @@ def main(argv: list[str] | None = None) -> None:
     from ..config import enable_persistent_compile_cache
     from ..harness.faults import install_from_env
 
-    enable_persistent_compile_cache(config.storage.root)
+    try:
+        enable_persistent_compile_cache()
+    except OSError:
+        log.exception("persistent compilation cache unavailable")
     # worker-side chaos: BEACON_FAULT_PLAN arms seeded fault injection
     install_from_env()
     token = args.token if args.token is not None else config.auth.worker_token

@@ -114,24 +114,6 @@ def _owner_default() -> bool:
     ).lower() not in ENV_OFF
 
 
-def shard_map_compat(body, *, mesh, in_specs, out_specs, check_rep=True):
-    """``jax.shard_map`` across the JAX API generations this repo meets:
-    ``jax.shard_map`` (new), ``jax.experimental.shard_map.shard_map``
-    (0.4.x — the CI pin, where the bare ``jax.shard_map`` attribute
-    does not exist yet), and the ``check_rep``→``check_vma`` kwarg
-    rename. Every mesh program goes through here; calling
-    ``jax.shard_map`` directly is what silently benched the whole mesh
-    tier on 0.4.x."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return sm(body, check_rep=check_rep, **kwargs)
-    except TypeError:
-        return sm(body, check_vma=check_rep, **kwargs)
-
-
 def make_mesh(
     n_devices: int | None = None,
     axis: str = AXIS,
@@ -569,7 +551,7 @@ def _build_sharded_fn(mesh: Mesh, axis: str, window_cap, record_cap, n_iters):
         n_iters=n_iters,
         axis=axis,
     )
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P()),
@@ -678,7 +660,7 @@ def sharded_selected_query(
             has_counts=has_counts,
         )
         fn = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(P(axis), P(), P(axis)),
@@ -1238,9 +1220,9 @@ class MeshFusedIndex:
                         [use_counts, np.zeros(tier - b, np.bool_)]
                     )
             local_b = int(enc["chrom"].shape[0])
-        gather_impl = (
-            "pallas" if jax.default_backend() == "tpu" else "portable"
-        )
+        from ..ops.gather_kernel import default_impl
+
+        gather_impl = default_impl()
         donate = _donate_uploads()
         key = (
             "mesh_fused",
@@ -1289,7 +1271,7 @@ class MeshFusedIndex:
                 extra_specs = ()
                 donate_nums = (2,)
             enc_spec = P(self.axis) if use_slice else P()
-            mapped = shard_map_compat(
+            mapped = jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=(P(self.axis), P(self.axis), enc_spec)
@@ -1300,7 +1282,7 @@ class MeshFusedIndex:
                 out_specs=P(self.axis) if owner_out else P(),
                 # axis_index-driven ownership masking defeats the
                 # replication checker either way
-                check_rep=False,
+                check_vma=False,
             )
             # donate the per-launch upload buffers (encode dict +
             # plane masks; the persistent index arrays at args 0-1 are

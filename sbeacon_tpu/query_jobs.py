@@ -623,10 +623,11 @@ class AsyncQueryRunner:
                 self.table.purge_expired()
                 self.table.checkpoint()
                 with self._lock:
+                    # (responses, expiry, unavailable)
                     dead = [
                         q
-                        for q, (_, exp) in self._results.items()
-                        if exp <= now
+                        for q, hit in self._results.items()
+                        if hit[1] <= now
                     ]
                     for q in dead:
                         del self._results[q]

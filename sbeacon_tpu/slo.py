@@ -109,6 +109,26 @@ _EXCLUDED_HEADS = tuple(
 )
 
 
+#: routes that are batch jobs rather than interactive requests. The
+#: API layer gives them no default deadline ("bulk ingest is a batch
+#: job and only an explicit header bounds it",
+#: ``BeaconApp._request_deadline``) and, by the same rule, they carry no
+#: latency threshold unless ``BEACON_SLO_ROUTES`` declares one. Held to
+#: the interactive default, ONE multi-second cohort ingest breached the
+#: route, the brownout ladder climbed and the queries beside it were
+#: shed with 429. Their availability objective is the default's.
+BATCH_ROUTES = frozenset({"submit"})
+
+
+#: bad events a window must hold before it can count as burning. On a
+#: route with little traffic ONE slow request is a 100x burn of a 1%
+#: budget on both windows at once, and stays one for the five minutes
+#: it sits in the fast window: the brownout ladder then climbs a rung
+#: every few seconds up to shedding all traffic, on a sample of one.
+#: A breach is a trend, so it takes a second bad event to call one.
+MIN_BAD_EVENTS = 2
+
+
 @dataclasses.dataclass(frozen=True)
 class SloObjective:
     """One route's objectives (availability + latency threshold)."""
@@ -296,11 +316,18 @@ class SloEngine:
             latency_ms=getattr(obs, "slo_latency_ms", 250.0),
             latency_target=getattr(obs, "slo_latency_target", 0.99),
         )
+        routes = {
+            route: dataclasses.replace(default, latency_ms=math.inf)
+            for route in BATCH_ROUTES
+        }
+        routes.update(
+            parse_route_objectives(
+                getattr(obs, "slo_routes", "") or "", default
+            )
+        )
         return cls(
             default=default,
-            routes=parse_route_objectives(
-                getattr(obs, "slo_routes", "") or "", default
-            ),
+            routes=routes,
             alert_burn_rate=getattr(obs, "slo_alert_burn_rate", 14.4),
             max_tenants=max_tenants,
         )
@@ -420,7 +447,12 @@ class SloEngine:
                 1.0 - obj.latency_target,
                 {
                     "target": obj.latency_target,
-                    "thresholdMs": obj.latency_ms,
+                    # null: a batch route with no declared threshold
+                    "thresholdMs": (
+                        obj.latency_ms
+                        if math.isfinite(obj.latency_ms)
+                        else None
+                    ),
                 },
             ),
         ):
@@ -437,7 +469,7 @@ class SloEngine:
                     "badRatio": round(bad / total, 5) if total else 0.0,
                     "burnRate": rate,
                 }
-                if rate < self.alert_burn_rate:
+                if rate < self.alert_burn_rate or bad < MIN_BAD_EVENTS:
                     burning_all = False
             breached = burning_all
             breached_any = breached_any or breached

@@ -1075,6 +1075,10 @@ class DeviceFlightRecorder:
         self._warmup_depth = 0
         self._mid_request = 0
         self._last_mid: dict | None = None
+        # device paths that failed and were served from the host (or a
+        # slower device path) instead, by code site — the answer stays
+        # right, so this count is the only place the failure shows
+        self._fallbacks: dict[str, int] = {}
 
     def configure(self, *, ring_size: int | None = None,
                   compile_tracking: bool | None = None) -> None:
@@ -1161,23 +1165,13 @@ class DeviceFlightRecorder:
             ptier = self._pad_tier.setdefault((family, int(tier)), [0, 0])
             ptier[0] += specs_real
             ptier[1] += specs_padded
-            if program_key is not None and self.compile_tracking:
-                key = self._key_str(program_key)
-                if key not in self._compiles:
-                    warm = self._warmup_depth > 0
-                    entry = {
-                        "key": key,
-                        "family": family,
-                        "tier": int(tier),
-                        "durationMs": round(float(launch_ms), 3),
-                        "time": rec["time"],
-                        "warmup": warm,
-                    }
-                    self._compiles[key] = entry
+            if program_key is not None:
+                entry = self._track_compile_locked(
+                    family, tier, program_key, launch_ms, rec["time"]
+                )
+                if entry is not None:
                     rec["compiled"] = True
-                    if not warm:
-                        self._mid_request += 1
-                        self._last_mid = entry
+                    if not entry["warmup"]:
                         compile_evt = entry
             self._ring.append(rec)
             self._by_seq[rec["seq"]] = rec
@@ -1195,6 +1189,53 @@ class DeviceFlightRecorder:
                 durationMs=compile_evt["durationMs"],
             )
         return rec["seq"]
+
+    def _track_compile_locked(
+        self, family, tier, program_key, duration_ms, when
+    ) -> dict | None:
+        """The tracker entry when ``program_key`` is first seen (held
+        under ``_lock``), else None."""
+        if not self.compile_tracking:
+            return None
+        key = self._key_str(program_key)
+        if key in self._compiles:
+            return None
+        entry = {
+            "key": key,
+            "family": family,
+            "tier": int(tier),
+            "durationMs": round(float(duration_ms), 3),
+            "time": when,
+            "warmup": self._warmup_depth > 0,
+        }
+        self._compiles[key] = entry
+        if not entry["warmup"]:
+            self._mid_request += 1
+            self._last_mid = entry
+        return entry
+
+    def record_compile(
+        self, family: str, *, tier: int, program_key,
+        duration_ms: float = 0.0,
+    ) -> None:
+        """Tell the compile tracker about a program that was compiled
+        WITHOUT a recorded launch — a warmup loop that calls the jitted
+        entry directly with all-padding slots. Counting those as
+        launches would put pure padding into the launch and pad-waste
+        series; not telling the tracker makes every first serving
+        launch of a warmed program read as a mid-request compile."""
+        with self._lock:
+            entry = self._track_compile_locked(
+                family, tier, program_key, duration_ms, time.time()
+            )
+        if entry is not None and not entry["warmup"]:
+            publish_event(
+                "device.compile",
+                program=family,
+                shape=entry["key"],
+                tier=entry["tier"],
+                durationMs=entry["durationMs"],
+            )
 
     @staticmethod
     def _key_str(program_key) -> str:
@@ -1226,6 +1267,18 @@ class DeviceFlightRecorder:
                 rec["fetchMs"] = round(float(fetch_ms), 3)
             if fetch_bytes is not None:
                 rec["fetchBytes"] = int(fetch_bytes)
+
+    def record_fallback(self, site: str) -> None:
+        """Count ONE device-path failure that was absorbed by a host
+        (or slower device) path at ``site`` — a literal label from the
+        DEPLOYMENT.md ``device.fallbacks`` row, so the series stays
+        bounded."""
+        with self._lock:
+            self._fallbacks[site] = self._fallbacks.get(site, 0) + 1
+
+    def fallbacks_by_site(self) -> dict:
+        with self._lock:
+            return dict(self._fallbacks)
 
     # -- back-compat module-property views ------------------------------------
 
@@ -1391,9 +1444,11 @@ class DeviceFlightRecorder:
             }
             worst = self._worst_pad_waste_locked()
             compiles = self._compile_snapshot_locked()
+            fallbacks = dict(self._fallbacks)
         return {
             "total": sum(families.values()),
             "byFamily": families,
+            "fallbacks": fallbacks,
             "sliced": sliced,
             "evaluatedPairs": pairs,
             "fetchedBytes": fetched,
@@ -1434,6 +1489,12 @@ def record_device_launch(family: str, **kw) -> int:
     return flight_recorder.record_launch(family, **kw)
 
 
+def record_device_compile(family: str, **kw) -> None:
+    """Feed the compile tracker for a program compiled without a
+    recorded launch (see :meth:`DeviceFlightRecorder.record_compile`)."""
+    flight_recorder.record_compile(family, **kw)
+
+
 def note_device_stage(seq, **kw) -> None:
     """Attach encode/fetch ms to a recorded launch; seq=None no-ops."""
     if seq is not None:
@@ -1444,6 +1505,15 @@ def device_warmup_phase():
     """``with device_warmup_phase(): engine.warmup()`` — compiles
     inside the scope are expected, not mid-request regressions."""
     return flight_recorder.warmup_phase()
+
+
+def record_device_fallback(site: str) -> None:
+    """Tick ``device.fallbacks{site}``: a device path failed at
+    ``site`` and the request/ingest was served another way. Every
+    handler that absorbs such a failure calls this next to its log
+    line, so a server whose kernels do not compile cannot look
+    healthy."""
+    flight_recorder.record_fallback(site)
 
 
 def register_device_metrics(registry) -> None:
@@ -1475,6 +1545,14 @@ def register_device_metrics(registry) -> None:
         "device-program compiles observed OUTSIDE a warmup phase (a "
         "novel batch shape paid its XLA compile inside a request)",
         fn=lambda: flight_recorder.mid_request_compiles(),
+    )
+    registry.counter(
+        "device.fallbacks",
+        "device paths that failed and were served from the host (or a "
+        "slower device path) instead, by code site — answers stay "
+        "right, so a healthy deployment holds every site at zero",
+        label="site",
+        fn=lambda: flight_recorder.fallbacks_by_site(),
     )
     registry.counter(
         "device.fetched_bytes",

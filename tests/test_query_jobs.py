@@ -195,6 +195,21 @@ def test_runner_executes_and_caches():
     assert eng.calls == 1
 
 
+def test_runner_sweep_drops_expired_handoffs():
+    """The purge thread runs to its end over the (responses, expiry,
+    unavailable) handoffs and drops the expired ones."""
+    runner = AsyncQueryRunner(SlowEngine(), QueryJobTable(query_ttl_s=0.01))
+    qid, _ = runner.submit(
+        VariantQueryPayload(dataset_ids=["ds1"], reference_name="22")
+    )
+    assert runner.result(qid, wait_s=5)
+    time.sleep(0.05)
+    runner._last_purge = 0.0
+    runner._maybe_purge()
+    runner._sweeper.join(timeout=5)
+    assert qid not in runner._results
+
+
 def test_runner_fingerprint_invalidates():
     eng = SlowEngine()
     table = QueryJobTable()

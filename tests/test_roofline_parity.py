@@ -293,22 +293,25 @@ def test_owner_sharded_parity_byte_identical():
         enc = encode_queries(
             [sp for sp, _ in pairs], shard_ids=[sid for _, sid in pairs]
         )
-        f0 = rec.fetched_bytes
-        own = mfi.run_mesh_queries(
-            dict(enc),
-            window_cap=2048,
-            record_cap=64,
-            owner_outputs=True,
-        )
-        owner_bytes = rec.fetched_bytes - f0
-        f0 = rec.fetched_bytes
-        repl = mfi.run_mesh_queries(
-            dict(enc),
-            window_cap=2048,
-            record_cap=64,
-            owner_outputs=False,
-        )
-        repl_bytes = rec.fetched_bytes - f0
+
+        def fewest_fetched(owner_outputs):
+            # the recorder is process-wide: a prober thread an earlier
+            # test left running can only ADD to a reading, so the
+            # smallest of a few is the call's own
+            seen = []
+            for _ in range(3):
+                f0 = rec.fetched_bytes
+                res = mfi.run_mesh_queries(
+                    dict(enc),
+                    window_cap=2048,
+                    record_cap=64,
+                    owner_outputs=owner_outputs,
+                )
+                seen.append(rec.fetched_bytes - f0)
+            return res, min(seen)
+
+        own, owner_bytes = fewest_fetched(True)
+        repl, repl_bytes = fewest_fetched(False)
         _assert_results_byte_identical(own, repl, label=name)
         # the output-diet claim: the owner fetch trims each device's
         # block to its real count instead of pulling a full replica

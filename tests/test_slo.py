@@ -6,6 +6,7 @@ a worker kill -> failover -> rediscovery heal leaves a matching event
 sequence in /ops/events, a burn-rate rise on the affected route at
 /slo, and an exemplar whose trace id resolves at /_trace."""
 
+import json
 import random
 import re
 import time
@@ -106,6 +107,9 @@ def test_breached_requires_both_windows_over_alert_factor():
     for _ in range(9):
         eng.record("g_variants", 200, 1.0)
     eng.record("g_variants", 500, 1.0)  # 10% vs 0.1% budget: burn 100x
+    # ... but ONE bad event is not a trend, whatever it burns
+    assert eng.breached() == {"g_variants": 0}
+    eng.record("g_variants", 500, 1.0)
     assert eng.breached() == {"g_variants": 1}
     assert eng.breached_routes() == ["g_variants"]
     # an hour later the fast window is clean: no longer breached (the
@@ -137,6 +141,30 @@ def test_route_objective_parsing_and_env():
     assert eng.overrides["boolean"].latency_ms == 50.0
     # declared routes surface at /slo even before any traffic
     assert "boolean" in eng.snapshot()["routes"]
+
+
+@obs
+def test_submit_is_a_batch_route_with_no_default_latency_threshold():
+    """Multi-second cohort ingests never burn a latency budget (and so
+    never brown out the queries beside them) unless the operator
+    declares a threshold for the route; 5xx still counts."""
+    eng = SloEngine.from_config(ObservabilityConfig())
+    for _ in range(5):
+        eng.record("submit", 200, 22_000.0)
+    doc = eng.snapshot()["routes"]["submit"]
+    assert doc["latency"]["thresholdMs"] is None
+    assert doc["latency"]["windows"]["5m"]["bad"] == 0
+    assert eng.breached_routes() == []
+    json.dumps(eng.snapshot(), allow_nan=False)  # no Infinity in /slo
+    for _ in range(2):
+        eng.record("submit", 500, 1.0)
+    assert eng.breached_routes() == ["submit"]
+    declared = SloEngine.from_config(
+        ObservabilityConfig(slo_routes="submit:latency_ms=1000")
+    )
+    for _ in range(2):
+        declared.record("submit", 200, 22_000.0)
+    assert declared.breached_routes() == ["submit"]
 
 
 # -- histogram exemplars -------------------------------------------------------

@@ -249,6 +249,7 @@ GOLDEN_METRICS = [
     "device.mid_request_compiles",
     "device.fetched_bytes",
     "device.donated_buffers",
+    "device.fallbacks",
     "migration.started",
     "migration.completed",
     "migration.rolled_back",

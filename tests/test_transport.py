@@ -43,11 +43,14 @@ def _make_echo_handler():
             self.wfile.write(body)
 
         def do_GET(self):
+            # read BEFORE answering: the client clears the flag as soon
+            # as it has the response, and may win that race
+            sneaky = getattr(self.server, "sneaky_close", False)
             if self.path == "/missing":
                 self._send(404, {"error": "not found"})
             else:
                 self._send(200, {"ok": True, "path": self.path})
-            if getattr(self.server, "sneaky_close", False):
+            if sneaky:
                 # close WITHOUT a Connection: close header — the silent
                 # idle-close a pooled client only discovers on its next
                 # send (the replay-once scenario)
