@@ -70,12 +70,12 @@ from ..telemetry import (
     annotate,
     current_context,
     journal,
-    profiler,
     register_device_metrics,
     request_context,
     sanitize_trace_id,
 )
-from ..utils.trace import span, tracer
+from ..utils import trace as trace_mod
+from ..utils.trace import span, stage, tracer
 from .envelopes import Envelopes
 from .framework import (
     configuration_response,
@@ -171,6 +171,22 @@ def bearer_token_verifier(token: str):
         return True, ""
 
     return verify
+
+
+def _start_profiler_server(port: int) -> None:
+    """``jax.profiler.start_server``: lets an operator capture a bounded
+    profile of the running server on demand (DEPLOYMENT.md). One per
+    process; a failure is logged, never fatal."""
+    import logging
+
+    try:
+        import jax
+
+        jax.profiler.start_server(port)
+    except Exception:
+        logging.getLogger(__name__).exception(
+            "profiler server on port %s not started", port
+        )
 
 
 class BeaconApp:
@@ -333,8 +349,8 @@ class BeaconApp:
         )
         self.canary.start()
         # flight recorder: the process journal was built from env
-        # defaults at import; the config tier re-applies here (like
-        # profiler.directory) so BEACON_EVENT_JOURNAL_* and explicit
+        # defaults at import; the config tier re-applies here so
+        # BEACON_EVENT_JOURNAL_* and explicit
         # ObservabilityConfig fields agree
         journal.configure(
             keep=getattr(obs, "event_journal_size", 1024),
@@ -350,24 +366,12 @@ class BeaconApp:
             ring_size=getattr(obs, "device_ring_size", 256),
             compile_tracking=getattr(obs, "compile_tracking", True),
         )
-        if obs.profile_dir:
-            # config-armed profiling (the env var SBEACON_PROFILE sets
-            # the same field at import); first profiled region starts
-            # the jax trace capture. The profiler is process-global
-            # (jax supports one capture per process), so a second app
-            # cannot redirect an already-armed capture — warn instead
-            # of silently dropping the request.
-            if not profiler.directory:
-                profiler.directory = obs.profile_dir
-            elif profiler.directory != obs.profile_dir:
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "profiling already armed for %s; ignoring "
-                    "profile_dir=%s (one capture per process)",
-                    profiler.directory,
-                    obs.profile_dir,
-                )
+        # the interpreter's collections as the ``gc`` stage, once per
+        # process; an operator's on-demand device profile (the stage
+        # annotations sit beside the device lines in it)
+        trace_mod.install_gc_stage()
+        if obs.profiler_port:
+            _start_profiler_server(obs.profiler_port)
         self._register_metrics()
         # mutating-route auth (reference /submit is AWS_IAM-gated,
         # api.tf:120-149): explicit verifier > config token > open (dev)
@@ -438,6 +442,19 @@ class BeaconApp:
             # recorder is process-global, so the usual app fallback
             # registration keeps a second app from double-registering
             register_device_metrics(reg)
+        reg.counter(
+            "runtime.gc_pauses",
+            "collections of the interpreter's garbage collector",
+            label="generation",
+            fn=lambda: {
+                str(g): n for g, n in enumerate(trace_mod.gc_pauses)
+            },
+        )
+        reg.counter(
+            "runtime.gc_pause_ms",
+            "milliseconds every thread stood still for a collection",
+            fn=lambda: tracer.stage_counts("gc")[1],
+        )
         self.canary.register_metrics(reg)
         register_plan_metrics(reg, self.plans)
         register_admission_metrics(reg, lambda: self.admission)
@@ -568,6 +585,23 @@ class BeaconApp:
                 method, path, query_params, body, headers
             )
         elapsed_ms = (time.perf_counter() - t0) * 1e3
+        # probes and diagnostic routes stay out of the stages, as out
+        # of SLO budgets and the cost fold: a scrape is not a request
+        tracked = self.slo.tracked(route)
+        if not tracked:
+            self._finish(ctx, route, status, payload, elapsed_ms, False)
+            return status, payload
+        # api.total is what meta.elapsedTimeMs reports: same two reads
+        tracer.observe("api.total", elapsed_ms)
+        with stage("api.finish"):
+            self._finish(ctx, route, status, payload, elapsed_ms, True)
+        return status, payload
+
+    def _finish(
+        self, ctx, route, status, payload, elapsed_ms, tracked
+    ) -> None:
+        """What follows the answer: latency histogram, SLO, cost and
+        plan folds, slow log, and the envelope's ``meta`` stamps."""
         # the exemplar is passed explicitly: this runs OUTSIDE the
         # request_context scope, so the ambient lookup would miss
         self._req_latency.observe(
@@ -581,7 +615,7 @@ class BeaconApp:
         # tenant work. Response bytes are measured here (the one place
         # the final payload exists); the serialization is the same one
         # the transport pays, bounded to tracked routes only.
-        if self.accounting is not None and self.slo.tracked(route):
+        if self.accounting is not None and tracked:
             cost = ctx.cost
             if isinstance(payload, dict):
                 try:
@@ -609,7 +643,7 @@ class BeaconApp:
         # sentinel. Probe/diagnostic routes are excluded exactly like
         # SLO budgets and the cost fold — the canary folds its own
         # probes under bounded synthetic shapes instead.
-        if self.slo.tracked(route):
+        if tracked:
             self.plans.observe(
                 query_shape(route, ctx.notes.get("granularity")),
                 ctx.plan,
@@ -656,7 +690,6 @@ class BeaconApp:
                         + ", ".join(unavailable)
                         + "; results are partial"
                     )
-        return status, payload
 
     def _handle(
         self, method, path, query_params, body, headers
@@ -712,9 +745,14 @@ class BeaconApp:
                 shape = query_shape(
                     ctx.route if ctx is not None else head, granularity
                 )
-                with deadline_scope(deadline), self.shaping.admit(
+                # only the ENTRY of the two gates is the wait: the stage
+                # closes once both are held (or a shed request leaves)
+                with stage("api.admit") as admit, deadline_scope(
+                    deadline
+                ), self.shaping.admit(
                     tenant, lane, shape
                 ), self.admission.admit():
+                    admit.close()
                     return self._route(
                         method.upper(), path, query_params, body
                     )
@@ -1014,6 +1052,9 @@ class BeaconApp:
         st = getattr(local, "stage_timing", None)
         if st is not None:
             stages.update(st())
+        # ... and every stage of utils/trace.STAGES under its own name,
+        # with count and sums: a reader differences two snapshots
+        stages.update(tracer.stage_summary())
         # ingest-while-serving rollup: per-dataset delta-tail depth
         # (rows queryable but not yet folded) + compactor counters —
         # "how stale is the base, and is the fold keeping up" in one
@@ -1034,10 +1075,12 @@ class BeaconApp:
         breached = sorted(
             r for r, doc in slo["routes"].items() if doc["breached"]
         )
+        # totals enclose other stages: api.total would always win
         stage_p99 = {
             name: q.get("p99", 0.0)
             for name, q in stages.items()
             if isinstance(q, dict) and q
+            and trace_mod.STAGES.get(name) != "total"
         }
         slowest_stage = (
             max(stage_p99, key=stage_p99.get)
@@ -1331,7 +1374,8 @@ class BeaconApp:
                 )
             return self._fleet_migrate(body or {})
 
-        req = parse_request(method, query_params, body)
+        with stage("api.parse"):
+            req = parse_request(method, query_params, body)
 
         if head == "g_variants":
             return self._route_g_variants(parts, req)

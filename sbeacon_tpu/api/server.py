@@ -14,6 +14,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
+from ..utils.trace import stage
 from .app import BeaconApp
 
 
@@ -24,29 +25,44 @@ def _make_handler(app: BeaconApp):
         def log_message(self, *args):  # quiet by default
             pass
 
+        def parse_request(self):
+            # the request line has just been read: ``http.read`` runs
+            # from here over the headers, and again in _respond over
+            # the URL and the body
+            with stage("http.read"):
+                return super().parse_request()
+
         def _respond(self):
-            parsed = urlparse(self.path)
-            # flatten single-valued query params (API-GW style)
-            query = {
-                k: (v[0] if len(v) == 1 else ",".join(v))
-                for k, v in parse_qs(parsed.query).items()
-            }
-            body = None
-            length = int(self.headers.get("Content-Length") or 0)
-            if length:
-                raw = self.rfile.read(length)
-                try:
-                    body = json.loads(raw)
-                except json.JSONDecodeError:
-                    self._send(400, {"error": "invalid JSON body"})
-                    return
+            with stage("http.read"):
+                parsed = urlparse(self.path)
+                # flatten single-valued query params (API-GW style)
+                query = {
+                    k: (v[0] if len(v) == 1 else ",".join(v))
+                    for k, v in parse_qs(parsed.query).items()
+                }
+                body = None
+                bad_body = False
+                length = int(self.headers.get("Content-Length") or 0)
+                if length:
+                    raw = self.rfile.read(length)
+                    try:
+                        body = json.loads(raw)
+                    except json.JSONDecodeError:
+                        bad_body = True
+                headers = dict(self.headers.items())
+            if bad_body:
+                self._send(400, {"error": "invalid JSON body"})
+                return
             status, payload = app.handle(
-                self.command, parsed.path, query, body,
-                headers=dict(self.headers.items()),
+                self.command, parsed.path, query, body, headers=headers
             )
             self._send(status, payload)
 
         def _send(self, status: int, payload):
+            with stage("http.write"):
+                self._write(status, payload)
+
+        def _write(self, status: int, payload):
             if isinstance(payload, str):
                 # text payloads (Prometheus exposition from /metrics)
                 # go out verbatim as text/plain

@@ -15,6 +15,7 @@ from ..metadata.filters import entity_search_conditions
 from ..payloads import VariantQueryPayload
 from ..plan import explain_active
 from ..utils.chrom import normalize_chromosome
+from ..utils.trace import stage
 from .envelopes import variant_entry
 from .requests import BeaconRequest, RequestError
 
@@ -37,6 +38,13 @@ def resolve_datasets(
     """
     if assembly_id is None:
         raise RequestError("assemblyId must be specified")
+    with stage("filters.resolve"):
+        return _resolve_datasets(
+            store, ontology, assembly_id, filters, dataset_ids
+        )
+
+
+def _resolve_datasets(store, ontology, assembly_id, filters, dataset_ids):
     samples_by_dataset: dict[str, list[str]] = {}
     if filters:
         conditions, params = entity_search_conditions(
@@ -239,10 +247,11 @@ def run_variant_search(
             responses = engine.search(payload)
     else:
         responses = engine.search(payload)
-    agg = VariantAggregation(req.assembly_id or "")
-    agg.add(
-        responses,
-        granularity=req.granularity,
-        check_all=check_all,
-    )
+    with stage("api.envelope"):
+        agg = VariantAggregation(req.assembly_id or "")
+        agg.add(
+            responses,
+            granularity=req.granularity,
+            check_all=check_all,
+        )
     return agg

@@ -163,10 +163,6 @@ class EngineConfig:
     # serial launch->fetch (pre-fusion behavior); 2 double-buffers so
     # host encode of batch i+1 overlaps device execution of batch i
     fetch_pipeline_depth: int = 2
-    # entries kept per timing ring (MicroBatcher wait/exec/stage
-    # decompositions) — bounds a long soak's memory, timing_summary()
-    # reports percentiles over this window
-    timing_window: int = 65536
     # cross-shard fused dispatch: stack every warm device shard into
     # ONE device index (ops.kernel.FusedDeviceIndex) so a k-dataset
     # query costs one launch and concurrent queries against DIFFERENT
@@ -451,8 +447,10 @@ class ObservabilityConfig:
       ``sbeacon.slowquery`` logger and the in-memory ring served at
       ``/_trace``. 0 records every request (debug); negative disables.
     slow_query_log: optional file the slow-query JSON lines append to.
-    profile_dir: arms ``jax.profiler`` capture of kernel launch/fetch
-      regions into this directory (the ``SBEACON_PROFILE`` env var).
+    profiler_port: port of ``jax.profiler.start_server`` (0 = off,
+      ``BEACON_PROFILER_PORT``): an operator asks a running server for a
+      bounded capture, in which the ``beacon.<stage>`` annotations of
+      utils/trace.py sit beside the device lines.
 
     SLO engine (slo.py, served at ``/slo`` + ``slo.*`` gauges):
     slo_availability_target: default max-good-ratio objective per route
@@ -527,7 +525,7 @@ class ObservabilityConfig:
 
     slow_query_ms: float = 1000.0
     slow_query_log: str = ""
-    profile_dir: str = ""
+    profiler_port: int = 0
     slo_availability_target: float = 0.999
     slo_latency_ms: float = 250.0
     slo_latency_target: float = 0.99
@@ -769,9 +767,8 @@ class BeaconConfig:
             obs_over["slow_query_ms"] = float(env["SBEACON_SLOW_QUERY_MS"])
         if "SBEACON_SLOW_QUERY_LOG" in env:
             obs_over["slow_query_log"] = env["SBEACON_SLOW_QUERY_LOG"]
-        if "SBEACON_PROFILE" in env:
-            obs_over["profile_dir"] = env["SBEACON_PROFILE"]
         _obs_env = {
+            "BEACON_PROFILER_PORT": ("profiler_port", int),
             "BEACON_SLO_AVAILABILITY": ("slo_availability_target", float),
             "BEACON_SLO_LATENCY_MS": ("slo_latency_ms", float),
             "BEACON_SLO_LATENCY_TARGET": ("slo_latency_target", float),

@@ -28,7 +28,6 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
@@ -52,13 +51,12 @@ from .telemetry import (
     charge_cost,
     current_context,
     device_warmup_phase,
-    percentiles,
     publish_event,
     record_device_fallback,
     request_context,
 )
 from .utils.chrom import chromosome_code
-from .utils.trace import span
+from .utils.trace import span, stage, tracer
 
 # uppercase LUT for vectorised case-insensitive byte compares
 _UPPER = np.arange(256, dtype=np.uint8)
@@ -733,7 +731,6 @@ class VariantEngine:
                 max_wait_ms=eng.microbatch_wait_ms,
                 default_timeout_s=getattr(res, "batch_timeout_s", None),
                 pipeline_depth=getattr(eng, "fetch_pipeline_depth", 2),
-                timing_window=getattr(eng, "timing_window", 65536),
             )
         else:
             self._batcher = None
@@ -750,12 +747,8 @@ class VariantEngine:
             )
         else:
             self._response_cache = None
-        # host materialisation timing (the post-fetch stage of the
-        # request pipeline), bounded like the batcher's rings
+        # guards the dispatch counters (fused_searches, mesh_searches)
         self._mat_lock = threading.Lock()
-        self._mat_ms: deque = deque(
-            maxlen=getattr(eng, "timing_window", 65536)
-        )
         # persistent per-dataset scatter pool (serving hot path: no
         # per-request thread churn)
         self._scatter = ThreadPoolExecutor(
@@ -2343,16 +2336,17 @@ class VariantEngine:
         scope = None
         gen = None
         if cache is not None:
-            key = response_cache_key(
-                self.cache_fingerprint(payload.dataset_ids), payload
-            )
-            hit = cache.get(key)
-            if hit is not None:
-                annotate(response_cache="hit")
-                plan_stage("cache", decision="hit")
-                return hit
-            scope = response_cache_scope(payload)
-            gen = cache.generation()
+            with stage("cache.lookup"):
+                key = response_cache_key(
+                    self.cache_fingerprint(payload.dataset_ids), payload
+                )
+                hit = cache.get(key)
+                if hit is not None:
+                    annotate(response_cache="hit")
+                    plan_stage("cache", decision="hit")
+                    return hit
+                scope = response_cache_scope(payload)
+                gen = cache.generation()
         outcome = "miss" if cache is not None else "off"
         annotate(response_cache=outcome)
         plan_stage("cache", decision=outcome)
@@ -2399,12 +2393,11 @@ class VariantEngine:
         register_delta_metrics(registry, self.delta_metrics)
 
     def _materialize_timing(self) -> dict:
-        """Host-materialisation quantiles alone — the gauge callback
-        reads just this, so a /metrics render doesn't also pay the
-        batcher's full per-stage summary."""
-        with self._mat_lock:
-            xs = list(self._mat_ms)
-        return percentiles(xs)
+        """Host-materialisation quantiles alone (the
+        ``engine.materialize`` stage's ring) — the gauge callback reads
+        just this, so a /metrics render doesn't also pay the batcher's
+        full per-stage summary."""
+        return tracer.stage_quantiles("engine.materialize")
 
     def stage_timing(self) -> dict:
         """The full per-stage latency decomposition: the batcher's
@@ -2653,24 +2646,25 @@ class VariantEngine:
             return None
         from .ops.scatter_kernel import ScatterDeviceIndex
 
-        wants_planes = self._wants_planes(payload)
-        findex, sid_of, shard_of = fst
-        routes = []
-        for ds, vcf, shard, dindex, planes, _native in targets:
-            if (
-                wants_planes
-                and planes is not None
-                and isinstance(dindex, ScatterDeviceIndex)
-            ):
-                continue  # _fused_selected serves this target whole
-            sid = sid_of.get((ds, vcf))
-            if sid is not None and shard_of[(ds, vcf)] is shard:
-                routes.append(((ds, vcf), sid))
-        if len(routes) < 2:
-            return None
-        eng = self.config.engine
-        specs = [spec_base] * len(routes)
-        sids = [sid for _k, sid in routes]
+        with stage("engine.plan"):
+            wants_planes = self._wants_planes(payload)
+            findex, sid_of, shard_of = fst
+            routes = []
+            for ds, vcf, shard, dindex, planes, _native in targets:
+                if (
+                    wants_planes
+                    and planes is not None
+                    and isinstance(dindex, ScatterDeviceIndex)
+                ):
+                    continue  # _fused_selected serves this target whole
+                sid = sid_of.get((ds, vcf))
+                if sid is not None and shard_of[(ds, vcf)] is shard:
+                    routes.append(((ds, vcf), sid))
+            if len(routes) < 2:
+                return None
+            eng = self.config.engine
+            specs = [spec_base] * len(routes)
+            sids = [sid for _k, sid in routes]
         if self._batcher is not None:
             res = self._batcher.submit_many(
                 findex,
@@ -2687,19 +2681,29 @@ class VariantEngine:
                 window_cap=eng.window_cap,
                 record_cap=eng.record_cap,
             )
-        out = {}
-        for i, (key, sid) in enumerate(routes):
-            if res.overflow[i] or res.n_matched[i] > eng.record_cap:
-                out[key] = None
-            else:
-                rows = res.rows[i][res.rows[i] >= 0]
-                out[key] = findex.to_local_rows(rows, sid)
+        with stage("engine.plan"):
+            out = {}
+            for i, (key, sid) in enumerate(routes):
+                if res.overflow[i] or res.n_matched[i] > eng.record_cap:
+                    out[key] = None
+                else:
+                    rows = res.rows[i][res.rows[i] >= 0]
+                    out[key] = findex.to_local_rows(rows, sid)
         with self._mat_lock:  # unlocked += would drop concurrent counts
             self.fused_searches += 1
         annotate(dispatch="fused")
         return out
 
     def _search(self, payload: VariantQueryPayload, sp):
+        with stage("engine.plan"):
+            spec_base, targets = self._targets(payload)
+        if not targets:
+            return []
+        return self._search_targets(payload, spec_base, targets, sp)
+
+    def _targets(self, payload: VariantQueryPayload):
+        """(spec, targets): the query as the kernels take it and the
+        (dataset, vcf) shards it has to ask."""
         spec_base = QuerySpec(
             chrom=payload.reference_name,
             start_min=payload.start_min,
@@ -2722,8 +2726,9 @@ class VariantEngine:
                 # get_matching_chromosome filter (search_variants.py:81-85)
                 continue
             targets.append((ds, vcf, shard, dindex, planes, native))
-        if not targets:
-            return []
+        return spec_base, targets
+
+    def _search_targets(self, payload, spec_base, targets, sp):
         # the submitting request's context: _one_target runs on the
         # scatter pool, whose threads do not inherit thread-locals —
         # re-installing it makes every charge (host rows, batcher
@@ -2826,13 +2831,21 @@ class VariantEngine:
             with request_context(req_ctx):
                 return _one_target_inner(target)
 
+        def _pooled_target(target):
+            # the request's own thread is parked in ``engine.fanout``
+            # for as long as the pool serves it: the pool's stages keep
+            # their count and sum and add nothing to the chain's req_ms
+            with tracer.serving(0):
+                return _one_target(target)
+
         def _one_target_inner(target):
             ds, vcf, shard, dindex, planes, native = target
             selected_idx = None
             fused = None
             rows = None
             if payload.selected_samples_only:
-                selected_idx = self._selected_idx(shard, payload, ds)
+                with stage("engine.plan"):
+                    selected_idx = self._selected_idx(shard, payload, ds)
             if planes is not None and self._wants_planes(payload):
                 # fused match+planes program: the whole selected-samples
                 # (or sample-extraction) leaf in ONE kernel dispatch —
@@ -2901,21 +2914,18 @@ class VariantEngine:
                 rows = self._device_rows(
                     shard, dindex, spec_base, key=(ds, vcf)
                 )
-            t_mat = time.perf_counter()
-            resp = materialize_response(
-                shard,
-                rows,
-                payload,
-                chrom_label=native,
-                dataset_id=ds,
-                vcf_location=vcf,
-                selected_idx=selected_idx,
-                plane_index=planes,
-                fused=fused,
-            )
-            with self._mat_lock:
-                self._mat_ms.append((time.perf_counter() - t_mat) * 1e3)
-            return resp
+            with stage("engine.materialize"):
+                return materialize_response(
+                    shard,
+                    rows,
+                    payload,
+                    chrom_label=native,
+                    dataset_id=ds,
+                    vcf_location=vcf,
+                    selected_idx=selected_idx,
+                    plane_index=planes,
+                    fused=fused,
+                )
 
         if len(targets) == 1:
             responses = [_one_target(targets[0])]
@@ -2923,7 +2933,8 @@ class VariantEngine:
             # per-dataset scatter (the reference's ThreadPoolExecutor(500)
             # per-dataset dispatch, search_variants.py:77-118): overlaps
             # the per-shard device round-trips instead of serialising them
-            responses = list(self._scatter.map(_one_target, targets))
+            with stage("engine.fanout"):
+                responses = list(self._scatter.map(_pooled_target, targets))
         else:
             # L0-covered tail targets have NO device work left — their
             # rows are already in hand, materialisation is pure host —
@@ -2991,10 +3002,11 @@ class VariantEngine:
         if not self._device_ref_ok(payload, spec_base):
             return None
         eng = self.config.engine
-        if selected_idx is not None:
-            mask = sample_mask_words(selected_idx, planes.n_words)
-        else:
-            mask = np.full(planes.n_words, 0xFFFFFFFF, np.uint32)
+        with stage("engine.plan"):
+            if selected_idx is not None:
+                mask = sample_mask_words(selected_idx, planes.n_words)
+            else:
+                mask = np.full(planes.n_words, 0xFFFFFFFF, np.uint32)
         try:
             fault_point("device.bringup", "fused_selected")
             res = run_selected_scattered(

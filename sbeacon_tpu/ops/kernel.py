@@ -28,7 +28,6 @@ from __future__ import annotations
 import math
 import os
 import threading
-import time
 import warnings
 from contextlib import contextmanager
 from dataclasses import dataclass
@@ -48,7 +47,7 @@ from ..index.columnar import (
 )
 from ..telemetry import note_device_stage, record_device_launch
 from ..utils.chrom import chromosome_code
-from ..utils.trace import graft_launch_span, span
+from ..utils.trace import graft_launch_span, span, stage
 
 # variant_type codes for the type-dispatch mode
 VT_DEL, VT_INS, VT_DUP, VT_DUP_TANDEM, VT_CNV, VT_OTHER = range(6)
@@ -865,32 +864,33 @@ class PendingQueryResults:
         self.flight_seq = flight_seq
 
     def fetch(self) -> QueryResults:
-        t0 = time.perf_counter()
-        out = jax.device_get(self._out)
-        note_device_stage(
-            self.flight_seq,
-            fetch_ms=(time.perf_counter() - t0) * 1e3,
-            fetch_bytes=sum(
-                np.asarray(v).nbytes for v in out.values()
-            ),
-        )
-        self._out = None  # free the device buffers promptly
-        b = self._b
-        extra = {
-            k: np.asarray(out[k])[:b]
-            for k in ("pc_call", "pc_tok", "or_words")
-            if k in out
-        }
-        return QueryResults(
-            exists=np.asarray(out["exists"])[:b],
-            call_count=np.asarray(out["call_count"])[:b],
-            n_variants=np.asarray(out["n_variants"])[:b],
-            all_alleles_count=np.asarray(out["all_alleles_count"])[:b],
-            n_matched=np.asarray(out["n_matched"])[:b],
-            overflow=np.asarray(out["overflow"])[:b],
-            rows=np.asarray(out["rows"])[:b],
-            **extra,
-        )
+        with stage("kernel.readback") as st:
+            out = jax.device_get(self._out)
+        with stage("kernel.unpack"):
+            note_device_stage(
+                self.flight_seq,
+                fetch_ms=st.ms,
+                fetch_bytes=sum(
+                    np.asarray(v).nbytes for v in out.values()
+                ),
+            )
+            self._out = None  # free the device buffers promptly
+            b = self._b
+            extra = {
+                k: np.asarray(out[k])[:b]
+                for k in ("pc_call", "pc_tok", "or_words")
+                if k in out
+            }
+            return QueryResults(
+                exists=np.asarray(out["exists"])[:b],
+                call_count=np.asarray(out["call_count"])[:b],
+                n_variants=np.asarray(out["n_variants"])[:b],
+                all_alleles_count=np.asarray(out["all_alleles_count"])[:b],
+                n_matched=np.asarray(out["n_matched"])[:b],
+                overflow=np.asarray(out["overflow"])[:b],
+                rows=np.asarray(out["rows"])[:b],
+                **extra,
+            )
 
 
 class ReadyQueryResults:
@@ -929,46 +929,47 @@ def run_queries(
     after dispatch (launch/fetch overlap); default blocks and returns
     :class:`QueryResults`.
     """
-    enc = (
-        encode_queries(queries) if isinstance(queries, list) else queries
-    )
-    b = int(enc["chrom"].shape[0])
-    # ragged-window clamp: the index's measured widest-hit-range bound
-    # (never adds an overflow — see window_hint_for). Applied HERE, the
-    # one choke point, so warmup and serving can't compile different
-    # window shapes for the same index.
-    window_cap = min(
-        window_cap, getattr(dindex, "window_hint", window_cap)
-    )
-    # an index may carry its own (finer) tier ladder — the L0
-    # mini-index does, so a per-tail-shard spec batch is not padded to
-    # the global 64 tier; everything else pads to the process ladder
-    tiers = getattr(dindex, "batch_tiers", None)
-    if tiers is None:
-        tiers = active_ladder().rungs
-    tier = next((t for t in tiers if b <= t), None)
-    if b and tier and tier != b:
-        enc = {
-            k: np.concatenate(
-                [v, np.repeat(v[:1], tier - b, axis=0)]
-            )
-            for k, v in enc.items()
-        }
-    padded = tier if (b and tier) else b
+    with stage("kernel.encode"):
+        enc = (
+            encode_queries(queries) if isinstance(queries, list) else queries
+        )
+        b = int(enc["chrom"].shape[0])
+        # ragged-window clamp: the index's measured widest-hit-range bound
+        # (never adds an overflow — see window_hint_for). Applied HERE, the
+        # one choke point, so warmup and serving can't compile different
+        # window shapes for the same index.
+        window_cap = min(
+            window_cap, getattr(dindex, "window_hint", window_cap)
+        )
+        # an index may carry its own (finer) tier ladder — the L0
+        # mini-index does, so a per-tail-shard spec batch is not padded to
+        # the global 64 tier; everything else pads to the process ladder
+        tiers = getattr(dindex, "batch_tiers", None)
+        if tiers is None:
+            tiers = active_ladder().rungs
+        tier = next((t for t in tiers if b <= t), None)
+        if b and tier and tier != b:
+            enc = {
+                k: np.concatenate(
+                    [v, np.repeat(v[:1], tier - b, axis=0)]
+                )
+                for k, v in enc.items()
+            }
+        padded = tier if (b and tier) else b
     donate = _donate_uploads()
     with span("kernel.run_queries") as sp:
-        t0 = time.perf_counter()
-        enc_dev = {k: jnp.asarray(v) for k, v in enc.items()}
-        batch_fn = _query_batch_donated if donate else _query_batch
-        with _quiet_donation():
-            out = batch_fn(
-                dindex.arrays,
-                enc_dev,
-                window_cap=window_cap,
-                record_cap=record_cap,
-                n_iters=dindex.n_iters,
-            )
-        launch_ms = (time.perf_counter() - t0) * 1e3
+        with stage("kernel.dispatch") as st:
+            enc_dev = {k: jnp.asarray(v) for k, v in enc.items()}
+            batch_fn = _query_batch_donated if donate else _query_batch
+            with _quiet_donation():
+                out = batch_fn(
+                    dindex.arrays,
+                    enc_dev,
+                    window_cap=window_cap,
+                    record_cap=record_cap,
+                    n_iters=dindex.n_iters,
+                )
+        launch_ms = st.ms
         # ONE flight-recorder seam per launch: counters, the launch
         # ring, and compile tracking (a first-seen (program, shape)
         # key below is an XLA compile — jit traces inside this call).
@@ -982,6 +983,9 @@ def run_queries(
             tier=padded,
             specs_real=b,
             specs_padded=padded,
+            # every spec of a stacked-index batch is one (query,
+            # dataset) pair
+            evaluated_pairs=b,
             launch_ms=launch_ms,
             donated=len(enc_dev) if donate else 0,
             program_key=(

@@ -57,7 +57,6 @@ unpacks row ids with one vectorised ``np.unpackbits`` per batch.
 
 from __future__ import annotations
 
-import time
 from functools import partial
 
 import jax
@@ -70,6 +69,7 @@ from ..telemetry import (
     record_device_compile,
     record_device_launch,
 )
+from ..utils.trace import stage
 from .kernel import (
     MODE_ANY_BASE,
     MODE_EXACT,
@@ -669,23 +669,24 @@ def run_selected_scattered(
             pc_tok=np.zeros((0, 0), np.int32),
             or_words=np.zeros((0, W), np.uint32),
         )
-    lo, hi = _window_bounds(sindex, enc)
-    q8, needs_host = pack_q8(enc, lo, hi)
-    tile_ids_all = (lo // T).astype(np.int32)
-    caps = _tier_caps(sindex, window_cap)
-    width = hi - lo
-    tier_of = np.searchsorted(np.asarray(caps), width, side="left")
-    tier_of = np.minimum(tier_of, len(caps) - 1)
-    single = (np.maximum(hi, lo + 1) - 1) // T <= tile_ids_all
-    tier_of = np.where(single & (tier_of == 0), -1, tier_of)
+    with stage("kernel.encode"):
+        lo, hi = _window_bounds(sindex, enc)
+        q8, needs_host = pack_q8(enc, lo, hi)
+        tile_ids_all = (lo // T).astype(np.int32)
+        caps = _tier_caps(sindex, window_cap)
+        width = hi - lo
+        tier_of = np.searchsorted(np.asarray(caps), width, side="left")
+        tier_of = np.minimum(tier_of, len(caps) - 1)
+        single = (np.maximum(hi, lo + 1) - 1) // T <= tile_ids_all
+        tier_of = np.where(single & (tier_of == 0), -1, tier_of)
 
-    R_top = min(record_cap, caps[-1])
-    agg = np.zeros((b, 8), np.int32)
-    rows = np.full((b, R_top), -1, np.int32)
-    pc_call = np.zeros((b, R_top), np.int32)
-    pc_tok = np.zeros((b, R_top), np.int32)
-    or_words = np.zeros((b, W), np.uint32)
-    is_exact = enc["alt_mode"] == MODE_EXACT
+        R_top = min(record_cap, caps[-1])
+        agg = np.zeros((b, 8), np.int32)
+        rows = np.full((b, R_top), -1, np.int32)
+        pc_call = np.zeros((b, R_top), np.int32)
+        pc_tok = np.zeros((b, R_top), np.int32)
+        or_words = np.zeros((b, W), np.uint32)
+        is_exact = enc["alt_mode"] == MODE_EXACT
     for ti, cap in [(-1, T)] + list(enumerate(caps)):
         in_tier = tier_of == ti
         R = min(record_cap, cap)
@@ -715,51 +716,55 @@ def run_selected_scattered(
                         np.zeros((pad, W), np.uint32),
                     ]
                 )
-                t0 = time.perf_counter()
-                a, r, pc, pt, ow = _selected_batch(
-                    sindex.tiles,
-                    pindex.gt,
-                    pindex.gt2 if with_counts else pindex.gt,
-                    pindex.tok1 if with_counts else pindex.gt,
-                    pindex.tok2 if with_counts else pindex.gt,
-                    jnp.asarray(tid),
-                    jnp.asarray(qq),
-                    jnp.asarray(mm.view(np.int32)),
-                    T=T,
-                    CAP=cap,
-                    nslots=nslots,
-                    C=1 if ti == -1 else None,
-                    exact_only=exact,
-                    R=R,
-                    with_counts=with_counts,
-                    seg_k=_static_seg_k(sindex),
-                )
+                with stage("kernel.dispatch") as st:
+                    a, r, pc, pt, ow = _selected_batch(
+                        sindex.tiles,
+                        pindex.gt,
+                        pindex.gt2 if with_counts else pindex.gt,
+                        pindex.tok1 if with_counts else pindex.gt,
+                        pindex.tok2 if with_counts else pindex.gt,
+                        jnp.asarray(tid),
+                        jnp.asarray(qq),
+                        jnp.asarray(mm.view(np.int32)),
+                        T=T,
+                        CAP=cap,
+                        nslots=nslots,
+                        C=1 if ti == -1 else None,
+                        exact_only=exact,
+                        R=R,
+                        with_counts=with_counts,
+                        seg_k=_static_seg_k(sindex),
+                    )
                 seq = record_device_launch(
                     "plane",
                     seam="scatter",
                     tier=nslots,
                     specs_real=bb,
                     specs_padded=nslots,
-                    launch_ms=(time.perf_counter() - t0) * 1e3,
+                    launch_ms=st.ms,
                     program_key=_selected_program_key(
                         sindex, pindex, nslots, cap, R,
                         1 if ti == -1 else None, exact, with_counts,
                     ),
                 )
-                t0 = time.perf_counter()
-                a, r, pc, pt, ow = jax.device_get((a, r, pc, pt, ow))
-                note_device_stage(
-                    seq,
-                    fetch_ms=(time.perf_counter() - t0) * 1e3,
-                    fetch_bytes=sum(
-                        np.asarray(v).nbytes for v in (a, r, pc, pt, ow)
-                    ),
-                )
-                agg[ss] = np.asarray(a)[:bb]
-                rows[ss, :R] = np.asarray(r)[:bb]
-                pc_call[ss, :R] = np.asarray(pc)[:bb]
-                pc_tok[ss, :R] = np.asarray(pt)[:bb]
-                or_words[ss] = np.asarray(ow)[:bb].view(np.uint32)
+                # the host waits here for the device to run the program
+                # (behind whatever other threads launched before it)
+                # and for the copy back
+                with stage("kernel.readback") as st:
+                    a, r, pc, pt, ow = jax.device_get((a, r, pc, pt, ow))
+                with stage("kernel.unpack"):
+                    note_device_stage(
+                        seq,
+                        fetch_ms=st.ms,
+                        fetch_bytes=sum(
+                            np.asarray(v).nbytes for v in (a, r, pc, pt, ow)
+                        ),
+                    )
+                    agg[ss] = np.asarray(a)[:bb]
+                    rows[ss, :R] = np.asarray(r)[:bb]
+                    pc_call[ss, :R] = np.asarray(pc)[:bb]
+                    pc_tok[ss, :R] = np.asarray(pt)[:bb]
+                    or_words[ss] = np.asarray(ow)[:bb].view(np.uint32)
 
     # a truncated row set would silently under-reduce the planes: the
     # per-tier R bound makes truncation part of the overflow contract
@@ -930,7 +935,28 @@ def _launch_tier(sindex, tile_ids, q8, *, cap, C=None, exact_only=False):
     nc = len(tile_ids) // nslots
     T = sindex.tile
     seg_k = _static_seg_k(sindex)
-    t0 = time.perf_counter()
+    with stage("kernel.dispatch") as st:
+        agg, masks = _dispatch_tier(
+            sindex, tile_ids, q8, nc, nslots, T, cap, C, exact_only, seg_k
+        )
+    seq = record_device_launch(
+        "scatter",
+        seam="scatter",
+        tier=nslots,
+        specs_real=b,
+        specs_padded=nc * nslots,
+        launch_ms=st.ms,
+        program_key=_match_program_key(
+            sindex, nslots, nc, cap, C, exact_only
+        ),
+    )
+    return agg, masks, seq
+
+
+def _dispatch_tier(
+    sindex, tile_ids, q8, nc, nslots, T, cap, C, exact_only, seg_k
+):
+    """Uploads and the jitted call of one tier, until it returns."""
     if nc == 1:
         agg, masks = _scatter_batch(
             sindex.tiles,
@@ -957,18 +983,7 @@ def _launch_tier(sindex, tile_ids, q8, *, cap, C=None, exact_only=False):
         )
         agg = agg.reshape(nc * nslots, 8)
         masks = masks.reshape(nc * nslots, -1)
-    seq = record_device_launch(
-        "scatter",
-        seam="scatter",
-        tier=nslots,
-        specs_real=b,
-        specs_padded=nc * nslots,
-        launch_ms=(time.perf_counter() - t0) * 1e3,
-        program_key=_match_program_key(
-            sindex, nslots, nc, cap, C, exact_only
-        ),
-    )
-    return agg, masks, seq
+    return agg, masks
 
 
 
@@ -1003,34 +1018,35 @@ def run_queries_scattered(
             overflow=np.zeros(0, bool),
             rows=np.zeros((0, record_cap), np.int32),
         )
-    lo, hi = _window_bounds(sindex, enc)
-    q8, needs_host = pack_q8(enc, lo, hi)
-    tile_ids_all = (lo // T).astype(np.int32)
-    caps = _tier_caps(sindex, window_cap)
-    width = hi - lo
-    # smallest tier that fits; oversize windows run (and overflow) in
-    # the top tier so their aggregate slots still exist
-    tier_of = np.searchsorted(np.asarray(caps), width, side="left")
-    tier_of = np.minimum(tier_of, len(caps) - 1)
-    # single-tile fast tier (tier -1): a window wholly inside one tile
-    # needs a C=1 gather — half the HBM bytes of the base C=2 tier. At
-    # point-query widths (a handful of rows) ~97% of queries qualify;
-    # only tile-straddlers pay the 2-tile gather. Empty windows
-    # (hi <= lo) qualify trivially.
-    single = (np.maximum(hi, lo + 1) - 1) // T <= tile_ids_all
-    tier_of = np.where(single & (tier_of == 0), -1, tier_of)
+    with stage("kernel.encode"):
+        lo, hi = _window_bounds(sindex, enc)
+        q8, needs_host = pack_q8(enc, lo, hi)
+        tile_ids_all = (lo // T).astype(np.int32)
+        caps = _tier_caps(sindex, window_cap)
+        width = hi - lo
+        # smallest tier that fits; oversize windows run (and overflow) in
+        # the top tier so their aggregate slots still exist
+        tier_of = np.searchsorted(np.asarray(caps), width, side="left")
+        tier_of = np.minimum(tier_of, len(caps) - 1)
+        # single-tile fast tier (tier -1): a window wholly inside one tile
+        # needs a C=1 gather — half the HBM bytes of the base C=2 tier. At
+        # point-query widths (a handful of rows) ~97% of queries qualify;
+        # only tile-straddlers pay the 2-tile gather. Empty windows
+        # (hi <= lo) qualify trivially.
+        single = (np.maximum(hi, lo + 1) - 1) // T <= tile_ids_all
+        tier_of = np.where(single & (tier_of == 0), -1, tier_of)
 
-    agg = np.zeros((b, 8), np.int32)
-    rows = (
-        np.full((b, record_cap), -1, np.int32)
-        if with_rows
-        else np.zeros((b, 0), np.int32)
-    )
-    # each tier further splits exact-mode queries from the rest so the
-    # dominant point-lookup shape compiles to the specialised
-    # exact-only program (the symbolic-type chain dropped); a tier
-    # whose queries are all one kind costs no extra dispatch
-    is_exact = enc["alt_mode"] == MODE_EXACT
+        agg = np.zeros((b, 8), np.int32)
+        rows = (
+            np.full((b, record_cap), -1, np.int32)
+            if with_rows
+            else np.zeros((b, 0), np.int32)
+        )
+        # each tier further splits exact-mode queries from the rest so the
+        # dominant point-lookup shape compiles to the specialised
+        # exact-only program (the symbolic-type chain dropped); a tier
+        # whose queries are all one kind costs no extra dispatch
+        is_exact = enc["alt_mode"] == MODE_EXACT
     # launch EVERY (tier, exact) split before fetching anything: the
     # dispatches overlap in flight, so a split batch pays ~one blocking
     # round trip instead of one per split
@@ -1051,36 +1067,36 @@ def run_queries_scattered(
             )
             launched.append((sel, a_dev, m_dev, seq))
     if launched:
-        t_fetch = time.perf_counter()
-        if with_rows:
-            fetched = jax.device_get(
-                [(a, m) for _s, a, m, _q in launched]
-            )
-        else:
-            fetched = [
-                (a, None)
-                for a in jax.device_get(
-                    [a for _s, a, _m, _q in launched]
+        with stage("kernel.readback") as st:
+            if with_rows:
+                fetched = jax.device_get(
+                    [(a, m) for _s, a, m, _q in launched]
                 )
-            ]
+            else:
+                fetched = [
+                    (a, None)
+                    for a in jax.device_get(
+                        [a for _s, a, _m, _q in launched]
+                    )
+                ]
         # ONE combined readback returns every tier's handles together:
         # its wall time is each launch's fetch stage (they complete as
         # a unit), so every record in the batch carries it
-        fetch_ms = (time.perf_counter() - t_fetch) * 1e3
-        for (_sel, _ad, _md, seq), (a, masks) in zip(launched, fetched):
-            note_device_stage(
-                seq,
-                fetch_ms=fetch_ms,
-                fetch_bytes=np.asarray(a).nbytes
-                + (np.asarray(masks).nbytes if masks is not None else 0),
-            )
-        for (sel, _ad, _md, _q), (a, masks) in zip(launched, fetched):
-            agg[sel] = np.asarray(a)[: len(sel)]
-            if with_rows:
-                base_rows = tile_ids_all[sel].astype(np.int64) * T
-                rows[sel] = _rows_from_masks(
-                    np.asarray(masks)[: len(sel)], base_rows, record_cap
+        with stage("kernel.unpack"):
+            for (_sel, _ad, _md, seq), (a, masks) in zip(launched, fetched):
+                note_device_stage(
+                    seq,
+                    fetch_ms=st.ms,
+                    fetch_bytes=np.asarray(a).nbytes
+                    + (np.asarray(masks).nbytes if masks is not None else 0),
                 )
+            for (sel, _ad, _md, _q), (a, masks) in zip(launched, fetched):
+                agg[sel] = np.asarray(a)[: len(sel)]
+                if with_rows:
+                    base_rows = tile_ids_all[sel].astype(np.int64) * T
+                    rows[sel] = _rows_from_masks(
+                        np.asarray(masks)[: len(sel)], base_rows, record_cap
+                    )
 
     # overflow honours the CALLER's window_cap (the engine's on-device
     # promise), not the tile-rounded top tier — answers for widths in

@@ -31,7 +31,6 @@ import sqlite3
 import threading
 import time
 import uuid
-from collections import deque
 from enum import Enum
 from pathlib import Path
 
@@ -49,10 +48,9 @@ from .resilience import (
 from .telemetry import (
     annotate,
     current_context,
-    percentiles,
     request_context,
 )
-from .utils.trace import span
+from .utils.trace import span, stage, tracer
 
 
 class JobStatus(Enum):
@@ -71,6 +69,38 @@ def hash_query(doc: dict | str) -> str:
     if not isinstance(doc, str):
         doc = json.dumps(doc, sort_keys=True, default=str)
     return hashlib.md5(doc.encode()).hexdigest()
+
+
+class _TableLock:
+    """The job table's one lock. Every request takes it half a dozen
+    times (status, claim, responses, completion), so under load request
+    threads queue here: that wait is the ``runner.table_wait`` stage,
+    timed only when the lock is contended. The sample is handed to the
+    stage after the release: whoever holds this lock holds up every
+    other request, so nothing but the table's own work runs under it."""
+
+    __slots__ = ("_lock", "_waited")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        # thread id -> ms its current hold waited for the lock
+        self._waited: dict[int, float] = {}
+
+    def __enter__(self):
+        if not self._lock.acquire(False):
+            t0 = time.perf_counter()
+            self._lock.acquire()
+            self._waited[threading.get_ident()] = (
+                time.perf_counter() - t0
+            ) * 1e3
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        if self._waited:
+            ms = self._waited.pop(threading.get_ident(), None)
+            if ms is not None:
+                tracer.observe("runner.table_wait", ms)
+        return False
 
 
 class QueryJobTable:
@@ -103,7 +133,7 @@ class QueryJobTable:
         # (WAL growth bounded by one sweep interval of TTL'd cache
         # traffic).
         self._conn.execute("PRAGMA wal_autocheckpoint=0")
-        self._lock = threading.Lock()
+        self._lock = _TableLock()
         self.spill_dir = Path(spill_dir) if spill_dir else None
         if self.spill_dir:
             self.spill_dir.mkdir(parents=True, exist_ok=True)
@@ -517,11 +547,15 @@ class AsyncQueryRunner:
         self._last_purge = time.time()
         self._sweeper: threading.Thread | None = None
         # admission-wait decomposition: submit -> execution start on
-        # the bounded pool (the stage BEFORE the batcher's queue wait).
-        # Ring for exact percentiles; the runner.queue_wait_ms
+        # the bounded pool (the stage BEFORE the batcher's queue wait)
+        # is the ``runner.wait`` stage; the runner.queue_wait_ms
         # histogram feeds once an app registry wires it
-        self._wait_ms: deque = deque(maxlen=4096)
         self._wait_hist = None
+        # how ``submit`` answered: from the in-memory hand-off, from
+        # the job table, or neither (claimed, coalesced or shed)
+        self._n_submits = 0
+        self._n_memory_hits = 0
+        self._n_table_hits = 0
 
     def close(self) -> None:
         self._pool.shutdown(wait=False, cancel_futures=True)
@@ -573,6 +607,22 @@ class AsyncQueryRunner:
             "bulk-lane submissions holding runner slots",
             fn=lambda: self._bulk_active,
         )
+        registry.counter(
+            "runner.submits",
+            "queries submitted to the runner",
+            fn=lambda: self._n_submits,
+        )
+        registry.counter(
+            "runner.memory_hits",
+            "submits answered by the in-memory result hand-off "
+            "(a repeat inside the query TTL: no search, no cache lookup)",
+            fn=lambda: self._n_memory_hits,
+        )
+        registry.counter(
+            "runner.table_hits",
+            "submits answered COMPLETED by the job table",
+            fn=lambda: self._n_table_hits,
+        )
         # the admission-wait slice of the queue-wait decomposition
         # (/debug/status composes it ahead of the batcher stages)
         self._wait_hist = registry.histogram(
@@ -591,19 +641,17 @@ class AsyncQueryRunner:
                 self._bulk_active -= 1
 
     def _note_queue_wait(self, wait_ms: float) -> None:
-        with self._lock:
-            self._wait_ms.append(wait_ms)
+        tracer.observe("runner.wait", wait_ms)
         h = self._wait_hist
         if h is not None:
             h.observe(wait_ms)
 
     def queue_wait_summary(self) -> dict:
-        """Percentiles of the runner's admission wait over the bounded
-        ring (empty dict before any async execution) — same summary
-        semantics as every other stage in /debug/status."""
-        with self._lock:
-            xs = list(self._wait_ms)
-        return percentiles(xs)
+        """Percentiles of the runner's admission wait (the
+        ``runner.wait`` stage's ring; empty dict before any async
+        execution) — same summary semantics as every other stage in
+        /debug/status."""
+        return tracer.stage_quantiles("runner.wait")
 
     def _maybe_purge(self) -> None:
         now = time.time()
@@ -645,6 +693,10 @@ class AsyncQueryRunner:
         """``fingerprint`` (e.g. the engine's index fingerprint) is folded
         into the query hash so cached results die with the data they were
         computed from."""
+        with stage("runner.lookup"):
+            return self._submit(payload, fingerprint)
+
+    def _submit(self, payload, fingerprint) -> tuple[str, JobStatus]:
         self._maybe_purge()
         query_id = hash_query(
             {"payload": dataclasses.asdict(payload), "fp": fingerprint}
@@ -652,8 +704,12 @@ class AsyncQueryRunner:
         # in-memory results are authoritative the moment the search
         # finished — the table may still be mid-persistence (background)
         with self._lock:
+            self._n_submits += 1
             hit = self._results.get(query_id)
-        if hit is not None and hit[1] > time.time():
+            fresh = hit is not None and hit[1] > time.time()
+            if fresh:
+                self._n_memory_hits += 1
+        if fresh:
             # job-layer outcome notes (telemetry): a repeat served here
             # never reaches engine.search, so the slow-query log would
             # otherwise show an unexplained fast request
@@ -661,6 +717,8 @@ class AsyncQueryRunner:
             return query_id, JobStatus.COMPLETED
         status = self.table.get_job_status(query_id)
         if status is JobStatus.COMPLETED:
+            with self._lock:
+                self._n_table_hits += 1
             annotate(query_job="table_hit")
             return query_id, status
         if status is JobStatus.RUNNING:
@@ -765,11 +823,14 @@ class AsyncQueryRunner:
                     # a coalesced waiter (different request context)
                     # must get the partial marking too, not a silently
                     # incomplete answer
+                    # (last rides the clock reading the hand-off was made
+                    # at: the woken waiter's ``handoff.back`` starts there)
                     with self._lock:
                         self._results[query_id] = (
                             responses,
                             time.time() + ttl,
                             unavailable,
+                            time.perf_counter(),
                         )
                     # waiters are served from the in-memory handoff the
                     # moment the search finishes; the sqlite persistence
@@ -778,19 +839,20 @@ class AsyncQueryRunner:
                     # (a WAL checkpoint fsync here was a >1 s soak-tail
                     # outlier with the kernels fully warm)
                     done.set()
-                    if partial:
-                        self.table.abandon(query_id, claim)
-                    else:
-                        for resp in responses:
-                            n = self.table.next_response_number(
-                                query_id, claim
-                            )
-                            if n:
-                                self.table.put_response(
-                                    query_id, n, resp, claim
+                    with stage("runner.persist"):
+                        if partial:
+                            self.table.abandon(query_id, claim)
+                        else:
+                            for resp in responses:
+                                n = self.table.next_response_number(
+                                    query_id, claim
                                 )
-                        self.table.mark_finished(query_id, claim)
-                        self.table.complete(query_id, claim)
+                                if n:
+                                    self.table.put_response(
+                                        query_id, n, resp, claim
+                                    )
+                            self.table.mark_finished(query_id, claim)
+                            self.table.complete(query_id, claim)
                 except Exception:
                     # never cache a failure as an empty result: drop the
                     # job so pollers fall back to a direct search (which
@@ -829,6 +891,7 @@ class AsyncQueryRunner:
     ) -> list[VariantSearchResponse] | None:
         """Responses if COMPLETED (optionally waiting), else None.
         The wait is clamped by the caller's ambient request deadline."""
+        waited = False
         if wait_s > 0:
             wait_s = current_deadline().clamp(wait_s)
             with self._lock:
@@ -837,6 +900,7 @@ class AsyncQueryRunner:
             if ev is not None:
                 # in-process job: block on its completion event (no poll)
                 ev.wait(wait_s)
+                waited = True
             elif not handed_off and not self.table.wait(
                 query_id, timeout_s=wait_s
             ):
@@ -850,7 +914,14 @@ class AsyncQueryRunner:
         with self._lock:
             hit = self._results.get(query_id)
         if hit is not None and hit[1] > time.time():
-            if len(hit) > 2 and hit[2]:
+            if waited:
+                # parked on the job's event until the worker handed the
+                # result over: from its clock reading to this thread
+                # running again
+                tracer.observe(
+                    "handoff.back", (time.perf_counter() - hit[3]) * 1e3
+                )
+            if hit[2]:
                 # replay the partial marking onto THIS caller's request
                 # context — the job thread annotated the submitter's,
                 # and a coalesced waiter has its own

@@ -34,7 +34,6 @@ import queue
 import threading
 import time
 import weakref
-from collections import deque
 from dataclasses import dataclass
 
 import numpy as np
@@ -55,11 +54,9 @@ from .telemetry import (
     charge_cost_to,
     current_context,
     note_device_stage,
-    percentiles,
-    profile_region,
     request_context,
 )
-from .utils.trace import span
+from .utils.trace import span, tracer
 
 
 @dataclass
@@ -83,6 +80,9 @@ class _Pending:
     result: object = None
     error: BaseException | None = None
     t_submit: float = 0.0
+    #: the fetcher's clock reading when the results were in hand: the
+    #: woken submitter's ``handoff.back`` starts there
+    t_ready: float = 0.0
     #: combined bound (request deadline ∧ batch timeout) — when waits end
     deadline: Deadline = NO_DEADLINE
     #: request deadline alone — decides 504 (request's fault) vs 503
@@ -197,7 +197,6 @@ class MicroBatcher:
         max_wait_ms: float = 2.0,
         default_timeout_s: float | None = None,
         pipeline_depth: int = 2,
-        timing_window: int = 65536,
     ):
         self.max_batch = max_batch
         self.max_wait_s = max_wait_ms / 1e3
@@ -220,20 +219,15 @@ class MicroBatcher:
         self._n_submits = 0
         self._n_specs = 0
         # per-request latency decomposition (soak-tail attribution,
-        # VERDICT r3 #10): queue wait (submit -> kernel launch), device
-        # execute (launch -> results ready), and the per-stage split
-        # (encode / launch dispatch / fetch). Bounded rings sized by
-        # ``timing_window`` so a long-lived server cannot grow them
-        # unboundedly; timing_summary() reports over this window.
-        self._wait_ms: deque = deque(maxlen=timing_window)
-        self._exec_ms: deque = deque(maxlen=timing_window)
-        self._encode_ms: deque = deque(maxlen=timing_window)
-        self._launch_ms: deque = deque(maxlen=timing_window)
-        self._fetch_ms: deque = deque(maxlen=timing_window)
-        # queue-wait decomposition histogram (batcher.stage_ms, stage
-        # label): the same points that feed the rings observe here once
-        # an app registry wired it (register_metrics). None until then,
-        # so engines without an app pay one attribute read
+        # VERDICT r3 #10): the stages of utils/trace.py hold it — the
+        # chain stages (batcher.wait, batcher.pipeline, kernel.*,
+        # batcher.fetch_wait, handoff.back) and, from the same clock
+        # reads, the composites timing_summary() reports under its old
+        # keys (batcher.queue_wait / encode / launch / fetch / exec).
+        # The queue-wait decomposition histogram (batcher.stage_ms,
+        # stage label) takes the same readings once an app registry
+        # wired it (register_metrics). None until then, so engines
+        # without an app pay one attribute read
         self._stage_hist = None
         # resilience observability: submits that expired before their
         # launch (leader-side filter) / timed out waiting (follower)
@@ -397,6 +391,11 @@ class MicroBatcher:
                     raise self._timeout_error(req_deadline)
         if me.error is not None:
             raise me.error
+        # this thread runs again: the hand-off back from the fetcher
+        # ends here (one reading, also the slow-query note's)
+        t_wake = time.perf_counter()
+        if me.t_ready:
+            tracer.observe("handoff.back", (t_wake - me.t_ready) * 1e3)
         # per-request stage note for the slow-query log: submit ->
         # result delivery (queue wait + device execute + fetch), the
         # batcher's share of this request's latency — plus the kernel
@@ -404,14 +403,10 @@ class MicroBatcher:
         # ScatterDeviceIndex / MeshFusedIndex), so a tail is
         # attributable to a dispatch tier without cross-referencing
         # counters
-        annotate(
-            batch_ms=round((time.perf_counter() - me.t_submit) * 1e3, 2),
-            batch_index=type(dindex).__name__,
-        )
+        batch_ms = round((t_wake - me.t_submit) * 1e3, 2)
+        annotate(batch_ms=batch_ms, batch_index=type(dindex).__name__)
         plan_stage(
-            "batch",
-            decision=type(dindex).__name__,
-            batch_ms=round((time.perf_counter() - me.t_submit) * 1e3, 2),
+            "batch", decision=type(dindex).__name__, batch_ms=batch_ms
         )
         return me.result
 
@@ -708,7 +703,8 @@ class MicroBatcher:
 
     def timing_summary(self) -> dict:
         """Percentiles of the per-request decomposition over the
-        bounded ``timing_window``: queue_wait_ms (submit -> kernel
+        stages' bounded rings (utils/trace.py, process-wide like the
+        counters): queue_wait_ms (submit -> kernel
         launch; server-side queueing behind in-flight launches) and
         exec_ms (launch -> results; the device dispatch incl. the
         host-device round trip), plus the per-launch stage split —
@@ -717,14 +713,13 @@ class MicroBatcher:
         client_latency ~= queue_wait + exec + HTTP/materialisation
         overhead — the soak harness reports all of these so tails are
         attributable to a stage."""
-        with self._stats_lock:
-            return {
-                "queue_wait_ms": percentiles(self._wait_ms),
-                "exec_ms": percentiles(self._exec_ms),
-                "encode_ms": percentiles(self._encode_ms),
-                "launch_ms": percentiles(self._launch_ms),
-                "fetch_ms": percentiles(self._fetch_ms),
-            }
+        return {
+            "queue_wait_ms": tracer.stage_quantiles("batcher.queue_wait"),
+            "exec_ms": tracer.stage_quantiles("batcher.exec"),
+            "encode_ms": tracer.stage_quantiles("batcher.encode"),
+            "launch_ms": tracer.stage_quantiles("batcher.launch"),
+            "fetch_ms": tracer.stage_quantiles("batcher.fetch"),
+        }
 
     def occupancy(self) -> dict:
         """{'submits': N, 'launches': M, 'mean_batch': x, 'histogram':
@@ -763,7 +758,7 @@ class MicroBatcher:
 
         The 17 instruments share ONE briefly-cached snapshot per
         render pass: ``timing_summary()`` copies five timing rings
-        (up to ``timing_window`` floats each) and runs percentile
+        (up to ``trace.STAGE_RING`` floats each) and runs percentile
         sorts under the hot-path stats lock — recomputing it per
         instrument would make every Prometheus scrape contend with
         request serving 17 times over."""
@@ -922,31 +917,30 @@ class MicroBatcher:
                     for p in batch
                 ]
             )
+        # one clock reading per boundary from here on; each feeds the
+        # chain stage that ends there, the composite behind the old
+        # /debug/status key, the histogram and the cost vector alike
+        n = len(batch)
+        t_taken = time.perf_counter()
         acc.pipeline.acquire()
         t_launch = time.perf_counter()
+        tracer.observe("batcher.pipeline", (t_launch - t_taken) * 1e3, n)
         with self._stats_lock:
-            self._batch_hist[len(batch)] = (
-                self._batch_hist.get(len(batch), 0) + 1
-            )
+            self._batch_hist[n] = self._batch_hist.get(n, 0) + 1
             self._fused_hist[len(specs)] = (
                 self._fused_hist.get(len(specs), 0) + 1
             )
-            for p in batch:
-                self._wait_ms.append((t_launch - p.t_submit) * 1e3)
         stage_hist = self._stage_hist
-        if stage_hist is not None:
-            for p in batch:
-                stage_hist.observe(
-                    (t_launch - p.t_submit) * 1e3,
-                    label_value="batch_wait",
-                )
         for p in batch:
+            tracer.observe("batcher.wait", (t_taken - p.t_submit) * 1e3)
+            wait_ms = (t_launch - p.t_submit) * 1e3
+            tracer.observe("batcher.queue_wait", wait_ms)
+            if stage_hist is not None:
+                stage_hist.observe(wait_ms, label_value="batch_wait")
             # batch wait is queued time on this request's clock — cost-
             # attributed like the fair-queue wait (per-submission ctx:
             # this runs on the launcher thread, not the request's)
-            charge_cost_to(
-                p.ctx, queue_wait_ms=(t_launch - p.t_submit) * 1e3
-            )
+            charge_cost_to(p.ctx, queue_wait_ms=wait_ms)
         # the batch leader's request context rides the launch thread
         # (ambient, like deadlines): the flight recorder stamps launch
         # records — and a mid-request device.compile journal event —
@@ -958,9 +952,7 @@ class MicroBatcher:
         try:
             with request_context(lead_ctx), span(
                 "serving.microbatch"
-            ) as sp, profile_region(
-                "sbeacon.kernel.launch"
-            ):
+            ) as sp, tracer.serving(n):
                 # chaos site: a raised fault takes the existing
                 # launch-failure path (every waiter gets the error)
                 fault_point("kernel.launch")
@@ -995,23 +987,23 @@ class MicroBatcher:
                 p.error = e
                 p.event.set()
             return
-        with self._stats_lock:
-            self._encode_ms.append((t_enc - t_launch) * 1e3)
-            self._launch_ms.append((t_disp - t_enc) * 1e3)
+        # the old keys' measuring points: encode = encode_queries,
+        # launch = the whole of run_queries_auto (for a family that
+        # fetches inside its call, the device run and readback too);
+        # the stages of that call are taken inside ops/
+        encode_ms = (t_enc - t_launch) * 1e3
+        launch_ms = (t_disp - t_enc) * 1e3
+        tracer.observe("batcher.encode", encode_ms, n)
+        tracer.observe("batcher.launch", launch_ms, n)
         # the launch's flight-recorder record gets the host encode
         # stage (the kernel seam only sees pre-encoded arrays; fetch ms
         # is attached by the pending handle's own fetch)
         note_device_stage(
-            getattr(pending, "flight_seq", None),
-            encode_ms=(t_enc - t_launch) * 1e3,
+            getattr(pending, "flight_seq", None), encode_ms=encode_ms
         )
         if stage_hist is not None:
-            stage_hist.observe(
-                (t_enc - t_launch) * 1e3, label_value="encode"
-            )
-            stage_hist.observe(
-                (t_disp - t_enc) * 1e3, label_value="launch"
-            )
+            stage_hist.observe(encode_ms, label_value="encode")
+            stage_hist.observe(launch_ms, label_value="launch")
         try:
             self._fetcher.submit(
                 self._fetch_batch,
@@ -1038,22 +1030,25 @@ class MicroBatcher:
         """FETCH stage (fetcher thread): block on the device results,
         hand each submission its row-slice, release the pipeline slot."""
         try:
-            with profile_region("sbeacon.kernel.fetch"):
+            n = len(batch)
+            t_fetch = time.perf_counter()
+            tracer.observe(
+                "batcher.fetch_wait", (t_fetch - t_disp) * 1e3, n
+            )
+            with tracer.serving(n):
                 res = pending.fetch()
             t_done = time.perf_counter()
-            with self._stats_lock:
-                exec_ms = (t_done - t_launch) * 1e3
-                self._fetch_ms.append((t_done - t_disp) * 1e3)
-                for _ in batch:
-                    self._exec_ms.append(exec_ms)
+            exec_ms = (t_done - t_launch) * 1e3
+            fetch_ms = (t_done - t_disp) * 1e3
+            tracer.observe("batcher.fetch", fetch_ms, n)
+            for _ in batch:
+                tracer.observe("batcher.exec", exec_ms)
             stage_hist = self._stage_hist
             if stage_hist is not None:
                 # device = launch -> results (exec), fetch = the
                 # readback tail of it; once per launch
                 stage_hist.observe(exec_ms, label_value="device")
-                stage_hist.observe(
-                    (t_done - t_disp) * 1e3, label_value="fetch"
-                )
+                stage_hist.observe(fetch_ms, label_value="fetch")
             # device-launch cost attribution: the launch's measured
             # execute time (launch -> results, the device's busy span
             # for this program) pro-rated to each submission by its
@@ -1085,6 +1080,7 @@ class MicroBatcher:
                     p.ctx,
                     device_us=exec_ms * 1e3 * len(p.specs) / n_specs,
                 )
+                p.t_ready = t_done
                 p.event.set()
         except BaseException as e:
             for p in batch:

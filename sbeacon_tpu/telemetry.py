@@ -37,14 +37,14 @@ This module is the single plane the stack wires through:
   at ``/ops/events``. Histograms can additionally carry **exemplars**
   — the trace id of the latest observation per bucket — so a slow
   latency bucket links directly to the request that landed in it.
-- **Profiling + slow-query hooks**: ``SBEACON_PROFILE=<dir>`` arms
-  :func:`profile_region` so kernel launch/fetch run under
-  ``jax.profiler`` trace annotations; :class:`SlowQueryLog` records a
-  structured JSON line (trace id, route, stage decomposition, outcome
-  notes) for every request above a configurable latency threshold.
+- **Slow-query hook**: :class:`SlowQueryLog` records a structured JSON
+  line (trace id, route, stage decomposition, outcome notes) for every
+  request above a configurable latency threshold. (The program's
+  regions in a device profile are the ``beacon.<stage>`` annotations
+  of ``utils/trace.py``.)
 
-Everything here is stdlib-only (jax is imported lazily and only when
-profiling is armed) and importable from any layer, like resilience.py.
+Everything here is stdlib-only and importable from any layer, like
+resilience.py.
 """
 
 from __future__ import annotations
@@ -1012,6 +1012,19 @@ DEVICE_FAMILIES = (
 )
 
 
+#: the jitted functions behind a family's launches, by the names the
+#: profiler shows them under (``jit_<name>`` on a device's ``XLA
+#: Modules`` line). ``benchmark/rooflines/<family>.py`` finds a family's
+#: device time by these; tests/test_stages.py holds the two together, so
+#: a rename fails a test instead of dropping ``<family>_kernel_ms``.
+DEVICE_PROGRAMS = {
+    "scatter": ("_scatter_batch", "_scatter_many"),
+    "plane": ("_selected_batch",),
+    "fused": ("_query_batch_impl",),
+    "fused_l0": ("_query_batch_impl",),
+}
+
+
 class DeviceFlightRecorder:
     """Per-launch telemetry for every compiled device program — the
     device-plane twin of the control plane's :class:`EventJournal`
@@ -1530,6 +1543,7 @@ def register_device_metrics(registry) -> None:
     registry.counter(
         "device.evaluated_pairs",
         "evaluated (device, query-slot) pairs summed over all mesh "
+        "launches, and (query, dataset) pairs over all fused-stack "
         "launches — the per-device FLOP proxy",
         fn=lambda: flight_recorder.evaluated_pairs,
     )
@@ -1566,77 +1580,3 @@ def register_device_metrics(registry) -> None:
         "of double-buffered in HBM (BEACON_DONATE_UPLOADS)",
         fn=lambda: flight_recorder.donated_buffers,
     )
-
-
-# -- profiling hooks ----------------------------------------------------------
-
-
-class _Profiler:
-    """``SBEACON_PROFILE=<dir>`` arms jax.profiler capture: the first
-    :func:`profile_region` entry starts one process-wide trace into the
-    directory (stopped at exit), and every region runs under a named
-    ``TraceAnnotation`` so kernel launch/fetch show up as labeled spans
-    in the profile. Unarmed (the default), a region entry is one
-    attribute check — the hot path pays nothing."""
-
-    def __init__(self, directory: str | None = None):
-        if directory is None:
-            directory = os.environ.get("SBEACON_PROFILE", "")
-        self.directory = directory
-        self._lock = threading.Lock()
-        self._started = False
-        self._failed = False
-
-    def _ensure_started(self) -> bool:
-        with self._lock:
-            if self._started:
-                return True
-            if self._failed:
-                return False
-            try:
-                import atexit
-
-                import jax
-
-                os.makedirs(self.directory, exist_ok=True)
-                jax.profiler.start_trace(self.directory)
-                atexit.register(self._stop)
-                self._started = True
-                return True
-            except Exception:
-                # profiling is an optimisation aid, never a dependency
-                log.exception("jax profiler unavailable; disabling")
-                self._failed = True
-                return False
-
-    def _stop(self) -> None:
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
-
-    @contextmanager
-    def region(self, name: str):
-        if not self.directory or not self._ensure_started():
-            yield
-            return
-        try:
-            import jax
-
-            ann = jax.profiler.TraceAnnotation(name)
-        except Exception:
-            yield
-            return
-        with ann:
-            yield
-
-
-profiler = _Profiler()
-
-
-def profile_region(name: str):
-    """``with profile_region("kernel.launch"): ...`` — no-op unless
-    ``SBEACON_PROFILE`` is set."""
-    return profiler.region(name)

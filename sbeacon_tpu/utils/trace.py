@@ -19,18 +19,299 @@ into per-name statistics (count / total / min / max) and retain the
 most recent N complete span trees; ``report()`` renders both, and a
 process enabled via ``SBEACON_TRACE=1`` prints the report to stderr at
 exit (the stopwatch-print role of reference main.cpp:238-241).
+
+Stages (ISSUE 24) are the part that is never off. ``stage(name)`` times
+one boundary of the serving path, named in the literal registry
+:data:`STAGES`, with ONE pair of clock reads that feeds every sink: the
+stage's monotone ``count`` / ``sum_ms`` / ``req_ms`` and its bounded
+ring (always), a ``jax.profiler.TraceAnnotation`` named
+``beacon.<stage>`` for ``work`` stages (always; free while nobody
+captures a profile, and on the device trace's clock when somebody
+does), and a :class:`Span` in the tree while tracing is enabled.
+``/debug/status`` serves :meth:`Tracer.stage_summary`; a reader takes
+the difference of two snapshots.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
+import gc
 import os
 import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
-from ..telemetry import current_context, new_span_id
+from ..telemetry import current_context, new_span_id, percentiles
+
+#: every stage of the serving path, name -> kind. ``work``: the thread
+#: computes, or drives or blocks on the device (annotated on the
+#: profiler's clock). ``wait``: the thread is parked behind a gate, a
+#: queue or another thread, or the interval is a hand-off between two
+#: threads (never annotated: sixteen parked request threads would
+#: out-cover the one working thread in every idle gap of a device
+#: trace). ``total``: an interval that encloses other stages, kept for
+#: its readers and left out of every sum.
+STAGES = {
+    # HTTP + router
+    "http.read": "work",  # request line read -> body parsed
+    "api.total": "total",  # app.handle entry -> return (meta.elapsedTimeMs)
+    "api.admit": "wait",  # shaping.admit + admission.admit entry
+    "api.parse": "work",  # admitted -> request parsed and dispatched
+    "api.envelope": "work",  # aggregation + the response envelope
+    "api.finish": "work",  # after _handle: SLO, accounting, plans, meta
+    "http.write": "work",  # json.dumps, headers, wfile.write
+    # filter resolution
+    "filters.resolve": "work",  # datasets of the assembly, filters -> samples
+    # admission, runner
+    "runner.lookup": "work",  # hash, memory and table status, claim
+    "runner.wait": "wait",  # submit -> execution start on the pool
+    # waiting for the job table's one lock, inside runner.lookup and
+    # runner.persist (readers subtract it from them); timed only when
+    # the lock is contended
+    "runner.table_wait": "wait",
+    "runner.persist": "work",  # sqlite persistence after the hand-off
+    # response cache
+    "cache.lookup": "work",
+    # engine
+    "engine.plan": "work",  # targets, path choice, selected-sample masks
+    "engine.fanout": "wait",  # parked while the scatter pool serves targets
+    "engine.materialize": "work",  # one target's response
+    # micro-batcher
+    "batcher.wait": "wait",  # submit -> the launcher has the batch
+    "batcher.pipeline": "wait",  # the fetch pipeline's slot
+    "batcher.fetch_wait": "wait",  # dispatch returned -> fetcher starts
+    "handoff.back": "wait",  # result set -> the waiting thread runs again
+    # query encode, H2D and D2H, inside ops/
+    "kernel.encode": "work",  # encode_queries, pack_q8, padding
+    "kernel.dispatch": "work",  # uploads + the jitted call until it returns
+    "kernel.readback": "work",  # device_get until host arrays exist
+    "kernel.unpack": "work",  # host arrays -> each query's rows and counts
+    # runtime
+    "gc": "work",  # one collection, annotated beacon.gc.gen<n>
+    # the batcher's composite intervals behind /debug/status's old keys
+    # (queue_wait_ms, encode_ms, launch_ms, fetch_ms, exec_ms): same
+    # clock reads as the stages above, same measuring points as before
+    "batcher.queue_wait": "total",
+    "batcher.encode": "total",
+    "batcher.launch": "total",
+    "batcher.fetch": "total",
+    "batcher.exec": "total",
+}
+
+#: the stages a request passes between ``app.handle``'s entry and its
+#: return, in order and without overlap: their ``req_ms`` over
+#: ``api.total``'s ``sum_ms`` is the span coverage. ``http.read``,
+#: ``api.finish`` and ``http.write`` lie outside on the socket's side
+#: (``api.total`` is what ``meta.elapsedTimeMs`` reports); ``gc`` and
+#: ``runner.persist`` run beside the chain, not in it.
+CHAIN = (
+    "api.admit", "api.parse", "filters.resolve", "runner.lookup",
+    "runner.wait", "cache.lookup", "engine.plan", "batcher.wait",
+    "batcher.pipeline", "kernel.encode", "kernel.dispatch",
+    "batcher.fetch_wait", "kernel.readback", "kernel.unpack",
+    "handoff.back", "engine.fanout", "engine.materialize", "api.envelope",
+)
+
+#: samples kept per stage for p50/p95/p99
+STAGE_RING = 16384
+#: samples a stage holds unfolded before a writer folds them itself
+FOLD_AT = 1024
+
+# jax.profiler, resolved at the first ``work`` stage (False where JAX
+# cannot be imported); TraceAnnotation is looked up per call
+_profiler = None
+
+
+def _find_profiler():
+    try:
+        import jax.profiler as found
+    except Exception:
+        found = False
+    return found
+
+
+def _annotation(label: str):
+    """An annotation for the profiler's trace, or None while nobody
+    captures one (or JAX is absent)."""
+    global _profiler
+    if _profiler is None:
+        _profiler = _find_profiler()
+    if _profiler and _profiler.TraceAnnotation.is_enabled():
+        return _profiler.TraceAnnotation(label)
+    return None
+
+
+class StageStats:
+    """One stage: its always-on sinks, and the scope that feeds them.
+
+    The sinks: ``count`` samples, their ``sum_ms``, ``req_ms`` (the sum
+    of duration x requests served by the sample, so that coverage still
+    adds up once a launch serves several) and the bounded ring behind
+    the quantiles.
+
+    ``with stage(name):`` enters this very object: the open reading is
+    kept by thread, so a stage must not nest inside itself on one
+    thread, and a sample costs its writer two dictionary operations and
+    one ``deque.append`` of a float, whether it served one request or
+    (on a fan-out's pool thread) none. Nothing the collector tracks is
+    allocated and no lock is taken: sixteen request threads pass some
+    twenty stages each, a fan-out thirty-two more on its pool, and both
+    showed on the chip (PERF.md 6, PR 24).
+    Readers, and a writer that finds ``FOLD_AT`` samples waiting, fold
+    them into the sums under the lock, so the sums are monotone and
+    exact whenever they are read."""
+
+    __slots__ = ("name", "kind", "label", "tracer", "count", "sum_ms",
+                 "req_ms", "_ring", "_pending", "_beside", "_lock", "_open",
+                 "_live", "_last")
+
+    def __init__(self, name: str, kind: str, tracer: "Tracer"):
+        self.name = name
+        self.kind = kind
+        self.label = f"beacon.{name}" if kind == "work" else None
+        self.tracer = tracer
+        self.count = 0
+        self.sum_ms = 0.0
+        self.req_ms = 0.0
+        self._ring: collections.deque = collections.deque(maxlen=STAGE_RING)
+        # durations of samples that served one request, not yet folded
+        self._pending: collections.deque = collections.deque()
+        # ... and of samples that served none (a fan-out's pool threads)
+        self._beside: collections.deque = collections.deque()
+        # re-entrant: a collection can start inside a reader of the
+        # ``gc`` stage, on the reader's own thread
+        self._lock = threading.RLock()
+        # by thread: the open scope's start, its annotation and span
+        # (only while a profile is captured or the span tree is on),
+        # and the last duration (``ms``)
+        self._open: dict[int, float] = {}
+        self._live: dict[int, tuple] = {}
+        self._last: dict[int, float] = {}
+
+    # -- the scope ------------------------------------------------------------
+
+    def __enter__(self):
+        me = threading.get_ident()
+        ann = _annotation(self.label) if self.label is not None else None
+        tracer = self.tracer
+        tree = (tracer._enabled or tracer._overrides) and tracer.is_enabled
+        if ann is not None:
+            ann.__enter__()
+        self._open[me] = t0 = time.perf_counter()
+        if tree or ann is not None:
+            span = tracer._open(self.name, t0, {}) if tree else None
+            self._live[me] = (ann, span)
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        me = threading.get_ident()
+        t0 = self._open.pop(me, None)
+        if t0 is None:  # closed already
+            return False
+        self._last[me] = ms = (t1 - t0) * 1e3
+        if self._live:
+            ann, span = self._live.pop(me, (None, None))
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            if span is not None:
+                self.tracer._finish(span, t1)
+        self.add(ms, self.tracer._serving_n.get(me, 1))
+        return False
+
+    def close(self) -> None:
+        """End the stage before its ``with`` block does (a stage that
+        covers only the entry of the blocks nested in it); closing
+        twice is harmless."""
+        self.__exit__(None, None, None)
+
+    @property
+    def ms(self) -> float:
+        """The duration of the scope this thread closed last."""
+        return self._last.get(threading.get_ident(), 0.0)
+
+    def note(self, **kw) -> None:
+        """Metadata for the scope's span, where the tree is on."""
+        span = self._live.get(threading.get_ident(), (None, None))[1]
+        if span is not None:
+            span.meta.update(kw)
+
+    # -- the sinks ------------------------------------------------------------
+
+    def add(self, ms: float, n: float = 1) -> None:
+        if n == 1 or n == 0:
+            pending = self._pending if n else self._beside
+            pending.append(ms)
+            if len(pending) > FOLD_AT:
+                self._fold()
+            return
+        with self._lock:
+            self.count += 1
+            self.sum_ms += ms
+            self.req_ms += ms * n
+            self._ring.append(ms)
+
+    @staticmethod
+    def _drain(pending: collections.deque, ring: collections.deque):
+        """Move what waits in ``pending`` to the ring; (count, sum)."""
+        count, sum_ms = 0, 0.0
+        try:
+            while True:
+                ms = pending.popleft()
+                count += 1
+                sum_ms += ms
+                ring.append(ms)
+        except IndexError:
+            pass
+        return count, sum_ms
+
+    def _fold(self) -> None:
+        with self._lock:
+            served, served_ms = self._drain(self._pending, self._ring)
+            beside, beside_ms = self._drain(self._beside, self._ring)
+            self.count += served + beside
+            self.sum_ms += served_ms + beside_ms
+            self.req_ms += served_ms
+
+    def _samples(self) -> list:
+        for _ in range(3):
+            try:
+                return list(self._ring)
+            except RuntimeError:  # a collection's fold landed mid-copy
+                continue
+        return []
+
+    def counts(self) -> tuple:
+        with self._lock:
+            self._fold()
+            return self.count, self.sum_ms, self.req_ms
+
+    def quantiles(self) -> dict:
+        with self._lock:
+            self._fold()
+            xs = self._samples()
+        return percentiles(xs)
+
+    def summary(self) -> dict:
+        with self._lock:
+            count, sum_ms, req_ms = self.counts()
+            xs = self._samples()
+        return {
+            "count": count,
+            "sum_ms": round(sum_ms, 3),
+            "req_ms": round(req_ms, 3),
+            **percentiles(xs),
+        }
+
+    def reset(self) -> None:
+        with self._lock:
+            self._pending.clear()
+            self._beside.clear()
+            self.count = 0
+            self.sum_ms = self.req_ms = 0.0
+            self._ring.clear()
 
 
 @dataclass(eq=False)  # identity equality: `in`-checks on the span stack
@@ -92,6 +373,36 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+class _Serving:
+    """``with tracer.serving(n):`` — see :meth:`Tracer.serving`. Holds
+    no state of one use, so the tracer keeps one per ``n`` and a pool
+    task allocates nothing for its scope; scopes do not nest on a
+    thread (launcher, fetcher and fan-out pools are threads of their
+    own)."""
+
+    __slots__ = ("_by_thread", "_n")
+
+    def __init__(self, by_thread: dict, n):
+        self._by_thread = by_thread
+        self._n = n
+
+    def __enter__(self):
+        self._by_thread[threading.get_ident()] = self._n
+
+    def __exit__(self, *exc):
+        self._by_thread.pop(threading.get_ident(), None)
+        return False
+
+
+class _Registry(dict):
+    """name -> StageStats; an unregistered name raises at the call."""
+
+    def __missing__(self, name):
+        raise ValueError(
+            f"unregistered stage {name!r}: add it to utils.trace.STAGES"
+        )
+
+
 class _ActiveSpan:
     __slots__ = ("tracer", "span")
 
@@ -127,6 +438,60 @@ class Tracer:
         # re-serialising and filtering the whole ring per lookup
         # (exemplar-to-trace resolution is a per-dashboard-click path)
         self._by_trace: dict[str, list[Span]] = {}
+        self._stages = _Registry(
+            (n, StageStats(n, k, self)) for n, k in STAGES.items()
+        )
+        # threads inside an ``enabled()`` scope: with none, and the
+        # process-wide flag off, a stage skips the span tree on two
+        # attribute reads
+        self._overrides = 0
+        # thread id -> requests served by what that thread is running
+        # (serving): a launch of the batcher, a pool task of a fan-out
+        self._serving_n: dict[int, int] = {}
+        self._serving_scopes: dict[int, _Serving] = {}
+
+    # -- stages: always on ----------------------------------------------------
+
+    def stage(self, name: str) -> StageStats:
+        """``with tracer.stage(name):`` times one boundary of the
+        serving path. The sample serves the thread's ambient
+        :meth:`serving` count of requests, or 1."""
+        return self._stages[name]
+
+    def observe(self, name: str, ms: float, n: float = 1) -> None:
+        """Feed an interval whose two ends were read elsewhere (a
+        hand-off between threads, a composite). ``work`` stages are
+        scoped, never observed: the annotation needs the live scope."""
+        acc = self._stages[name]
+        if acc.label is not None:
+            raise ValueError(f"work stage {name!r} must be a `with stage(...)`")
+        acc.add(ms, n)
+
+    def serving(self, n: int) -> _Serving:
+        """Stages opened on this thread inside the scope serve ``n``
+        requests each (a launch of the micro-batcher; 0 on a pool thread
+        whose request is parked in ``engine.fanout`` meanwhile)."""
+        scope = self._serving_scopes.get(n)
+        if scope is None:
+            scope = self._serving_scopes[n] = _Serving(self._serving_n, n)
+        return scope
+
+    def stage_quantiles(self, name: str) -> dict:
+        """{p50, p95, p99} of one stage's ring, or {} before a sample."""
+        return self._stages[name].quantiles()
+
+    def stage_counts(self, name: str) -> tuple:
+        """(count, sum_ms, req_ms) of one stage: monotone, never reset."""
+        return self._stages[name].counts()
+
+    def stage_summary(self) -> dict:
+        """{stage: {count, sum_ms, req_ms, p50, p95, p99}} for every
+        registered stage (no quantiles before its first sample)."""
+        return {n: acc.summary() for n, acc in self._stages.items()}
+
+    def reset_stages(self) -> None:
+        for acc in self._stages.values():
+            acc.reset()
 
     # -- gating -------------------------------------------------------------
 
@@ -148,17 +513,24 @@ class Tracer:
         threads neither see it nor clobber the process-wide flag."""
         prev = getattr(self._local, "override", None)
         self._local.override = on
+        with self._lock:
+            self._overrides += 1
         try:
             yield self
         finally:
             self._local.override = prev
+            with self._lock:
+                self._overrides -= 1
 
     # -- span recording -----------------------------------------------------
 
     def span(self, name: str, **meta):
         if not self.is_enabled:
             return _NULL
-        sp = Span(name=name, t_start=time.perf_counter(), meta=dict(meta))
+        return _ActiveSpan(self, self._open(name, time.perf_counter(), meta))
+
+    def _open(self, name: str, t_start: float, meta: dict) -> Span:
+        sp = Span(name=name, t_start=t_start, meta=dict(meta))
         ctx = current_context()
         if ctx is not None:
             sp.trace_id = ctx.trace_id
@@ -167,10 +539,10 @@ class Tracer:
         if stack is None:
             stack = self._local.stack = []
         stack.append(sp)
-        return _ActiveSpan(self, sp)
+        return sp
 
-    def _finish(self, sp: Span) -> None:
-        sp.t_end = time.perf_counter()
+    def _finish(self, sp: Span, t_end: float | None = None) -> None:
+        sp.t_end = time.perf_counter() if t_end is None else t_end
         # a span entered on one thread may be exited on another (the
         # batcher's launcher/fetcher pools hand work across threads):
         # the finishing thread then has no span stack at all — record
@@ -309,6 +681,47 @@ if tracer.is_enabled:
 
 def span(name: str, **meta):
     return tracer.span(name, **meta)
+
+
+_process_stages = tracer._stages
+
+
+def stage(name: str) -> StageStats:
+    return _process_stages[name]
+
+
+# -- the interpreter's collections, as a stage --------------------------------
+
+#: collections per generation and their summed pause, for /metrics
+gc_pauses = [0, 0, 0]
+_GC_LABELS = ("beacon.gc.gen0", "beacon.gc.gen1", "beacon.gc.gen2")
+_gc_open = None
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry: the collection is the ``gc`` stage, its
+    annotation ``beacon.gc.gen<n>`` opened at start and closed at stop.
+    Collections do not nest, so one module slot holds the open one."""
+    global _gc_open
+    if phase == "start":
+        ann = _annotation(_GC_LABELS[info["generation"]])
+        if ann is not None:
+            ann.__enter__()
+        _gc_open = (ann, time.perf_counter())
+    elif _gc_open is not None:
+        ann, t0 = _gc_open
+        _gc_open = None
+        ms = (time.perf_counter() - t0) * 1e3
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        tracer._stages["gc"].add(ms)
+        gc_pauses[info["generation"]] += 1
+
+
+def install_gc_stage() -> None:
+    """Hook the collector once per process (the app does, at start)."""
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
 
 
 def graft_launch_span(active, *, elapsed_ms: float = 0.0, **meta) -> None:

@@ -398,6 +398,10 @@ def test_debug_status_schema_and_diagnosis(app):
 @obs
 def test_debug_status_names_slowest_stage(app):
     # feed the runner's admission-wait ring so a stage has quantiles
+    # (the runner.wait stage of the process-wide tracer: start it empty)
+    from sbeacon_tpu.utils.trace import tracer
+
+    tracer.reset_stages()
     app.query_runner._note_queue_wait(125.0)
     _, doc = app.handle("GET", "/debug/status")
     assert doc["stages"]["admission_wait_ms"]["p50"] == 125.0

@@ -254,6 +254,11 @@ GOLDEN_METRICS = [
     "migration.completed",
     "migration.rolled_back",
     "migration.bytes_copied",
+    "runner.submits",
+    "runner.memory_hits",
+    "runner.table_hits",
+    "runtime.gc_pauses",
+    "runtime.gc_pause_ms",
 ]
 
 
