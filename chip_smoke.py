@@ -1349,7 +1349,7 @@ def check_device_memory(run: Run, devices, status: dict) -> None:
     from sbeacon_tpu.ops.scatter_kernel import ScatterDeviceIndex
 
     engine = run.engine
-    index_bytes = plane_bytes = plane_ledger_bytes = 0
+    index_bytes = plane_bytes = plane_gate_bytes = 0
     for key, shard, planes in engine.index_snapshot():
         dindex = engine._indexes[key][1]
         require(
@@ -1362,14 +1362,10 @@ def check_device_memory(run: Run, devices, status: dict) -> None:
                 isinstance(planes, PlaneDeviceIndex),
                 f"{key}: genotype planes are not on the device",
             )
-            # the arrays' own size; the ledger's figure assumes the
-            # minor dimension is padded to 128 lanes
-            plane_bytes += sum(
-                int(a.nbytes)
-                for a in (planes.gt, planes.gt2, planes.tok1, planes.tok2)
-                if a is not None
-            )
-            plane_ledger_bytes += planes.nbytes_hbm()
+            # the arrays' own size, held padded to whole 128-lane
+            # tiles, and what the budget gate reserved for them
+            plane_bytes += planes.nbytes_hbm()
+            plane_gate_bytes += PlaneDeviceIndex.estimate_hbm(shard)
     fused = engine._fused_state
     fused_bytes = (
         sum(int(a.size) * a.dtype.itemsize for a in fused[0].arrays.values())
@@ -1390,12 +1386,17 @@ def check_device_memory(run: Run, devices, status: dict) -> None:
     # what still lives on device 0 alone, whatever the chip count
     run.summary["device0_only_bytes"] = {
         "tiles": index_bytes, "planes": plane_bytes,
-        "planes_by_ledger": plane_ledger_bytes,
+        "planes_by_ledger": status["hbm"]["residentBytes"],
         "fused_stack": fused_bytes,
     }
     require(
-        status["hbm"]["residentBytes"] == plane_ledger_bytes,
+        status["hbm"]["residentBytes"] == plane_bytes,
         "the plane ledger and the plane indexes disagree",
+    )
+    require(
+        plane_gate_bytes == plane_bytes,
+        f"the planes hold {plane_bytes} B, the budget gate reserved "
+        f"{plane_gate_bytes} B",
     )
     if devices[0].platform != "tpu":
         return  # the CPU backend reports no memory statistics
@@ -1413,6 +1414,79 @@ def check_device_memory(run: Run, devices, status: dict) -> None:
             "plane_hbm_budget_gb",
         )
         require(h["bytes_in_use"] > 0, f"device {h['id']} holds nothing")
+
+
+def check_plane_programs(run: Run, devices) -> None:
+    """The programs that gather plane rows hold no copy of a plane: the
+    planes are resident in the layout the gather reads (PERF.md, PR 25;
+    resident ``[n, 79]`` every launch re-tiled the whole plane first).
+    Then the compile PR 23 saw refused, 2e7 rows with planes: reported,
+    not required."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from sbeacon_tpu.ops.plane_kernel import _plane_stats, padded_words
+    from sbeacon_tpu.ops.scatter_kernel import (
+        CHUNK_SMALL,
+        ScatterDeviceIndex,
+        _selected_batch,
+        _static_seg_k,
+    )
+
+    on_dev0 = SingleDeviceSharding(devices[0])
+    key, _shard, planes = max(
+        (e for e in run.engine.index_snapshot() if e[2] is not None),
+        key=lambda e: e[2].n_rows,
+    )
+    sindex = run.engine._indexes[key][1]
+    tile, n_words = sindex.tile, planes.n_words
+    plane_bytes = int(planes.gt.nbytes)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=on_dev0)
+
+    def selected(n_rows, seg_k):
+        n_tiles = n_rows // tile + 1 + ScatterDeviceIndex.MAX_C
+        plane = shape(n_rows, padded_words(n_words))
+        return _selected_batch.lower(
+            shape(n_tiles, 8, tile), plane, plane, plane, plane,
+            shape(CHUNK_SMALL), shape(CHUNK_SMALL, 8),
+            shape(CHUNK_SMALL, n_words),
+            T=tile, CAP=tile, nslots=CHUNK_SMALL, C=1,
+            exact_only=True, R=tile, with_counts=False, seg_k=seg_k,
+        ).compile().memory_analysis()
+
+    plane = shape(*planes.gt.shape)
+    temps = {
+        "_selected_batch": selected(planes.n_rows, _static_seg_k(sindex)),
+        "_plane_stats": _plane_stats.lower(
+            plane, plane, plane, plane, shape(1024), shape(1024),
+            shape(n_words), R=1024, with_counts=False, with_or=True,
+        ).compile().memory_analysis(),
+    }
+    temps = {k: int(m.temp_size_in_bytes) for k, m in temps.items()}
+    report = {"plane_bytes": plane_bytes, "temp_bytes": temps}
+    if not run.args.rehearsal:  # a toy plane is smaller than a launch's rows
+        for name, temp in temps.items():
+            require(
+                temp < plane_bytes // 8,
+                f"{name} holds {temp} B of temp beside a plane of "
+                f"{plane_bytes} B: the whole-plane copy is back",
+            )
+    try:
+        big = selected(20_000_000, 2)
+        report["compile_2e7_rows"] = {
+            "compiled": True,
+            "temp_bytes": int(big.temp_size_in_bytes),
+            "argument_bytes": int(big.argument_size_in_bytes),
+        }
+    except Exception as e:  # the compiler's refusal is the finding
+        report["compile_2e7_rows"] = {
+            "compiled": False, "error": str(e).splitlines()[0][:300],
+        }
+    run.summary["plane_programs"] = report
+    print(json.dumps({"plane_programs": report}), file=sys.stderr, flush=True)
 
 
 def check_multichip(run: Run, n_dev: int, platform: str) -> None:
@@ -1507,6 +1581,8 @@ def run_smoke(args, stage: Stages) -> dict:
         summary["parity"] = f"{run.parity}/{run.parity}"
         status = check_server_surfaces(run, n_dev, host_rows)
         check_device_memory(run, devices, status)
+        with stage("plane_programs"):
+            check_plane_programs(run, devices)
         if n_dev > 1:
             check_multichip(run, n_dev, platform)
     finally:

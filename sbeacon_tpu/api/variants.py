@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import base64
 import dataclasses
+import json
 
 from ..metadata.filters import entity_search_conditions
 from ..payloads import VariantQueryPayload
@@ -50,15 +51,17 @@ def _resolve_datasets(store, ontology, assembly_id, filters, dataset_ids):
         conditions, params = entity_search_conditions(
             filters, "analyses", "analyses", ontology=ontology, id_modifier="A.id"
         )
+        # one row per dataset, not one per sample: sqlite hands the
+        # interpreter lock back and forth once per row it steps, and
+        # with other requests on the host every such hand-over waits
+        # for a thread to wake (PERF.md, PR 25: 100 rows, 58 ms)
         rows = store.query(
-            f"SELECT A._datasetid, A._vcfsampleid FROM analyses A "
-            f"{conditions}",
+            f"SELECT A._datasetid, json_group_array(A._vcfsampleid) "
+            f"FROM analyses A {conditions} GROUP BY A._datasetid",
             params,
         )
-        for ds, sample in rows:
-            samples_by_dataset.setdefault(ds, [])
-            if sample:
-                samples_by_dataset[ds].append(sample)
+        for ds, samples in rows:
+            samples_by_dataset[ds] = [s for s in json.loads(samples) if s]
         ids = sorted(samples_by_dataset)
         if dataset_ids:
             allowed = set(dataset_ids)

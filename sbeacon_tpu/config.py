@@ -182,7 +182,9 @@ class EngineConfig:
     # chunk size for staged genotype-plane H2D uploads (plane_kernel):
     # planes larger than one chunk upload as pre-staged contiguous
     # chunks whose transfers overlap, instead of one giant synchronous
-    # copy (the 28 MB/s config7 upload wall). <=0 disables chunking.
+    # copy (the 28 MB/s config7 upload wall), each written on the device
+    # into the resident padded plane. <=0 disables chunking: the whole
+    # unpadded plane then stands beside the resident one until written.
     plane_upload_chunk_mb: int = 256
     # device-resident genotype planes (selected-samples leaf): upload a
     # shard's bit planes to HBM when their padded size fits the budget;

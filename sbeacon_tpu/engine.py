@@ -923,20 +923,15 @@ class VariantEngine:
             # or here on failure — never while the upload is in neither
             # ledger. The token rides on the object so the publisher
             # releases exactly this upload's reservation.
+            # every upload writes its chunks into the resident padded
+            # array in place (staged_device_put): beside the bytes the
+            # gate reserved it holds only the chunks in flight, so a
+            # monolithic upload (chunk_mb <= 0) is the one that holds
+            # more, and nothing falls back to it
             chunk_mb = getattr(eng, "plane_upload_chunk_mb", 256)
             chunk_bytes = (
                 chunk_mb * 1024 * 1024 if chunk_mb > 0 else None
             )
-            # chunked upload transiently holds ~2x the plane set
-            # (staged chunks + the on-device concatenate): only chunk
-            # when that peak ALSO fits the budget; otherwise fall back
-            # to the monolithic 1x copy the gate actually reserved for
-            if (
-                chunk_bytes is not None
-                and est > chunk_bytes
-                and used + 2 * est > budget
-            ):
-                chunk_bytes = None
             fault_point("device.bringup", "plane_upload")
             planes = PlaneDeviceIndex(
                 shard, upload_chunk_bytes=chunk_bytes
@@ -2277,7 +2272,13 @@ class VariantEngine:
                     lo = int(shard.chrom_offsets[code])
                     hi = int(shard.chrom_offsets[code + 1])
                     flags = np.asarray(shard.cols["flags"][lo:hi])
-                    plain = np.nonzero((flags & FLAG.SYMBOLIC) == 0)[0]
+                    # ... and it has to be CALLED: a monomorphic row
+                    # (AC 0) answers exists=False on every path, the
+                    # same standing false alarm
+                    plain = np.nonzero(
+                        ((flags & FLAG.SYMBOLIC) == 0)
+                        & (np.asarray(shard.cols["ac"][lo:hi]) > 0)
+                    )[0]
                     if plain.size:
                         row = lo + int(plain[0])
                         chrom = rchrom
@@ -2380,6 +2381,13 @@ class VariantEngine:
             "engine.mesh_searches",
             "queries answered by the one-pjit mesh path",
             fn=lambda: self.mesh_searches,
+        )
+        registry.gauge(
+            "device.plane_resident_bytes",
+            "HBM bytes of the genotype planes resident per dataset, "
+            "each plane n_rows x 512 B per 4096 samples (what "
+            "plane_hbm_budget_gb is spent on; hosts are sized by it)",
+            fn=lambda: self.plane_ledger()["residentBytes"],
         )
         registry.gauge(
             "engine.materialize_ms",

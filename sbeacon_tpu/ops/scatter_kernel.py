@@ -509,7 +509,11 @@ def _selected_batch(
       order (stable argsort of the match mask — the in-device
       ``_rows_from_masks``),
     - their gt/count planes are gathered, masked per-query
-      (``mask`` int32 [nslots, W]) and popcounted,
+      (``mask`` int32 [nslots, W]) and popcounted. The planes are
+      resident ``[n, Wp]``, W padded to whole 128-lane tiles
+      (``PlaneDeviceIndex``), so the gather reads them as they lie;
+      the mask is zero-extended to Wp here and ``or_words`` cut back
+      to W, both on the device,
     - the sample-hit OR runs over the exact ``grp >= k0`` row subset
       via the same segmented scans as ``parallel.mesh._local_selected``
       (k0 = first record with positive cumulative rc; ploidy>2
@@ -548,8 +552,9 @@ def _selected_batch(
 
     n_rows = gt.shape[0]
     safe = jnp.clip(rows, 0, n_rows - 1)
-    m = mask[:, None, :]  # [B, 1, W]
-    g = gt[safe] & m  # [B, R, W]
+    n_words = mask.shape[1]
+    m = jnp.pad(mask, ((0, 0), (0, gt.shape[1] - n_words)))[:, None, :]
+    g = gt[safe] & m  # [B, R, Wp]
     pcw = lambda x: jnp.sum(
         jax.lax.population_count(x), axis=-1
     ).astype(jnp.int32)
@@ -600,7 +605,7 @@ def _selected_batch(
         np.int32(0),
         jax.lax.bitwise_or,
         dimensions=(1,),
-    )  # [B, W]
+    )[:, :n_words]  # [B, W]
     return agg, rows, pc_call, pc_tok, or_words
 
 

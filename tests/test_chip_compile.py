@@ -1,0 +1,143 @@
+"""The plane programs compiled for the chip at the benchmark's real
+shapes, without the chip: the TPU's compiler is installed here and
+compiles for a described v5e. Nothing runs, so these say nothing of
+results or times; they hold the per-launch programs to what they may
+touch. A plane resident ``[n, 79]`` was re-tiled whole inside every
+launch (5.12 GB of temp at 1e7 rows, refused outright at 2e7); resident
+``[n, 128]`` the gather reads it as it lies.
+
+All of them live in this one file: the worker that is given it loads
+the TPU's library, and keeps it.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from sbeacon_tpu.ops.plane_kernel import (
+    _plane_stats,
+    _write_rows,
+    padded_words,
+)
+from sbeacon_tpu.ops.scatter_kernel import (
+    CHUNK_SMALL,
+    ScatterDeviceIndex,
+    _selected_batch,
+)
+
+KG1_ROWS = 10_000_000  # benchmark/configs/kg1.json
+KG1_WORDS = 79  # 2504 samples
+TILE = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _shape(one_chip, *dims):
+    return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one_chip)
+
+
+def _plane_sized_outputs(compiled, n_rows: int) -> list[str]:
+    """Instructions of the optimised program, parameters apart, whose
+    output has the plane's row count."""
+    made = re.compile(rf"= \w+\[{n_rows},\d+\]")
+    return [
+        line.strip()[:160]
+        for line in compiled.as_text().splitlines()
+        if made.search(line) and " parameter(" not in line
+    ]
+
+
+def _selected(one_chip, n_rows, cap, C, with_counts=False):
+    n_tiles = n_rows // TILE + 1 + ScatterDeviceIndex.MAX_C
+    plane = _shape(one_chip, n_rows, padded_words(KG1_WORDS))
+    return _selected_batch.lower(
+        _shape(one_chip, n_tiles, 8, TILE),
+        plane, plane, plane, plane,
+        _shape(one_chip, CHUNK_SMALL),
+        _shape(one_chip, CHUNK_SMALL, 8),
+        _shape(one_chip, CHUNK_SMALL, KG1_WORDS),
+        T=TILE, CAP=cap, nslots=CHUNK_SMALL, C=C, exact_only=True,
+        R=min(1024, cap), with_counts=with_counts, seg_k=2,
+    ).compile()
+
+
+def _rows_that_fit(with_counts: bool) -> int:
+    """Four planes of 1e7 rows do not fit one chip: a quarter each."""
+    return KG1_ROWS // 4 if with_counts else KG1_ROWS
+
+
+def _holds_no_plane_copy(compiled, n_rows):
+    plane_bytes = n_rows * padded_words(KG1_WORDS) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes // 8
+    assert _plane_sized_outputs(compiled, n_rows) == []
+
+
+@pytest.mark.parametrize(
+    "cap,C,with_counts",
+    [(128, 1, False), (128, None, True), (2048, None, False)],
+)
+def test_selected_batch_touches_no_whole_plane(one_chip, cap, C, with_counts):
+    n_rows = _rows_that_fit(with_counts)
+    _holds_no_plane_copy(
+        _selected(one_chip, n_rows, cap, C, with_counts), n_rows
+    )
+
+
+@pytest.mark.parametrize("R,with_counts", [(128, False), (8192, True)])
+def test_plane_stats_touches_no_whole_plane(one_chip, R, with_counts):
+    n_rows = _rows_that_fit(with_counts)
+    plane = _shape(one_chip, n_rows, padded_words(KG1_WORDS))
+    compiled = _plane_stats.lower(
+        plane, plane, plane, plane,
+        _shape(one_chip, R), _shape(one_chip, R),
+        _shape(one_chip, KG1_WORDS),
+        R=R, with_counts=with_counts, with_or=True,
+    ).compile()
+    _holds_no_plane_copy(compiled, n_rows)
+
+
+def test_selected_batch_compiles_at_twice_the_rows(one_chip):
+    """2e7 rows, a quarter of the callset: refused while the launch
+    copied the plane (9.54 GB of temp beside 6.56 GB of arguments)."""
+    _holds_no_plane_copy(
+        _selected(one_chip, 2 * KG1_ROWS, 128, 1), 2 * KG1_ROWS
+    )
+
+
+def test_upload_writes_its_chunk_in_place(one_chip):
+    """The resident plane is donated to each chunk's write and comes
+    back as its output: beside it the program holds the chunk alone."""
+    rows = 256 * 1024 * 1024 // (KG1_WORDS * 4) // 8 * 8
+    wp = padded_words(KG1_WORDS)
+    compiled = _write_rows.lower(
+        _shape(one_chip, KG1_ROWS, wp),
+        _shape(one_chip, rows, KG1_WORDS),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == KG1_ROWS * wp * 4
+    assert memory.temp_size_in_bytes <= 2 * rows * wp * 4

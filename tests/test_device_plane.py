@@ -268,6 +268,15 @@ def test_device_status_golden_schema_and_reconciliation(warm_stack):
     _, metrics = app.handle("GET", "/metrics")
     assert metrics["device"]["launches"]["fused"] >= 1
     assert "pad_waste" in metrics["device"]
+    # what the planes hold, to the byte: the gauge hosts are sized by
+    held = sum(
+        int(a.nbytes)
+        for _k, _s, p in eng.index_snapshot()
+        if p is not None
+        for a in p.planes()
+    )
+    assert metrics["device"]["plane_resident_bytes"] == held > 0
+    assert held == doc["hbm"]["residentBytes"]
 
 
 @obs

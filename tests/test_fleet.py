@@ -406,6 +406,29 @@ def test_canary_symbolic_only_dataset_gets_miss_probe_only():
 
 
 @obs
+def test_canary_hit_probe_skips_a_row_nobody_carries():
+    """A plain row with an allele count of 0 answers exists=False on
+    every path: anchoring the known-hit probe on it is a standing false
+    alarm (the chip smoke met one: the newest delta's first row was
+    monomorphic). The bracket takes the first plain row that is called."""
+    from sbeacon_tpu.canary import CanaryProber
+
+    recs = random_records(random.Random(43), chrom="1", n=40, n_samples=2)
+    recs.sort(key=lambda r: r.pos)
+    recs[0].alts, recs[0].ac = ["A" if recs[0].ref != "A" else "C"], [0]
+    recs[0].genotypes = ["0|0", "0|0"]
+    eng = _engine("mono", recs)
+    shard = eng.index_snapshot()[0][1]
+    assert int(shard.cols["ac"][0]) == 0
+    bracket = eng.canary_brackets()["mono"]
+    assert bracket["pos"] > int(shard.cols["pos"][0])
+    prober = CanaryProber(eng, enabled=False)
+    assert prober.sync_probes() == 2
+    out = prober.run_once()
+    assert out["mismatches"] == 0 and out["failures"] == 0
+
+
+@obs
 def test_canary_symbolic_delta_falls_back_to_base_hit_row():
     """A symbolic-only DELTA on top of a plain base must not drop the
     hit probe: the bracket walks shards newest-first and anchors on

@@ -477,3 +477,63 @@ def test_cross_entity_record_page_is_index_backed(store):
     assert [d["id"] for d in docs] == [
         f"i{k:03d}" for k in range(90) if k % 3 == 1
     ][:10]
+
+
+def test_variant_filters_resolve_to_samples_one_row_per_dataset(store):
+    """A variant query's filters resolve to each dataset's VCF sample
+    ids; the store answers with one row per dataset however many
+    samples it selects, and analyses without a sample id select none."""
+    from sbeacon_tpu.api.variants import resolve_datasets
+
+    store.upsert(
+        "biosamples",
+        [{"id": "b3", "datasetId": "ds1", "individualId": "i2"}],
+    )
+    store.upsert(
+        "runs",
+        [{"id": "r2", "datasetId": "ds2", "biosampleId": "b2",
+          "individualId": "i3"},
+         {"id": "r3", "datasetId": "ds1", "biosampleId": "b3",
+          "individualId": "i2"}],
+    )
+    store.upsert(
+        "analyses",
+        [
+            {"id": f"a{k}", "datasetId": ds, "individualId": ind,
+             "biosampleId": bio, "runId": run, "vcfSampleId": sample}
+            for k, (ds, ind, bio, run, sample) in enumerate(
+                [("ds1", "i1", "b1", "r1", "S0002"),
+                 ("ds1", "i1", "b1", "r1", ""),
+                 ("ds1", "i1", "b1", "r1", None),
+                 ("ds2", "i3", "b2", "r2", "T0001"),
+                 ("ds2", "i3", "b2", "r2", "T0002"),
+                 ("ds1", "i2", "b3", "r3", "S0009")],  # male: not selected
+                start=2,
+            )
+        ],
+    )
+    store.rebuild_indexes()
+    asked = []
+    read = store.query
+    store.query = lambda sql, params=(): asked.append(read(sql, params)) or asked[-1]
+    datasets, samples = resolve_datasets(
+        store, None, "GRCh38",
+        [{"id": "NCIT:C16576", "scope": "individuals"}],
+    )
+    assert [len(rows) for rows in asked] == [2]
+    assert {ds: sorted(names) for ds, names in samples.items()} == {
+        "ds1": ["S0001", "S0002"],
+        "ds2": ["T0001", "T0002"],
+    }
+    assert sorted(d["id"] for d in datasets) == ["ds1", "ds2"]
+    # a dataset filter is applied to the resolved ids
+    datasets, _ = resolve_datasets(
+        store, None, "GRCh38",
+        [{"id": "NCIT:C16576", "scope": "individuals"}],
+        dataset_ids=["ds2"],
+    )
+    assert [d["id"] for d in datasets] == ["ds2"]
+    # no filters: the plain assembly scan, no sample selection
+    datasets, samples = resolve_datasets(store, None, "GRCh38", [])
+    assert sorted(d["id"] for d in datasets) == ["ds1", "ds2"]
+    assert samples == {}

@@ -248,6 +248,7 @@ GOLDEN_METRICS = [
     "device.pad_waste",
     "device.mid_request_compiles",
     "device.fetched_bytes",
+    "device.plane_resident_bytes",
     "device.donated_buffers",
     "device.fallbacks",
     "migration.started",
