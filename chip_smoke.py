@@ -129,14 +129,20 @@ def make_datasets(sizes: dict, seed: int, n_dev: int, scratch: Path, stage):
                 per, n_samples=N_SAMPLES, seed=seed + i, dataset_id=ds
             )
     with stage("gen_planes"):
-        shards["kgp"] = synthetic_shard(
-            sizes["plane_rows"],
-            n_samples=N_SAMPLES,
-            seed=seed + 100,
-            dataset_id="kgp",
-            with_gt_planes=True,
-            plane_density=0.25,
-        )
+        # (b), and on several chips one more like it per further chip
+        # (no metadata, no checks of their own): planes are most of
+        # what a chip holds, so a plane dataset a chip lets the
+        # placement come out even
+        for i in range(n_dev):
+            ds = "kgp" if i == 0 else f"kgq-{i}"
+            shards[ds] = synthetic_shard(
+                sizes["plane_rows"],
+                n_samples=N_SAMPLES,
+                seed=seed + 100 + i,
+                dataset_id=ds,
+                with_gt_planes=True,
+                plane_density=0.25,
+            )
     with stage("gen_vcf"):
         vcf = scratch / "cohort_chr20.vcf.gz"
         stats = write_cohort_vcf(
@@ -966,6 +972,7 @@ class Run:
         # filled by the phases, in order
         self.shards: dict = {}
         self.index_ids: list[str] = []
+        self.plane_ids: list[str] = []
         self.vcf: Path | None = None
         self.deltas: list = []
         self.config = None
@@ -998,8 +1005,8 @@ def start_server(run: Run, platform: str) -> None:
         # make_device_index would pick the XLA family off the TPU
         import sbeacon_tpu.engine as engine_mod
 
-        engine_mod.make_device_index = lambda shard, **_kw: ScatterDeviceIndex(
-            shard
+        engine_mod.make_device_index = lambda shard, **kw: ScatterDeviceIndex(
+            shard, device=kw.get("device")
         )
 
     # the one setting that is not a default: /submit wants a token
@@ -1013,7 +1020,8 @@ def start_server(run: Run, platform: str) -> None:
         for ds in run.index_ids:
             run.engine.add_index(run.shards[ds])
     with run.stage("upload_planes"):
-        run.engine.add_index(run.shards["kgp"])
+        for ds in run.plane_ids:
+            run.engine.add_index(run.shards[ds])
     with run.stage("warmup"):
         run.summary["programs_warmed"] = warm_app(run.app)
     require(run.engine.warmup_failed_phases == 0, "a warmup phase failed")
@@ -1037,7 +1045,7 @@ def submit_everything(run: Run) -> None:
         return doc
 
     with run.stage("submit_metadata"):
-        for ds in run.index_ids:
+        for ds in run.index_ids + run.plane_ids[1:]:
             submit(ds, metadata_submission(ds, [], None))
         submit(
             "kgp",
@@ -1344,18 +1352,29 @@ def check_server_surfaces(run: Run, n_dev: int, host_rows_expected: int):
 
 def check_device_memory(run: Run, devices, status: dict) -> None:
     """Every loaded index is the chip's family with its planes
-    resident, and each device holds at least what the engine reports."""
+    resident on its owner chip, each device holds at least what the
+    engine reports there, and on several chips they hold alike."""
     from sbeacon_tpu.ops.plane_kernel import PlaneDeviceIndex
     from sbeacon_tpu.ops.scatter_kernel import ScatterDeviceIndex
 
     engine = run.engine
     index_bytes = plane_bytes = plane_gate_bytes = 0
+    owner_of = {
+        (row["dataset"], row["vcf"]): row["chip"]
+        for row in engine.placement_table()
+    }
     for key, shard, planes in engine.index_snapshot():
         dindex = engine._indexes[key][1]
         require(
             isinstance(dindex, ScatterDeviceIndex),
             f"{key}: index is {type(dindex).__name__}",
         )
+        for a in [dindex.tiles] + (planes.planes() if planes else []):
+            require(
+                {d.id for d in a.devices()} == {owner_of[key]},
+                f"{key}: an array lies on {a.devices()}, its owner is "
+                f"chip {owner_of[key]}",
+            )
         index_bytes += dindex.nbytes()
         if shard.gt_bits is not None:
             require(
@@ -1383,12 +1402,25 @@ def check_device_memory(run: Run, devices, status: dict) -> None:
             }
         )
     run.summary["hbm"] = hbm
-    # what still lives on device 0 alone, whatever the chip count
-    run.summary["device0_only_bytes"] = {
+    run.summary["placement"] = engine.placement_table()
+    # what the engine holds by chip (tiles and planes on their owners,
+    # the mesh stack's slice), and what lies on the default device alone
+    by_chip: dict[int, int] = {}
+    for (chip, _kind), nbytes in engine.resident_bytes().items():
+        by_chip[int(chip)] = by_chip.get(int(chip), 0) + nbytes
+    by_chip[devices[0].id] = by_chip.get(devices[0].id, 0) + fused_bytes
+    run.summary["resident_bytes"] = {
+        "by_chip": {str(c): n for c, n in sorted(by_chip.items())},
         "tiles": index_bytes, "planes": plane_bytes,
         "planes_by_ledger": status["hbm"]["residentBytes"],
-        "fused_stack": fused_bytes,
+        "fused_stack_on_device_0": fused_bytes,
     }
+    print(
+        f"[smoke] placement {json.dumps(run.summary['placement'])}; "
+        f"bytes_in_use {[h['bytes_in_use'] for h in hbm]}; the engine's "
+        f"own count by chip {run.summary['resident_bytes']['by_chip']}",
+        file=sys.stderr, flush=True,
+    )
     require(
         status["hbm"]["residentBytes"] == plane_bytes,
         "the plane ledger and the plane indexes disagree",
@@ -1400,12 +1432,27 @@ def check_device_memory(run: Run, devices, status: dict) -> None:
     )
     if devices[0].platform != "tpu":
         return  # the CPU backend reports no memory statistics
-    on_dev0 = index_bytes + plane_bytes + fused_bytes
-    require(
-        hbm[0]["bytes_in_use"] >= on_dev0,
-        f"device 0 holds {hbm[0]['bytes_in_use']} B, the engine reports "
-        f"{on_dev0} B resident",
-    )
+    for h in hbm:
+        require(
+            h["bytes_in_use"] >= by_chip.get(h["id"], 0),
+            f"device {h['id']} holds {h['bytes_in_use']} B, the engine "
+            f"reports {by_chip.get(h['id'], 0)} B resident there",
+        )
+    if len(devices) > 1:
+        # a plane dataset and one or two index datasets a chip. The
+        # fused stack lies whole on the default device, placed by
+        # nobody (PERF.md 7): it is taken off that chip's reading, so
+        # what has to be alike is what the placement governs
+        used = [
+            h["bytes_in_use"]
+            - (fused_bytes if h["id"] == devices[0].id else 0)
+            for h in hbm
+        ]
+        require(
+            min(used) >= 0.85 * max(used),
+            "the chips do not hold alike: bytes_in_use less the fused "
+            f"stack on device {devices[0].id} {used}",
+        )
     budget = run.config.engine.plane_hbm_budget_gb * 1e9
     for h in hbm:
         require(
@@ -1562,10 +1609,14 @@ def run_smoke(args, stage: Stages) -> dict:
             sizes, args.seed, n_dev, run.scratch, stage
         )
         run.index_ids = sorted(d for d in run.shards if d.startswith("kg-"))
+        run.plane_ids = ["kgp"] + sorted(
+            d for d in run.shards if d.startswith("kgq-")
+        )
         summary["sizes"] = {
             "n_samples": N_SAMPLES,
             "index_datasets": len(run.index_ids),
             "index_rows": sum(run.shards[d].n_rows for d in run.index_ids),
+            "plane_datasets": len(run.plane_ids),
             "plane_rows": run.shards["kgp"].n_rows,
             "plane_rows_note": "a tenth of the index rows; widths are not cut",
             "vcf_records": vcf_stats["records"],

@@ -1116,6 +1116,9 @@ class BeaconApp:
             "launches": recorder.launch_summary(),
             "padWaste": recorder.pad_waste_by_family(),
             "midRequestCompiles": recorder.mid_request_compiles(),
+            # who owns what: every published key with its owner chip
+            # and the bytes placed there
+            "placement": getattr(local, "placement_table", list)(),
         }
         last_compile = recorder.last_mid_request_compile()
         # execution-plan rollup: observation/sample counters + the

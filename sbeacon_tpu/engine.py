@@ -784,12 +784,24 @@ class VariantEngine:
         # the way the L0 tier always has. Before that (load_all at
         # start-up) publishes are plain and warmup() compiles them all.
         self._keep_warm = False
-        # selected-samples queries served by the one-pjit
-        # sharded_selected_query path (VERDICT r4 next #3)
-        self.mesh_selected_searches = 0
-        # key -> bytes reserved for an in-flight plane upload (counts
-        # against plane_hbm_budget_gb until the planes are published)
+        # token -> (owner chip, bytes) reserved for an in-flight plane
+        # upload: counts against the OWNER's plane_hbm_budget_gb until
+        # the planes are published. Owner None = an external stack that
+        # lies on every chip (register_plane_bytes) and counts on each.
         self._plane_reserved: dict = {}
+        # placement: key -> [owner device, bytes placed there]. Every
+        # published key has ONE owner among jax.local_devices(): its
+        # tiles and its planes are resident there and its programs run
+        # there. A key keeps its owner across republishes (a re-ingest
+        # or a fold lands where the old copy lived); a dropped dataset
+        # gives its chip back. Guarded by _mesh_lock.
+        self._placement: dict[tuple[str, str], list] = {}
+        self._assignments: dict[str, int] = {}
+        # keys whose planes should be on their owner and are not (the
+        # owner's budget declined them, or the upload failed): a
+        # request that reads such a key's planes is served from the
+        # host's copy and COUNTED (device.fallbacks{host_planes})
+        self._planes_declined: set = set()
         # last computed HBM-ledger snapshot: /device/status reads it
         # when the publish lock is busy (a rebuild can hold _mesh_lock
         # for seconds, and a status probe must answer anyway)
@@ -797,6 +809,7 @@ class VariantEngine:
             "residentBytes": 0,
             "reservedBytes": 0,
             "reservedTokens": 0,
+            "fullestChipBytes": 0,
         }
         # wall time the current fused stack was published (stack age
         # on the /device/status stacks surface)
@@ -867,12 +880,48 @@ class VariantEngine:
 
     # -- index management ---------------------------------------------------
 
-    def _build_planes(self, key, shard, dindex):
+    def _place(self, key, shard, at=None):
+        """The owner chip of ``key``: the one it already has, else
+        ``at`` (where a prebuilt index already lies), else the local
+        device with the fewest bytes placed on it (ties: the lowest
+        device id), by what each key holds on its owner (32 B a row of
+        tiles and, when it has them, its planes). Nothing to configure:
+        the owner follows from the devices JAX reports and the bytes of
+        what is published, and start-up publishes in one fixed order,
+        so a restart places alike. On one device the owner is that
+        device."""
+        import jax
+
+        from .ops.plane_kernel import PlaneDeviceIndex, chip_of
+
+        want = shard.n_rows * 32 + (
+            PlaneDeviceIndex.estimate_hbm(shard)
+            if getattr(self.config.engine, "device_planes", True)
+            else 0
+        )
+        with self._mesh_lock:
+            placed = self._placement.get(key)
+            if placed is not None:
+                placed[1] = int(want)
+                return placed[0]
+            devices = jax.local_devices()
+            load = {d: 0 for d in devices}
+            for dev, nbytes in self._placement.values():
+                load[dev] = load.get(dev, 0) + nbytes
+            owner = at or min(devices, key=lambda d: (load[d], d.id))
+            self._placement[key] = [owner, int(want)]
+            chip = str(chip_of(owner))
+            self._assignments[chip] = self._assignments.get(chip, 0) + 1
+            return owner
+
+    def _build_planes(self, key, shard, dindex, owner):
         """Device-resident genotype planes for the selected-samples leaf
-        (ops/plane_kernel.py), gated on the HBM budget — oversized plane
-        sets stay host-resident and materialisation falls back to the
-        numpy path exactly as before."""
+        (ops/plane_kernel.py) on ``owner``, gated on that chip's HBM
+        budget — oversized plane sets stay host-resident, and a request
+        that then reads them from the host is a counted fall-back
+        (``_planes_declined``)."""
         eng = self.config.engine
+        self._planes_declined.discard(key)
         if (
             dindex is None
             or shard.gt_bits is None
@@ -883,10 +932,11 @@ class VariantEngine:
 
         budget = getattr(eng, "plane_hbm_budget_gb", 11.0) * 1e9
         est = PlaneDeviceIndex.estimate_hbm(shard)
-        # CUMULATIVE gate: other shards' resident planes AND in-flight
-        # uploads (reservations) count against the budget — reserve
-        # under the lock BEFORE uploading so two concurrent add_index
-        # calls cannot both pass the gate and jointly exceed it.
+        # PER-CHIP gate: the planes resident ON THE OWNER and the
+        # in-flight uploads to it (reservations) count against the
+        # budget, not their sum over the process — reserve under the
+        # lock BEFORE uploading so two concurrent add_index calls
+        # cannot both pass the gate and jointly exceed it.
         # Re-ingestion republishes the key plane-less first so searches
         # in that window take the host fallback (the old PlaneDeviceIndex
         # may still be referenced by an in-flight search or a mesh stack,
@@ -899,20 +949,21 @@ class VariantEngine:
                 self._indexes[key] = (prior[0], prior[1], None)
                 self._rebuild_serving_state_locked()
             prior = None  # noqa: F841
-            # resident planes (the same key's were just republished
-            # plane-less above, so every remaining p counts) + EVERY
-            # in-flight reservation, including concurrent uploads of
-            # this same key — each holds its own token
-            used = self._plane_hbm_resident_locked()
-            if used + est > budget:
-                over = True
+            # the owner's resident planes (the same key's were just
+            # republished plane-less above, so every remaining p counts)
+            # + EVERY in-flight reservation on it, including concurrent
+            # uploads of this same key — each holds its own token
+            used = self._plane_hbm_resident_locked(owner)
+            over = used + est > budget
+            if over:
+                self._planes_declined.add(key)
             else:
-                over = False
-                self._plane_reserved[token] = est
+                self._plane_reserved[token] = (owner, est)
         if over:
             logging.getLogger(__name__).info(
-                "genotype planes for %s exceed HBM budget "
-                "(%.1f GB resident+reserved); host-resident",
+                "genotype planes for %s exceed the HBM budget of their "
+                "owner chip (%.1f GB resident+reserved there); "
+                "host-resident",
                 key,
                 used / 1e9,
             )
@@ -934,7 +985,7 @@ class VariantEngine:
             )
             fault_point("device.bringup", "plane_upload")
             planes = PlaneDeviceIndex(
-                shard, upload_chunk_bytes=chunk_bytes
+                shard, upload_chunk_bytes=chunk_bytes, device=owner
             )
             planes._hbm_reservation = token
             return planes
@@ -946,14 +997,16 @@ class VariantEngine:
             )
             with self._mesh_lock:
                 self._plane_reserved.pop(token, None)
+                self._planes_declined.add(key)
             return None
 
     def add_index(self, shard: VariantIndexShard) -> None:
         key = (shard.meta.get("dataset_id", ""), shard.meta.get("vcf_location", ""))
+        owner = self._place(key, shard)
         try:
             fault_point("device.bringup", "index_build")
             dindex = make_device_index(
-                shard, window=self.config.engine.window_cap
+                shard, window=self.config.engine.window_cap, device=owner
             )
         except Exception:
             # accelerator unavailable (backend init failure, OOM): serve
@@ -967,7 +1020,7 @@ class VariantEngine:
                 key,
             )
             dindex = None
-        planes = self._build_planes(key, shard, dindex)
+        planes = self._build_planes(key, shard, dindex, owner)
         if self._keep_warm:
             # a serving engine: the new index's programs compile HERE,
             # on the publishing thread (ingest, compaction, /reload),
@@ -1395,6 +1448,9 @@ class VariantEngine:
                 for k in delta_keys:
                     deltas.pop(k, None)
                 self._deltas = deltas
+            for k in base_keys:
+                self._placement.pop(k, None)
+                self._planes_declined.discard(k)
             for k in set(base_keys) | set(delta_keys):
                 self._delta_seq.pop(k, None)
                 self._l0_touch_key_locked(k)
@@ -1815,7 +1871,16 @@ class VariantEngine:
         caller already tried and failed); omitted means auto-build."""
         key = (shard.meta.get("dataset_id", ""), shard.meta.get("vcf_location", ""))
         if planes is VariantEngine._AUTO_PLANES:
-            planes = self._build_planes(key, shard, dindex)
+            # the planes go where the prebuilt index's tiles lie
+            import jax
+
+            owner = self._place(
+                key,
+                shard,
+                at=getattr(dindex, "device", None)
+                or jax.local_devices()[0],
+            )
+            planes = self._build_planes(key, shard, dindex, owner)
         self._publish_index(key, shard, dindex, planes)
 
     def rebuild_stacks(self) -> None:
@@ -1875,9 +1940,24 @@ class VariantEngine:
         self.warmup_failed_phases = 0
         with self._mesh_lock:
             snapshot = list(self._indexes.values())
-        n = 0
-        for shard, dindex, planes in snapshot:
-            n += self._warm_index(shard, dindex, planes)
+        # a program is compiled for the chip its operands live on: one
+        # thread per owner, so four chips' programs compile side by side
+        by_owner: dict = {}
+        for triple in snapshot:
+            by_owner.setdefault(
+                getattr(triple[1], "device", None), []
+            ).append(triple)
+
+        def warm_owner(triples) -> int:
+            return sum(self._warm_index(*t) for t in triples)
+
+        if len(by_owner) > 1:
+            with ThreadPoolExecutor(
+                max_workers=len(by_owner), thread_name_prefix="warm-owner"
+            ) as pool:
+                n = sum(pool.map(warm_owner, by_owner.values()))
+        else:
+            n = warm_owner(snapshot)
         fst = self._fused_ready(wait=True)
         if fst is not None:
             n += self._warm_fused(fst[0])
@@ -1887,7 +1967,8 @@ class VariantEngine:
         return n
 
     def _warm_failed(self, site: str, msg: str, *args) -> None:
-        self.warmup_failed_phases += 1
+        with self._mat_lock:  # owners warm on threads of their own
+            self.warmup_failed_phases += 1
         _device_fallback(site, msg, *args)
 
     def _warm_index(self, shard, dindex, planes) -> int:
@@ -1961,17 +2042,14 @@ class VariantEngine:
         return n
 
     def _warm_mesh(self, state) -> int:
-        """Mesh pjit programs (multi-dataset + selected-samples paths):
-        a cold sharded_query compile mid-request is the same class of
-        tail as a cold tier program."""
+        """The mesh pjit program (multi-dataset path): a cold
+        sharded_query compile mid-request is the same class of tail as
+        a cold tier program."""
         eng = self.config.engine
         n = 0
         try:
             fault_point("device.bringup", "warmup_mesh")
-            from .parallel.mesh import (
-                sharded_query,
-                sharded_selected_query,
-            )
+            from .parallel.mesh import sharded_query
 
             mesh, stacked, arrays, _iof, _sof, _pof = state
             probe = QuerySpec("1", 1, 1, 1, 2)
@@ -1986,22 +2064,6 @@ class VariantEngine:
                     aggregates_only=True,
                 )
                 n += 1
-                if stacked.has_planes:
-                    sharded_selected_query(
-                        arrays,
-                        [probe],
-                        np.zeros(
-                            (stacked.n_datasets_padded, stacked.plane_words),
-                            np.uint32,
-                        ),
-                        mesh=mesh,
-                        n_iters=stacked.n_iters,
-                        window_cap=eng.window_cap,
-                        record_cap=eng.record_cap,
-                        has_counts=stacked.has_count_planes,
-                        aggregates_only=True,
-                    )
-                    n += 1
         except Exception:
             self._warm_failed("warmup_mesh", "mesh warmup failed")
         return n
@@ -2046,23 +2108,46 @@ class VariantEngine:
                 (k, v[0], v[2]) for k, v in sorted(self._indexes.items())
             ]
 
-    def _plane_hbm_resident_locked(self) -> int:
-        """resident per-dataset planes + every reservation, under the
-        publish lock — THE one summation all three budget gates share
-        (the upload gate, ``_mesh_ready``'s stack gate, and the
-        dispatch tier via :meth:`plane_hbm_resident`), so the
-        accounting can never disagree between them."""
-        return sum(
-            p.nbytes_hbm()
-            for _s, _d, p in self._indexes.values()
-            if p is not None
-        ) + sum(self._plane_reserved.values())
+    def _plane_bytes_by_chip_locked(self) -> tuple[dict, int]:
+        """({chip: plane bytes resident or reserved on it}, bytes an
+        external stack holds on EVERY chip), under the publish lock."""
+        from .ops.plane_kernel import chip_of
+
+        by_chip: dict[int, int] = {}
+        for _s, _d, p in self._indexes.values():
+            if p is not None:
+                c = chip_of(p.device)
+                by_chip[c] = by_chip.get(c, 0) + p.nbytes_hbm()
+        everywhere = 0
+        for owner, nbytes in self._plane_reserved.values():
+            if owner is None:
+                everywhere += nbytes
+            else:
+                c = chip_of(owner)
+                by_chip[c] = by_chip.get(c, 0) + nbytes
+        return by_chip, everywhere
+
+    def _plane_hbm_resident_locked(self, owner=None) -> int:
+        """Plane bytes resident or reserved ON ONE CHIP, under the
+        publish lock: ``owner``'s, or with None the fullest chip's
+        (what a stack that lies on every chip has to fit beside) — THE
+        one summation all three budget gates share (the upload gate,
+        ``_mesh_ready``'s stack gate, and the dispatch tier via
+        :meth:`plane_hbm_resident`), so the accounting can never
+        disagree between them. The budget is a chip's: four planes of
+        10 GB pass on four chips, two on one chip do not."""
+        from .ops.plane_kernel import chip_of
+
+        by_chip, everywhere = self._plane_bytes_by_chip_locked()
+        if owner is None:
+            return max(by_chip.values(), default=0) + everywhere
+        return by_chip.get(chip_of(owner), 0) + everywhere
 
     def plane_hbm_resident(self) -> int:
-        """Bytes of HBM already committed to per-dataset genotype-plane
-        uploads (resident plane indexes + in-flight reservations) —
-        the dispatch tier's plane-stack budget gates against this, the
-        same accounting ``_mesh_ready``'s own gate applies."""
+        """Bytes of HBM already committed to genotype planes on the
+        fullest chip (resident plane indexes + in-flight reservations)
+        — the dispatch tier's plane-stack budget gates against this,
+        the same accounting ``_mesh_ready``'s own gate applies."""
         with self._mesh_lock:
             return self._plane_hbm_resident_locked()
 
@@ -2091,19 +2176,66 @@ class VariantEngine:
                         )
                     ),
                     "reservedBytes": int(
-                        sum(self._plane_reserved.values())
+                        sum(n for _o, n in self._plane_reserved.values())
                     ),
                     "reservedTokens": len(self._plane_reserved),
+                    "fullestChipBytes": int(
+                        self._plane_hbm_resident_locked()
+                    ),
                 }
             finally:
                 self._mesh_lock.release()
         out = dict(self._plane_ledger_cache)
         out["budgetBytes"] = int(budget)
+        # the budget is a chip's: the headroom is the fullest chip's
         out["headroomBytes"] = int(
-            budget - out["residentBytes"] - out["reservedBytes"]
+            budget - out.get("fullestChipBytes", 0)
         )
         out["stale"] = not got
         return out
+
+    def resident_bytes(self) -> dict:
+        """{(chip, kind): bytes} of what this engine holds in HBM: the
+        ``tiles`` and ``planes`` of every published key on the chip the
+        arrays report, and per chip its slice of the mesh ``stack``.
+        Lock-free over the copy-on-write serve list, like every
+        diagnostic read."""
+        from .ops.plane_kernel import chip_of
+
+        out: dict[tuple, int] = {}
+
+        def add(chip, kind, nbytes):
+            out[(str(chip), kind)] = out.get((str(chip), kind), 0) + int(nbytes)
+
+        for _ds, _vcf, (_s, dindex, planes) in self._serve_list:
+            tiles = getattr(dindex, "tiles", None)
+            if tiles is not None:
+                add(chip_of(dindex.device), "tiles", tiles.nbytes)
+            if planes is not None:
+                add(chip_of(planes.device), "planes", planes.nbytes_hbm())
+        state = self._mesh_state
+        if state is not None:
+            for arr in state[2].values():
+                for piece in arr.addressable_shards:
+                    add(chip_of(piece.device), "stack", piece.data.nbytes)
+        return out
+
+    def placement_table(self) -> list[dict]:
+        """[{dataset, vcf, chip, bytes}] of every placed key, sorted:
+        who owns what (``/debug/status`` serves it)."""
+        from .ops.plane_kernel import chip_of
+
+        with self._mesh_lock:
+            placed = sorted(self._placement.items())
+        return [
+            {
+                "dataset": ds,
+                "vcf": vcf,
+                "chip": chip_of(owner),
+                "bytes": nbytes,
+            }
+            for (ds, vcf), (owner, nbytes) in placed
+        ]
 
     def fused_stack_status(self) -> dict:
         """The fused cross-shard stack's state, lock-free (GIL-atomic
@@ -2137,7 +2269,7 @@ class VariantEngine:
         replaces (the tier's rebuild semantics)."""
         with self._mesh_lock:
             if nbytes > 0:
-                self._plane_reserved[token] = int(nbytes)
+                self._plane_reserved[token] = (None, int(nbytes))
             else:
                 self._plane_reserved.pop(token, None)
 
@@ -2154,11 +2286,11 @@ class VariantEngine:
         should already include whatever of it still stands). Returns
         False (ledger untouched) when ``nbytes`` does not fit."""
         with self._mesh_lock:
-            prev = self._plane_reserved.get(token, 0)
+            prev = self._plane_reserved.get(token, (None, 0))[1]
             used = self._plane_hbm_resident_locked() - prev
             if used + nbytes > budget:
                 return False
-            self._plane_reserved[token] = int(nbytes)
+            self._plane_reserved[token] = (None, int(nbytes))
             return True
 
     def index_fingerprint(self) -> str:
@@ -2388,6 +2520,21 @@ class VariantEngine:
             "each plane n_rows x 512 B per 4096 samples (what "
             "plane_hbm_budget_gb is spent on; hosts are sized by it)",
             fn=lambda: self.plane_ledger()["residentBytes"],
+        )
+        registry.gauge(
+            "device.resident_bytes",
+            "HBM bytes this engine holds resident by chip and kind: "
+            "tiles and planes of the datasets the chip owns, stack = "
+            "its slice of the mesh stack's columns",
+            label=("chip", "kind"),
+            fn=self.resident_bytes,
+        )
+        registry.counter(
+            "placement.assignments",
+            "keys given an owner chip (a republish keeps its owner and "
+            "is not counted)",
+            label="chip",
+            fn=lambda: dict(self._assignments),
         )
         registry.gauge(
             "engine.materialize_ms",
@@ -2749,7 +2896,40 @@ class VariantEngine:
         # rides the per-shard scatter below — the base stack stays warm
         # across delta publishes instead of going cold per ingest
         mesh_responses: dict | None = None
-        if len(targets) > 1:
+        wants_planes = self._wants_planes(payload)
+        on_owners = wants_planes and sum(
+            1 for t in targets if t[4] is not None
+        )
+        if wants_planes and self._planes_declined:
+            on_host = sum(
+                1
+                for t in targets
+                if t[4] is None and (t[0], t[1]) in self._planes_declined
+            )
+            if on_host:
+                # planes that should lie on their owner and do not: the
+                # host's copy answers, and the request is COUNTED, so a
+                # deployment whose planes fell off their chips cannot
+                # look like one that holds them
+                record_device_fallback("host_planes")
+                plan_stage(
+                    "fallback",
+                    decision="host_planes",
+                    reason="planes_budget",
+                    datasets=on_host,
+                )
+        if len(targets) > 1 and on_owners:
+            # the planes are resident ONCE, on their datasets' owner
+            # chips: a request that reads them fans out below, one
+            # match+planes launch per dataset on its owner, in parallel
+            # from the pool; the mesh stack carries no second copy
+            plan_stage(
+                "mesh",
+                decision="owner_fanout",
+                reason="planes_on_owners",
+                owners=on_owners,
+            )
+        elif len(targets) > 1:
             state = self._mesh_ready()
             if state is not None:
                 shard_of = state[4]
@@ -3090,46 +3270,11 @@ class VariantEngine:
                 shards = [self._indexes[k][0] for k in keys]
                 n_mesh = int(mesh.devices.size)
                 d_pad = -(-len(shards) // n_mesh) * n_mesh
-                # stack the genotype planes with their datasets when
-                # every shard has them and the per-device slice fits
-                # the plane budget: the mesh then serves the selected-
-                # samples leaf as ONE pjit program (sharded_selected_
-                # query) instead of falling back to per-dataset scatter
-                with_planes = all(
-                    s.gt_bits is not None for s in shards
-                )
-                if with_planes:
-                    # StackedIndex itself computes what its planes will
-                    # occupy per device (one source of truth with the
-                    # actual stackp allocation); resident per-dataset
-                    # planes + in-flight uploads share the same HBM and
-                    # count against the gate too
-                    per_dev = StackedIndex.plane_bytes_per_device(
-                        shards,
-                        n_datasets_padded=d_pad,
-                        n_mesh=n_mesh,
-                    )
-                    resident = self._plane_hbm_resident_locked()
-                    budget = (
-                        getattr(eng, "plane_hbm_budget_gb", 11.0) * 1e9
-                    )
-                    from .parallel.mesh import plane_budget_verdict
-
-                    verdict = plane_budget_verdict(
-                        per_dev, resident, budget
-                    )
-                    # kept for the life of the stack: every later
-                    # selected-samples query that has to take the
-                    # planeless road cites this measured headroom as
-                    # the reason the mesh leg wasn't taken
-                    self._plane_budget_verdict = verdict
-                    if not verdict["fits"]:
-                        with_planes = False
-                stacked = StackedIndex(
-                    shards,
-                    n_datasets_padded=d_pad,
-                    with_planes=with_planes,
-                )
+                # columns only: a dataset's planes are resident ONCE,
+                # on its owner chip (PlaneDeviceIndex), and
+                # _search_targets fans plane-reading requests out to
+                # the owners; the stack serves booleans and counts
+                stacked = StackedIndex(shards, n_datasets_padded=d_pad)
                 arrays = stacked.shard_to_mesh(mesh)
                 # the state carries its OWN shard snapshot: row ids from
                 # the stacked arrays are only valid against the exact
@@ -3168,75 +3313,25 @@ class VariantEngine:
         pjit dispatch. Per-dataset row ids come back device-sharded and
         materialise host-side with the same cumulative semantics as the
         scatter path."""
-        from .parallel.mesh import sharded_query, sharded_selected_query
+        from .parallel.mesh import sharded_query
 
         mesh, stacked, arrays, index_of, shard_of, planes_of = state
         eng = self.config.engine
         device_ref_ok = self._device_ref_ok(payload, spec_base)
         ref_wild = payload.selected_samples_only
-
-        # selected-samples leaf over the mesh (VERDICT r4 next #3): the
-        # SAME one-pjit fan-out serves both leaf types, like the
-        # reference's splitQuery->performQuery chain switching workers
-        # (performQuery/lambda_function.py:43-46). Per-dataset rows +
-        # masked popcounts + the grp>=k0 sample-hit OR come back
-        # dataset-sharded and materialise host-side through the fused
-        # contract — no per-dataset plane dispatches.
-        selected_mesh = (
-            payload.selected_samples_only
-            and stacked.has_planes
-            and device_ref_ok
+        # the stack carries columns only: it matches rows, and a
+        # request that reads planes arrives here only where no target
+        # has them on its owner (device_planes off, or declined and
+        # counted in _search_targets): materialisation then reads the
+        # host's planes
+        per_ds, agg = sharded_query(
+            arrays,
+            [spec_base],
+            mesh=mesh,
+            n_iters=stacked.n_iters,
+            window_cap=eng.window_cap,
+            record_cap=eng.record_cap,
         )
-        if payload.selected_samples_only and not stacked.has_planes:
-            # the alternative not taken: the one-pjit selected-samples
-            # leaf exists but the build-time budget gate declined to
-            # stack the planes — cite the measured shortfall
-            v = getattr(self, "_plane_budget_verdict", None) or {}
-            if v.get("fits") is False:
-                plan_stage(
-                    "mesh",
-                    decision="planes_declined",
-                    reason="planes_budget",
-                    headroom_bytes=v.get("headroomBytes"),
-                    per_device_bytes=v.get("perDeviceBytes"),
-                )
-        sel_idx_of: dict = {}
-        if selected_mesh:
-            from .ops.plane_kernel import sample_mask_words
-
-            W = stacked.plane_words
-            masks = np.zeros(
-                (stacked.n_datasets_padded, W), np.uint32
-            )
-            for ds, vcf, _s, _d, _p, _n in targets:
-                key = (ds, vcf)
-                if key not in index_of:
-                    raise KeyError(key)  # stale stack: thread scatter
-                sel_idx_of[key] = self._selected_idx(
-                    shard_of[key], payload, ds
-                )
-                masks[index_of[key]] = sample_mask_words(
-                    sel_idx_of[key], W
-                )
-            per_ds, agg = sharded_selected_query(
-                arrays,
-                [spec_base],
-                masks,
-                mesh=mesh,
-                n_iters=stacked.n_iters,
-                window_cap=eng.window_cap,
-                record_cap=eng.record_cap,
-                has_counts=stacked.has_count_planes,
-            )
-        else:
-            per_ds, agg = sharded_query(
-                arrays,
-                [spec_base],
-                mesh=mesh,
-                n_iters=stacked.n_iters,
-                window_cap=eng.window_cap,
-                record_cap=eng.record_cap,
-            )
 
         def _one(target):
             ds, vcf, _shard, _dindex, _planes, native = target
@@ -3247,10 +3342,7 @@ class VariantEngine:
             shard = shard_of[(ds, vcf)]
             di = index_of[(ds, vcf)]
             selected_idx = (
-                sel_idx_of.get(
-                    (ds, vcf),
-                    self._selected_idx(shard, payload, ds),
-                )
+                self._selected_idx(shard, payload, ds)
                 if payload.selected_samples_only
                 else None
             )
@@ -3258,36 +3350,13 @@ class VariantEngine:
                 bool(per_ds["overflow"][di, 0])
                 or int(per_ds["n_matched"][di, 0]) > eng.record_cap
             )
-            fused = None
             if not device_ref_ok or overflow:
                 rows = host_match_rows(
                     shard, spec_base, ref_wildcard=ref_wild
                 )
             else:
                 r = per_ds["rows"][di, 0]
-                keep = r >= 0
-                rows = r[keep].astype(np.int64)
-                # the device outputs are only exact for this shard when
-                # its count-plane availability matches the stack-wide
-                # static (a shard WITH count planes in a stack that ran
-                # has_counts=False was counted full-cohort on device —
-                # its restricted semantics must come from the host/
-                # plane_index path instead)
-                if selected_mesh and (
-                    stacked.has_count_planes
-                    or not shard.has_count_planes
-                ):
-                    # or_words come back stack-wide (plane_words = the
-                    # WIDEST shard); this shard's materialisation works
-                    # in its own width — truncate (tail words are zero
-                    # by construction: stack zero-padding AND the mask)
-                    w_shard = shard.gt_bits.shape[1]
-                    fused = (
-                        per_ds["pc_call"][di, 0][keep],
-                        per_ds["pc_tok"][di, 0][keep],
-                        np.asarray(per_ds["or_words"][di, 0])
-                        .view(np.uint32)[:w_shard],
-                    )
+                rows = r[r >= 0].astype(np.int64)
             return materialize_response(
                 shard,
                 rows,
@@ -3297,7 +3366,6 @@ class VariantEngine:
                 vcf_location=vcf,
                 selected_idx=selected_idx,
                 plane_index=planes_of.get((ds, vcf)),
-                fused=fused,
             )
 
         if len(targets) == 1:
@@ -3306,13 +3374,10 @@ class VariantEngine:
             responses = list(self._scatter.map(_one, targets))
         self.mesh_searches += 1
         annotate(dispatch="mesh")
-        if selected_mesh:
-            self.mesh_selected_searches += 1
         sp.note(
             targets=len(targets),
             responses=len(responses),
             mesh=int(mesh.devices.size),
-            selected=selected_mesh,
             psum_exists=bool(agg["exists"][0]),
         )
         return responses

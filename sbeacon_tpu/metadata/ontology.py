@@ -23,17 +23,26 @@ from __future__ import annotations
 
 import json
 import sqlite3
+import threading
 from collections import defaultdict
 from pathlib import Path
 from typing import Callable, Iterable
+
+from ..utils.trace import stage
 
 
 class OntologyStore:
     def __init__(self, path: str | Path = ":memory:"):
         if path != ":memory:":
             Path(path).parent.mkdir(parents=True, exist_ok=True)
-        # served from HTTP worker threads; sqlite objects are guarded by
-        # the GIL for our single-statement usage
+        # served from every request thread over ONE connection: a
+        # sqlite connection is not safe to step from two threads at
+        # once (a statement releases the interpreter lock while it
+        # runs, and a second thread's statement on the same connection
+        # then fails with "bad parameter or other API misuse"), so
+        # every use of it holds this lock. A closure is one row (a
+        # JSON list), so a read is one statement and one row stepped.
+        self._lock = threading.Lock()
         self.conn = sqlite3.connect(str(path), check_same_thread=False)
         self.conn.executescript(
             """
@@ -54,25 +63,26 @@ class OntologyStore:
     # -- ontology metadata (reference Ontologies table) ---------------------
 
     def put_ontology(self, prefix: str, data: dict) -> None:
-        self.conn.execute(
-            "INSERT OR REPLACE INTO ontologies VALUES (?, ?)",
-            (prefix, json.dumps(data)),
-        )
-        self.conn.commit()
+        with self._lock:
+            self.conn.execute(
+                "INSERT OR REPLACE INTO ontologies VALUES (?, ?)",
+                (prefix, json.dumps(data)),
+            )
+            self.conn.commit()
 
     def get_ontology(self, prefix: str) -> dict | None:
-        row = self.conn.execute(
-            "SELECT data FROM ontologies WHERE prefix = ?", (prefix,)
-        ).fetchone()
+        with self._lock:
+            row = self.conn.execute(
+                "SELECT data FROM ontologies WHERE prefix = ?", (prefix,)
+            ).fetchone()
         return json.loads(row[0]) if row else None
 
     def list_ontologies(self) -> list[dict]:
-        return [
-            json.loads(r[0])
-            for r in self.conn.execute(
+        with self._lock:
+            rows = self.conn.execute(
                 "SELECT data FROM ontologies ORDER BY prefix"
-            )
-        ]
+            ).fetchall()
+        return [json.loads(r[0]) for r in rows]
 
     # -- closure ------------------------------------------------------------
 
@@ -116,26 +126,33 @@ class OntologyStore:
         for t, ancs in anc.items():
             for a in ancs:
                 desc[a].add(t)
-        cur = self.conn.cursor()
-        for t, ancs in anc.items():
-            ancs |= self.get_ancestors(t) or set()
-            cur.execute(
-                "INSERT OR REPLACE INTO ancestors VALUES (?, ?)",
-                (t, json.dumps(sorted(ancs))),
-            )
-        for t, descs in desc.items():
-            descs |= self.get_descendants(t) or set()
-            cur.execute(
-                "INSERT OR REPLACE INTO descendants VALUES (?, ?)",
-                (t, json.dumps(sorted(descs))),
-            )
-        self.conn.commit()
+        # one hold for the whole read-merge-write: two merges that
+        # interleaved would each write back a closure without the other's
+        with self._lock:
+            cur = self.conn.cursor()
+            for t, ancs in anc.items():
+                ancs |= self._get_locked("ancestors", t) or set()
+                cur.execute(
+                    "INSERT OR REPLACE INTO ancestors VALUES (?, ?)",
+                    (t, json.dumps(sorted(ancs))),
+                )
+            for t, descs in desc.items():
+                descs |= self._get_locked("descendants", t) or set()
+                cur.execute(
+                    "INSERT OR REPLACE INTO descendants VALUES (?, ?)",
+                    (t, json.dumps(sorted(descs))),
+                )
+            self.conn.commit()
 
-    def _get(self, table: str, term: str) -> set[str] | None:
+    def _get_locked(self, table: str, term: str) -> set[str] | None:
         row = self.conn.execute(
             f"SELECT terms FROM {table} WHERE term = ?", (term,)
         ).fetchone()
         return set(json.loads(row[0])) if row else None
+
+    def _get(self, table: str, term: str) -> set[str] | None:
+        with self._lock:
+            return self._get_locked(table, term)
 
     def get_ancestors(self, term: str) -> set[str] | None:
         return self._get("ancestors", term)
@@ -161,8 +178,9 @@ class OntologyStore:
 
     def term_descendants(self, term: str) -> set[str]:
         """Descendants incl. self; unknown term -> {term}."""
-        got = self.get_descendants(term)
-        return got if got is not None else {term}
+        with stage("filters.descendants"):
+            got = self.get_descendants(term)
+            return got if got is not None else {term}
 
     def expand_filter_term(
         self,
@@ -190,4 +208,5 @@ class OntologyStore:
         return families[-1]  # low
 
     def close(self) -> None:
-        self.conn.close()
+        with self._lock:
+            self.conn.close()

@@ -15,7 +15,11 @@ from .scatter_kernel import (
 
 
 def make_device_index(
-    shard, *, window: int | None = None, pad_unit: int | None = None
+    shard,
+    *,
+    window: int | None = None,
+    pad_unit: int | None = None,
+    device=None,
 ):
     """Device index for serving: the scattered C-tile gather kernel on
     real TPU backends, the XLA gather kernel elsewhere.
@@ -31,11 +35,13 @@ def make_device_index(
     sizes the XLA fallback index; the scattered kernel applies the
     engine's window_cap per BATCH (tier split in
     run_queries_scattered), so the index needs no build-time
-    window."""
+    window. ``device`` is the owner chip the scattered index commits
+    its tiles to (the XLA fallback is not placed: its columns lie on
+    the default device)."""
     import jax
 
     if jax.default_backend() == "tpu":
-        return ScatterDeviceIndex(shard)
+        return ScatterDeviceIndex(shard, device=device)
     return DeviceIndex(shard, pad_unit=pad_unit)
 
 

@@ -988,6 +988,9 @@ def run_queries(
             evaluated_pairs=b,
             launch_ms=launch_ms,
             donated=len(enc_dev) if donate else 0,
+            # the XLA-gather families are not placed: their arrays lie
+            # on the default device
+            chip=0,
             program_key=(
                 "xla_gather",
                 # the donated entry is a distinct compiled program

@@ -67,9 +67,19 @@ def _write_rows(out, chunk, row0):
     return jax.lax.dynamic_update_slice(out, chunk, (row0, 0))
 
 
-def staged_device_put(a: np.ndarray, chunk_bytes: int | None):
+def chip_of(device) -> int:
+    """The chip label of a device in counters and program keys: the
+    id JAX reports for it (None, the default device, is chip 0)."""
+    return 0 if device is None else int(device.id)
+
+
+def staged_device_put(
+    a: np.ndarray, chunk_bytes: int | None, device=None
+):
     """H2D upload of an ``[n, W]`` plane into its resident ``[n, Wp]``
-    form (``Wp = padded_words(W)``), as pre-staged contiguous row chunks.
+    form (``Wp = padded_words(W)``) on ``device`` (the zero fill, every
+    chunk and the pad: nothing touches another chip), as pre-staged
+    contiguous row chunks.
 
     One monolithic ``jnp.asarray`` of a GB-scale plane serialises
     host staging and transfer (the config7 wall: ~28 MB/s, 35.9 s for
@@ -92,9 +102,12 @@ def staged_device_put(a: np.ndarray, chunk_bytes: int | None):
     def put(i):
         if i >= n:
             return None
-        return jax.device_put(np.ascontiguousarray(a[i : i + rows_per]))
+        return jax.device_put(
+            np.ascontiguousarray(a[i : i + rows_per]), device
+        )
 
-    out = jnp.zeros((n, padded_words(w)), a.dtype)
+    with jax.default_device(device):
+        out = jnp.zeros((n, padded_words(w)), a.dtype)
     ahead = put(0)
     for i in range(0, n, rows_per):
         chunk, ahead = ahead, put(i + rows_per)
@@ -144,9 +157,13 @@ class PlaneDeviceIndex:
         self,
         shard: VariantIndexShard,
         upload_chunk_bytes: int | None = 256 * 1024 * 1024,
+        device=None,
     ):
         if shard.gt_bits is None:
             raise ValueError("shard has no genotype planes")
+        # the chip every plane is committed to, and so the chip every
+        # program that reads them runs on (None: the default device)
+        self.device = device
         # n_words is the logical width (the mask's and or_words'); the
         # resident arrays are padded_words(n_words) wide
         self.n_rows, self.n_words = shard.gt_bits.shape
@@ -158,7 +175,9 @@ class PlaneDeviceIndex:
         # appended zero row would cost a full host-side copy of the
         # largest array in the system.)
         def up(a):
-            return staged_device_put(a.view(np.int32), upload_chunk_bytes)
+            return staged_device_put(
+                a.view(np.int32), upload_chunk_bytes, device
+            )
 
         self.gt = up(shard.gt_bits)
         if self.has_counts:
@@ -290,9 +309,9 @@ def plane_row_stats(
         pindex.gt2 if with_counts else pindex.gt,
         pindex.tok1 if with_counts else pindex.gt,
         pindex.tok2 if with_counts else pindex.gt,
-        jnp.asarray(rows_p),
-        jnp.asarray(sel_p),
-        jnp.asarray(mask.view(np.int32)),
+        jax.device_put(rows_p, pindex.device),
+        jax.device_put(sel_p, pindex.device),
+        jax.device_put(mask.view(np.int32), pindex.device),
         R=tier,
         with_counts=with_counts,
         with_or=or_sel is not None,
@@ -311,6 +330,7 @@ def plane_row_stats(
         specs_real=R,
         specs_padded=tier,
         launch_ms=(time.perf_counter() - t0) * 1e3,
+        chip=chip_of(pindex.device),
     )
     counts, or_words = jax.device_get((counts, or_words))
     return (

@@ -70,6 +70,7 @@ from ..telemetry import (
     record_device_launch,
 )
 from ..utils.trace import stage
+from .plane_kernel import chip_of
 from .kernel import (
     MODE_ANY_BASE,
     MODE_EXACT,
@@ -142,10 +143,13 @@ def __getattr__(name: str):
 def _match_program_key(sindex, nslots, nc, cap, C, exact_only) -> tuple:
     """Compile-tracker identity of one match program. ``tiles`` is an
     argument array, so the tile count joins the identity: another
-    dataset compiles a fresh program at the same slot count."""
+    dataset compiles a fresh program at the same slot count. So does
+    the chip: a program is compiled for the device its operands live
+    on, and a twin dataset on another chip compiles its own."""
     return (
         "scatter", int(sindex.tiles.shape[0]), nslots, nc, cap, C,
         exact_only, _static_seg_k(sindex), sindex.tile,
+        chip_of(sindex.device),
     )
 
 
@@ -158,8 +162,88 @@ def _selected_program_key(
         "scatter_selected", int(sindex.tiles.shape[0]),
         tuple(int(d) for d in pindex.gt.shape), pindex.n_words, nslots,
         cap, R, C, exact_only, with_counts, _static_seg_k(sindex),
-        sindex.tile,
+        sindex.tile, chip_of(sindex.device),
     )
+
+
+#: rows packed by one thread at a time (a multiple of every tile size):
+#: numpy releases the interpreter lock, so the blocks of one shard are
+#: packed side by side
+PACK_BLOCK_ROWS = 1 << 20
+
+
+def _pack_block(c, n: int, a: int, b: int, tile: int, tiles, same) -> None:
+    """Rows ``[a, b)`` of the packed hot matrix, written tile-major into
+    ``tiles[a // tile : b // tile]``; rows at and past ``n`` are padding.
+    ``same[a:min(b, n)]`` gets the SAME_PREV bits."""
+    m = max(0, min(b, n) - a)  # real rows of the block
+    block = np.empty((N_PACKED, b - a), dtype=np.int32)
+
+    def fill(row, values, pad):
+        block[row, :m] = values
+        block[row, m:] = pad
+
+    sl = slice(a, a + m)
+    fill(P_POS, c["pos"][sl], _PAD_FILLS["pos"])
+    fill(P_REC_END, c["rec_end"][sl], _PAD_FILLS["rec_end"])
+    fill(P_REF_HASH, c["ref_hash"][sl], 0)
+    fill(P_ALT_HASH, c["alt_hash"][sl], 0)
+    ref_len = c["ref_len"][sl].astype(np.int64)
+    alt_len = c["alt_len"][sl].astype(np.int64)
+    lens = np.minimum(alt_len, _ALT_LEN_CLAMP) | (
+        np.minimum(ref_len, _REF_LEN_CLAMP) << 16
+    )
+    fill(P_LENS, lens.astype(np.int32), 0)
+    flags = stage_symbolic_flags(c["flags"][sl], c["alt_prefix"][sl])
+    k1 = np.clip(c["ref_repeat_k"][sl].astype(np.int64) + 1, 0, 127)
+    flags |= k1 << 19
+    clamped = (ref_len > _REF_LEN_CLAMP) | (alt_len > _ALT_LEN_CLAMP)
+    flags |= np.where(clamped, np.int64(ROW_CLAMPED), 0)
+    rec = c["rec_id"]
+    if m:
+        # row a belongs to the previous row's record across the block's
+        # edge too; row 0 has no previous row
+        before = rec[a - 1 : a + m - 1] if a else np.concatenate(
+            (rec[:1] - 1, rec[: m - 1])
+        )
+        same_here = rec[sl] == before
+        same[sl] = same_here
+        flags |= same_here.astype(np.int64) * SAME_PREV
+    fill(P_FLAGS, flags.astype(np.int32), 0)
+    fill(P_AC, c["ac"][sl], 0)
+    fill(P_AN, c["an"][sl], 0)
+    # tile-major layout: tiles[t] = packed[:, t*T : (t+1)*T]
+    tiles[a // tile : b // tile] = block.reshape(
+        N_PACKED, (b - a) // tile, tile
+    ).transpose(1, 0, 2)
+
+
+def pack_tiles(c, n: int, n_tiles: int, tile: int):
+    """(tiles int32[n_tiles, 8, tile], same int8[n]): the packed columns
+    of ``n`` rows tile by tile with their padding tiles, and each row's
+    SAME_PREV bit. Packed in row blocks on half the host's cores (a
+    serving engine republishes beside its request threads); the result
+    does not depend on how many."""
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    tiles = np.empty((n_tiles, N_PACKED, tile), dtype=np.int32)
+    same = np.zeros(n, dtype=np.int8)
+    step = PACK_BLOCK_ROWS // tile * tile
+    edges = list(range(0, n_tiles * tile, step)) + [n_tiles * tile]
+    jobs = list(zip(edges, edges[1:]))
+    if len(jobs) == 1:
+        _pack_block(c, n, 0, n_tiles * tile, tile, tiles, same)
+    else:
+        with ThreadPoolExecutor(
+            max_workers=min(max(1, (os.cpu_count() or 2) // 2), len(jobs)),
+            thread_name_prefix="pack-tiles",
+        ) as pool:
+            list(pool.map(
+                lambda ab: _pack_block(c, n, ab[0], ab[1], tile, tiles, same),
+                jobs,
+            ))
+    return tiles, same
 
 
 class ScatterDeviceIndex:
@@ -176,65 +260,31 @@ class ScatterDeviceIndex:
 
     MAX_C = 17  # supports caps up to 2048 lanes at T=128
 
-    def __init__(self, shard: VariantIndexShard, tile: int = 128):
+    def __init__(
+        self, shard: VariantIndexShard, tile: int = 128, device=None
+    ):
         if tile % 128:
             raise ValueError("tile must be a multiple of 128 lanes")
         self.tile = tile
+        # the chip the tiles are committed to: the programs run where
+        # their operands live (None: the default device)
+        self.device = device
         n = shard.n_rows
-        c = shard.cols
         n_tiles = n // tile + 1 + self.MAX_C
-        L = n_tiles * tile
-        packed = np.empty((N_PACKED, L), dtype=np.int32)
-
-        def fill(row, values, pad):
-            packed[row, :n] = values
-            packed[row, n:] = pad
-
-        fill(P_POS, c["pos"], _PAD_FILLS["pos"])
-        fill(P_REC_END, c["rec_end"], _PAD_FILLS["rec_end"])
-        fill(P_REF_HASH, c["ref_hash"], 0)
-        fill(P_ALT_HASH, c["alt_hash"], 0)
-        lens = np.minimum(
-            c["alt_len"].astype(np.int64), _ALT_LEN_CLAMP
-        ) | (
-            np.minimum(c["ref_len"].astype(np.int64), _REF_LEN_CLAMP) << 16
-        )
-        fill(P_LENS, lens.astype(np.int64).astype(np.int32), 0)
-        flags = stage_symbolic_flags(c["flags"], c["alt_prefix"])
-        k1 = np.clip(c["ref_repeat_k"].astype(np.int64) + 1, 0, 127)
-        flags |= k1 << 19
-        clamped = (c["ref_len"].astype(np.int64) > _REF_LEN_CLAMP) | (
-            c["alt_len"].astype(np.int64) > _ALT_LEN_CLAMP
-        )
-        flags |= np.where(clamped, np.int64(ROW_CLAMPED), 0)
-        rec = c["rec_id"]
-        same = np.zeros(n, dtype=np.int64)
-        if n > 1:
-            same[1:] = (rec[1:] == rec[:-1]).astype(np.int64)
-        flags |= same * SAME_PREV
-        fill(P_FLAGS, flags.astype(np.int32), 0)
-        fill(P_AC, c["ac"], 0)
-        fill(P_AN, c["an"], 0)
+        tiles, same = pack_tiles(shard.cols, n, n_tiles, tile)
 
         # longest SAME_PREV run = (max rows per record) - 1: lets the
         # kernel replace the 14-pass cumsum+cummax segmented first-match
         # scan with K cheap shifted ANDs (K is static per shard; real
         # corpora have 1-3 alts per record so K is tiny)
-        z = np.flatnonzero(
-            np.concatenate(([0], same.astype(np.int8), [0])) == 0
-        )
+        z = np.flatnonzero(np.concatenate(([0], same, [0])) == 0)
         self.seg_k = int(np.diff(z).max()) - 1
 
-        # tile-major layout: tiles[t] = packed[:, t*T : (t+1)*T]
-        self.tiles = jnp.asarray(
-            np.ascontiguousarray(
-                packed.reshape(N_PACKED, n_tiles, tile).transpose(1, 0, 2)
-            )
-        )  # [n_tiles, 8, T]
+        self.tiles = jax.device_put(tiles, device)  # [n_tiles, 8, T]
         self.n_rows = n
         self.n_tiles = n_tiles
         self.shard = shard
-        self.pos_host = c["pos"]
+        self.pos_host = shard.cols["pos"]
         self.offsets_host = shard.chrom_offsets.astype(np.int64)
 
     def nbytes(self) -> int:
@@ -515,7 +565,7 @@ def _selected_batch(
       the mask is zero-extended to Wp here and ``or_words`` cut back
       to W, both on the device,
     - the sample-hit OR runs over the exact ``grp >= k0`` row subset
-      via the same segmented scans as ``parallel.mesh._local_selected``
+      via the same segmented scans as ``parallel.mesh._plane_reduce``
       (k0 = first record with positive cumulative rc; ploidy>2
       overflow extras can never flip rc positivity — a saturated
       2-bit plane cell popcounts >= 2 — so the device subset equals
@@ -570,7 +620,7 @@ def _selected_batch(
     rc = rc * matched
 
     # or_sel == (record index >= k0) for matched lanes — the segmented
-    # forward/backward scans from parallel.mesh._local_selected
+    # forward/backward scans from parallel.mesh._plane_reduce
     rec_eff = jnp.where(matched, rec_r, jnp.int32(-2))
     first = matched & jnp.concatenate(
         [
@@ -728,9 +778,9 @@ def run_selected_scattered(
                         pindex.gt2 if with_counts else pindex.gt,
                         pindex.tok1 if with_counts else pindex.gt,
                         pindex.tok2 if with_counts else pindex.gt,
-                        jnp.asarray(tid),
-                        jnp.asarray(qq),
-                        jnp.asarray(mm.view(np.int32)),
+                        jax.device_put(tid, sindex.device),
+                        jax.device_put(qq, sindex.device),
+                        jax.device_put(mm.view(np.int32), sindex.device),
                         T=T,
                         CAP=cap,
                         nslots=nslots,
@@ -751,6 +801,7 @@ def run_selected_scattered(
                         sindex, pindex, nslots, cap, R,
                         1 if ti == -1 else None, exact, with_counts,
                     ),
+                    chip=chip_of(sindex.device),
                 )
                 # the host waits here for the device to run the program
                 # (behind whatever other threads launched before it)
@@ -824,7 +875,7 @@ def warmup_index(
     n = 0
     outs = []
     for nslots in sorted(set(batch_shapes)):
-        tid = jnp.zeros(nslots, jnp.int32)
+        tid = jax.device_put(np.zeros(nslots, np.int32), sindex.device)
         for ti, cap in [(-1, T)] + list(enumerate(caps)):
             C = 1 if ti == -1 else None
             for exact in (True, False):
@@ -836,7 +887,7 @@ def warmup_index(
                 q8[:, Q_META] = (
                     (MODE_EXACT if exact else MODE_ANY_BASE) << 1
                 )
-                qd = jnp.asarray(q8)
+                qd = jax.device_put(q8, sindex.device)
                 outs.append(
                     _scatter_batch(
                         sindex.tiles, tid, qd,
@@ -858,8 +909,9 @@ def warmup_index(
                     # A plane set WITH count planes serves two programs:
                     # restricted counting (selected samples) and plain
                     # sample extraction (with_counts=False)
-                    mask = jnp.zeros(
-                        (nslots, pindex.n_words), jnp.int32
+                    mask = jax.device_put(
+                        np.zeros((nslots, pindex.n_words), np.int32),
+                        sindex.device,
                     )
                     for with_counts in sorted({bool(pindex.has_counts), False}):
                         outs.append(
@@ -954,6 +1006,7 @@ def _launch_tier(sindex, tile_ids, q8, *, cap, C=None, exact_only=False):
         program_key=_match_program_key(
             sindex, nslots, nc, cap, C, exact_only
         ),
+        chip=chip_of(sindex.device),
     )
     return agg, masks, seq
 
@@ -965,8 +1018,8 @@ def _dispatch_tier(
     if nc == 1:
         agg, masks = _scatter_batch(
             sindex.tiles,
-            jnp.asarray(tile_ids),
-            jnp.asarray(q8),
+            jax.device_put(tile_ids, sindex.device),
+            jax.device_put(q8, sindex.device),
             T=T,
             CAP=cap,
             nslots=nslots,
@@ -977,8 +1030,8 @@ def _dispatch_tier(
     else:
         agg, masks = _scatter_many(
             sindex.tiles,
-            jnp.asarray(tile_ids.reshape(nc, nslots)),
-            jnp.asarray(q8.reshape(nc, nslots, 8)),
+            jax.device_put(tile_ids.reshape(nc, nslots), sindex.device),
+            jax.device_put(q8.reshape(nc, nslots, 8), sindex.device),
             T=T,
             CAP=cap,
             nslots=nslots,

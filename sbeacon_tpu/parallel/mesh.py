@@ -166,7 +166,6 @@ class StackedIndex:
         *,
         n_datasets_padded: int | None = None,
         pad_unit: int = DeviceIndex.PAD_UNIT,
-        with_planes: bool = False,
     ):
         if not shards:
             raise ValueError("StackedIndex needs at least one shard")
@@ -201,91 +200,16 @@ class StackedIndex:
         )
         self.n_iters = bisect_iters(n_pad)
 
-        # genotype planes, dataset-sharded WITH their index rows: each
-        # device holds the planes of the datasets it owns (the 25 GB
-        # 1000-Genomes plane set fits a pod by construction — ~3 GB per
-        # chip on 8 devices). W is padded to the widest shard; absent
-        # planes stack as zeros for padding datasets.
-        self.plane_words = 0
-        self.has_planes = False
-        self.has_count_planes = False
-        if with_planes and all(s.gt_bits is not None for s in shards):
-            W = max(s.gt_bits.shape[1] for s in shards)
-            self.plane_words = W
-            self.has_planes = True
-            self.has_count_planes = all(
-                s.has_count_planes for s in shards
-            )
-
-            def stackp(attr):
-                # fill one preallocated block: per-shard padded copies +
-                # np.stack would transiently double the (multi-GB) host
-                # footprint of a 1000-Genomes plane set
-                out = np.zeros((d_pad, n_pad, W), np.uint32)
-                for di, sh in enumerate(shards):
-                    a = getattr(sh, attr)
-                    out[di, : a.shape[0], : a.shape[1]] = a
-                return out.view(np.int32)
-
-            self.arrays["plane_gt"] = stackp("gt_bits")
-            if self.has_count_planes:
-                self.arrays["plane_gt2"] = stackp("gt_bits2")
-                self.arrays["plane_tok1"] = stackp("tok_bits1")
-                self.arrays["plane_tok2"] = stackp("tok_bits2")
-
-    @classmethod
-    def plane_bytes_per_device(
-        cls,
-        shards,
-        *,
-        n_datasets_padded: int,
-        n_mesh: int,
-        pad_unit: int = DeviceIndex.PAD_UNIT,
-    ) -> int:
-        """Per-device HBM bytes the stacked genotype planes will occupy
-        (incl. row padding, widest-shard W lane-rounded, and the
-        count-plane multiplicity). The engine's mesh budget gate asks
-        THIS instead of re-deriving the allocation math, so gate and
-        ``stackp`` can never drift."""
-        if not shards or any(s.gt_bits is None for s in shards):
-            return 0
-        W = max(s.gt_bits.shape[1] for s in shards)
-        n_pad = padded_rows(max(s.n_rows for s in shards), pad_unit)
-        n_planes = 4 if all(s.has_count_planes for s in shards) else 1
-        w_lane = -(-W // 128) * 128  # XLA minor-dim lane tiling
-        return (
-            -(-n_datasets_padded // n_mesh)
-            * n_pad
-            * w_lane
-            * 4
-            * n_planes
-        )
-
     def shard_to_mesh(self, mesh: Mesh, axis: str = AXIS) -> dict:
         """Device-put the stack with axis 0 partitioned over ``axis``."""
         sharding = NamedSharding(mesh, P(axis))
+        # straight from the host, each device its slice: an array made
+        # whole on the default device first would stand there beside
+        # what that chip already owns
         return {
-            k: jax.device_put(jnp.asarray(v), sharding)
+            k: jax.device_put(np.asarray(v), sharding)
             for k, v in self.arrays.items()
         }
-
-
-def plane_budget_verdict(
-    per_device_bytes: int, resident_bytes: int, budget_bytes: float
-) -> dict:
-    """The plane-budget gate's decision WITH its evidence: whether the
-    stacked planes fit next to what is already resident, and the
-    measured headroom either way. The engine stores the verdict so a
-    later refusal ("mesh declined planes") can say not just *that* the
-    road wasn't taken but *by how many bytes* it missed."""
-    budget = int(budget_bytes)
-    return {
-        "fits": per_device_bytes + resident_bytes <= budget,
-        "perDeviceBytes": int(per_device_bytes),
-        "residentBytes": int(resident_bytes),
-        "budgetBytes": budget,
-        "headroomBytes": budget - resident_bytes - per_device_bytes,
-    }
 
 
 def _local_query(arrays_local, enc, *, window_cap, record_cap, n_iters, axis):
@@ -340,8 +264,7 @@ def _plane_reduce(
     has_counts,
     use_counts=None,
 ):
-    """The per-query masked-plane reduction shared by the StackedIndex
-    selected path (:func:`_local_selected`) and the fused mesh program
+    """The per-query masked-plane reduction of the fused mesh program
     (:func:`_local_fused_query`): per-row masked popcounts, the
     record-segmented selected call/allele counts, and the sample-hit OR
     over the exact ``record-cumulative > 0`` row subset (the same
@@ -355,8 +278,7 @@ def _plane_reduce(
     [B] bool switch: False rows take the INFO-column ac/an semantics
     (the extraction-shape contract, where materialize reads the
     columns and only consumes ``or_words``); None means all-True (the
-    selected-samples restricted counting every caller of
-    ``_local_selected`` wants). Ploidy>2 saturation side-tables are
+    selected-samples restricted counting). Ploidy>2 saturation side-tables are
     host-only — materialize adds those extras on top of the saturated
     popcounts, and rc POSITIVITY (hence k0 and the OR subset) is
     extras-invariant.
@@ -439,90 +361,6 @@ def _plane_reduce(
     }
 
 
-def _local_selected(
-    arrays_local,
-    enc,
-    masks_local,
-    *,
-    window_cap,
-    record_cap,
-    n_iters,
-    axis,
-    has_counts,
-):
-    """Selected-samples body per device: match rows, then reduce each
-    dataset's LOCAL genotype planes under its sample mask — popcount
-    counting for genotype-derived rows, AN from token planes, and the
-    sample-hit OR over the exact ``record-cumulative > 0`` row subset
-    (the same ``grp >= k0`` selection materialize_response uses).
-
-    The planes never leave their owning device: only [B]-scalar
-    aggregates cross the mesh (psum), the per-dataset sample words stay
-    sharded. Ploidy>2 saturation side-tables are host-only — callers
-    needing those exact values use the per-dataset engine path.
-    """
-
-    def one_dataset(arrays_one, mask_one):
-        res = jax.vmap(
-            partial(
-                _query_one,
-                arrays_one,
-                window_cap=window_cap,
-                record_cap=record_cap,
-                n_iters=n_iters,
-            )
-        )(enc)
-        rows = res["rows"]  # [B, R] int32, -1 padded
-        valid = rows >= 0
-        n = arrays_one["pos"].shape[0]
-        safe = jnp.clip(rows, 0, n - 1)
-        m = mask_one[None, None, :]  # [1, 1, W]
-        gt = arrays_one["plane_gt"][safe] & m  # [B, R, W]
-        pr = _plane_reduce(
-            arrays_one["flags"][safe],
-            arrays_one["ac"][safe].astype(jnp.int32),
-            arrays_one["an"][safe].astype(jnp.int32),
-            arrays_one["rec_id"][safe],
-            gt,
-            arrays_one["plane_gt2"][safe] & m if has_counts else None,
-            arrays_one["plane_tok1"][safe] & m if has_counts else None,
-            arrays_one["plane_tok2"][safe] & m if has_counts else None,
-            valid,
-            has_counts=has_counts,
-        )
-        # window overflow OR record_cap truncation: the plane sums above
-        # only cover the returned [record_cap] rows, so a truncated row
-        # set silently undercounts unless flagged (the engine's scatter
-        # path applies the same n_matched guard)
-        trunc = res["n_matched"] > jnp.int32(record_cap)
-        return {
-            **pr,
-            "overflow": res["overflow"] | trunc,
-            "n_matched": res["n_matched"],
-            # per-row outputs for host materialisation (the engine's
-            # mesh serving path feeds these straight into
-            # materialize_response(fused=...) — same contract as the
-            # single-device fused kernel): matched row ids and the
-            # masked popcounts, aligned
-            "rows": rows,
-        }
-
-    per_ds = jax.vmap(one_dataset)(arrays_local, masks_local)
-    agg = {
-        "call_count": jax.lax.psum(
-            jnp.sum(per_ds["call_count"], axis=0), axis
-        ),
-        "all_alleles_count": jax.lax.psum(
-            jnp.sum(per_ds["all_alleles_count"], axis=0), axis
-        ),
-        "n_overflow": jax.lax.psum(
-            jnp.sum(per_ds["overflow"].astype(jnp.int32), axis=0), axis
-        ),
-    }
-    agg["exists"] = agg["call_count"] > 0
-    return per_ds, agg
-
-
 _FN_CACHE: dict = {}
 
 #: XLA:CPU runs a multi-device mesh as virtual devices rendezvousing on
@@ -592,84 +430,6 @@ def sharded_query(
     fn = _build_sharded_fn(mesh, axis, window_cap, record_cap, n_iters)
     with _collective_guard():
         per_ds, agg = fn(stacked_arrays, enc_dev)
-        agg = jax.device_get(agg)
-        if aggregates_only:
-            per_out: dict = {}
-        else:
-            per_ds = jax.device_get(per_ds)
-            per_out = {k: np.asarray(v) for k, v in per_ds.items()}
-    return per_out, {k: np.asarray(v) for k, v in agg.items()}
-
-
-def sharded_selected_query(
-    stacked_arrays: dict,
-    queries,
-    sample_masks: np.ndarray,
-    *,
-    mesh: Mesh,
-    n_iters: int,
-    axis: str = AXIS,
-    window_cap: int = 2048,
-    record_cap: int = 1024,
-    has_counts: bool = False,
-    aggregates_only: bool = False,
-):
-    """Selected-samples query batch over mesh-sharded planes.
-
-    ``sample_masks``: uint32 [D, W] — dataset d's selected-sample bit
-    mask (sharded over the mesh axis with its planes). Returns
-    (per_dataset, aggregates): per-dataset ``or_words`` [D, B, W] are
-    the masked sample-hit unions, aggregates are psum'd selected
-    call/allele counts. ``n_overflow > 0`` means a window overflowed
-    and the caller must re-answer those datasets host-side, as in
-    ``sharded_query``.
-
-    Aggregate semantics caveat: call/allele counts sum over ALL matched
-    records, which equals ``materialize_response`` only for the
-    include_details shapes (granularity record/aggregated with details).
-    Boolean / no-details responses truncate at the first positive-count
-    record (``call_count = cum[k0]``, AN through k0) — serving callers
-    must route those granularities to the per-dataset engine path, like
-    the ploidy>2 saturation side-tables (host-only) noted above.
-    """
-    enc = (
-        encode_queries(queries) if isinstance(queries, list) else queries
-    )
-    enc_dev = {k: jnp.asarray(v) for k, v in enc.items()}
-    masks_dev = jax.device_put(
-        jnp.asarray(np.asarray(sample_masks, np.uint32).view(np.int32)),
-        NamedSharding(mesh, P(axis)),
-    )
-    key = (
-        "selected",
-        mesh,
-        axis,
-        window_cap,
-        record_cap,
-        n_iters,
-        has_counts,
-    )
-    fn = _FN_CACHE.get(key)
-    if fn is None:
-        body = partial(
-            _local_selected,
-            window_cap=window_cap,
-            record_cap=record_cap,
-            n_iters=n_iters,
-            axis=axis,
-            has_counts=has_counts,
-        )
-        fn = jax.jit(
-            jax.shard_map(
-                body,
-                mesh=mesh,
-                in_specs=(P(axis), P(), P(axis)),
-                out_specs=(P(axis), P()),
-            )
-        )
-        _FN_CACHE[key] = fn
-    with _collective_guard():
-        per_ds, agg = fn(stacked_arrays, enc_dev, masks_dev)
         agg = jax.device_get(agg)
         if aggregates_only:
             per_out: dict = {}

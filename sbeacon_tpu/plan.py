@@ -100,7 +100,8 @@ PLAN_REASONS = frozenset({
     "unbuilt",        # mesh stack not built yet (pre-warmup)
     "planes",         # plane-reading shape the mesh stack cannot serve
     "min_shards",     # query spans too few shards to pay the launch
-    "planes_budget",  # stack built WITHOUT planes: HBM headroom short
+    "planes_budget",  # planes not on their owner chip (budget, upload)
+    "planes_on_owners",  # planes resident once, on owner chips: fan-out
     "mesh_error",     # mesh launch failed; fell back to the scatter
     "breaker_open",   # worker leg fast-failed on an open circuit
     "no_replica",     # every replica unreachable: partial results

@@ -1071,6 +1071,8 @@ class DeviceFlightRecorder:
         # lifetime counters: per family, per seam (the module-property
         # back-compat views), sliced launches, evaluated pairs
         self._families: dict[str, int] = {}
+        # launches of one-chip programs by the chip they ran on
+        self._chips: dict[str, int] = {}
         self._seams: dict[str, int] = {}
         self._sliced = 0
         self._pairs = 0
@@ -1136,13 +1138,16 @@ class DeviceFlightRecorder:
         program_key=None,
         sliced: bool = False,
         donated: int = 0,
+        chip: int | None = None,
     ) -> int:
         """Record ONE device launch; returns its sequence number (the
         handle :meth:`note_stage` later attaches encode/fetch timings
         to). ``seam`` is the dispatching module (``kernel`` / ``mesh``
         / ``scatter`` — the back-compat module properties read these);
         ``program_key`` is a hashable (program, shape) identity fed to
-        the compile tracker (None skips tracking for this launch)."""
+        the compile tracker (None skips tracking for this launch);
+        ``chip`` is the device a one-chip program ran on (None for a
+        program that spans the mesh)."""
         specs_real = int(specs_real)
         specs_padded = max(int(specs_padded), specs_real, 1)
         rec: dict = {
@@ -1167,6 +1172,8 @@ class DeviceFlightRecorder:
             self._seq += 1
             rec["seq"] = self._seq
             self._families[family] = self._families.get(family, 0) + 1
+            if chip is not None:
+                self._chips[str(chip)] = self._chips.get(str(chip), 0) + 1
             self._seams[seam] = self._seams.get(seam, 0) + 1
             if sliced:
                 self._sliced += 1
@@ -1342,6 +1349,10 @@ class DeviceFlightRecorder:
     def launches_by_family(self) -> dict:
         with self._lock:
             return dict(self._families)
+
+    def launches_by_chip(self) -> dict:
+        with self._lock:
+            return dict(self._chips)
 
     def _pad_waste_by_family_locked(self) -> dict:
         return {
@@ -1539,6 +1550,14 @@ def register_device_metrics(registry) -> None:
         "/ fused_l0 / mesh_replicated / mesh_sliced / plane)",
         label="family",
         fn=lambda: flight_recorder.launches_by_family(),
+    )
+    registry.counter(
+        "device.launches_by_chip",
+        "launches of one-chip programs (scatter / plane / fused "
+        "families) by the chip whose resident arrays they read; mesh "
+        "programs, which run on every chip at once, are not counted",
+        label="chip",
+        fn=lambda: flight_recorder.launches_by_chip(),
     )
     registry.counter(
         "device.evaluated_pairs",

@@ -64,6 +64,9 @@ STAGES = {
     "http.write": "work",  # json.dumps, headers, wfile.write
     # filter resolution
     "filters.resolve": "work",  # datasets of the assembly, filters -> samples
+    # one term's closure read from the ontology store, inside
+    # filters.resolve (readers of both count the outer one only)
+    "filters.descendants": "work",
     # admission, runner
     "runner.lookup": "work",  # hash, memory and table status, claim
     "runner.wait": "wait",  # submit -> execution start on the pool

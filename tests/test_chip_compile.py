@@ -35,7 +35,8 @@ TILE = 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def chips():
+    """One ``SingleDeviceSharding`` per chip of a described v5e 2x2."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -51,9 +52,14 @@ def one_chip():
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield [SingleDeviceSharding(d) for d in topo.devices]
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(chips):
+    return chips[0]
 
 
 def _shape(one_chip, *dims):
@@ -126,6 +132,16 @@ def test_selected_batch_compiles_at_twice_the_rows(one_chip):
     _holds_no_plane_copy(
         _selected(one_chip, 2 * KG1_ROWS, 128, 1), 2 * KG1_ROWS
     )
+
+
+def test_selected_batch_compiles_on_an_owner_that_is_not_chip_0(chips):
+    """``kg4``: each of four chips owns 2e7 rows with their plane, and
+    the program is compiled for the chip its operands are committed to."""
+    owner = chips[3]
+    assert owner.device_set != chips[0].device_set
+    compiled = _selected(owner, 2 * KG1_ROWS, 128, 1)
+    _holds_no_plane_copy(compiled, 2 * KG1_ROWS)
+    assert compiled.input_shardings[0][0].device_set == owner.device_set
 
 
 def test_upload_writes_its_chunk_in_place(one_chip):

@@ -222,6 +222,7 @@ GOLDEN_HBM_KEYS = {
     "reservedTokens",
     "budgetBytes",
     "headroomBytes",
+    "fullestChipBytes",
     "stale",
 }
 
@@ -250,10 +251,12 @@ def test_device_status_golden_schema_and_reconciliation(warm_stack):
         1 - real / padded, abs=1e-3
     )
     assert set(doc["hbm"]) == GOLDEN_HBM_KEYS
-    # the HBM numbers reconcile with the engine's own ledger sum
+    # the HBM numbers reconcile with the engine's own ledger: the
+    # budget is a chip's, and the gates read the fullest chip
+    assert doc["hbm"]["fullestChipBytes"] == eng.plane_hbm_resident()
     assert (
-        doc["hbm"]["residentBytes"] + doc["hbm"]["reservedBytes"]
-        == eng.plane_hbm_resident()
+        doc["hbm"]["headroomBytes"]
+        == doc["hbm"]["budgetBytes"] - doc["hbm"]["fullestChipBytes"]
     )
     # stack states: fused stack + mesh tier, with identity and age
     assert doc["stacks"]["fused"]["built"] is True

@@ -116,7 +116,9 @@ def test_mesh_selected_samples_parity():
         include_samples=True,
     )
     rm, rt = em.search(pay), et.search(pay)
-    assert em.mesh_searches == 1
+    # the planes are resident once, on their datasets' owner chips: a
+    # request that reads them fans out to the owners, not to the mesh
+    assert em.mesh_searches == 0
     _assert_same(rm, rt)
 
 
@@ -296,23 +298,38 @@ def test_concurrent_queries_during_reingestion():
     assert {r.dataset_id for r in rs} == {"d0", "d1", "d2", "d3"}
 
 
-def test_sharded_selected_query_planes():
-    """Mesh-sharded genotype planes (sharded_selected_query): selected
-    call/allele counts and sample-hit unions across an 8-device mesh
-    must equal the engine's per-dataset materialisation (VERDICT r3 #2:
-    the 25 GB plane set shards with its datasets; only psum scalars
-    cross the mesh)."""
-    import jax
-
-    from sbeacon_tpu.engine import host_match_rows, materialize_response
-    from sbeacon_tpu.ops.kernel import QuerySpec
-    from sbeacon_tpu.parallel.mesh import (
-        StackedIndex,
-        make_mesh,
-        sharded_selected_query,
+def _selected_payload(ds_names, spec, names):
+    return VariantQueryPayload(
+        dataset_ids=list(ds_names),
+        reference_name=spec.chrom,
+        start_min=spec.start_min,
+        start_max=spec.start_max,
+        end_min=1,
+        end_max=1 << 30,
+        alternate_bases="N",
+        requested_granularity="record",
+        include_datasets="HIT",
+        include_samples=True,
+        selected_samples_only=True,
+        sample_names={ds: list(names) for ds in ds_names},
     )
 
+
+def test_selected_samples_on_owner_planes_equal_host_planes():
+    """Genotype planes resident once, on their datasets' owner chips:
+    a selected-samples request over every dataset of a mesh engine fans
+    out to the owners, and its selected call/allele counts and
+    sample-hit unions equal the host-plane materialisation of each
+    dataset (count planes present: restricted counting comes from the
+    planes)."""
+    from sbeacon_tpu.engine import host_match_rows, materialize_response
+    from sbeacon_tpu.ops.kernel import QuerySpec
+    from sbeacon_tpu.telemetry import flight_recorder
+
     names = [f"S{i}" for i in range(7)]
+    eng = VariantEngine(
+        BeaconConfig(engine=EngineConfig(microbatch=False))
+    )
     shards = []
     for d in range(5):
         rng = random.Random(700 + d)
@@ -331,102 +348,54 @@ def test_sharded_selected_query_planes():
                 sample_names=names,
             )
         )
-    mesh = make_mesh(len(jax.devices()))
-    d_pad = -(-len(shards) // mesh.devices.size) * mesh.devices.size
-    stacked = StackedIndex(
-        shards, n_datasets_padded=int(d_pad), with_planes=True
-    )
-    assert stacked.has_planes and stacked.has_count_planes
-    arrays = stacked.shard_to_mesh(mesh)
-
+        eng.add_index(shards[-1])
+    assert all(s.has_count_planes for s in shards)
+    owners = {
+        p.device for _k, _s, p in eng.index_snapshot() if p is not None
+    }
+    assert len(owners) == 5, "five planed datasets, five owner chips"
     selected = [0, 2, 6]
-    w = stacked.plane_words
-    from sbeacon_tpu.ops.plane_kernel import sample_mask_words
-
-    mask_row = sample_mask_words(selected, w)
-    masks = np.tile(mask_row, (int(d_pad), 1))
-
+    fallbacks = sum(flight_recorder.fallbacks_by_site().values())
     rng = random.Random(99)
     pos0 = shards[0].cols["pos"]
-    specs = []
     for _ in range(12):
         p = int(pos0[rng.randrange(len(pos0))])
-        specs.append(
-            QuerySpec(
-                "7", max(1, p - 150), p + 150, 1, 1 << 30,
-                alternate_bases="N",
-            )
+        spec = QuerySpec(
+            "7", max(1, p - 150), p + 150, 1, 1 << 30,
+            alternate_bases="N",
         )
-    per_ds, agg = sharded_selected_query(
-        arrays,
-        specs,
-        masks,
-        mesh=mesh,
-        n_iters=stacked.n_iters,
-        window_cap=2048,
-        record_cap=1024,
-        has_counts=True,
-    )
-    assert int(agg["n_overflow"].sum()) == 0
-
-    # ground truth: per-dataset engine materialisation (record+details
-    # granularity = full sums, the same contract the psum aggregates)
-    for qi, spec in enumerate(specs):
-        want_call = want_all = 0
+        pay = _selected_payload(
+            [f"p{d}" for d in range(5)], spec, [names[i] for i in selected]
+        )
+        got = {r.dataset_id: r for r in eng.search(pay)}
         for di, shard in enumerate(shards):
             rows = host_match_rows(shard, spec, ref_wildcard=True)
-            payload = VariantQueryPayload(
-                dataset_ids=[f"p{di}"],
-                reference_name="7",
-                start_min=spec.start_min,
-                start_max=spec.start_max,
-                end_min=1,
-                end_max=1 << 30,
-                alternate_bases="N",
-                requested_granularity="record",
-                include_datasets="HIT",
-                include_samples=True,
-                selected_samples_only=True,
-                sample_names={f"p{di}": [names[i] for i in selected]},
-            )
-            resp = materialize_response(
+            want = materialize_response(
                 shard,
                 rows,
-                payload,
+                pay,
                 chrom_label="7",
                 dataset_id=f"p{di}",
                 selected_idx=selected,
             )
-            want_call += resp.call_count
-            want_all += resp.all_alleles_count
-            # per-dataset sample-hit union must match the device OR
-            got_words = per_ds["or_words"][di, qi].view(np.uint32)
-            got_bits = np.unpackbits(
-                got_words.view(np.uint8), bitorder="little"
-            ).astype(bool)
-            got_sel = [k for k, si in enumerate(selected) if got_bits[si]]
-            assert got_sel == resp.sample_indices, (qi, di)
-        assert int(agg["call_count"][qi]) == want_call, qi
-        assert int(agg["all_alleles_count"][qi]) == want_all, qi
+            resp = got[f"p{di}"]
+            assert resp.exists == want.exists
+            assert resp.call_count == want.call_count
+            assert resp.all_alleles_count == want.all_alleles_count
+            assert resp.sample_indices == want.sample_indices
+    assert eng.mesh_searches == 0, "plane readers fan out to the owners"
+    assert sum(flight_recorder.fallbacks_by_site().values()) == fallbacks
 
 
-def test_sharded_selected_query_or_sel_edges():
-    """Regression (r4 review): (a) a query whose only matches are the
-    dataset's FIRST record must still report sample hits (padding lanes
-    alias rec_id[0]); (b) an INFO row with ac=0 but set gt bits in a
-    record BEFORE the first hit must stay excluded from the sample
-    union (the grp >= k0 contract)."""
-    import jax
-
+def test_selected_or_sel_edges_on_the_owner():
+    """Regression (r4 review), on the one resident copy: (a) a query
+    whose only matches are the dataset's FIRST record must still report
+    sample hits (padding lanes alias rec_id[0]); (b) an INFO row with
+    ac=0 but set gt bits in a record BEFORE the first hit must stay
+    excluded from the sample union (the grp >= k0 contract)."""
     from sbeacon_tpu.engine import host_match_rows, materialize_response
     from sbeacon_tpu.genomics.vcf import VcfRecord
     from sbeacon_tpu.ops.kernel import QuerySpec
-    from sbeacon_tpu.parallel.mesh import (
-        StackedIndex,
-        make_mesh,
-        sharded_selected_query,
-    )
-    from sbeacon_tpu.ops.plane_kernel import sample_mask_words
 
     names = ["S0", "S1", "S2"]
     # record 1 (first in the shard): a real hit for S1
@@ -443,61 +412,34 @@ def test_sharded_selected_query_or_sel_edges():
     shard = build_index(
         recs, dataset_id="edge", vcf_location="v", sample_names=names
     )
-    mesh = make_mesh(len(jax.devices()))
-    d_pad = int(mesh.devices.size)
-    stacked = StackedIndex(
-        [shard], n_datasets_padded=d_pad, pad_unit=1024, with_planes=True
+    eng = VariantEngine(
+        BeaconConfig(engine=EngineConfig(microbatch=False))
     )
-    arrays = stacked.shard_to_mesh(mesh)
+    eng.add_index(shard)
+    assert eng.index_snapshot()[0][2] is not None, "planes on the owner"
     selected = [0, 1, 2]
-    masks = np.tile(
-        sample_mask_words(selected, stacked.plane_words), (d_pad, 1)
-    )
     specs = [
         # (a) matches ONLY the first record
         QuerySpec("1", 100, 100, 1, 1 << 30, alternate_bases="N"),
         # (b) window covers the ac=0 record then the rec-3 hit
         QuerySpec("1", 150, 350, 1, 1 << 30, alternate_bases="N"),
     ]
-    per_ds, agg = sharded_selected_query(
-        arrays,
-        specs,
-        masks,
-        mesh=mesh,
-        n_iters=stacked.n_iters,
-        has_counts=stacked.has_count_planes,
-    )
-    for qi, spec in enumerate(specs):
+    hits = []
+    for spec in specs:
+        pay = _selected_payload(["edge"], spec, names)
+        (resp,) = eng.search(pay)
         rows = host_match_rows(shard, spec, ref_wildcard=True)
-        payload = VariantQueryPayload(
-            dataset_ids=["edge"],
-            reference_name="1",
-            start_min=spec.start_min,
-            start_max=spec.start_max,
-            end_min=1,
-            end_max=1 << 30,
-            alternate_bases="N",
-            requested_granularity="record",
-            include_datasets="HIT",
-            include_samples=True,
-            selected_samples_only=True,
-            sample_names={"edge": names},
-        )
-        resp = materialize_response(
-            shard, rows, payload, chrom_label="1", dataset_id="edge",
+        want = materialize_response(
+            shard, rows, pay, chrom_label="1", dataset_id="edge",
             selected_idx=selected,
         )
-        got_words = per_ds["or_words"][0, qi].view(np.uint32)
-        got_bits = np.unpackbits(
-            got_words.view(np.uint8), bitorder="little"
-        ).astype(bool)
-        got_sel = [k for k, si in enumerate(selected) if got_bits[si]]
-        assert got_sel == resp.sample_indices, (qi, got_sel, resp)
-        assert int(agg["call_count"][qi]) == resp.call_count, qi
+        assert resp.sample_indices == want.sample_indices, (spec, resp)
+        assert resp.call_count == want.call_count, spec
+        hits.append(resp.sample_indices)
     # (a) must see S1's hit; (b) must NOT include S2 (ac=0 record is
     # before k0) but must include S0
-    q0_bits = per_ds["or_words"][0, 0].view(np.uint32)
-    assert q0_bits.any(), "first-record-only query lost its sample hits"
+    assert hits[0] == [1], "first-record-only query lost its sample hits"
+    assert hits[1] == [0]
 
 
 def _genotype_derived_engines(n_ds=4, seed0=900):
@@ -537,11 +479,11 @@ def _genotype_derived_engines(n_ds=4, seed0=900):
     return out
 
 
-def test_mesh_serves_selected_samples_as_one_program():
-    """VERDICT r4 next #3: a multi-dataset selected-samples query through
-    the engine runs sharded_selected_query (mesh_selected_searches
-    increments) and returns oracle-equal per-dataset sample hits —
-    layout-4 dryrun semantics served end-to-end."""
+def test_mesh_engine_serves_selected_samples_from_the_owners():
+    """A multi-dataset selected-samples query through a mesh engine
+    reads the one resident copy of the planes, on their owner chips
+    (no mesh launch, no second copy in the stack), and returns
+    oracle-equal per-dataset sample hits."""
     em, et = _genotype_derived_engines()
     for gran in ("record", "count", "boolean"):
         for details in (True, False):
@@ -552,9 +494,8 @@ def test_mesh_serves_selected_samples_as_one_program():
                 requested_granularity=gran,
                 include_datasets="HIT" if details else "NONE",
             )
-            before = em.mesh_selected_searches
             rm, rt = em.search(pay), et.search(pay)
-            assert em.mesh_selected_searches == before + 1
+            assert em.mesh_searches == 0
             _assert_same(rm, rt)
     # narrow-window selected queries (per-record loop oracle)
     from sbeacon_tpu.engine import host_match_rows, materialize_response_loop
@@ -596,10 +537,9 @@ def test_mesh_serves_selected_samples_as_one_program():
 
 
 def test_mesh_selected_heterogeneous_sample_widths():
-    """Shards with DIFFERENT sample counts (plane widths) must still be
-    served by the mesh selected path — or_words come back stack-wide
-    and must truncate to each shard's own width (regression: ValueError
-    broadcast crash silently demoted every such query to scatter)."""
+    """Shards with DIFFERENT sample counts (plane widths) are each
+    served from their own planes on their owner, in their own width,
+    by a mesh engine as by a scatter engine."""
     out = []
     widths = [3, 40, 70]  # 1, 2, 3 plane words
     for use_mesh in (True, False):
@@ -634,7 +574,9 @@ def test_mesh_selected_heterogeneous_sample_widths():
         include_samples=True,
     )
     rm, rt = em.search(pay), et.search(pay)
-    assert em.mesh_selected_searches == 1, (
-        "heterogeneous widths must not demote the mesh selected path"
+    assert em.mesh_searches == 0
+    widths_held = sorted(
+        p.n_words for _k, _s, p in em.index_snapshot() if p is not None
     )
+    assert widths_held == [1, 2, 3]
     _assert_same(rm, rt)

@@ -478,8 +478,8 @@ def _assert_same_responses(ra, rb):
 @multi_device
 def test_plane_budget_flip_drifts_within_one_window(tmp_path):
     """The acceptance scenario end to end: shrinking the plane HBM
-    budget flips selected-samples serving from the mesh planes leg to
-    the planeless road. Within ONE window the sentinel publishes
+    budget flips selected-samples serving from the planes on their
+    owner chips to the host's copy. Within ONE window the sentinel publishes
     ``plan.drift``, ``/debug/status`` names the query shape,
     ``/ops/plans`` shows the new dominant with an exemplar resolving
     through ``/_trace`` — and the answers stay byte-identical."""
@@ -489,9 +489,10 @@ def test_plane_budget_flip_drifts_within_one_window(tmp_path):
         BeaconConfig(engine=EngineConfig(microbatch=False))
     )
     samples = ["S0", "S1", "S2"]
+    shards = []
     for d in range(3):
         rng = random.Random(500 + d)
-        eng.add_index(
+        shards.append(
             build_index(
                 random_records(rng, chrom="1", n=200, n_samples=3),
                 dataset_id=f"d{d}",
@@ -499,6 +500,7 @@ def test_plane_budget_flip_drifts_within_one_window(tmp_path):
                 sample_names=samples,
             )
         )
+        eng.add_index(shards[-1])
     cfg = BeaconConfig(
         engine=EngineConfig(microbatch=False),
         observability=ObservabilityConfig(slow_query_ms=-1.0),
@@ -522,27 +524,30 @@ def test_plane_budget_flip_drifts_within_one_window(tmp_path):
         with tracer.enabled():
             before = run_window()
             assert app.plans.roll_window() == []
-            # no-op republish: the stack rebuilds under the SAME
-            # budget — the dominant shape must not move
-            eng._mesh_dirty = True
+            # no-op republish: the planes land on their owners again
+            # under the SAME budget — the dominant shape must not move
+            for shard in shards:
+                eng.add_index(shard)
             run_window()
             assert app.plans.roll_window() == []
             assert app.plans.drifted_shapes() == []
-            # the seeded regression: a budget no plane set fits
+            # the seeded regression: a budget no plane set fits, so
+            # the next publish leaves every dataset's planes on the host
             eng.config = dataclasses.replace(
                 eng.config,
                 engine=dataclasses.replace(
                     eng.config.engine, plane_hbm_budget_gb=1e-9
                 ),
             )
-            eng._mesh_dirty = True
+            for shard in shards:
+                eng.add_index(shard)
             after = run_window()
             drifts = app.plans.roll_window()
             assert len(drifts) == 1
             d = drifts[0]
             assert d["shape"] == qshape and d["from"] != d["to"]
             # the new dominant names the alternative not taken and why
-            assert "planes_declined" in d["to"]
+            assert "host_planes" in d["to"]
             assert "planes_budget" in d["to"]
             # byte-identical answers across the flip
             _assert_same_responses(before[0], after[0])
@@ -562,7 +567,8 @@ def test_plane_budget_flip_drifts_within_one_window(tmp_path):
             _, metrics = app.handle("GET", "/metrics")
             assert metrics["plan"]["drift"] == {qshape: 1}
             # /ops/plans: the aggregate shows the flip with a sampled
-            # exemplar, and the declined stage cites measured headroom
+            # exemplar, and the stage says how many datasets it read
+            # from the host
             s, plans = app.handle("GET", "/ops/plans")
             assert s == 200
             agg = plans["shapes"][qshape]
@@ -572,10 +578,10 @@ def test_plane_budget_flip_drifts_within_one_window(tmp_path):
             declined = [
                 e
                 for e in new["sampledStages"]
-                if e.get("decision") == "planes_declined"
+                if e.get("decision") == "host_planes"
             ]
             assert declined
-            assert declined[0]["detail"]["headroom_bytes"] < 0
+            assert declined[0]["detail"]["datasets"] == 3
             # ... and the exemplar resolves through /_trace
             exemplar = new["exemplarTraceIds"][0]
             s, tr = app.handle(

@@ -381,7 +381,7 @@ def test_debug_status_schema_and_diagnosis(app):
     # device-plane rollup (ISSUE 14): launch decomposition + padding
     # waste + mid-request compile count ride the same document
     assert set(doc["device"]) == {
-        "launches", "padWaste", "midRequestCompiles",
+        "launches", "padWaste", "midRequestCompiles", "placement",
     }
     assert doc["device"]["launches"]["total"] >= 0
     assert set(doc["diagnosis"]) == {

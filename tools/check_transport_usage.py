@@ -63,18 +63,6 @@ ROUTE_PATTERN = re.compile(
     r"|\.replica_table\(\s*[^)]*\)\s*\["
 )
 
-#: plane-shape mesh dispatch flows through ONE seam (ISSUE 13): the
-#: engine's own mesh serving path and ``parallel/mesh.py`` (which
-#: defines it). Any other module reaching for
-#: ``sharded_selected_query`` re-opens a second plane-dispatch path
-#: that the MeshDispatchTier's resolve/refusal telemetry cannot see —
-#: exactly the per-dataset fan-out the single-launch tier removed.
-SELECTED_QUERY_ALLOWED = {
-    "engine.py",
-    "parallel/mesh.py",
-}
-SELECTED_QUERY_PATTERN = re.compile(r"\bsharded_selected_query\b")
-
 
 def scan(root: Path = PKG) -> list[str]:
     """["file:line: matched text"] for every disallowed urlopen use or
@@ -100,17 +88,6 @@ def scan(root: Path = PKG) -> list[str]:
                 "(dispatch.ReplicaRouter.pick), never by indexing the "
                 "route table (loses failover and p2c routing)"
             )
-        if rel not in SELECTED_QUERY_ALLOWED:
-            for m in SELECTED_QUERY_PATTERN.finditer(src):
-                line = src[: m.start()].count("\n") + 1
-                hits.append(
-                    f"sbeacon_tpu/{rel}:{line}: {m.group(0)!r} — "
-                    "plane-shape dispatch flows through the mesh "
-                    "tier's single seam (MeshDispatchTier / the "
-                    "engine's mesh path); importing "
-                    "sharded_selected_query elsewhere re-opens a "
-                    "per-dataset plane fan-out the tier cannot see"
-                )
     return hits
 
 
