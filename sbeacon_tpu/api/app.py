@@ -247,9 +247,11 @@ class BeaconApp:
             self.config, engine=ingest_engine, store=self.store
         )
         self.env = Envelopes(self.config.info)
-        # async query job table (VariantQueries/VariantQueryResponses roles):
-        # coalesces concurrent identical queries, caches results for the
-        # query TTL, spills oversized response sets to query_results_dir
+        # async query runner over the job table (VariantQueries/
+        # VariantQueryResponses roles): coalesces concurrent identical
+        # queries and caches results for the query TTL in memory; its
+        # writer thread journals finished jobs to the table (oversized
+        # response sets spilled to query_results_dir) for a restart
         storage.ensure()
         self.query_jobs = QueryJobTable(
             storage.root / "query-jobs.sqlite",
@@ -386,7 +388,8 @@ class BeaconApp:
 
     def close(self) -> None:
         """Release app-owned resources: the async runner's worker pool
-        and the job table. The engine is NOT closed here — it may be
+        and writer thread (which stores what is queued first), then the
+        job table. The engine is NOT closed here — it may be
         caller-owned and shared (pass-in wiring); call engine.close()
         separately when this app owns it."""
         self.query_runner.close()

@@ -21,8 +21,10 @@ Instrumented sites:
 - ``kernel.launch`` — device kernel dispatch (``serving.py``
   micro-batch execute and ``engine.py`` direct path).
 - ``sqlite.commit`` — job-table persistence commits
-  (``query_jobs.py``); ``latency`` here models the WAL-checkpoint
-  fsync stalls the r5 soak chased.
+  (``query_jobs.py``): once a transaction of the runner's writer
+  thread (``detail`` ``write_jobs``), so ``latency`` here (the
+  WAL-checkpoint fsync stalls the r5 soak chased) delays rows and no
+  request, and an ``error`` loses that batch's rows to a restart only.
 - ``admission.queue`` — the tenant fair-queue admission path
   (``shaping.py FairQueueAdmission.acquire``); ``detail`` is
   ``tenant:lane``, so a rule can target one tenant or lane with

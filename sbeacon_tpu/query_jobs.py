@@ -16,9 +16,33 @@ the whole query (SURVEY.md §2.5) — but the *job* semantics remain useful
 and are implemented for real rather than stubbed: request-hash keyed
 jobs, RUNNING detection (concurrent identical queries coalesce), COMPLETE
 result caching with TTL, spill-to-file for oversized response sets, and a
-crash-surviving sqlite ledger (same pattern as ``ingest.ledger``). The
-``fan_out``/``responses`` counters are kept per job for observability
-parity with the reference's table schema.
+crash-surviving sqlite ledger (same pattern as ``ingest.ledger``).
+
+What is written when, and by which thread. In one process everything a
+request needs is in the runner's memory: ``_results`` (the finished jobs'
+hand-offs, kept for the query TTL), ``_done`` (the jobs in flight: the
+single-flight registry and the claim) and ``QueryJobTable.restored`` (the
+completed rows a restart found, id -> expiry). A request reads those
+under the runner's lock and runs NO sqlite statement unless its id is a
+restored row. Nothing is written for a job that is merely RUNNING. A job
+that finished whole is handed to its waiters, then queued for the
+runner's ONE writer thread, which takes whatever is queued and writes it
+in one transaction (``QueryJobTable.write_jobs``: the query row already
+complete, its response rows, oversized bodies spilled first), commits
+once and loops: group commit with no timer, so an idle server writes a
+job at once and a busy one shares the commit. The minute's TTL purge and
+WAL checkpoint run on that thread between batches. So the table is a
+write-behind journal: it answers what memory cannot only after a restart
+(completed jobs inside their TTL survive with their spills; a job in
+flight at a crash left no row and reads NEW). The client was never told
+its result was durable: the hand-off precedes the write, as it always
+did. Degraded (partial) and failed results are never queued.
+
+The step-by-step methods (``start``, ``next_response_number``,
+``put_response``, ``mark_finished``, ``complete``, ``abandon``, ``wait``)
+are the reference's state machine, row by row, and are kept as such; the
+runner no longer calls them. The ``fan_out``/``responses`` counters are
+kept per job for observability parity with the reference's table schema.
 """
 
 from __future__ import annotations
@@ -27,12 +51,14 @@ import dataclasses
 import hashlib
 import json
 import logging
+import queue
 import sqlite3
 import threading
 import time
 import uuid
 from enum import Enum
 from pathlib import Path
+from typing import NamedTuple
 
 from concurrent.futures import ThreadPoolExecutor
 
@@ -71,13 +97,25 @@ def hash_query(doc: dict | str) -> str:
     return hashlib.md5(doc.encode()).hexdigest()
 
 
+class FinishedJob(NamedTuple):
+    """A job that finished whole, as the runner hands it to its writer:
+    everything its rows hold (``QueryJobTable.write_jobs``)."""
+
+    query_id: str
+    responses: list
+    start_time: float
+    end_time: float
+    expires_at: float
+
+
 class _TableLock:
-    """The job table's one lock. Every request takes it half a dozen
-    times (status, claim, responses, completion), so under load request
-    threads queue here: that wait is the ``runner.table_wait`` stage,
-    timed only when the lock is contended. The sample is handed to the
-    stage after the release: whoever holds this lock holds up every
-    other request, so nothing but the table's own work runs under it."""
+    """The job table's one lock, around its one connection. The writer
+    thread holds it for a batch's transaction and for the sweep; a
+    request takes it only to read a restored job's responses. A thread
+    that finds it taken waits: that wait is the ``runner.table_wait``
+    stage, timed only when the lock is contended. The sample is handed
+    to the stage after the release, so nothing but the table's own work
+    runs under the lock."""
 
     __slots__ = ("_lock", "_waited")
 
@@ -107,7 +145,8 @@ class QueryJobTable:
     """Sqlite-backed VariantQueries + VariantQueryResponses equivalent.
 
     Thread-safe within a process (one lock around the shared connection,
-    matching ``ingest.ledger``); durable across restarts.
+    matching ``ingest.ledger``); durable across restarts: ``restored``
+    holds what this open found.
     """
 
     def __init__(
@@ -129,9 +168,9 @@ class QueryJobTable:
         # NO auto-checkpoint: whichever commit crosses the page
         # threshold absorbs the full checkpoint fsync — on the serving
         # thread that was a >1 s p99 outlier with warm kernels. The
-        # runner's background purge sweep calls checkpoint() instead
-        # (WAL growth bounded by one sweep interval of TTL'd cache
-        # traffic).
+        # runner's writer thread calls checkpoint() between batches
+        # instead (WAL growth bounded by one sweep interval of TTL'd
+        # cache traffic).
         self._conn.execute("PRAGMA wal_autocheckpoint=0")
         self._lock = _TableLock()
         self.spill_dir = Path(spill_dir) if spill_dir else None
@@ -192,10 +231,114 @@ class QueryJobTable:
                     "DELETE FROM variant_query_responses WHERE query_id = ?",
                     (qid,),
                 )
+            #: the completed jobs that survived the restart and can
+            #: still answer, id -> expires_at: read once, here. The
+            #: runner consults it from memory (under its own lock) and
+            #: drops entries as they expire; after ``query_ttl_s`` it is
+            #: empty and stays so.
+            self.restored: dict[str, float] = dict(
+                self._conn.execute(
+                    "SELECT id, expires_at FROM variant_queries"
+                    " WHERE complete = 1 AND expires_at > ?",
+                    (time.time(),),
+                )
+            )
         for (p,) in spilled:
             Path(p).unlink(missing_ok=True)
 
-    # -- job lifecycle -------------------------------------------------------
+    # -- finished jobs, written whole ----------------------------------------
+
+    def _spill(self, body: str) -> tuple[str | None, str | None]:
+        """(body, spill_path) as a response row holds them: a body past
+        ``inline_limit`` goes to a file of ``spill_dir`` — reference
+        performQuery/search_variants.py:282-300."""
+        if len(body) <= self.inline_limit or self.spill_dir is None:
+            return body, None
+        spill_path = str(self.spill_dir / f"{uuid.uuid4()}.json")
+        Path(spill_path).write_text(body)
+        return None, spill_path
+
+    def write_jobs(self, jobs: list[FinishedJob]) -> None:
+        """Write finished jobs in ONE transaction: per job the query row
+        already complete and one row a response, oversized bodies
+        spilled before the transaction opens. Rows an earlier run of the
+        same id left behind (its response rows outlive the query row)
+        are replaced and their spills unlinked. If the commit fails
+        nothing of the batch is stored and its spills are removed."""
+        now = time.time()
+        query_rows, response_rows, spills = [], [], []
+        for job in jobs:
+            for n, resp in enumerate(job.responses, 1):
+                body, spill_path = self._spill(resp.dumps())
+                if spill_path:
+                    spills.append(spill_path)
+                response_rows.append(
+                    (job.query_id, n, body, spill_path,
+                     now + self.response_ttl_s)
+                )
+            n = len(job.responses)
+            query_rows.append(
+                (job.query_id, uuid.uuid4().hex, 1, 0, n, n, job.start_time,
+                 job.end_time, job.end_time - job.start_time,
+                 job.expires_at)
+            )
+        try:
+            fault_point("sqlite.commit", "write_jobs")
+            with self._lock, self._conn:
+                stale = self._in_chunks(
+                    "DELETE FROM variant_query_responses WHERE query_id IN"
+                    " (VALUES {}) RETURNING spill_path",
+                    [(job.query_id,) for job in jobs],
+                )
+                self._in_chunks(
+                    "INSERT OR REPLACE INTO variant_queries"
+                    " (id, claim, complete, fan_out, responses,"
+                    " responses_counter, start_time, end_time,"
+                    " elapsed_time, expires_at) VALUES {}",
+                    query_rows,
+                )
+                self._in_chunks(
+                    "INSERT INTO variant_query_responses"
+                    " (query_id, response_number, body, spill_path,"
+                    " expires_at) VALUES {}",
+                    response_rows,
+                )
+        except BaseException:
+            for p in spills:
+                Path(p).unlink(missing_ok=True)
+            raise
+        for (p,) in stale:
+            if p:
+                Path(p).unlink(missing_ok=True)
+
+    #: bound values a statement of ``_in_chunks`` carries at most (sqlite
+    #: builds before 3.32 refuse more than 999)
+    MAX_BOUND = 900
+
+    def _in_chunks(self, sql: str, rows: list[tuple]) -> list:
+        """Run ``sql`` once for as many ``rows`` as one statement can
+        bind (``{}`` takes their placeholders, a row in parentheses), and
+        return what the statements return.
+        Not ``executemany``: sqlite3 steps that once a row, and gives the
+        interpreter lock up for every step; under a serving load each
+        such hand-over costs a thread's wake-up (PERF.md 6, PR 25), so a
+        job of 32 responses is written in as many statements as a job of
+        one."""
+        out = []
+        if not rows:
+            return out
+        width = len(rows[0])
+        one = "(" + ",".join("?" * width) + ")"
+        per = self.MAX_BOUND // width
+        for i in range(0, len(rows), per):
+            chunk = rows[i:i + per]
+            out += self._conn.execute(
+                sql.format(",".join([one] * len(chunk))),
+                [value for row in chunk for value in row],
+            ).fetchall()
+        return out
+
+    # -- job lifecycle, step by step (the reference's state machine) ---------
 
     def get_job_status(self, query_id: str) -> JobStatus:
         """The un-stubbed version of reference variant_queries.py:94-103."""
@@ -287,12 +430,7 @@ class QueryJobTable:
         """Store one worker response, spilling past ``inline_limit`` —
         reference performQuery/search_variants.py:282-300. Refused (False)
         when the claim is no longer held."""
-        body = resp.dumps()
-        spill_path = None
-        if len(body) > self.inline_limit and self.spill_dir is not None:
-            spill_path = str(self.spill_dir / f"{uuid.uuid4()}.json")
-            Path(spill_path).write_text(body)
-            body = None
+        body, spill_path = self._spill(resp.dumps())
         fault_point("sqlite.commit", "put_response")
         now = time.time()
         with self._lock, self._conn:
@@ -450,9 +588,10 @@ class QueryJobTable:
         return n
 
     def checkpoint(self) -> None:
-        """WAL checkpoint + truncate — called from the runner's
-        background sweep so no serving-thread commit ever absorbs the
-        checkpoint fsync (auto-checkpoint is disabled)."""
+        """WAL checkpoint + truncate — called from the runner's writer
+        thread between batches, so no commit ever absorbs the
+        checkpoint fsync (auto-checkpoint is disabled) and no request
+        waits for it."""
         with self._lock:
             self._conn.execute("PRAGMA wal_checkpoint(TRUNCATE)")
 
@@ -461,18 +600,27 @@ class QueryJobTable:
             self._conn.close()
 
 
+#: what ``close()`` queues behind the last job: the writer writes
+#: everything ahead of it, then leaves
+_CLOSE = object()
+
+
 class AsyncQueryRunner:
     """Background execution + result caching over a :class:`QueryJobTable`.
 
-    ``submit`` hashes the payload, coalesces concurrent identical queries,
-    runs ``engine.search`` on a worker thread, stores the per-(dataset,vcf)
-    response set through the job table (spill included), and completes the
-    job; ``poll``/``result`` give the async API surface the reference's
-    RUNNING/COMPLETED envelope switch needs
-    (route_g_variants.py:199-214 elif status == JobStatus.RUNNING).
+    ``submit`` hashes the payload and decides from memory: a finished
+    job's hand-off answers a repeat, a job in flight takes the caller on
+    (single-flight), a row a restart left answers from the table, and
+    anything else is claimed and runs ``engine.search`` on a worker
+    thread. A job that finished whole goes to the one writer thread,
+    which stores the per-(dataset,vcf) response set through the job table
+    (spill included) in grouped commits; ``poll``/``result`` give the
+    async API surface the reference's RUNNING/COMPLETED envelope switch
+    needs (route_g_variants.py:199-214 elif status == JobStatus.RUNNING).
     """
 
-    #: seconds between opportunistic TTL sweeps piggybacked on submit()
+    #: seconds between the writer thread's sweeps: the table's TTL purge
+    #: and WAL checkpoint, the expired hand-offs and restored ids
     PURGE_INTERVAL_S = 60.0
     #: in-memory lifetime of a PARTIAL (replicas-down, degraded) result:
     #: long enough to hand to the waiters coalesced onto the job, far
@@ -536,16 +684,16 @@ class AsyncQueryRunner:
         self._gate = AdmissionController(
             self.max_pending, retry_after_s=self.shed_retry_after_s
         )
-        # in-process completion events: waiters block on these instead of
-        # polling sqlite; cross-process (or post-restart) waiters fall
-        # back to the table's poll loop
+        # the jobs in flight, by id: the single-flight registry AND the
+        # claim (whoever inserts the event runs the job); waiters block
+        # on the event
         self._done: dict[str, threading.Event] = {}
-        # in-process result handoff: (responses, expiry) — waiters read
-        # these directly, skipping the sqlite round-trip + re-parse
-        self._results: dict[str, tuple[list, float]] = {}
+        # the finished jobs' hand-off: (responses, expiry, unavailable,
+        # clock reading of the hand-off) — waiters and repeats inside
+        # the TTL read these; sqlite is not on their way
+        self._results: dict[str, tuple] = {}
+        # guards _done, _results, table.restored and the counters below
         self._lock = threading.Lock()
-        self._last_purge = time.time()
-        self._sweeper: threading.Thread | None = None
         # admission-wait decomposition: submit -> execution start on
         # the bounded pool (the stage BEFORE the batcher's queue wait)
         # is the ``runner.wait`` stage; the runner.queue_wait_ms
@@ -556,9 +704,24 @@ class AsyncQueryRunner:
         self._n_submits = 0
         self._n_memory_hits = 0
         self._n_table_hits = 0
+        # the write-behind journal: finished jobs wait here for the one
+        # thread that writes to the table. Its counters are its own.
+        self._queue: queue.SimpleQueue = queue.SimpleQueue()
+        self._n_persisted_jobs = 0
+        self._n_persist_commits = 0
+        self._n_persist_expired = 0
+        self._next_sweep = time.monotonic() + self.PURGE_INTERVAL_S
+        self._writer = threading.Thread(
+            target=self._write_loop, name="query-jobs-writer", daemon=True
+        )
+        self._writer.start()
 
     def close(self) -> None:
+        """Stop the pool, and let the writer write what is queued before
+        it leaves: the caller closes the table next."""
         self._pool.shutdown(wait=False, cancel_futures=True)
+        self._queue.put(_CLOSE)
+        self._writer.join()
 
     def metrics(self) -> dict:
         gate = self._gate.metrics()
@@ -620,8 +783,31 @@ class AsyncQueryRunner:
         )
         registry.counter(
             "runner.table_hits",
-            "submits answered COMPLETED by the job table",
+            "submits answered COMPLETED by the job table "
+            "(a row that survived a restart)",
             fn=lambda: self._n_table_hits,
+        )
+        registry.counter(
+            "runner.persisted_jobs",
+            "finished jobs the writer thread stored in the job table",
+            fn=lambda: self._n_persisted_jobs,
+        )
+        registry.counter(
+            "runner.persist_commits",
+            "job-table transactions the writer thread committed "
+            "(each stores every job that was waiting)",
+            fn=lambda: self._n_persist_commits,
+        )
+        registry.counter(
+            "runner.persist_expired",
+            "finished jobs skipped by the writer: their TTL had "
+            "lapsed before it reached them",
+            fn=lambda: self._n_persist_expired,
+        )
+        registry.gauge(
+            "runner.persist_queue",
+            "finished jobs waiting for the writer thread",
+            fn=self._queue.qsize,
         )
         # the admission-wait slice of the queue-wait decomposition
         # (/debug/status composes it ahead of the batcher stages)
@@ -629,16 +815,6 @@ class AsyncQueryRunner:
             "runner.queue_wait_ms",
             "async-runner submit -> execution-start wait",
         )
-
-    def _note_coalesced(self) -> None:
-        with self._lock:
-            self._coalesced += 1
-        annotate(query_job="coalesced")
-
-    def _release_bulk(self, bulk_slot: bool) -> None:
-        if bulk_slot:
-            with self._lock:
-                self._bulk_active -= 1
 
     def _note_queue_wait(self, wait_ms: float) -> None:
         tracer.observe("runner.wait", wait_ms)
@@ -653,39 +829,72 @@ class AsyncQueryRunner:
         /debug/status."""
         return tracer.stage_quantiles("runner.wait")
 
-    def _maybe_purge(self) -> None:
-        now = time.time()
-        with self._lock:
-            if now - self._last_purge < self.PURGE_INTERVAL_S:
+    # -- the writer thread ---------------------------------------------------
+
+    def _write_loop(self) -> None:
+        """Everything that writes to the table runs here, and nowhere
+        else. Take all that is queued, store it in one transaction,
+        loop: a job waits for the commit ahead of it and for nothing
+        else. An empty queue is waited on until the next sweep is due."""
+        log = logging.getLogger(__name__)
+        while True:
+            batch, closing = [], False
+            try:
+                job = self._queue.get(
+                    timeout=max(0.0, self._next_sweep - time.monotonic())
+                )
+                while True:
+                    if job is _CLOSE:
+                        closing = True
+                    else:
+                        batch.append(job)
+                    job = self._queue.get_nowait()
+            except queue.Empty:
+                pass
+            # this thread must outlive a failed commit (a full disk, an
+            # injected fault): the jobs stay served from memory for
+            # their TTL and are only not there after a restart
+            try:
+                self._persist(batch)
+            except Exception:
+                log.exception("job table: %d jobs not stored", len(batch))
+            if closing:
                 return
-            # one sweeper at a time: a slow sweep (WAL checkpoint on a
-            # busy disk) must not stack a fresh thread every interval
-            if self._sweeper is not None and self._sweeper.is_alive():
-                self._last_purge = now  # re-check next interval, not
-                return  # on every submit meanwhile
+            if time.monotonic() >= self._next_sweep:
+                try:
+                    self._sweep()
+                except Exception:
+                    log.exception("job table: sweep failed")
 
-            # the sweep DELETEs + commits — run it off the serving
-            # thread (piggybacked purges used to stall ~1 request per
-            # minute by a full fsync; the r5 soak tail caught it)
-            def sweep():
-                self.table.purge_expired()
-                self.table.checkpoint()
-                with self._lock:
-                    # (responses, expiry, unavailable)
-                    dead = [
-                        q
-                        for q, hit in self._results.items()
-                        if hit[1] <= now
-                    ]
-                    for q in dead:
-                        del self._results[q]
+    def _persist(self, batch: list[FinishedJob]) -> None:
+        now = time.time()
+        live = [job for job in batch if job.expires_at > now]
+        self._n_persist_expired += len(batch) - len(live)
+        if not live:
+            return
+        # one sample a transaction, serving its jobs
+        with tracer.serving(len(live)), stage("runner.persist"):
+            self.table.write_jobs(live)
+        self._n_persisted_jobs += len(live)
+        self._n_persist_commits += 1
 
-            self._last_purge = now
-            t = threading.Thread(
-                target=sweep, name="query-jobs-purge", daemon=True
-            )
-            self._sweeper = t
-        t.start()
+    def _sweep(self) -> None:
+        """TTL enforcement, once a ``PURGE_INTERVAL_S``: the table's
+        expired rows and spills, its WAL (a checkpoint fsync of 1-2 s on
+        a busy disk: why it runs here, where no request waits for it),
+        the expired hand-offs and restored ids."""
+        self._next_sweep = time.monotonic() + self.PURGE_INTERVAL_S
+        self.table.purge_expired()
+        self.table.checkpoint()
+        now = time.time()
+        restored = self.table.restored
+        with self._lock:
+            for q in [q for q, hit in self._results.items() if hit[1] <= now]:
+                del self._results[q]
+            for q in [q for q, exp in restored.items() if exp <= now]:
+                del restored[q]
+
+    # -- the request's side --------------------------------------------------
 
     def submit(
         self, payload, *, fingerprint: str | None = None
@@ -697,86 +906,73 @@ class AsyncQueryRunner:
             return self._submit(payload, fingerprint)
 
     def _submit(self, payload, fingerprint) -> tuple[str, JobStatus]:
-        self._maybe_purge()
         query_id = hash_query(
             {"payload": dataclasses.asdict(payload), "fp": fingerprint}
         )
-        # in-memory results are authoritative the moment the search
-        # finished — the table may still be mid-persistence (background)
-        with self._lock:
-            self._n_submits += 1
-            hit = self._results.get(query_id)
-            fresh = hit is not None and hit[1] > time.time()
-            if fresh:
-                self._n_memory_hits += 1
-        if fresh:
-            # job-layer outcome notes (telemetry): a repeat served here
-            # never reaches engine.search, so the slow-query log would
-            # otherwise show an unexplained fast request
-            annotate(query_job="memory_hit")
-            return query_id, JobStatus.COMPLETED
-        status = self.table.get_job_status(query_id)
-        if status is JobStatus.COMPLETED:
-            with self._lock:
-                self._n_table_hits += 1
-            annotate(query_job="table_hit")
-            return query_id, status
-        if status is JobStatus.RUNNING:
-            # single-flight: coalesce onto the in-flight execution —
-            # consumes no pool slot, so it must happen before the
-            # capacity gate (and before the bulk-lane cap: a follower
-            # attaches to the leader's pending result, it adds no work)
-            self._note_coalesced()
-            return query_id, status
         # lane-aware admission: the ambient lane note (set by the API
         # layer's classifier) decides whether this submission draws
         # from the bulk share of the pending slots
-        ctx = current_context()
-        lane = (ctx.notes.get("lane") if ctx is not None else None) or (
-            "interactive"
-        )
-        bulk_slot = False
-        if lane == "bulk":
-            with self._lock:
-                if self._bulk_active >= self._bulk_cap:
-                    raise Overloaded(
-                        f"query runner bulk lane at capacity "
-                        f"({self._bulk_cap} of {self.max_pending} slots)",
-                        retry_after_s=self.shed_retry_after_s,
-                    )
-                self._bulk_active += 1
-                bulk_slot = True
-        # reserve a pool slot BEFORE claiming: shedding after a claim
-        # would leave the job RUNNING with nobody executing it, stalling
-        # coalesced waiters for the full TTL. Coalescing onto an
-        # existing claim consumes no slot and is never shed.
-        if not self._gate.try_acquire():
-            self._release_bulk(bulk_slot)
+        job_ctx = current_context()
+        bulk = job_ctx is not None and job_ctx.notes.get("lane") == "bulk"
+        # ONE critical section looks the id up and, on a miss, claims
+        # it: whoever inserts into ``_done`` runs the job, so concurrent
+        # identical requests run once. No sqlite statement is on this
+        # path: memory knows every job of this process, ``restored``
+        # every row of the last one.
+        with self._lock:
+            self._n_submits += 1
+            now = time.time()
+            hit = self._results.get(query_id)
+            if hit is not None and hit[1] > now:
+                # authoritative the moment the search finished (the
+                # table may not have the rows yet)
+                self._n_memory_hits += 1
+                outcome = "memory_hit"
+            elif query_id in self._done:
+                # single-flight: coalesce onto the in-flight execution
+                # — consumes no pool slot, so it comes before the
+                # capacity gate and the bulk-lane cap (a follower
+                # attaches to the leader's pending result, it adds no
+                # work) and is never shed
+                self._coalesced += 1
+                outcome = "coalesced"
+            elif self.table.restored.get(query_id, 0.0) > now:
+                self._n_table_hits += 1
+                outcome = "table_hit"
+            elif bulk and self._bulk_active >= self._bulk_cap:
+                outcome = "bulk_shed"
+            elif not self._gate.try_acquire():
+                # a pool slot is reserved BEFORE the claim: a claim
+                # that is then shed would leave waiters coalesced onto
+                # a job nobody executes
+                outcome = "shed"
+            else:
+                outcome = "claimed"
+                self._bulk_active += bulk
+                self._done[query_id] = done = threading.Event()
+                self._results.pop(query_id, None)  # expired
+                self.table.restored.pop(query_id, None)  # expired
+        if outcome == "bulk_shed":
+            raise Overloaded(
+                f"query runner bulk lane at capacity "
+                f"({self._bulk_cap} of {self.max_pending} slots)",
+                retry_after_s=self.shed_retry_after_s,
+            )
+        if outcome == "shed":
             raise Overloaded(
                 f"query runner at capacity ({self.max_pending} pending)",
                 retry_after_s=self.shed_retry_after_s,
             )
-        try:
-            claim = self.table.start(query_id, fan_out=1)
-        except BaseException:
-            # a failed claim (sqlite locked, disk full) must release
-            # the reserved slot, or leaks accumulate until every
-            # submit sheds 429 against an idle pool
-            self._gate.release()
-            self._release_bulk(bulk_slot)
-            raise
-        if claim is None:
-            # someone else holds an unexpired claim: coalesce
-            self._gate.release()
-            self._release_bulk(bulk_slot)
-            self._note_coalesced()
-            return query_id, JobStatus.RUNNING
+        if outcome != "claimed":
+            # job-layer outcome notes (telemetry): a repeat served here
+            # never reaches engine.search, so the slow-query log would
+            # otherwise show an unexplained fast request
+            annotate(query_job=outcome)
+            if outcome == "coalesced":
+                return query_id, JobStatus.RUNNING
+            return query_id, JobStatus.COMPLETED
 
         pl = dataclasses.replace(payload, query_id=query_id)
-        done = threading.Event()
-        with self._lock:
-            self._done[query_id] = done
-            self._results.pop(query_id, None)
         # the SPAWNING request's deadline rides into the worker thread
         # (thread-locals don't cross): the search abandons at its next
         # check-point once the deadline lapses — worker calls clamp,
@@ -787,8 +983,14 @@ class AsyncQueryRunner:
         # recorded on the pool thread — and the trace header on any
         # coordinator->worker hop — keep the ingress trace id.
         job_deadline = current_deadline()
-        job_ctx = current_context()
+        t_start = time.time()
         t_enqueue = time.perf_counter()
+
+        def release():
+            self._gate.release()
+            with self._lock:
+                self._bulk_active -= bulk
+                self._done.pop(query_id, None)
 
         def run():
             self._note_queue_wait(
@@ -804,19 +1006,18 @@ class AsyncQueryRunner:
                     # replica — dispatch annotated unavailable_datasets
                     # on the request context) must not be cached as THE
                     # answer for the query TTL: it is handed to the
-                    # waiters coalesced onto this job, then the job is
-                    # dropped so later identical queries re-execute
-                    # against the (possibly healed) routes instead of
-                    # replaying a stale empty result
+                    # waiters coalesced onto this job and kept a few
+                    # seconds, never stored, so later identical queries
+                    # re-execute against the (possibly healed) routes
+                    # instead of replaying a stale empty result
                     unavailable = tuple(
                         job_ctx.notes.get("unavailable_datasets") or ()
                         if job_ctx is not None
                         else ()
                     )
-                    partial = bool(unavailable)
                     ttl = (
                         self.PARTIAL_HANDOFF_TTL_S
-                        if partial
+                        if unavailable
                         else self.table.query_ttl_s
                     )
                     # the unavailable set rides WITH the cached handoff:
@@ -825,34 +1026,27 @@ class AsyncQueryRunner:
                     # incomplete answer
                     # (last rides the clock reading the hand-off was made
                     # at: the woken waiter's ``handoff.back`` starts there)
+                    end_time = time.time()
                     with self._lock:
                         self._results[query_id] = (
                             responses,
-                            time.time() + ttl,
+                            end_time + ttl,
                             unavailable,
                             time.perf_counter(),
                         )
                     # waiters are served from the in-memory handoff the
-                    # moment the search finishes; the sqlite persistence
-                    # below exists for cross-process/restart consumers
-                    # and must not sit on the request's critical path
-                    # (a WAL checkpoint fsync here was a >1 s soak-tail
-                    # outlier with the kernels fully warm)
+                    # moment the search finishes. The rows are for a
+                    # restart: the writer thread stores them, so that
+                    # neither this worker, its admission slot nor any
+                    # request waits for sqlite
                     done.set()
-                    with stage("runner.persist"):
-                        if partial:
-                            self.table.abandon(query_id, claim)
-                        else:
-                            for resp in responses:
-                                n = self.table.next_response_number(
-                                    query_id, claim
-                                )
-                                if n:
-                                    self.table.put_response(
-                                        query_id, n, resp, claim
-                                    )
-                            self.table.mark_finished(query_id, claim)
-                            self.table.complete(query_id, claim)
+                    if not unavailable:
+                        self._queue.put(
+                            FinishedJob(
+                                query_id, responses, t_start, end_time,
+                                end_time + ttl,
+                            )
+                        )
                 except Exception:
                     # never cache a failure as an empty result: drop the
                     # job so pollers fall back to a direct search (which
@@ -862,29 +1056,33 @@ class AsyncQueryRunner:
                     )
                     with self._lock:
                         self._results.pop(query_id, None)
-                    self.table.abandon(query_id, claim)
                 finally:
                     done.set()
-                    self._gate.release()
-                    self._release_bulk(bulk_slot)
-                    with self._lock:
-                        self._done.pop(query_id, None)
+                    release()
 
         try:
             self._pool.submit(run)
         except RuntimeError:
             # pool shut down (close() raced a late submit): release
             # everything so the job doesn't read RUNNING forever
-            self._gate.release()
-            self._release_bulk(bulk_slot)
-            with self._lock:
-                self._done.pop(query_id, None)
-            self.table.abandon(query_id, claim)
+            release()
             raise
         return query_id, JobStatus.RUNNING
 
     def poll(self, query_id: str) -> JobStatus:
-        return self.table.get_job_status(query_id)
+        """The job as this process knows it: in flight, answerable
+        (a whole result inside its TTL, in memory or restored), or NEW.
+        A degraded result is handed to waiters, never COMPLETED."""
+        now = time.time()
+        with self._lock:
+            if query_id in self._done:
+                return JobStatus.RUNNING
+            hit = self._results.get(query_id)
+            if hit is not None and hit[1] > now and not hit[2]:
+                return JobStatus.COMPLETED
+            if self.table.restored.get(query_id, 0.0) > now:
+                return JobStatus.COMPLETED
+        return JobStatus.NEW
 
     def result(
         self, query_id: str, *, wait_s: float = 0.0
@@ -893,27 +1091,17 @@ class AsyncQueryRunner:
         The wait is clamped by the caller's ambient request deadline."""
         waited = False
         if wait_s > 0:
-            wait_s = current_deadline().clamp(wait_s)
             with self._lock:
                 ev = self._done.get(query_id)
-                handed_off = query_id in self._results
             if ev is not None:
-                # in-process job: block on its completion event (no poll)
-                ev.wait(wait_s)
+                # block on the job's completion event (no poll)
+                ev.wait(current_deadline().clamp(wait_s))
                 waited = True
-            elif not handed_off and not self.table.wait(
-                query_id, timeout_s=wait_s
-            ):
-                # no in-memory handoff either; the table never
-                # completed (a PARTIAL job is abandoned there by
-                # design, so the handoff check must come first)
-                return None
-        # in-memory handoff FIRST: for in-process jobs the results exist
-        # the moment the search finishes, before (and regardless of) the
-        # background sqlite persistence
         with self._lock:
             hit = self._results.get(query_id)
-        if hit is not None and hit[1] > time.time():
+            restored = self.table.restored.get(query_id, 0.0)
+        now = time.time()
+        if hit is not None and hit[1] > now:
             if waited:
                 # parked on the job's event until the worker handed the
                 # result over: from its clock reading to this thread
@@ -927,6 +1115,8 @@ class AsyncQueryRunner:
                 # and a coalesced waiter has its own
                 annotate(unavailable_datasets=hit[2])
             return hit[0]
-        if self.table.get_job_status(query_id) is not JobStatus.COMPLETED:
-            return None
-        return self.table.get_responses(query_id)
+        if restored > now:
+            # the one read of the table a request makes: a job of the
+            # process before this one
+            return self.table.get_responses(query_id)
+        return None

@@ -68,13 +68,15 @@ STAGES = {
     # filters.resolve (readers of both count the outer one only)
     "filters.descendants": "work",
     # admission, runner
-    "runner.lookup": "work",  # hash, memory and table status, claim
+    "runner.lookup": "work",  # hash, the lookup in memory and the claim
     "runner.wait": "wait",  # submit -> execution start on the pool
-    # waiting for the job table's one lock, inside runner.lookup and
-    # runner.persist (readers subtract it from them); timed only when
-    # the lock is contended
+    # waiting for the job table's one lock (the writer's transaction, a
+    # restored job's read; readers subtract it from the work stages
+    # around it); timed only when the lock is contended
     "runner.table_wait": "wait",
-    "runner.persist": "work",  # sqlite persistence after the hand-off
+    # the writer thread's transaction: one sample a commit, serving the
+    # finished jobs it stores
+    "runner.persist": "work",
     # response cache
     "cache.lookup": "work",
     # engine

@@ -528,20 +528,25 @@ def test_runner_bounded_pool_sheds_not_spawns():
 
 @resilience
 def test_runner_releases_slot_when_claim_fails(monkeypatch):
-    """A table.start that raises (sqlite locked, disk full) must not
-    leak the reserved pool slot — leaks would eventually shed every
-    submit against an idle pool."""
-    from sbeacon_tpu.query_jobs import AsyncQueryRunner, QueryJobTable
+    """A claim that cannot be handed to the pool (it was shut down under
+    a late submit) must leak neither the reserved slot — leaks would
+    eventually shed every submit against an idle pool — nor the claim:
+    the id must not read RUNNING with nobody executing it."""
+    from sbeacon_tpu.query_jobs import (
+        AsyncQueryRunner,
+        JobStatus,
+        QueryJobTable,
+    )
 
     eng = _BlockingEngine()
     table = QueryJobTable(":memory:")
     runner = AsyncQueryRunner(eng, table, workers=1, max_pending=1)
     try:
         monkeypatch.setattr(
-            table,
-            "start",
+            runner._pool,
+            "submit",
             lambda *a, **k: (_ for _ in ()).throw(
-                RuntimeError("database is locked")
+                RuntimeError("cannot schedule new futures after shutdown")
             ),
         )
         for _ in range(3):
@@ -549,8 +554,10 @@ def test_runner_releases_slot_when_claim_fails(monkeypatch):
                 runner.submit(_payload(7))
         assert runner.metrics()["active"] == 0  # no leaked reservations
         monkeypatch.undo()
-        _, status = runner.submit(_payload(7))  # capacity intact
+        qid, status = runner.submit(_payload(7))  # capacity and id intact
+        assert status is JobStatus.RUNNING and eng.calls <= 1
         eng.release.set()
+        assert runner.result(qid, wait_s=5.0) == []
     finally:
         eng.release.set()
         runner.close()
@@ -559,42 +566,52 @@ def test_runner_releases_slot_when_claim_fails(monkeypatch):
 
 @resilience
 def test_runner_single_purge_sweeper(monkeypatch):
-    """_maybe_purge must not stack a fresh sweeper thread per interval
-    while a slow sweep is still running."""
+    """The sweep runs on the one writer thread: a slow one (WAL
+    checkpoint on a busy disk) is never stacked with a second, and holds
+    up no request, only the rows behind it."""
     from sbeacon_tpu.query_jobs import AsyncQueryRunner, QueryJobTable
 
     eng = _BlockingEngine()
+    eng.release.set()
     table = QueryJobTable(":memory:")
-    runner = AsyncQueryRunner(eng, table, workers=1, max_pending=4)
+    runner = AsyncQueryRunner(eng, table, workers=2, max_pending=4)
     gate = threading.Event()
     try:
         entered = threading.Event()
         sweeps = []
 
         def slow_purge():
-            sweeps.append(1)
+            sweeps.append(threading.current_thread())
             entered.set()
             assert gate.wait(10), "test deadlock"
             return 0
 
+        def written(n):
+            deadline = time.time() + 10
+            while runner._n_persisted_jobs < n and time.time() < deadline:
+                time.sleep(0.005)
+            return runner._n_persisted_jobs
+
         monkeypatch.setattr(table, "purge_expired", slow_purge)
-        runner._last_purge = 0.0  # interval lapsed
-        runner._maybe_purge()
+        runner._next_sweep = 0.0  # interval lapsed
+        q0, _ = runner.submit(_payload(0))  # its row wakes the writer
         assert entered.wait(5)
-        first = runner._sweeper
-        for _ in range(5):
-            runner._last_purge = 0.0
-            runner._maybe_purge()
-        assert runner._sweeper is first  # no second sweeper stacked
-        assert sweeps == [1]
+        assert written(1) == 1  # stored before the sweep began
+        for i in range(1, 6):  # the sweep is parked: requests answer
+            qid, _ = runner.submit(_payload(i))
+            assert runner.result(qid, wait_s=5.0) == []
+        assert sweeps == [runner._writer]  # no second sweeper stacked
+        assert runner._n_persisted_jobs == 1  # their rows wait behind it
         gate.set()
-        first.join(10)
-        assert not first.is_alive()
-        # sweeper finished: the next lapsed interval starts a new one
-        runner._last_purge = 0.0
-        runner._maybe_purge()
-        assert runner._sweeper is not first
-        runner._sweeper.join(10)
+        assert written(6) == 6
+        # sweep finished: the next lapsed interval starts a new one
+        runner._next_sweep = 0.0
+        runner.submit(_payload(6))
+        assert written(7) == 7
+        deadline = time.time() + 10
+        while len(sweeps) < 2 and time.time() < deadline:
+            time.sleep(0.005)
+        assert sweeps == [runner._writer] * 2
     finally:
         gate.set()
         runner.close()

@@ -258,6 +258,10 @@ GOLDEN_METRICS = [
     "runner.submits",
     "runner.memory_hits",
     "runner.table_hits",
+    "runner.persisted_jobs",
+    "runner.persist_commits",
+    "runner.persist_expired",
+    "runner.persist_queue",
     "runtime.gc_pauses",
     "runtime.gc_pause_ms",
 ]
