@@ -28,7 +28,7 @@ then, last, the verdict alone:
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}``.
 
 The deployment (sizes cut only by scale, never by width):
-  (a) the index corpus of ``bench.py build_corpus``: 2e7 rows over
+  (a) an index corpus of 2e7 rows over
       chr1-22 at 2504 samples, one dataset on one chip, ``2n-2``
       datasets on ``n`` chips (same total rows);
   (b) a genotype-plane dataset at the full 2504-sample width, 2e6 rows

@@ -362,7 +362,7 @@ class BeaconApp:
         # re-application as the journal — the process global was built
         # from BEACON_DEVICE_RING_SIZE / BEACON_COMPILE_TRACKING env
         # defaults at import. Resolved through the module at call time
-        # (never bound by value here), so a test or bench that swaps
+        # (never bound by value here), so a test that swaps
         # telemetry.flight_recorder swaps this app's view too.
         telemetry_mod.flight_recorder.configure(
             ring_size=getattr(obs, "device_ring_size", 256),

@@ -432,8 +432,8 @@ class ShapingConfig:
     # charges a grant the MEASURED mean cost of its query shape
     # (normalized to the lane mean, clamped [0.25, 2.0]) instead of
     # the flat 1-per-request deficit. Off (default) keeps the flat
-    # charge byte-identical — observability first, scheduling proven
-    # in the config15 bench probe before it defaults on.
+    # charge byte-identical — observability first, scheduling to be
+    # proven on a benchmark cell before it defaults on.
     cost_drr: bool = False
 
 
@@ -880,7 +880,7 @@ def enable_persistent_compile_cache() -> Path:
     """Turn on XLA's persistent compilation cache and return its
     directory, so the warmed kernel programs compile once per index and
     config shape, not once per process start. Every deployment entry
-    calls this (api.server, parallel.dispatch, bench.py, chip_smoke.py).
+    calls this (api.server, parallel.dispatch, chip_smoke.py).
 
     ``JAX_COMPILATION_CACHE_DIR`` decides the PLACE: JAX reads the
     variable itself, so when it is set no directory is set here. Unset,

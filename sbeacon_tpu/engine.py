@@ -861,8 +861,9 @@ class VariantEngine:
         # fresh per-key blocks whose inputs did not move (their stack
         # work is never thrown away with the raced composite)
         self._l0_key_gens: dict[tuple[str, str], int] = {}
-        # per-key L0 block build counts (label-capped telemetry +
-        # the bench's structural untouched-keys-not-restacked assert)
+        # per-key L0 block build counts (label-capped telemetry; a
+        # publish burst on one key must leave the other keys' counts
+        # unmoved: tests/test_delta_ingest.py)
         self._l0_key_builds: dict[str, int] = {}
         self.l0_block_reuses = 0
         # L0 program shapes already warmed: the shard-tier/row padding
@@ -1741,7 +1742,7 @@ class VariantEngine:
 
     def l0_status(self) -> dict:
         """The L0 tier's state, lock-free (GIL-atomic reference read)
-        — the ``/debug/status`` ingest section and the bench read it."""
+        — the ``/debug/status`` ingest section reads it."""
         state = self._l0_state
         doc: dict = {
             "built": state is not None,
@@ -1752,9 +1753,9 @@ class VariantEngine:
             doc["shards"] = len(state[1])
             doc["rows"] = state[3]
             doc["ageS"] = round(time.time() - state[4], 1)
-        # per-key block detail (ISSUE 20): the bench's structural
-        # "untouched keys are not restacked" assert reads the per-key
-        # build counts; blockReuses is the complementary signal
+        # per-key block detail (ISSUE 20): an untouched key's build
+        # count must not move when another key is restacked;
+        # blockReuses is the complementary signal
         blocks = self._l0_blocks
         if blocks:
             doc["keys"] = {
@@ -1907,7 +1908,7 @@ class VariantEngine:
         """Pre-compile every kernel program serving can dispatch against
         the currently loaded indexes (tiers x exact split x batch
         shapes x fused-planes) so no request ever pays a first-compile
-        (the BENCH_r04 soak tail attribution; VERDICT r4 next #7).
+        (1-2 s per novel signature; VERDICT r4 next #7).
         Returns the number of programs touched. Call at server start,
         once the persisted shards are pinned; cached signatures make
         repeats near-free. From its first run on, the engine keeps
@@ -2558,8 +2559,8 @@ class VariantEngine:
         """The full per-stage latency decomposition: the batcher's
         queue-wait/encode/launch/device/fetch quantiles (when a batcher
         serves) plus host materialisation — the stage after fetch —
-        over the bounded windows. ``/debug/status`` and the bench soak
-        read this one dict to attribute a tail to a stage."""
+        over the bounded windows. ``/debug/status`` reads this one dict
+        to attribute a tail to a stage."""
         out: dict = {}
         if self._batcher is not None:
             out.update(self._batcher.timing_summary())

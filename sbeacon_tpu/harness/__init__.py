@@ -1,7 +1,7 @@
-"""Bench/simulation/chaos harness.
+"""Simulation/chaos harness.
 
 ``faults`` (stdlib-only chaos hooks) is imported eagerly — the serving
-path calls its ``fault_point`` — but the simulation/benchmark tooling
+path calls its ``fault_point`` — but the simulation tooling
 is exposed LAZILY (PEP 562): core modules import
 ``sbeacon_tpu.harness.faults`` at module load, and that must not drag
 the synthetic-data writers and genomics fixtures into every production

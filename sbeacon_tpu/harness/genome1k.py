@@ -9,8 +9,7 @@ chr1-22 VCF text at real cohort shape — 2504 genotype columns whose
 AC/AN INFO stays exactly consistent with the GT carriers — and push it
 through the REAL ingest pipeline (BGZF -> tabix -> slice planner ->
 native tokenizer -> genotype planes -> merge), recording wall times in
-a manifest (`INGEST_r03.json` at repo root when driven by
-``build_corpus``).
+the manifest ``build_corpus`` returns.
 
 Generation is vectorised per chunk: the genotype block starts as a
 tiled ``\\t0|0`` byte matrix and carriers are painted by fancy

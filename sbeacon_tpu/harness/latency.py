@@ -196,136 +196,3 @@ def run_latency_suite(
     gv_rec["query"]["includeResultsetResponses"] = "HIT"
     check("g_variants[record]", lambda: c.post("/g_variants", gv_rec))
     return out
-
-
-def run_concurrent_soak(
-    base_url: str,
-    *,
-    queries: list[dict],
-    n_clients: int = 16,
-    requests_per_client: int = 50,
-    engine=None,
-    path: str = "/g_variants",
-) -> dict:
-    """N concurrent clients against the live HTTP server: p50/p95/p99
-    per-request latency + sustained q/s, plus the micro-batcher's
-    occupancy histogram when the serving engine is handed in — the
-    evidence that batching engages under contention (reference shape:
-    simulations/test.py, which measured a deployed API; VERDICT r2 #5).
-
-    ``queries`` are POST bodies cycled across clients so the batcher
-    sees a mixed stream, as concurrent real clients would produce.
-    NOTE: repeated identical bodies are answered by the query-job
-    result cache without touching the kernel — pass one distinct query
-    per request when the goal is measuring batching rather than cache
-    hits.
-    """
-    import threading
-
-    batcher = getattr(engine, "_batcher", None) if engine is not None else None
-    before = batcher.occupancy() if batcher is not None else None
-    lat: list[float] = []
-    errors: list[str] = []
-    lock = threading.Lock()
-    start = threading.Barrier(n_clients + 1)
-
-    def client(k: int):
-        c = Client(base_url)
-        mine = []
-        start.wait()
-        for i in range(requests_per_client):
-            body = queries[(k * requests_per_client + i) % len(queries)]
-            t0 = time.perf_counter()
-            try:
-                status, _ = c.post(path, body)
-                if status != 200:
-                    raise RuntimeError(f"status {status}")
-            except Exception as e:  # noqa: BLE001 - recorded, not raised
-                with lock:
-                    errors.append(f"client{k}:{e}")
-                continue
-            mine.append(time.perf_counter() - t0)
-        with lock:
-            lat.extend(mine)
-
-    threads = [
-        threading.Thread(target=client, args=(k,), daemon=True)
-        for k in range(n_clients)
-    ]
-    for t in threads:
-        t.start()
-    start.wait()
-    t0 = time.perf_counter()
-    for t in threads:
-        t.join()
-    wall = time.perf_counter() - t0
-
-    lat.sort()
-
-    def pct(p):
-        if not lat:  # all requests failed: report, don't crash
-            return None
-        return round(lat[min(len(lat) - 1, int(p * len(lat)))] * 1e3, 2)
-
-    out = {
-        "n_clients": n_clients,
-        "requests": len(lat),
-        "errors": len(errors),
-        "wall_s": round(wall, 2),
-        "qps": round(len(lat) / wall, 1) if wall else 0.0,
-        "p50_ms": pct(0.50),
-        "p95_ms": pct(0.95),
-        "p99_ms": pct(0.99),
-    }
-    if batcher is not None:
-        after = batcher.occupancy()
-        hist = {
-            k: after["histogram"].get(k, 0) - before["histogram"].get(k, 0)
-            for k in set(after["histogram"]) | set(before["histogram"])
-        }
-        hist = {k: v for k, v in sorted(hist.items()) if v}
-        launches = sum(hist.values())
-        submits = after["submits"] - before["submits"]
-        out["batcher"] = {
-            "submits": submits,
-            "launches": launches,
-            "mean_batch": round(submits / launches, 2) if launches else 0.0,
-            "histogram": hist,
-        }
-        # tail attribution (VERDICT r3 #10): server-side queue wait vs
-        # device execute — now split per stage (encode / launch /
-        # fetch, plus the engine's host materialize) so p99 is
-        # explainable down to the pipeline stage that owns it
-        if hasattr(batcher, "timing_summary"):
-            out["decomposition"] = batcher.timing_summary()
-        if hasattr(engine, "stage_timing"):
-            out.setdefault("decomposition", {}).update(
-                engine.stage_timing()
-            )
-    if engine is not None and callable(getattr(engine, "cache_stats", None)):
-        stats = engine.cache_stats()
-        if stats is not None:
-            out["response_cache"] = {
-                k: stats[k]
-                for k in ("hits", "misses", "hit_rate", "entries",
-                          "negative_hits", "evictions")
-            }
-    if errors:
-        out["first_errors"] = errors[:3]
-    return out
-
-
-def main():  # pragma: no cover - CLI
-    import argparse
-
-    ap = argparse.ArgumentParser(description="Beacon latency suite")
-    ap.add_argument("--url", required=True)
-    ap.add_argument("--reps", type=int, default=3)
-    args = ap.parse_args()
-    results = run_latency_suite(args.url, reps=args.reps)
-    for name, t in results.items():
-        print(f"{name:40s} {t * 1000:9.2f} ms")
-
-
-if __name__ == "__main__":  # pragma: no cover
-    main()

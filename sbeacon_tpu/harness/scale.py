@@ -14,8 +14,7 @@ the REAL HTTP route handlers (``BeaconApp.handle``), so the read path
 exercises the filter compiler, ontology expansion, relations joins and
 response envelopes end-to-end.
 
-Driven out-of-band (METADATA_r03.json at repo root); unit tests pin
-the harness at small scale.
+Unit tests pin the harness at small scale.
 """
 
 from __future__ import annotations

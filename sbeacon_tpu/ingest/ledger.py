@@ -275,7 +275,7 @@ class JobLedger:
         write_amp: float | None = None,
     ) -> None:
         """One completed fold: stamps the folded deltas and appends a
-        compaction row (the audit trail /debug and the bench read).
+        compaction row (the audit trail /debug reads).
         ``tier`` names the fold level (``l1`` = raw tail -> epoch-
         ranged intermediate artifact, ``base`` = full base merge);
         ``in_bytes``/``out_bytes``/``write_amp`` record the fold's IO
@@ -367,7 +367,7 @@ class JobLedger:
 
     def compaction_log(self, dataset_id: str | None = None) -> list[dict]:
         """The per-fold audit rows, oldest first — tier, IO bytes and
-        write amplification per fold (the bench's per-fold record)."""
+        write amplification per fold."""
         sql = (
             "SELECT dataset_id, vcf_location, folded_through, "
             "folded_shards, folded_rows, COALESCE(tier, 'base'), "

@@ -51,8 +51,7 @@ class _SliceDiskTracker:
     (``ingest.slice_disk_bytes``). Slices used to coexist on disk until
     the post-merge bulk delete; now each file is deleted the moment its
     rows are folded (held in memory / merged), so a many-sample
-    cohort's peak temp-disk is ~one slice — ``peak`` lets the bench
-    assert that."""
+    cohort's peak temp-disk is ~one slice — ``peak`` records that."""
 
     def __init__(self):
         self._lock = threading.Lock()

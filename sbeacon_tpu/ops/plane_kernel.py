@@ -337,69 +337,3 @@ def plane_row_stats(
         np.asarray(counts)[:R].astype(np.int64),
         np.asarray(or_words).view(np.uint32),
     )
-
-
-def device_plane_probe(
-    pindex: PlaneDeviceIndex,
-    rows: np.ndarray,
-    selected_mask_words: np.ndarray,
-    *,
-    iters: int = 64,
-) -> float:
-    """Seconds per plane-stats call on-device, by the same two-chain
-    differencing the query kernels use (see
-    ``scatter_kernel.device_time_probe``; bench-only)."""
-    import time as _time
-
-    R = len(rows)
-    tier = next((t for t in _R_TIERS if R <= t), _R_TIERS[-1])
-    rows_p = np.zeros(tier, np.int32)
-    rows_p[: min(R, tier)] = rows[:tier]
-    sel_p = np.ones(tier, np.int32)
-    mask = jnp.asarray(
-        np.asarray(selected_mask_words, dtype=np.uint32).view(np.int32)
-    )
-    rd = jnp.asarray(rows_p)
-    sd = jnp.asarray(sel_p)
-    n_rows = jnp.int32(pindex.n_rows)
-
-    @partial(jax.jit, static_argnames=("k",))
-    def rep(rows0, k):
-        def body(carry, _):
-            counts, _ow = _plane_stats(
-                pindex.gt,
-                pindex.gt2 if pindex.has_counts else pindex.gt,
-                pindex.tok1 if pindex.has_counts else pindex.gt,
-                pindex.tok2 if pindex.has_counts else pindex.gt,
-                carry,
-                sd,
-                mask,
-                R=tier,
-                with_counts=pindex.has_counts,
-                with_or=True,
-            )
-            # real data dependency (XLA hoists invariant loop bodies)
-            return (carry + counts[0, 0]) % n_rows, counts[0, 0]
-
-        _, outs = jax.lax.scan(body, rows0, None, length=k)
-        return jnp.sum(outs)
-
-    def timed(k, reps=3):
-        best = float("inf")
-        for _ in range(reps):
-            t0 = _time.perf_counter()
-            np.asarray(jax.device_get(rep(rd, k)))
-            best = min(best, _time.perf_counter() - t0)
-        return best
-
-    # auto-escalate the chain length until the signal CLEARS the
-    # jitter floor (merely-positive deltas are noise — see
-    # scatter_kernel._probe_one_tier)
-    floor_s = 0.020
-    for k_iters in (iters, iters * 4, iters * 16, iters * 64):
-        timed(4, reps=1)
-        timed(4 + k_iters, reps=1)
-        delta = timed(4 + k_iters) - timed(4)
-        if delta >= floor_s:
-            return delta / k_iters
-    raise RuntimeError("device_plane_probe: below the jitter floor")

@@ -4,7 +4,7 @@ Why this exists: the round-2 grouped Pallas kernel (deleted in r5;
 see git history for the measured comparison) packed
 G=64 start-sorted queries per shared tile pair, which amortises HBM
 traffic G-fold **only while queries are dense relative to the index** —
-at the round-2 bench scale (~100k rows) consecutive sorted queries sit
+at ~100k rows consecutive sorted queries sit
 ~10 rows apart and grouping wins big. At 1000-Genomes scale (>=2e7
 rows) random point queries land ~2000 rows apart: virtually every
 64-slot group holds ONE real query, so the kernel DMAs and evaluates a
@@ -126,9 +126,7 @@ SEG_K_MAX = 8
 def __getattr__(name: str):
     """Module back-compat property (PEP 562): ``N_DISPATCHES`` — one
     per kernel program launched (a multi-chunk _scatter_many lax.map
-    is ONE dispatch; the bench divides deltas by request count to
-    evidence the one-dispatch-per-request-batch serving contract,
-    VERDICT r3 #4) — now served by the device flight recorder
+    is ONE dispatch) — now served by the device flight recorder
     (telemetry.py), whose lock owns the increment instead of the old
     unlocked module-global read-modify-write."""
     if name == "N_DISPATCHES":
@@ -862,9 +860,9 @@ def warmup_index(
     (exact / non-exact) x each fixed batch shape, plus the fused
     match+planes program when ``pindex`` planes are resident.
 
-    The soak tail was first-compiles, not queueing (BENCH_r04 config9
-    attribution): a cold engine pays 1-2 s per novel (tier, shape)
-    signature mid-request. Returns the number of programs compiled
+    A soak's tail is first-compiles, not queueing: a cold engine pays
+    1-2 s per novel (tier, shape) signature mid-request. Returns the
+    number of programs compiled
     (cached signatures are near-free, so calling this twice is cheap).
     VERDICT r4 next #7.
     """
@@ -1196,166 +1194,3 @@ def _scatter_many(
         )
 
     return jax.lax.map(run, (tile_ids, qarr))
-
-
-@partial(
-    jax.jit,
-    static_argnames=("T", "CAP", "nslots", "k", "C", "exact_only", "seg_k"),
-)
-def _probe_rep(
-    tiles, tile_ids, qarr, *, T, CAP, nslots, k, C=None, exact_only=False,
-    seg_k=None,
-):
-    """k serialized batch executions inside ONE dispatch.
-
-    The carry must be a REAL data dependency: the grouped-kernel probe's
-    always-zero word trick fails here because without an opaque
-    pallas_call boundary XLA constant-folds ``carry + 0``, proves the
-    loop invariant, and hoists the single batch out of the scan (first
-    observed as a negative differencing delta on v5e). Instead the
-    carry drifts by the (unknowable) call_count, kept in gather range
-    by a static modulo — iteration VALUES are garbage by design; the
-    scalar result is timing ballast only, never assert on it."""
-    n_tiles = jnp.int32(tiles.shape[0])
-
-    def body(carry, _):
-        agg, _masks = _scatter_batch(
-            tiles, carry, qarr, T=T, CAP=CAP, nslots=nslots, C=C,
-            exact_only=exact_only, seg_k=seg_k,
-        )
-        return (carry + agg[0, 1]) % n_tiles, agg[0, 1]
-
-    _, outs = jax.lax.scan(body, tile_ids, None, length=k)
-    return jnp.sum(outs)
-
-
-def _probe_one_tier(
-    sindex, tile_ids, q8, *, cap, C, iters, exact_only=False
-) -> tuple[float, int]:
-    """Chain-differenced (seconds per batch, bytes gathered per batch)
-    for ONE compiled tier batch (tile_ids/q8 already nslots-sized)."""
-    import time as _time
-
-    T = sindex.tile
-    nslots = len(tile_ids)
-    td = jnp.asarray(tile_ids)
-    qd = jnp.asarray(q8)
-    k1 = 8
-    k2 = k1 + iters
-
-    def timed(k, reps=3):
-        best = float("inf")
-        for _ in range(reps):
-            t0 = _time.perf_counter()
-            np.asarray(
-                jax.device_get(
-                    _probe_rep(
-                        sindex.tiles,
-                        td,
-                        qd,
-                        T=T,
-                        CAP=cap,
-                        nslots=nslots,
-                        k=k,
-                        C=C,
-                        exact_only=exact_only,
-                        seg_k=_static_seg_k(sindex),
-                    )
-                )
-            )
-            best = min(best, _time.perf_counter() - t0)
-        return best
-
-    # auto-escalate the chain length until the differencing signal
-    # CLEARS a jitter floor — merely-positive deltas are noise: a ~2 ms
-    # delta under ~ms host-clock jitter once measured a physically
-    # impossible 1.48x-of-HBM-roofline gather rate (r5 BENCH run 1,
-    # config2). A genuinely faster kernel still measures — it just
-    # rides a longer chain.
-    JITTER_FLOOR_S = 0.020
-    MAX_CHAIN_S = 4.0  # wall budget per timed chain — the real ceiling
-    delta = 0.0
-    k_iters = iters
-    while True:
-        k2 = k1 + k_iters
-        timed(k1, reps=1)
-        t2_warm = timed(k2, reps=1)
-        delta = timed(k2) - timed(k1)
-        if delta >= JITTER_FLOOR_S:
-            iters = k_iters
-            break
-        if t2_warm > MAX_CHAIN_S:
-            # a multi-second chain whose delta still hides under the
-            # floor means per-batch time < floor/k — genuinely
-            # unmeasurable from the host clock
-            raise RuntimeError(
-                f"device_time_probe: unmeasurable — {k_iters}-batch "
-                f"signal below the jitter floor ({delta * 1e3:.3f} ms)"
-            )
-        k_iters *= 4
-    n_gather_tiles = C if C is not None else cap // T + 1
-    gathered = nslots * N_PACKED * n_gather_tiles * T * 4
-    return delta / iters, gathered
-
-
-def device_time_probe(
-    sindex: ScatterDeviceIndex,
-    queries,
-    *,
-    window_cap: int | None = None,
-    iters: int = 128,
-) -> tuple[float, int]:
-    """(seconds per batch on-device, HBM bytes gathered per batch) by
-    two-chain differencing through ``device_get`` — dispatch, sync and
-    transfer cancel exactly (methodology: time a k1-long and a k2-long
-    serialized in-dispatch chain and difference). A bench-only probe:
-    ROADMAP Speed 4 replaces it with kernel time read from a device
-    trace.
-
-    Times the SAME tier mix serving runs: queries whose window sits in
-    one tile are timed in the C=1 fast tier (split exact/non-exact like
-    serving), the rest in the windowed C-tile tier, and the reported
-    per-batch figure is the share-weighted combination (each tier
-    probed as a full batch of its own queries, cycled to batch size)."""
-    enc = encode_queries(queries) if isinstance(queries, list) else queries
-    T = sindex.tile
-    # round UP like _tier_caps does for serving, so the probe times the
-    # same gather width serving actually performs
-    cap = min(-(-(window_cap or T) // T) * T, (sindex.MAX_C - 1) * T)
-    lo, hi = _window_bounds(sindex, enc)
-    q8, _nh = pack_q8(enc, lo, hi)
-    tile_ids = (lo // T).astype(np.int32)
-    b = len(tile_ids)
-    nslots = CHUNK_SMALL if b <= CHUNK_SMALL else CHUNK
-    single = (np.maximum(hi, lo + 1) - 1) // T <= tile_ids
-    is_exact = enc["alt_mode"] == MODE_EXACT
-
-    def cycle(sel):
-        reps = -(-nslots // len(sel))
-        idx = np.tile(sel, reps)[:nslots]
-        return tile_ids[idx], q8[idx]
-
-    per = 0.0
-    gathered = 0.0
-    for mask, C, tier_cap in (
-        (single, 1, T),
-        (~single, None, cap),
-    ):
-        for exact in (True, False):
-            sel = np.flatnonzero(mask & (is_exact == exact))
-            share = len(sel) / b
-            if share == 0.0:
-                continue
-            t_ids, qs = cycle(sel)
-            p, g = _probe_one_tier(
-                sindex,
-                t_ids,
-                qs,
-                cap=tier_cap,
-                C=C,
-                iters=iters,
-                exact_only=exact,
-            )
-            per += share * p
-            gathered += share * g
-    return per, int(gathered)

@@ -57,7 +57,7 @@ def __getattr__(name: str):
     flight recorder (telemetry.py): the old unlocked module-global
     increments raced across request threads on real accelerators
     (no ``_CPU_COLLECTIVE_LOCK`` there); the recorder's lock now owns
-    them and these names stay readable for tests and bench.
+    them and these names stay readable for tests.
 
     - ``N_LAUNCHES``: compiled mesh-program dispatches (one per jitted
       sharded/fused query-batch launch) — the perf_smoke evidence that
@@ -68,8 +68,9 @@ def __getattr__(name: str):
     - ``N_EVALUATED_PAIRS``: per-device FLOP proxy — evaluated
       (device, query-slot) pairs summed over the mesh, per launch
       (replicated layout evaluates batch x n_dev pairs, the sliced
-      layout ~batch total). bench config17's structural scaling assert
-      reads this instead of wall-clock (virtual-CPU honesty rule).
+      layout ~batch total). The structural scaling assert of
+      tests/test_mesh_dispatch.py reads this instead of wall-clock
+      (virtual-CPU honesty rule).
     """
     from ..telemetry import flight_recorder
 

@@ -95,7 +95,7 @@ class _Pending:
     #: the submitting request's context (cost attribution): the fetch
     #: stage pro-rates the launch's measured device time to each
     #: submission's share of the specs and charges it here — None
-    #: (warmup, bench direct) charges the unattributed residue
+    #: (warmup, direct callers) charges the unattributed residue
     ctx: object = None
 
 

@@ -481,7 +481,7 @@ def percentiles(xs) -> dict:
     """{p50, p95, p99} of a sample window (numpy-interpolated, 2dp),
     or {} when empty — the one summary shape every stage-timing
     producer (batcher, engine materialisation, runner admission wait)
-    feeds into /debug/status and the bench records."""
+    feeds into /debug/status."""
     xs = list(xs)
     if not xs:
         return {}

@@ -224,8 +224,8 @@ def synthetic_shard(
 
     ``position_model``: 'uniform' spreads rows evenly across each
     chromosome's real GRCh38 length; 'clustered' mixes 70% uniform with
-    30% hotspot-clustered positions (real genomes are not uniform —
-    BENCH skew configs, VERDICT r2 #8).
+    30% hotspot-clustered positions (real genomes are not uniform;
+    VERDICT r2 #8).
 
     All rows carry AC_INFO/AN_INFO (INFO-sourced counts, the common
     case for cohort VCFs), so genotype planes — generated when

@@ -1,8 +1,7 @@
 """1000-Genomes-scale harness: generator output is real-pipeline food.
 
-The scale run itself happens out-of-band (INGEST_r03.json manifest);
-these tests pin the properties the scale proof depends on: generated
-AC/AN INFO is exactly consistent with the painted GT carriers after a
+A scale run happens out-of-band; these tests pin the properties the
+scale proof depends on: generated AC/AN INFO is exactly consistent with the painted GT carriers after a
 trip through the REAL ingest pipeline, and the per-chromosome driver
 is resumable.
 """

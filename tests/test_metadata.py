@@ -446,9 +446,8 @@ def test_count_fast_path_respects_deletes():
 
 def test_cross_entity_record_page_is_index_backed(store):
     """The /datasets/{id}/individuals record page must run as an index
-    range walk, not a 1M-row scan-and-sort (VERDICT r4 next #6:
-    dataset_individuals_record p50 378 ms -> sub-ms at 1M individuals;
-    METADATA_r05). Pins both the plan and the results."""
+    range walk, not a 1M-row scan-and-sort (VERDICT r4 next #6).
+    Pins both the plan and the results."""
     store.upsert(
         "datasets", [{"id": f"ds{d}", "name": f"D{d}"} for d in range(3)]
     )

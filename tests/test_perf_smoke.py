@@ -3,8 +3,8 @@
 These assert the two launch-count invariants the fused-dispatch /
 response-cache overhaul exists to provide, on the CPU backend in
 seconds: a k-shard query is ONE kernel launch (not k), and a warm
-cache hit is ZERO launches. They are contracts, not benchmarks — the
-timing claims live in bench.py.
+cache hit is ZERO launches. They are contracts, not benchmarks: a
+time is a chip run of ``benchmark/run.py``.
 """
 
 import random
@@ -492,7 +492,7 @@ def test_observability_keeps_warm_path_contract():
         p50_ms = times[len(times) // 2] * 1e3
         # generous CI bound; the real number is sub-millisecond — the
         # contract is "observability did not add a visible tax", not a
-        # benchmark claim (those live in bench.py)
+        # benchmark claim (those are chip runs of benchmark/run.py)
         assert p50_ms < 25.0, f"warm handle p50 {p50_ms:.2f} ms"
         # the surfaces actually engaged: exemplars recorded, SLO
         # counted the traffic

@@ -1,8 +1,7 @@
 """Execution-plan plane (ISSUE 19): per-request plan documents
 (plan.plan_stage / ``meta.executionPlan``), the sampled ``/ops/plans``
-aggregate, the plan-drift sentinel, the ``?explain=1`` trust gate, the
-``tools/check_plan_stages.py`` static lint, and the
-``tools/bench_history.py`` round differ."""
+aggregate, the plan-drift sentinel, the ``?explain=1`` trust gate and
+the ``tools/check_plan_stages.py`` static lint."""
 
 import dataclasses
 import json
@@ -659,139 +658,3 @@ def test_plan_stage_lint_catches_violations(tmp_path):
     assert any(
         "not found" in e for e in cps.lint({"cache": ["x:1"]}, {}, None, set())
     )
-
-
-# -- bench-round history differ ------------------------------------------------
-
-
-@obs
-def test_bench_history_direction_and_flatten():
-    sys.path.insert(0, str(REPO / "tools"))
-    try:
-        import bench_history as bh
-    finally:
-        sys.path.pop(0)
-    assert bh.direction("xla_qps") == 1
-    assert bh.direction("value") == 1
-    assert bh.direction("detail.config2_x.vs_baseline") == 1
-    assert bh.direction("detail.config1_x.p50_ms") == -1
-    assert bh.direction("best_batch_s") == -1
-    assert bh.direction("detail.parity") == 0
-    flat = bh.flatten(
-        {
-            "value": 1,
-            "flag": True,
-            "name": "k",
-            "detail": {"c1": {"qps": 2.0, "kernel": "x"}},
-        }
-    )
-    assert flat == {"value": 1.0, "detail.c1.qps": 2.0}
-    # the repo's own rounds diff without crashing (r03-r05 wrapper
-    # docs carry parsed=null and must be skipped, not fatal)
-    assert bh.main(["--dir", str(REPO)]) == 0
-
-
-@obs
-def test_bench_history_flags_regressions(tmp_path, capsys):
-    sys.path.insert(0, str(REPO / "tools"))
-    try:
-        import bench_history as bh
-    finally:
-        sys.path.pop(0)
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps(
-            {
-                "n": 1,
-                "parsed": {
-                    "value": 100.0,
-                    "detail": {"c1": {"qps": 50.0, "p50_ms": 10.0}},
-                },
-            }
-        )
-    )
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps(
-            {
-                "n": 2,
-                "parsed": {
-                    "value": 50.0,
-                    "detail": {"c1": {"qps": 55.0, "p50_ms": 30.0}},
-                },
-            }
-        )
-    )
-    (tmp_path / "BENCH_bad.json").write_text("{not json")
-    (tmp_path / "BENCH_null.json").write_text(
-        json.dumps({"n": 3, "parsed": None})
-    )
-    rounds, skipped = bh.load_rounds(tmp_path)
-    assert [n for n, _ in rounds] == ["BENCH_r01.json", "BENCH_r02.json"]
-    assert set(skipped) == {"BENCH_bad.json", "BENCH_null.json"}
-    regressions, changes = bh.diff_rounds(rounds, 0.10)
-    reg_keys = {r["key"] for r in regressions}
-    # value dropped and latency rose: regressions; qps rose: a change
-    # in the good direction only, never a regression
-    assert reg_keys == {"value", "detail.c1.p50_ms"}
-    assert "detail.c1.qps" not in reg_keys
-    assert reg_keys <= {c["key"] for c in changes}
-    # default exit stays green (history inspection never breaks a
-    # build), --strict gates
-    assert bh.main(["--dir", str(tmp_path)]) == 0
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "skipped" in out
-    assert bh.main(["--dir", str(tmp_path), "--strict"]) == 1
-
-
-@obs
-def test_bench_history_diffs_ingest_and_metadata_families(tmp_path, capsys):
-    """ISSUE 20 satellite: INGEST_rNN / METADATA_rNN rounds are bare
-    parsed documents (no harness wrapper) diffed within their own
-    family — never against BENCH rounds — ordered by the filename's
-    rNN ordinal."""
-    sys.path.insert(0, str(REPO / "tools"))
-    try:
-        import bench_history as bh
-    finally:
-        sys.path.pop(0)
-    # rate keys beat the generic _s latency suffix; campaign wall
-    # clocks are latency-like
-    assert bh.direction("chroms.1.ingest_rec_per_s") == 1
-    assert bh.direction("populate.entities_per_s") == 1
-    assert bh.direction("chroms.1.ingest_seconds") == -1
-    assert bh.direction("queries.probe.p50_ms") == -1
-    assert bh.direction("chroms.1.records") == 0  # dataset size: informative
-
-    (tmp_path / "INGEST_r01.json").write_text(
-        json.dumps({"chroms": {"1": {"ingest_rec_per_s": 2000.0}}})
-    )
-    (tmp_path / "INGEST_r02.json").write_text(
-        json.dumps({"chroms": {"1": {"ingest_rec_per_s": 1000.0}}})
-    )
-    # a BENCH round in the same dir must not enter the INGEST diff
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"n": 1, "parsed": {"value": 1.0}})
-    )
-    (tmp_path / "METADATA_r09.json").write_text(
-        json.dumps({"queries": {"probe": {"p50_ms": 1.0}}})
-    )
-    (tmp_path / "METADATA_r10.json").write_text(
-        json.dumps({"queries": {"probe": {"p50_ms": 5.0}}})
-    )
-    rounds, skipped = bh.load_rounds(tmp_path, "INGEST")
-    assert [n for n, _ in rounds] == ["INGEST_r01.json", "INGEST_r02.json"]
-    assert skipped == []
-    regressions, _ = bh.diff_rounds(rounds, 0.10)
-    assert {r["key"] for r in regressions} == {"chroms.1.ingest_rec_per_s"}
-    # r09 < r10 by ordinal, not lexical luck: two-digit ordinals sort
-    rounds, _ = bh.load_rounds(tmp_path, "METADATA")
-    assert [n for n, _ in rounds] == [
-        "METADATA_r09.json",
-        "METADATA_r10.json",
-    ]
-    regressions, _ = bh.diff_rounds(rounds, 0.10)
-    assert {r["key"] for r in regressions} == {"queries.probe.p50_ms"}
-    # main() walks all three families; strict gates on any of them
-    assert bh.main(["--dir", str(tmp_path)]) == 0
-    out = capsys.readouterr().out
-    assert "INGEST: 2 rounds" in out and "METADATA: 2 rounds" in out
-    assert bh.main(["--dir", str(tmp_path), "--strict"]) == 1
