@@ -151,17 +151,19 @@ class EngineConfig:
     request_timeout_s: float = 600.0  # variantutils REQUEST_TIMEOUT
     mesh_axis: str = "d"
     use_tpu: bool = True
-    # serving micro-batcher (SURVEY.md §7): with wait=0 the leader runs
-    # immediately and batches form from requests queuing behind an
-    # in-flight kernel launch (continuous batching); raise wait_ms to
-    # trade single-query latency for fuller batches
+    # serving micro-batcher (SURVEY.md §7): with wait=0 nobody waits
+    # for company, and batches form from requests queuing behind a
+    # leader that waits for a fetch-pipeline slot (continuous
+    # batching); raise wait_ms to trade single-query latency for
+    # fuller batches
     microbatch: bool = True
     microbatch_max: int = 512
     microbatch_wait_ms: float = 0.0
     # launched-but-unfetched kernel batches allowed per accumulator:
-    # the launch/fetch overlap window (serving.py pipeline). 1 = fully
-    # serial launch->fetch (pre-fusion behavior); 2 double-buffers so
-    # host encode of batch i+1 overlaps device execution of batch i
+    # the launch/fetch overlap window (serving.py pipeline), taken by
+    # the accumulator's leader BEFORE it pops. 1 = fully serial
+    # launch->fetch (pre-fusion behavior); 2 double-buffers so host
+    # encode of batch i+1 overlaps device execution of batch i
     fetch_pipeline_depth: int = 2
     # cross-shard fused dispatch: stack every warm device shard into
     # ONE device index (ops.kernel.FusedDeviceIndex) so a k-dataset

@@ -6,10 +6,12 @@ from .kernel import (
     QuerySpec,
     ReadyQueryResults,
     encode_queries,
+    padded_batch,
     run_queries,
 )
 from .scatter_kernel import (
     ScatterDeviceIndex,
+    chunk_slots,
     run_queries_scattered,
 )
 
@@ -105,6 +107,22 @@ def run_queries_auto(
     )
 
 
+def launch_capacity(index, n_specs: int) -> int:
+    """How many query specs ride a launch whose first entry brings
+    ``n_specs``, at the padded shape that entry alone already pays for:
+    the scattered kernel's chunk (every tier of the batch pads to whole
+    chunks of it), else the batch-ladder rung ``run_queries`` and the
+    mesh tier pad to. The same per-family choice ``run_queries_auto``
+    makes, asked before the launch: the micro-batcher fills a launch
+    this far and no further, so a batched launch costs the device what
+    a launch of its head entry costs and runs a shape warm-up
+    compiled."""
+    if isinstance(index, ScatterDeviceIndex):
+        slots = chunk_slots(n_specs)
+        return -(-n_specs // slots) * slots
+    return padded_batch(index, n_specs)
+
+
 __all__ = [
     "DeviceIndex",
     "FusedDeviceIndex",
@@ -113,6 +131,7 @@ __all__ = [
     "QuerySpec",
     "ReadyQueryResults",
     "encode_queries",
+    "launch_capacity",
     "make_device_index",
     "run_queries",
     "run_queries_auto",

@@ -906,6 +906,23 @@ class ReadyQueryResults:
         return self._res
 
 
+def padded_batch(dindex, b: int) -> int:
+    """The batch size a launch of ``b`` queries on this index runs at:
+    the smallest rung holding it, on the index's own ladder where it
+    carries one (``batch_tiers``: the L0 mini-indexes) and on the
+    process ladder otherwise; past the top rung, and for an empty
+    batch, ``b`` itself. ``run_queries`` and the mesh tier's replicated
+    layout pad to it (the sliced layout pads each device's slice to a
+    slice rung, never past it), and the micro-batcher fills a launch no
+    further (``ops.launch_capacity``)."""
+    if not b:
+        return 0
+    tiers = getattr(dindex, "batch_tiers", None)
+    if tiers is None:
+        tiers = active_ladder().rungs
+    return next((t for t in tiers if b <= t), b)
+
+
 def run_queries(
     dindex: DeviceIndex,
     queries: list[QuerySpec] | dict[str, np.ndarray],
@@ -944,18 +961,14 @@ def run_queries(
         # an index may carry its own (finer) tier ladder — the L0
         # mini-index does, so a per-tail-shard spec batch is not padded to
         # the global 64 tier; everything else pads to the process ladder
-        tiers = getattr(dindex, "batch_tiers", None)
-        if tiers is None:
-            tiers = active_ladder().rungs
-        tier = next((t for t in tiers if b <= t), None)
-        if b and tier and tier != b:
+        padded = padded_batch(dindex, b)
+        if padded != b:
             enc = {
                 k: np.concatenate(
-                    [v, np.repeat(v[:1], tier - b, axis=0)]
+                    [v, np.repeat(v[:1], padded - b, axis=0)]
                 )
                 for k, v in enc.items()
             }
-        padded = tier if (b and tier) else b
     donate = _donate_uploads()
     with span("kernel.run_queries") as sp:
         with stage("kernel.dispatch") as st:

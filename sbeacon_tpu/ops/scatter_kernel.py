@@ -973,6 +973,13 @@ def _static_seg_k(sindex) -> int | None:
     return k if k is not None and k <= SEG_K_MAX else None
 
 
+def chunk_slots(b: int) -> int:
+    """The slots of one chunk of a ``b``-query tier: a launch pads each
+    of its tiers to whole chunks of this size, so up to that many
+    queries cost the device what one costs."""
+    return CHUNK_SMALL if b <= CHUNK_SMALL else CHUNK
+
+
 def _launch_tier(sindex, tile_ids, q8, *, cap, C=None, exact_only=False):
     """ASYNC device launch for one tier, chunk-padded; returns device
     handles (agg, masks) still shaped [ceil(b/nslots)*nslots, ...].
@@ -982,7 +989,7 @@ def _launch_tier(sindex, tile_ids, q8, *, cap, C=None, exact_only=False):
     qps vs r3's single-dispatch batches). ``C=1`` is the single-tile
     fast tier."""
     b = len(tile_ids)
-    nslots = CHUNK_SMALL if b <= CHUNK_SMALL else CHUNK
+    nslots = chunk_slots(b)
     pad = (-b) % nslots
     if pad:
         tile_ids = np.concatenate([tile_ids, np.zeros(pad, np.int32)])

@@ -10,13 +10,18 @@ replacement for that entire fan-out layer at serving time.
 
 Leader-election design (no dedicated flusher thread, zero idle cost):
 the first request into an empty accumulator becomes the leader, waits up
-to ``max_wait_ms`` for followers (or until ``max_batch`` arrive), then
-executes the whole batch with one ``run_queries_auto`` call (scatter or
-XLA kernel by index type) and hands each waiter its row of the results.
+to ``max_wait_ms`` for followers (0 by default: nobody waits for
+company), takes one of the accumulator's fetch-pipeline slots, and only
+THEN pops: while every slot is held by a launch in flight the leader
+stays claimed, so arrivals queue behind it as followers and one pop
+takes what has gathered (backpressure before the pop is what batches).
+The batch runs as one ``run_queries_auto`` call (scatter or XLA kernel
+by index type) and each waiter is handed its row of the results.
 Batch-shape bucketing lives inside the kernels (the active
 kernel.TierLadder rungs — kernel.BATCH_TIERS is the legacy default —
 plus the scatter chunk slots), so XLA compiles one program per tier
-instead of one per batch size.
+instead of one per batch size; a pop fills a launch only up to the
+padded shape its first entry already pays for (``ops.launch_capacity``).
 
 Ingest-while-serving contract: the accumulators here are keyed by the
 DEVICE INDEX object (base shards, fused/mesh stacks), and delta shards
@@ -39,7 +44,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from .harness.faults import fault_point
-from .ops import run_queries_auto
+from .ops import launch_capacity, run_queries_auto
 from .ops.kernel import QueryResults, encode_queries
 from .resilience import (
     NO_DEADLINE,
@@ -59,7 +64,7 @@ from .telemetry import (
 from .utils.trace import span, tracer
 
 
-@dataclass
+@dataclass(eq=False)  # an entry is only ever itself (acc.items.remove)
 class _Pending:
     #: the submission's query specs — one for a plain submit, several
     #: for a fused multi-shard submission (submit_many); the result is
@@ -80,6 +85,11 @@ class _Pending:
     result: object = None
     error: BaseException | None = None
     t_submit: float = 0.0
+    #: the part of this entry's queued time during which whoever led
+    #: its launch was waiting for the fetch-pipeline slot (its share of
+    #: ``batcher.pipeline``; the rest up to the launcher is
+    #: ``batcher.wait``)
+    slot_ms: float = 0.0
     #: the fetcher's clock reading when the results were in hand: the
     #: woken submitter's ``handoff.back`` starts there
     t_ready: float = 0.0
@@ -106,11 +116,14 @@ class _Accumulator:
         self.lock = threading.Lock()
         self.items: list[_Pending] = []
         self.leader_active = False
-        # bounds launched-but-unfetched batches: launch stage acquires,
-        # fetch stage releases. Depth 1 reproduces the old fully-serial
-        # launch->fetch behaviour; depth 2 overlaps the host-side
-        # encode of batch i+1 with the device execution of batch i
-        # while still making arrivals queue (continuous batching)
+        # bounds launched-but-unfetched batches: whoever leads takes a
+        # slot BEFORE it pops (holding the leadership while it waits),
+        # the fetch stage gives it back. Depth 1 is fully serial
+        # launch->fetch; depth 2 overlaps the host-side encode of
+        # batch i+1 with the device execution of batch i. With every
+        # slot taken, arrivals queue in ``items`` behind the waiting
+        # leader and ride ONE launch when a slot frees: the
+        # backpressure is what batches (continuous batching)
         self.pipeline = threading.BoundedSemaphore(max(1, pipeline_depth))
 
 
@@ -246,10 +259,11 @@ class MicroBatcher:
         # follower's: a wedged device strands a (daemon) launcher
         # thread — which recovers if the launch ever returns and never
         # blocks process exit — not the request thread and its
-        # admission slot. The leader still BLOCKS on the in-flight
-        # launch stage before returning — combined with the
-        # accumulator's bounded fetch pipeline that is what makes
-        # arrivals accumulate into batches (continuous batching).
+        # admission slot. A task here already holds its accumulator's
+        # fetch-pipeline slot (the leader took it before the pop, on
+        # its own thread and its own bound), so no launcher thread
+        # parks on the semaphore; the leader blocks on the launch
+        # stage until the dispatch returns.
         self._launcher = _LaunchPool(16, "kernel-launch")
         # device-to-host fetches run here, decoupled from launches:
         # while batch i's results stream back, the launcher is already
@@ -456,14 +470,20 @@ class MicroBatcher:
     def _serve(
         self, acc, dindex, window_cap, record_cap, me, req_deadline
     ) -> None:
-        """The leadership loop: pop batches, filter expired entries,
-        launch, wait bounded. ``me`` is the leading request's own entry
-        (None when run as a background drainer): the moment its answer
-        is in, any remaining backlog is handed to a transient daemon
-        drainer and this request RETURNS — a leader must not keep
-        serving other requests' batches on its own clock (and its own
-        admission slot). The drainer exists only while backlog does, so
-        the zero-idle-cost property of leader election is kept."""
+        """The leadership loop: take a fetch-pipeline slot, pop what has
+        gathered, filter expired entries, launch, wait bounded. The slot
+        comes FIRST and leadership is held while waiting for it: with no
+        slot free, arrivals append to ``acc.items`` as followers, and
+        when one frees ONE pop takes them all (``_pop``). Nobody waits
+        for company: a lone request that finds a slot free is popped and
+        launched at once. ``me`` is the leading request's own entry
+        (None when run as a background drainer): the moment its launch
+        is dispatched, any remaining backlog is handed to a transient
+        daemon drainer and this request RETURNS — a leader must not
+        keep serving other requests' batches on its own clock (and its
+        own admission slot). The drainer exists only while backlog
+        does, so the zero-idle-cost property of leader election is
+        kept."""
         while True:
             if me is not None and me.event.is_set():
                 # our answer is ready: hand off any backlog and return.
@@ -472,61 +492,40 @@ class MicroBatcher:
                 # which a new submit would elect a second leader.
                 self._handoff_or_release(acc, dindex, window_cap, record_cap)
                 return
+            # the wait for the slot is bounded like every other wait
+            # here: by the leading request's own deadline, a drainer's
+            # by the default bound — a wedged fetch strands neither a
+            # request thread nor the leadership
+            t_slot = time.perf_counter()
+            if not acc.pipeline.acquire(timeout=self._bound(me)):
+                if me is not None:
+                    # no launch in our time: withdraw as a follower
+                    # would, pass the leadership on, and report the
+                    # same 503 / 504
+                    with acc.lock:
+                        try:
+                            acc.items.remove(me)
+                        except ValueError:
+                            pass
+                    self._handoff_or_release(
+                        acc, dindex, window_cap, record_cap
+                    )
+                    raise self._timeout_error(req_deadline)
+                # a drainer goes on waiting while anyone is queued
+                # (each entry leaves at its own bound) and dies with
+                # the backlog
+                with acc.lock:
+                    if not acc.items:
+                        acc.leader_active = False
+                        return
+                continue
+            t_got = time.perf_counter()
+            # the slot is ours until a launch takes it: every way out
+            # of this iteration that launched nothing gives it back
+            done = None
             batch: list[_Pending] = []
             try:
-                with acc.lock:
-                    # lane-ordered pop: when the queue holds both
-                    # lanes, interactive entries ride the next launch
-                    # ahead of bulk ones (stable within a lane, so
-                    # FIFO fairness survives). Only matters when the
-                    # backlog exceeds one batch — entries sharing a
-                    # launch share its latency regardless of order.
-                    # The leading request's own entry stays first (the
-                    # loop below assumes `me` rides the first pop).
-                    if len(acc.items) > 1:
-                        head = (
-                            1
-                            if me is not None and acc.items[0] is me
-                            else 0
-                        )
-                        tail = acc.items[head:]
-                        if any(p.lane == "bulk" for p in tail) and any(
-                            p.lane != "bulk" for p in tail
-                        ):
-                            # aged bulk entries keep their FIFO spot: a
-                            # steady interactive stream re-sorting every
-                            # pop must not displace an admitted bulk
-                            # entry until its deadline (the admission
-                            # queue's starvation escape, mirrored here)
-                            now_pc = time.perf_counter()
-                            exempt_s = self.BULK_SORT_STARVATION_MS / 1e3
-                            tail.sort(
-                                key=lambda p: p.lane == "bulk"
-                                and now_pc - p.t_submit < exempt_s
-                            )
-                            acc.items[head:] = tail
-                    # cap by FLATTENED spec count, not submissions: a
-                    # fused submit_many entry carries k specs, and a
-                    # batch whose flattened size tops the active tier
-                    # ladder (kernel.active_ladder().rungs)
-                    # would compile a fresh exact-size program
-                    # mid-request (the r4 soak tail). A single
-                    # oversized submission still goes alone.
-                    n_specs = n_take = 0
-                    for p in acc.items:
-                        if n_take and n_specs + len(p.specs) > self.max_batch:
-                            break
-                        n_take += 1
-                        n_specs += len(p.specs)
-                        if n_take >= self.max_batch:
-                            break
-                    batch = acc.items[:n_take]
-                    acc.items = acc.items[n_take:]
-                    more = bool(acc.items)
-                    if not more:
-                        acc.leader_active = False
-                if not batch:
-                    return
+                batch, more = self._pop(acc, dindex, me)
                 # deadline filter: an entry that expired while queued
                 # must not consume a kernel lane — and a batch whose
                 # EVERY member expired must not launch at all (the
@@ -541,104 +540,172 @@ class MicroBatcher:
                         p.event.set()
                     else:
                         live.append(p)
+                # our OWN entry resolved by the filter just now
+                # (expired while we led): no launch on this thread
+                mine_gone = me is not None and me.event.is_set()
+                if live and not mine_gone:
+                    # the slot wait, read once a launch:
+                    # ``batcher.pipeline`` serves each entry for the
+                    # part of the wait it was queued through, and
+                    # ``batcher.wait`` (_execute) takes the rest of its
+                    # time up to the launcher
+                    slot_ms = (t_got - t_slot) * 1e3
+                    for p in live:
+                        p.slot_ms = min(
+                            slot_ms, max(0.0, (t_got - p.t_submit) * 1e3)
+                        )
+                    tracer.observe(
+                        "batcher.pipeline",
+                        slot_ms,
+                        sum(p.slot_ms for p in live) / slot_ms
+                        if slot_ms > 0
+                        else len(live),
+                    )
+                    # launch on the launcher pool, wait bounded: a
+                    # wedged launch fails this request with 503/504
+                    # instead of stranding it (and its admission slot)
+                    # forever. The launch stage ends at kernel DISPATCH
+                    # (the fetch runs on the fetcher pool, and gives
+                    # the slot back).
+                    bound = self._bound(me)
+                    done = self._launcher.submit(
+                        self._run_batch, acc, live, dindex, window_cap,
+                        record_cap,
+                    )
             except BaseException as e:
-                # a failure between pop and dispatch must not strand
-                # the popped batch: _run_batch never got it
+                # a failure between pop and dispatch (the launcher
+                # closed mid-shutdown) must not strand the popped
+                # batch: _run_batch never got it — fail its members
+                # here or they wait out their full bounds for a launch
+                # that will never happen
                 for p in batch:
                     if not p.event.is_set():
                         p.error = e
                         p.event.set()
                 raise
-            if me is not None and me.event.is_set() and live:
-                # our OWN entry was resolved by the filter just now
-                # (expired while we led): return its 503/504 at once
-                # instead of blocking this request's thread — and its
-                # admission slot — on other requests' launch. Push the
-                # live remainder back (front) so a drainer serves it;
-                # if leadership lapsed at the pop and someone else
-                # claimed it meanwhile, they will pop the push-back
-                # themselves — never spawn a second leader.
-                with acc.lock:
-                    acc.items = live + acc.items
-                    if more or not acc.leader_active:
+            finally:
+                if done is None:
+                    acc.pipeline.release()
+            if done is None:
+                if live:
+                    # return our own 503/504 at once instead of
+                    # blocking this request's thread — and its
+                    # admission slot — on other requests' launch. Push
+                    # the live remainder back (front) so a drainer
+                    # serves it with a slot of its own; if leadership
+                    # lapsed at the pop and someone else claimed it
+                    # meanwhile, they will pop the push-back themselves
+                    # — never spawn a second leader.
+                    with acc.lock:
+                        acc.items = live + acc.items
+                        spawn = more or not acc.leader_active
                         acc.leader_active = True
-                        spawn = True
-                    else:
-                        spawn = False
-                if spawn:
-                    threading.Thread(
-                        target=self._drain,
-                        args=(acc, dindex, window_cap, record_cap),
-                        name="batch-drain",
-                        daemon=True,
-                    ).start()
-                return
-            if live:
-                # launch on the launcher pool, wait bounded: a wedged
-                # launch fails this request with 503/504 instead of
-                # stranding it (and its admission slot) forever. The
-                # launch stage ends at kernel DISPATCH (the fetch runs
-                # on the fetcher pool) — per-accumulator backpressure
-                # comes from the bounded fetch pipeline the launch
-                # stage acquires into. The bound is the leading
-                # request's own deadline until its answer is in; a
-                # drainer uses a fresh default bound per launch.
-                bound = (
-                    me.deadline.remaining()
-                    if me is not None and not me.event.is_set()
-                    else self.default_timeout_s
-                )
-                try:
-                    done = self._launcher.submit(
-                        self._run_batch, acc, live, dindex, window_cap,
-                        record_cap,
-                    )
-                except BaseException as e:
-                    # dispatch failure (launcher closed mid-shutdown):
-                    # the popped batch never reached _run_batch — fail
-                    # its members here or they wait out their full
-                    # bounds for a launch that will never happen
-                    for p in live:
-                        if not p.event.is_set():
-                            p.error = e
-                            p.event.set()
-                    raise
-                if not done.wait(bound):
-                    # the launch may still complete: its members keep
-                    # their own bounded event waits and get results or
-                    # their own expiry — only this serving loop gives
-                    # up. Leadership (held iff items remained at the
-                    # pop) passes to a fresh drainer so queued items
-                    # are served the moment the slow launch frees the
-                    # device, instead of stalling until the next
-                    # submit; when more was False it was already
-                    # released, and a NEW leader may hold it now —
-                    # don't clobber that.
-                    if more:
-                        self._handoff_or_release(
-                            acc, dindex, window_cap, record_cap
-                        )
-                    if me is None or me.event.is_set():
-                        # re-check live, not a pre-launch snapshot: the
-                        # launch may have delivered our answer right at
-                        # the bound — return it rather than miscast a
-                        # served request as an error
-                        return
-                    raise self._timeout_error(req_deadline)
-                if me is not None:
-                    # our own entry was in that batch (the leading
-                    # request is always in the FIRST pop): its result
-                    # (or error) arrives via the fetch stage and
-                    # submit_many's bounded event wait — hand any
-                    # backlog to a drainer and stop serving other
-                    # requests' batches on this request's clock
-                    if more:
-                        self._handoff_or_release(
+                    if spawn:
+                        self._spawn_drainer(
                             acc, dindex, window_cap, record_cap
                         )
                     return
+                # nothing to launch: the queue emptied while we waited,
+                # or every popped entry had expired
+                if more:
+                    continue
+                return
+            if not done.wait(bound):
+                # the launch may still complete: its members keep
+                # their own bounded event waits and get results or
+                # their own expiry — only this serving loop gives
+                # up. Leadership (held iff items remained at the
+                # pop) passes to a fresh drainer so queued items
+                # are served the moment the slow launch frees the
+                # device, instead of stalling until the next
+                # submit; when more was False it was already
+                # released, and a NEW leader may hold it now —
+                # don't clobber that.
+                if more:
+                    self._handoff_or_release(
+                        acc, dindex, window_cap, record_cap
+                    )
+                if me is None or me.event.is_set():
+                    # re-check live, not a pre-launch snapshot: the
+                    # launch may have delivered our answer right at
+                    # the bound — return it rather than miscast a
+                    # served request as an error
+                    return
+                raise self._timeout_error(req_deadline)
+            if me is not None:
+                # our own entry was in that batch (the leading
+                # request is always in the FIRST pop): its result
+                # (or error) arrives via the fetch stage and
+                # submit_many's bounded event wait — hand any
+                # backlog to a drainer and stop serving other
+                # requests' batches on this request's clock
+                if more:
+                    self._handoff_or_release(
+                        acc, dindex, window_cap, record_cap
+                    )
+                return
             if not more:
                 return
+
+    def _pop(self, acc, dindex, me) -> tuple[list, bool]:
+        """One launch's entries off the queue, and whether any remain
+        (leadership is released here when none do). Called with a fetch
+        slot in hand, so what is taken is launched next."""
+        with acc.lock:
+            # lane-ordered pop: when the queue holds both lanes,
+            # interactive entries ride the next launch ahead of bulk
+            # ones (stable within a lane, so FIFO fairness survives).
+            # Only matters when the backlog exceeds one launch —
+            # entries sharing a launch share its latency regardless of
+            # order. The leading request's own entry stays first (the
+            # serving loop assumes `me` rides the first pop).
+            if len(acc.items) > 1:
+                head = 1 if me is not None and acc.items[0] is me else 0
+                tail = acc.items[head:]
+                if any(p.lane == "bulk" for p in tail) and any(
+                    p.lane != "bulk" for p in tail
+                ):
+                    # aged bulk entries keep their FIFO spot: a steady
+                    # interactive stream re-sorting every pop must not
+                    # displace an admitted bulk entry until its
+                    # deadline (the admission queue's starvation
+                    # escape, mirrored here)
+                    now_pc = time.perf_counter()
+                    exempt_s = self.BULK_SORT_STARVATION_MS / 1e3
+                    tail.sort(
+                        key=lambda p: p.lane == "bulk"
+                        and now_pc - p.t_submit < exempt_s
+                    )
+                    acc.items[head:] = tail
+            # cap by FLATTENED spec count, not submissions (a fused
+            # submit_many entry carries k specs), at the padded shape
+            # the head entry already pays for: entries ride in order
+            # while the launch still fits the size a launch of the head
+            # alone would run at (the index's own choice:
+            # ops.launch_capacity), and the next one opens the next
+            # launch. So a batched launch costs the device what a
+            # launch of one costs, and never compiles a shape
+            # mid-request that warm-up did not (the r4 soak tail). A
+            # single oversized submission still goes alone.
+            n_specs = n_take = 0
+            fits = self.max_batch
+            for p in acc.items:
+                if not n_take:
+                    fits = min(
+                        fits, launch_capacity(dindex, len(p.specs))
+                    )
+                elif n_specs + len(p.specs) > fits:
+                    break
+                n_take += 1
+                n_specs += len(p.specs)
+                if n_take >= self.max_batch:
+                    break
+            batch = acc.items[:n_take]
+            acc.items = acc.items[n_take:]
+            more = bool(acc.items)
+            if not more:
+                acc.leader_active = False
+        return batch, more
 
     def _handoff_or_release(self, acc, dindex, window_cap, record_cap):
         """Pass held leadership to a transient daemon drainer when
@@ -649,12 +716,25 @@ class MicroBatcher:
             if not handoff:
                 acc.leader_active = False
         if handoff:
-            threading.Thread(
-                target=self._drain,
-                args=(acc, dindex, window_cap, record_cap),
-                name="batch-drain",
-                daemon=True,
-            ).start()
+            self._spawn_drainer(acc, dindex, window_cap, record_cap)
+
+    def _spawn_drainer(self, acc, dindex, window_cap, record_cap) -> None:
+        threading.Thread(
+            target=self._drain,
+            args=(acc, dindex, window_cap, record_cap),
+            name="batch-drain",
+            daemon=True,
+        ).start()
+
+    def _bound(self, me: _Pending | None) -> float | None:
+        """Seconds a serving loop may block on one wait (the slot, the
+        launch's dispatch): the leading request's own deadline, a fresh
+        default bound for a drainer; None is unbounded."""
+        return (
+            me.deadline.remaining()
+            if me is not None
+            else self.default_timeout_s
+        )
 
     def _drain(self, acc, dindex, window_cap, record_cap) -> None:
         """Transient background drainer: continues the leadership loop
@@ -686,14 +766,22 @@ class MicroBatcher:
         """Launcher-thread entry: _execute plus a failsafe so NO batch
         member can be left without a result/error even if result
         distribution itself raises — waiters' bounds are a backstop,
-        not the primary delivery mechanism."""
+        not the primary delivery mechanism. The batch arrives with its
+        accumulator's fetch-pipeline slot (taken by whoever led it): a
+        launch that never reached the fetch stage gives it back here."""
+        fetching = False
         try:
-            self._execute(acc, batch, dindex, window_cap, record_cap)
+            fetching = self._execute(
+                acc, batch, dindex, window_cap, record_cap
+            )
         except BaseException as e:  # pragma: no cover - failsafe
             for p in batch:
                 if not p.event.is_set():
                     p.error = e
                     p.event.set()
+        finally:
+            if not fetching:
+                acc.pipeline.release()
 
     def close(self) -> None:
         """Release the launcher + fetcher pools (long-lived batchers
@@ -887,9 +975,12 @@ class MicroBatcher:
         the leader's ``done`` event) means only that the launch is
         dispatched — results are delivered by :meth:`_fetch_batch`, so
         host encode of the next batch overlaps device execution of
-        this one. The accumulator's bounded fetch pipeline is acquired
-        BEFORE dispatch and released by the fetch stage: at most
-        ``pipeline_depth`` batches are ever launched-but-unfetched."""
+        this one. The batch comes with its slot of the accumulator's
+        bounded fetch pipeline (taken before the pop, ``_serve``), so
+        at most ``pipeline_depth`` batches are ever
+        launched-but-unfetched. True once the fetch stage has the
+        launch and will give the slot back; otherwise the caller
+        (``_run_batch``) does."""
         specs: list = []
         offsets: list[int] = []
         for p in batch:
@@ -921,10 +1012,7 @@ class MicroBatcher:
         # chain stage that ends there, the composite behind the old
         # /debug/status key, the histogram and the cost vector alike
         n = len(batch)
-        t_taken = time.perf_counter()
-        acc.pipeline.acquire()
         t_launch = time.perf_counter()
-        tracer.observe("batcher.pipeline", (t_launch - t_taken) * 1e3, n)
         with self._stats_lock:
             self._batch_hist[n] = self._batch_hist.get(n, 0) + 1
             self._fused_hist[len(specs)] = (
@@ -932,8 +1020,8 @@ class MicroBatcher:
             )
         stage_hist = self._stage_hist
         for p in batch:
-            tracer.observe("batcher.wait", (t_taken - p.t_submit) * 1e3)
             wait_ms = (t_launch - p.t_submit) * 1e3
+            tracer.observe("batcher.wait", wait_ms - p.slot_ms)
             tracer.observe("batcher.queue_wait", wait_ms)
             if stage_hist is not None:
                 stage_hist.observe(wait_ms, label_value="batch_wait")
@@ -982,11 +1070,10 @@ class MicroBatcher:
                 t_disp = time.perf_counter()
                 sp.note(batch=len(specs))
         except BaseException as e:
-            acc.pipeline.release()
             for p in batch:
                 p.error = e
                 p.event.set()
-            return
+            return False
         # the old keys' measuring points: encode = encode_queries,
         # launch = the whole of run_queries_auto (for a family that
         # fetches inside its call, the device run and readback too);
@@ -1018,11 +1105,12 @@ class MicroBatcher:
             # fetcher closed mid-shutdown: the dispatched launch has no
             # fetcher — fail the batch here or its members wait out
             # their full bounds for results that will never arrive
-            acc.pipeline.release()
             for p in batch:
                 if not p.event.is_set():
                     p.error = e
                     p.event.set()
+            return False
+        return True
 
     def _fetch_batch(
         self, acc, batch, offsets, pending, t_launch, t_disp

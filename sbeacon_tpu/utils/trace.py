@@ -84,8 +84,12 @@ STAGES = {
     "engine.fanout": "wait",  # parked while the scatter pool serves targets
     "engine.materialize": "work",  # one target's response
     # micro-batcher
-    "batcher.wait": "wait",  # submit -> the launcher has the batch
-    "batcher.pipeline": "wait",  # the fetch pipeline's slot
+    # submit -> the launcher has the batch, less the entry's share of
+    # batcher.pipeline; one sample a request
+    "batcher.wait": "wait",
+    # the leader's wait for a fetch-pipeline slot, before it pops; one
+    # sample a launch, serving each entry for the part it queued through
+    "batcher.pipeline": "wait",
     "batcher.fetch_wait": "wait",  # dispatch returned -> fetcher starts
     "handoff.back": "wait",  # result set -> the waiting thread runs again
     # query encode, H2D and D2H, inside ops/

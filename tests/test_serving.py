@@ -377,3 +377,424 @@ def test_engine_warmup_compiles_all_paths():
     assert dist.warmup() >= n
     dist.close()
     eng.close()
+
+
+# -- backpressure before the pop: a launch takes what is waiting --------------
+
+
+def _answers_equal(got, ref):
+    assert bool(got.exists[0]) == bool(ref.exists[0])
+    assert int(got.call_count[0]) == int(ref.call_count[0])
+    assert int(got.all_alleles_count[0]) == int(ref.all_alleles_count[0])
+    np.testing.assert_array_equal(got.rows[0], ref.rows[0])
+
+
+def _hold_fetch(monkeypatch, seconds=None, gate=None):
+    """The fetch stage held (a sleep, or until ``gate`` is set): stands
+    for the time a launch keeps its fetch-pipeline slot on the chip's
+    host. Returns the list of fetches entered."""
+    import time
+
+    import sbeacon_tpu.ops.kernel as kernel_mod
+
+    entered = []
+    orig = kernel_mod.PendingQueryResults.fetch
+
+    def held(self):
+        entered.append(self)
+        if gate is not None:
+            assert gate.wait(30), "test deadlock"
+        else:
+            time.sleep(seconds)
+        return orig(self)
+
+    monkeypatch.setattr(kernel_mod.PendingQueryResults, "fetch", held)
+    return entered
+
+
+def _wait_until(cond, seconds=10.0):
+    import time
+
+    t_end = time.time() + seconds
+    while time.time() < t_end and not cond():
+        time.sleep(0.002)
+    assert cond()
+
+
+def test_arrivals_behind_a_held_slot_ride_one_launch(dindex, monkeypatch):
+    """16 threads x 40 submits on one accumulator with the fetch stage
+    held 10 ms: whoever leads waits for the slot with the leadership
+    claimed, so arrivals queue as followers and one pop takes them —
+    launches are fewer than half the submits, every answer is that of
+    the spec alone, and a pop always takes the head of the queue in
+    order (first come first served)."""
+    shard, di = dindex
+    n_threads, per_thread = 16, 40
+    pool = specs_for(shard, 48)
+    refs = [
+        run_queries(di, [s], window_cap=256, record_cap=64) for s in pool
+    ]
+    _hold_fetch(monkeypatch, seconds=0.010)
+    mb = MicroBatcher(max_batch=64, max_wait_ms=0)
+    acc = mb._accum(di, (256, 64))
+    out_of_order = []
+    orig_pop = mb._pop
+
+    def spying_pop(acc_, dindex_, me):
+        queued = list(acc_.items)  # arrivals only ever extend its end
+        batch, more = orig_pop(acc_, dindex_, me)
+        k = min(len(queued), len(batch))
+        if any(a is not b for a, b in zip(queued[:k], batch[:k])):
+            out_of_order.append((queued, batch))
+        return batch, more
+
+    mb._pop = spying_pop
+    wrong = []
+
+    def client(t):
+        for j in range(per_thread):
+            i = (t * per_thread + j * 7) % len(pool)
+            got = mb.submit(di, pool[i], window_cap=256, record_cap=64)
+            try:
+                _answers_equal(got, refs[i])
+            except AssertionError as e:
+                wrong.append((t, j, e))
+
+    threads = [
+        threading.Thread(target=client, args=(t,)) for t in range(n_threads)
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    mb.close()
+    occ = mb.occupancy()
+    assert not wrong, wrong[:2]
+    assert not out_of_order
+    assert occ["submits"] == n_threads * per_thread
+    assert sum(k * v for k, v in occ["histogram"].items()) == occ["submits"]
+    assert occ["launches"] < occ["submits"] / 2, occ["histogram"]
+    assert max(occ["histogram"]) > 1
+    # a launch of single specs never outgrows the rung one spec pads to
+    assert max(occ["fused_hist"]) <= 8
+    assert acc.leader_active is False and acc.items == []
+    # every slot came back
+    assert acc.pipeline.acquire(blocking=False)
+    assert acc.pipeline.acquire(blocking=False)
+    assert not acc.pipeline.acquire(blocking=False)
+
+
+def test_lone_submit_with_a_free_slot_is_launched_at_once(
+    dindex, monkeypatch
+):
+    """No wait was added: with one launch held in its fetch and the
+    second slot free, a lone arrival is popped and launched at once, as
+    a batch of one, while the first launch is still out."""
+    shard, di = dindex
+    (spec,) = specs_for(shard, 1)
+    gate = threading.Event()
+    entered = _hold_fetch(monkeypatch, gate=gate)
+    mb = MicroBatcher(max_batch=64, max_wait_ms=0)
+    got = [None, None]
+
+    def one(i):
+        got[i] = mb.submit(di, spec, window_cap=256, record_cap=64)
+
+    first = threading.Thread(target=one, args=(0,))
+    first.start()
+    _wait_until(lambda: len(entered) == 1)
+    second = threading.Thread(target=one, args=(1,))
+    second.start()
+    # the second launch reaches ITS fetch with the first still held
+    _wait_until(lambda: len(entered) == 2)
+    assert got == [None, None]
+    assert mb.occupancy()["histogram"] == {1: 2}
+    gate.set()
+    for t in (first, second):
+        t.join(30)
+        assert not t.is_alive()
+    ref = run_queries(di, [spec], window_cap=256, record_cap=64)
+    for g in got:
+        _answers_equal(g, ref)
+    mb.close()
+
+
+def _queue_behind_held_slots(acc, submit_one, n):
+    """Both fetch slots taken by the test, then ``n`` submissions queued
+    one after the other (so their order is known). Returns the threads;
+    the caller gives the slots back."""
+    assert acc.pipeline.acquire(blocking=False)
+    assert acc.pipeline.acquire(blocking=False)
+    threads = []
+    for i in range(n):
+        t = threading.Thread(target=submit_one, args=(i,))
+        t.start()
+        threads.append(t)
+        _wait_until(lambda: len(acc.items) == i + 1)
+    return threads
+
+
+@pytest.mark.parametrize(
+    "family, specs_each, per_launch",
+    [
+        # DeviceIndex: one spec pads to rung 8, so eight ride a launch
+        ("xla", 1, 8),
+        # fused entries of a whole rung go one a launch ...
+        ("fused", 8, 1),
+        # ... and entries of half a rung go two a launch
+        ("fused", 4, 2),
+        # an odd size still fills only the rung its head pays for
+        ("fused", 3, 2),
+        # the scattered kernel pads every tier to its 64-slot chunk
+        ("scatter", 1, 64),
+    ],
+)
+def test_a_launch_fills_only_the_shape_its_head_pays_for(
+    dindex, family, specs_each, per_launch
+):
+    from sbeacon_tpu.ops import launch_capacity
+    from sbeacon_tpu.ops.kernel import FusedDeviceIndex
+    from sbeacon_tpu.ops.scatter_kernel import ScatterDeviceIndex
+
+    shard, di = dindex
+    if family == "fused":
+        index = FusedDeviceIndex([shard, shard], pad_unit=1024)
+    elif family == "scatter":
+        index = ScatterDeviceIndex(shard)
+    else:
+        index = di
+    assert launch_capacity(index, specs_each) // specs_each == per_launch
+    n = 2 * per_launch + 1 if per_launch < 64 else 70
+    pool = specs_for(shard, n * specs_each)
+    mb = MicroBatcher(max_batch=512, max_wait_ms=0)
+    acc = mb._accum(index, (256, 64))
+    launched = []
+    orig_execute = mb._execute
+
+    def spying_execute(acc_, batch, *a):
+        launched.append(list(batch))
+        return orig_execute(acc_, batch, *a)
+
+    mb._execute = spying_execute
+    got = [None] * n
+
+    def submit_one(i):
+        specs = pool[i * specs_each : (i + 1) * specs_each]
+        got[i] = mb.submit_many(
+            index,
+            specs,
+            window_cap=256,
+            record_cap=64,
+            shard_ids=(
+                [k % 2 for k in range(specs_each)]
+                if family == "fused"
+                else None
+            ),
+        )
+
+    threads = _queue_behind_held_slots(acc, submit_one, n)
+    with acc.lock:
+        entries = list(acc.items)
+        assert acc.leader_active
+    acc.pipeline.release()
+    acc.pipeline.release()
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    mb.close()
+    # first come first served, and each launch as full as its head's
+    # padded shape allows, no fuller
+    assert [p for batch in launched for p in batch] == entries
+    sizes = [len(batch) for batch in launched]
+    full, rest = divmod(n, per_launch)
+    assert sizes == [per_launch] * full + ([rest] if rest else [])
+    for i in range(n):
+        specs = pool[i * specs_each : (i + 1) * specs_each]
+        for k, spec in enumerate(specs):
+            ref = run_queries(di, [spec], window_cap=256, record_cap=64)
+            assert bool(got[i].exists[k]) == bool(ref.exists[0]), (i, k)
+            assert int(got[i].call_count[k]) == int(ref.call_count[0])
+
+
+def test_no_shape_is_launched_that_warmup_did_not_compile(dindex):
+    """Under concurrency the batcher launches only (program, shape)
+    keys that a launch of ONE entry of each size already compiled."""
+    from sbeacon_tpu.ops.kernel import FusedDeviceIndex
+    from sbeacon_tpu.telemetry import device_warmup_phase, flight_recorder
+
+    shard, _di = dindex
+    findex = FusedDeviceIndex([shard, shard], pad_unit=2048)
+    sizes = (1, 3, 4, 8, 12, 16)
+    pool = specs_for(shard, 64)
+    mb = MicroBatcher(max_batch=512, max_wait_ms=0)
+
+    def submit(k, off=0):
+        return mb.submit_many(
+            findex,
+            pool[off : off + k],
+            shard_ids=[j % 2 for j in range(k)],
+            window_cap=256,
+            record_cap=64,
+        )
+
+    with device_warmup_phase():
+        for k in sizes:
+            submit(k)
+
+    def keys():
+        return {
+            e["key"]
+            for e in flight_recorder.compile_snapshot()["entries"]
+            if "FusedDeviceIndex:2048" in e["key"]
+        }
+
+    warmed = keys()
+    assert warmed
+    before = mb.occupancy()["launches"]
+
+    def client(t):
+        for j in range(12):
+            submit(sizes[(t + j) % len(sizes)], off=(3 * t + j) % 40)
+
+    threads = [threading.Thread(target=client, args=(t,)) for t in range(12)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    mb.close()
+    assert keys() == warmed
+    assert mb.occupancy()["launches"] - before <= 12 * 12
+
+
+def test_follower_behind_a_waiting_leader_times_out_and_withdraws(
+    dindex, monkeypatch
+):
+    """A follower queued while the leader waits for a slot leaves at
+    its own bound, with today's classification: 503 for the local
+    timeout, 504 under a lapsed request deadline."""
+    from sbeacon_tpu.resilience import (
+        BatchTimeout,
+        Deadline,
+        DeadlineExceeded,
+        deadline_scope,
+    )
+
+    shard, di = dindex
+    (spec,) = specs_for(shard, 1)
+    mb = MicroBatcher(max_batch=64, max_wait_ms=0)
+    acc = mb._accum(di, (256, 64))
+    leader_got = []
+    threads = _queue_behind_held_slots(
+        acc,
+        lambda i: leader_got.append(
+            mb.submit(di, spec, window_cap=256, record_cap=64)
+        ),
+        1,
+    )
+    assert acc.leader_active  # parked on the slot, leadership claimed
+    with pytest.raises(BatchTimeout):
+        mb.submit(di, spec, window_cap=256, record_cap=64, timeout_s=0.15)
+    with deadline_scope(Deadline.after(0.15)):
+        with pytest.raises(DeadlineExceeded):
+            mb.submit(di, spec, window_cap=256, record_cap=64)
+    occ = mb.occupancy()
+    assert (occ["timeouts"], occ["expired"], occ["launches"]) == (1, 1, 0)
+    assert len(acc.items) == 1 and acc.leader_active  # both withdrew
+    acc.pipeline.release()
+    acc.pipeline.release()
+    threads[0].join(30)
+    assert not threads[0].is_alive()
+    assert leader_got and leader_got[0].exists is not None
+    assert mb.occupancy()["histogram"] == {1: 1}
+    mb.close()
+
+
+def test_leader_whose_slot_never_comes_returns_at_its_deadline(dindex):
+    """The leader's wait for the slot is bounded by its own deadline:
+    it withdraws, the leadership passes to a drainer that serves the
+    follower once a slot frees, and no slot is lost."""
+    from sbeacon_tpu.resilience import BatchTimeout
+
+    shard, di = dindex
+    (spec,) = specs_for(shard, 1)
+    mb = MicroBatcher(max_batch=64, max_wait_ms=0)
+    acc = mb._accum(di, (256, 64))
+    raised = []
+
+    def leader(_i):
+        try:
+            mb.submit(di, spec, window_cap=256, record_cap=64, timeout_s=0.2)
+        except BaseException as e:  # noqa: BLE001 - recorded
+            raised.append(e)
+
+    threads = _queue_behind_held_slots(acc, leader, 1)
+    follower_got = []
+    ft = threading.Thread(
+        target=lambda: follower_got.append(
+            mb.submit(di, spec, window_cap=256, record_cap=64)
+        )
+    )
+    ft.start()
+    _wait_until(lambda: len(acc.items) == 2)
+    threads[0].join(10)
+    assert not threads[0].is_alive()
+    assert len(raised) == 1 and isinstance(raised[0], BatchTimeout)
+    # the follower is still queued and somebody still leads for it
+    assert len(acc.items) == 1 and acc.leader_active
+    assert mb.occupancy()["launches"] == 0
+    acc.pipeline.release()
+    acc.pipeline.release()
+    ft.join(30)
+    assert not ft.is_alive()
+    assert follower_got and follower_got[0].exists is not None
+    _wait_until(lambda: not acc.leader_active)
+    mb.close()
+    assert acc.items == []
+    assert acc.pipeline.acquire(blocking=False)
+    assert acc.pipeline.acquire(blocking=False)
+    assert not acc.pipeline.acquire(blocking=False)
+
+
+@pytest.mark.parametrize("path", ["empty", "all_expired", "failed_dispatch"])
+def test_a_slot_taken_for_nothing_is_given_back(dindex, path):
+    """The ways out that launch nothing: the queue emptied while the
+    drainer waited, every popped entry had expired, the launcher
+    refused the batch. Each gives its slot back."""
+    import time
+
+    from sbeacon_tpu.resilience import NO_DEADLINE, Deadline
+    from sbeacon_tpu.serving import _Pending
+
+    shard, di = dindex
+    (spec,) = specs_for(shard, 1)
+    mb = MicroBatcher(max_batch=64, max_wait_ms=0, default_timeout_s=5.0)
+    acc = mb._accum(di, (256, 64))
+    entry = _Pending(
+        specs=[spec], event=threading.Event(), t_submit=time.perf_counter()
+    )
+    with acc.lock:
+        acc.leader_active = True
+        if path != "empty":
+            acc.items.append(entry)
+    if path == "all_expired":
+        entry.deadline = entry.req_deadline = Deadline(time.monotonic() - 1)
+        mb._serve(acc, di, 256, 64, None, NO_DEADLINE)
+        assert entry.event.is_set() and entry.error is not None
+    elif path == "failed_dispatch":
+        mb.close()  # the launcher refuses every task from here on
+        with pytest.raises(RuntimeError):
+            mb._serve(acc, di, 256, 64, None, NO_DEADLINE)
+        assert entry.event.is_set()
+        assert isinstance(entry.error, RuntimeError)
+    else:
+        mb._serve(acc, di, 256, 64, None, NO_DEADLINE)
+    assert mb.occupancy()["launches"] == 0
+    assert acc.items == []
+    if path != "failed_dispatch":
+        assert acc.leader_active is False
+    assert acc.pipeline.acquire(blocking=False)
+    assert acc.pipeline.acquire(blocking=False)
+    assert not acc.pipeline.acquire(blocking=False)
+    mb.close()

@@ -1193,6 +1193,8 @@ def test_batcher_pops_interactive_lane_first():
             order.append((p.lane, p.specs[0]))
             p.result = "ok"
             p.event.set()
+        # a launch arrives with its fetch-pipeline slot and gives it back
+        acc_.pipeline.release()
 
     b._run_batch = fake_run
     lanes = ["bulk", "bulk", "interactive", "interactive", "bulk",
@@ -1236,6 +1238,8 @@ def test_batcher_aged_bulk_entry_keeps_fifo_spot():
             order.append((p.lane, p.specs[0]))
             p.result = "ok"
             p.event.set()
+        # a launch arrives with its fetch-pipeline slot and gives it back
+        acc_.pipeline.release()
 
     b._run_batch = fake_run
     now = time.perf_counter()
