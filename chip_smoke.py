@@ -1381,8 +1381,8 @@ def check_device_memory(run: Run, devices, status: dict) -> None:
                 isinstance(planes, PlaneDeviceIndex),
                 f"{key}: genotype planes are not on the device",
             )
-            # the arrays' own size, held padded to whole 128-lane
-            # tiles, and what the budget gate reserved for them
+            # the arrays' own size, held in whole 128-lane rows, and
+            # what the budget gate reserved for them
             plane_bytes += planes.nbytes_hbm()
             plane_gate_bytes += PlaneDeviceIndex.estimate_hbm(shard)
     fused = engine._fused_state
@@ -1473,7 +1473,7 @@ def check_plane_programs(run: Run, devices) -> None:
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from sbeacon_tpu.ops.plane_kernel import _plane_stats, padded_words
+    from sbeacon_tpu.ops.plane_kernel import _plane_stats, resident_shape
     from sbeacon_tpu.ops.scatter_kernel import (
         CHUNK_SMALL,
         ScatterDeviceIndex,
@@ -1495,7 +1495,7 @@ def check_plane_programs(run: Run, devices) -> None:
 
     def selected(n_rows, seg_k):
         n_tiles = n_rows // tile + 1 + ScatterDeviceIndex.MAX_C
-        plane = shape(n_rows, padded_words(n_words))
+        plane = shape(*resident_shape(n_rows, n_words))
         return _selected_batch.lower(
             shape(n_tiles, 8, tile), plane, plane, plane, plane,
             shape(CHUNK_SMALL), shape(CHUNK_SMALL, 8),

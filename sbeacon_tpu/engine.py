@@ -774,6 +774,9 @@ class VariantEngine:
         # belongs to (two racing builds could publish out of order)
         self._fused_gen = 0
         self.fused_searches = 0
+        # targets requests had served from the scatter pool (one task a
+        # target; engine.pool_wait times each task's wait for a thread)
+        self.fanout_targets = 0
         self.mesh_searches = 0
         # warm phases that failed and were skipped since the last
         # warmup() run started (see _warm_failed)
@@ -2221,6 +2224,23 @@ class VariantEngine:
                     add(chip_of(piece.device), "stack", piece.data.nbytes)
         return out
 
+    def plane_fill(self) -> dict:
+        """{chip: per cent} of the plane bytes resident on a chip that
+        are the planes' own words (``n_rows x n_words x 4``); the rest
+        is the zero padding of their lane rows: 100 at 1000 samples
+        (four rows fill a lane row), 61.7 at 2504. Lock-free, as
+        ``resident_bytes``."""
+        from .ops.plane_kernel import chip_of
+
+        own: dict[str, int] = {}
+        held: dict[str, int] = {}
+        for _ds, _vcf, (_s, _d, planes) in self._serve_list:
+            if planes is not None:
+                chip = str(chip_of(planes.device))
+                own[chip] = own.get(chip, 0) + planes.logical_bytes()
+                held[chip] = held.get(chip, 0) + planes.nbytes_hbm()
+        return {c: 100.0 * own[c] / n for c, n in held.items() if n}
+
     def placement_table(self) -> list[dict]:
         """[{dataset, vcf, chip, bytes}] of every placed key, sorted:
         who owns what (``/debug/status`` serves it)."""
@@ -2515,12 +2535,27 @@ class VariantEngine:
             "queries answered by the one-pjit mesh path",
             fn=lambda: self.mesh_searches,
         )
+        registry.counter(
+            "engine.fanout_targets",
+            "targets of multi-dataset requests served one pool task "
+            "each (engine.fanout parks the request meanwhile)",
+            fn=lambda: self.fanout_targets,
+        )
         registry.gauge(
             "device.plane_resident_bytes",
-            "HBM bytes of the genotype planes resident per dataset, "
-            "each plane n_rows x 512 B per 4096 samples (what "
+            "HBM bytes of the genotype planes resident per dataset, in "
+            "whole 128-lane rows: n_rows x 512 B per 4096 samples above "
+            "2048 samples, below that k = 128 // p rows to a lane row, "
+            "p the words of a row rounded up to a power of two (what "
             "plane_hbm_budget_gb is spent on; hosts are sized by it)",
             fn=lambda: self.plane_ledger()["residentBytes"],
+        )
+        registry.gauge(
+            "device.plane_fill",
+            "per cent of a chip's resident plane bytes that are the "
+            "planes' own words (n_rows x n_words x 4), the rest padding",
+            label="chip",
+            fn=self.plane_fill,
         )
         registry.gauge(
             "device.resident_bytes",
@@ -3023,7 +3058,11 @@ class VariantEngine:
         def _pooled_target(target):
             # the request's own thread is parked in ``engine.fanout``
             # for as long as the pool serves it: the pool's stages keep
-            # their count and sum and add nothing to the chain's req_ms
+            # their count and sum and add nothing to the chain's req_ms.
+            # First the task's own wait for one of the pool's threads
+            tracer.observe(
+                "engine.pool_wait", (time.perf_counter() - t_pool) * 1e3, 0
+            )
             with tracer.serving(0):
                 return _one_target(target)
 
@@ -3123,7 +3162,10 @@ class VariantEngine:
             # per-dataset dispatch, search_variants.py:77-118): overlaps
             # the per-shard device round-trips instead of serialising them
             with stage("engine.fanout"):
+                t_pool = time.perf_counter()
                 responses = list(self._scatter.map(_pooled_target, targets))
+            with self._mat_lock:  # unlocked += drops concurrent counts
+                self.fanout_targets += len(targets)
         else:
             # L0-covered tail targets have NO device work left — their
             # rows are already in hand, materialisation is pure host —

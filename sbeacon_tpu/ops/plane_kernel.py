@@ -7,13 +7,16 @@ response), capping the path at one host's RAM (VERDICT r3 missing #2).
 This module puts the planes themselves in HBM and runs the per-row
 masked popcounts and the sample-hit OR-reduction in one jitted program:
 
-- ``PlaneDeviceIndex`` holds the shard's planes as ``[n, Wp]`` int32
-  device arrays: W = ceil(n_samples/32) words, zero-padded on the device
-  at upload to Wp = the next multiple of 128. That is the layout a TPU
-  row gather reads as it is; an ``[n, W]`` argument is re-tiled whole
-  inside every program that gathers from it. A 2504-sample corpus
-  costs 512 B/row/plane of HBM. The count planes (gt2/tok1/tok2) are
-  uploaded only when the
+- ``PlaneDeviceIndex`` holds the shard's planes as int32 device arrays
+  whose minor dimension is whole 128-lane rows, the layout a TPU row
+  gather reads as it is (an ``[n, W]`` argument, W = ceil(n_samples/32)
+  words, is re-tiled whole inside every program that gathers from it).
+  A row of more than 64 words is zero-padded to the next multiple of
+  128: ``[n, Wp]``, 512 B/row/plane at 2504 samples. A narrower row is
+  zero-padded to p words, p the least power of two >= W, and k = 128 // p
+  rows share one lane row: ``[ceil(n / k), 128]``, 128 B/row/plane at
+  1000 samples (``pack_factor``, ``resident_shape``). The count planes
+  (gt2/tok1/tok2) are uploaded only when the
   shard has genotype-derived rows at all — INFO-sourced corpora (the
   common cohort-VCF case, and the bench corpus) only ever touch ``gt``
   for sample-hit extraction, so only it occupies HBM.
@@ -53,10 +56,69 @@ from ..telemetry import record_device_launch
 _R_TIERS = (128, 1024, 8192)
 
 
+#: words of one resident lane row: the minor tile of a TPU array
+LANES = 128
+
+
 def padded_words(n_words: int) -> int:
-    """Words per resident plane row: ``n_words`` rounded up to the 128
-    lanes of a TPU tile."""
-    return -(-n_words // 128) * 128
+    """Words a row of ``n_words`` takes in the resident array: rounded
+    up to whole 128-lane rows above 64 words, else to the least power
+    of two that holds it (so that ``pack_factor`` rows fill a lane
+    row)."""
+    if n_words > LANES // 2:
+        return -(-n_words // LANES) * LANES
+    return 1 << max(0, n_words - 1).bit_length()
+
+
+def pack_factor(n_words: int) -> int:
+    """k: rows of a plane that share one resident lane row (4 at 32
+    words, 2 up to 64, 1 above). It follows from the width alone, so
+    the upload, the budget and the programs agree without being told."""
+    return max(1, LANES // padded_words(n_words))
+
+
+def resident_shape(n_rows: int, n_words: int) -> tuple[int, int]:
+    """Shape of the resident array of an ``[n_rows, n_words]`` plane:
+    ``[ceil(n_rows / k), k * padded_words]``; row r lies in lane row
+    ``r // k`` at words ``(r % k) * padded_words`` onward (the rows past
+    the last in its lane row are zeros nobody reads)."""
+    k = pack_factor(n_words)
+    return -(-n_rows // k), k * padded_words(n_words)
+
+
+def masked_rows(plane, rows, mask):
+    """``plane[rows] & mask`` read from the resident layout: int32
+    ``[..., R, lanes]`` for ``rows`` ``[..., R]`` (below
+    ``plane.shape[0] * k``) and ``mask`` ``[..., W]``. With k rows to a
+    lane row the whole lane row ``rows // k`` is gathered and the mask,
+    zero-extended and repeated for every part, keeps part ``rows % k``
+    alone: the other parts and the padding words come out zero, so a
+    popcount over all lanes counts row r and nothing else."""
+    n_words = mask.shape[-1]
+    k = pack_factor(n_words)
+    p = plane.shape[1] // k
+    m = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, p - n_words)])
+    m = m[..., None, :]
+    if k == 1:
+        return plane[rows] & m
+    part = jax.lax.iota(jnp.int32, plane.shape[1]) // p
+    keep = part == (rows % k)[..., None]
+    return plane[rows // k] & jnp.where(keep, jnp.tile(m, k), jnp.int32(0))
+
+
+def fold_parts(words, n_words: int):
+    """``[..., lanes]`` words OR-reduced over ``masked_rows`` outputs ->
+    the ``[..., n_words]`` OR of the rows themselves: the k parts of
+    the lane row folded together, the padding words cut."""
+    k = pack_factor(n_words)
+    if k > 1:
+        words = jax.lax.reduce(
+            words.reshape(words.shape[:-1] + (k, words.shape[-1] // k)),
+            np.int32(0),
+            jax.lax.bitwise_or,
+            dimensions=(words.ndim - 1,),
+        )
+    return words[..., :n_words]
 
 
 @partial(jax.jit, donate_argnums=0)
@@ -73,11 +135,29 @@ def chip_of(device) -> int:
     return 0 if device is None else int(device.id)
 
 
+def _lane_rows(chunk: np.ndarray, k: int) -> np.ndarray:
+    """Host rows ``[m, w]`` as they cross to the device. One row to a
+    lane row they cross as they are (the write pads them there). k > 1
+    to a lane row they cross as the ``[ceil(m / k), 128]`` lane rows
+    they fill: a view where the rows are already ``padded_words`` wide
+    and m divides by k (32 words: 4m rows ARE ``[m, 128]``), else a
+    zero-padded copy of this chunk alone."""
+    if k == 1:
+        return np.ascontiguousarray(chunk)
+    m, w = chunk.shape
+    p = LANES // k
+    if w != p or m % k:
+        padded = np.zeros((-(-m // k) * k, p), chunk.dtype)
+        padded[:m, :w] = chunk
+        chunk = padded
+    return np.ascontiguousarray(chunk).reshape(-1, LANES)
+
+
 def staged_device_put(
     a: np.ndarray, chunk_bytes: int | None, device=None
 ):
-    """H2D upload of an ``[n, W]`` plane into its resident ``[n, Wp]``
-    form (``Wp = padded_words(W)``) on ``device`` (the zero fill, every
+    """H2D upload of an ``[n, W]`` plane into its resident form
+    (``resident_shape(n, W)``) on ``device`` (the zero fill, every
     chunk and the pad: nothing touches another chip), as pre-staged
     contiguous row chunks.
 
@@ -86,34 +166,39 @@ def staged_device_put(
     1.02 GB). Chunking double-buffers it: ``jax.device_put`` is
     asynchronous, so chunk i+1 streams to the device while chunk i is
     written into the zero-filled resident array, on the device and in
-    place. The host array is never padded or copied whole, the bytes
-    that cross are the unpadded ones, and the transient footprint is
+    place. The host array is never padded or copied whole: a wide row
+    crosses unpadded and is padded by the write on the device, rows
+    that share a lane row cross as the lane rows they fill
+    (``_lane_rows``). The transient footprint is
     the resident array plus two chunks and one chunk's padded form (a
     monolithic upload holds the whole unpadded array and its padded
     form beside it until the write has run). ``chunk_bytes`` None/<=0
     or a small array is one chunk.
     """
     n, w = a.shape
+    k = pack_factor(w)
     rows_per = max(n, 1)
     if chunk_bytes and 0 < chunk_bytes < a.nbytes:
         # whole (8, 128) tiles per write
-        rows_per = max(8, int(chunk_bytes // max(1, w * a.itemsize)) // 8 * 8)
+        tile_rows = 8 * k
+        rows_per = max(
+            tile_rows,
+            int(chunk_bytes // max(1, w * a.itemsize)) // tile_rows * tile_rows,
+        )
 
     def put(i):
         if i >= n:
             return None
-        return jax.device_put(
-            np.ascontiguousarray(a[i : i + rows_per]), device
-        )
+        return jax.device_put(_lane_rows(a[i : i + rows_per], k), device)
 
     with jax.default_device(device):
-        out = jnp.zeros((n, padded_words(w)), a.dtype)
+        out = jnp.zeros(resident_shape(n, w), a.dtype)
     ahead = put(0)
     for i in range(0, n, rows_per):
         chunk, ahead = ahead, put(i + rows_per)
         # one transfer ahead of the write and no more: the transfers
         # are enqueued at once, each holding its buffer on the device
-        out = _write_rows(out, chunk, i).block_until_ready()
+        out = _write_rows(out, chunk, i // k).block_until_ready()
     return out
 
 
@@ -164,8 +249,8 @@ class PlaneDeviceIndex:
         # the chip every plane is committed to, and so the chip every
         # program that reads them runs on (None: the default device)
         self.device = device
-        # n_words is the logical width (the mask's and or_words'); the
-        # resident arrays are padded_words(n_words) wide
+        # n_rows x n_words is the logical shape (the mask's and
+        # or_words' width); the resident arrays are resident_shape of it
         self.n_rows, self.n_words = shard.gt_bits.shape
         self.has_counts = self.wants_count_planes(shard)
 
@@ -196,9 +281,14 @@ class PlaneDeviceIndex:
 
     def nbytes_hbm(self) -> int:
         """HBM bytes of the resident planes, exactly: each is held
-        ``[n_rows, padded_words(n_words)]`` int32. What the budget gate
+        ``resident_shape(n_rows, n_words)`` int32. What the budget gate
         reserved before the upload (``estimate_hbm``)."""
         return sum(int(a.nbytes) for a in self.planes())
+
+    def logical_bytes(self) -> int:
+        """Bytes of the planes' own words, ``n_rows x n_words x 4``
+        each: what ``nbytes_hbm`` would be with no padding at all."""
+        return self.n_rows * self.n_words * 4 * len(self.planes())
 
     @staticmethod
     def estimate_hbm(shard: VariantIndexShard) -> int:
@@ -206,9 +296,9 @@ class PlaneDeviceIndex:
         count-plane predicate as the constructor)."""
         if shard.gt_bits is None:
             return 0
-        n, w = shard.gt_bits.shape
+        lane_rows, lanes = resident_shape(*shard.gt_bits.shape)
         has_counts = PlaneDeviceIndex.wants_count_planes(shard)
-        return n * padded_words(w) * 4 * (4 if has_counts else 1)
+        return lane_rows * lanes * 4 * (4 if has_counts else 1)
 
 
 @partial(jax.jit, static_argnames=("R", "with_counts", "with_or"))
@@ -219,33 +309,35 @@ def _plane_stats(
 
     ``rows`` int32[R] (padding slots point at row 0; callers discard
     their outputs), ``or_sel`` int32[R] 0/1, ``mask`` int32[W]: the
-    planes are ``[n, Wp]`` (``PlaneDeviceIndex``), the mask is
-    zero-extended to their width here. Popcount columns:
+    planes lie in their resident layout (``PlaneDeviceIndex``) and are
+    read through ``masked_rows``. Popcount columns:
     0=gt, 1=gt2, 2=tok1, 3=tok2 (count columns zero when the plane set
     has no count planes)."""
     n_words = mask.shape[0]
-    m = jnp.pad(mask, (0, gt.shape[1] - n_words))[None, :]
 
-    def pc(plane):
-        return jnp.sum(
-            jax.lax.population_count(plane[rows] & m), axis=1
-        ).astype(jnp.int32)
+    def pc(g):
+        return jnp.sum(jax.lax.population_count(g), axis=1).astype(jnp.int32)
 
-    g = gt[rows] & m  # [R, Wp]
-    pc_gt = jnp.sum(jax.lax.population_count(g), axis=1).astype(jnp.int32)
+    g = masked_rows(gt, rows, mask)  # [R, lanes]
+    pc_gt = pc(g)
     zero = jnp.zeros_like(pc_gt)
     if with_counts:
-        cols = [pc_gt, pc(gt2), pc(tok1), pc(tok2)]
+        cols = [pc_gt] + [
+            pc(masked_rows(plane, rows, mask)) for plane in (gt2, tok1, tok2)
+        ]
     else:
         cols = [pc_gt, zero, zero, zero]
     counts = jnp.stack(cols, axis=1)
     if with_or:
-        or_words = jax.lax.reduce(
-            jnp.where(or_sel[:, None] != 0, g, jnp.int32(0)),
-            np.int32(0),
-            jax.lax.bitwise_or,
-            dimensions=(0,),
-        )[:n_words]
+        or_words = fold_parts(
+            jax.lax.reduce(
+                jnp.where(or_sel[:, None] != 0, g, jnp.int32(0)),
+                np.int32(0),
+                jax.lax.bitwise_or,
+                dimensions=(0,),
+            ),
+            n_words,
+        )
     else:
         or_words = jnp.zeros((n_words,), jnp.int32)
     return counts, or_words

@@ -70,7 +70,7 @@ from ..telemetry import (
     record_device_launch,
 )
 from ..utils.trace import stage
-from .plane_kernel import chip_of
+from .plane_kernel import chip_of, fold_parts, masked_rows, pack_factor
 from .kernel import (
     MODE_ANY_BASE,
     MODE_EXACT,
@@ -158,7 +158,8 @@ def _selected_program_key(
     plane shapes are argument shapes too)."""
     return (
         "scatter_selected", int(sindex.tiles.shape[0]),
-        tuple(int(d) for d in pindex.gt.shape), pindex.n_words, nslots,
+        tuple(int(d) for d in pindex.gt.shape), pindex.n_words,
+        pack_factor(pindex.n_words), nslots,
         cap, R, C, exact_only, with_counts, _static_seg_k(sindex),
         sindex.tile, chip_of(sindex.device),
     )
@@ -558,10 +559,11 @@ def _selected_batch(
       ``_rows_from_masks``),
     - their gt/count planes are gathered, masked per-query
       (``mask`` int32 [nslots, W]) and popcounted. The planes are
-      resident ``[n, Wp]``, W padded to whole 128-lane tiles
-      (``PlaneDeviceIndex``), so the gather reads them as they lie;
-      the mask is zero-extended to Wp here and ``or_words`` cut back
-      to W, both on the device,
+      resident in whole 128-lane rows, a wide row zero-padded to them
+      and k narrow rows sharing one (``PlaneDeviceIndex``), so the
+      gather reads them as they lie: ``masked_rows`` gathers row r's
+      lane row and keeps its part, ``fold_parts`` brings ``or_words``
+      back to W, both on the device,
     - the sample-hit OR runs over the exact ``grp >= k0`` row subset
       via the same segmented scans as ``parallel.mesh._plane_reduce``
       (k0 = first record with positive cumulative rc; ploidy>2
@@ -598,18 +600,20 @@ def _selected_batch(
     )
     rec_r = jnp.take_along_axis(seg_id, order, axis=1)
 
-    n_rows = gt.shape[0]
-    safe = jnp.clip(rows, 0, n_rows - 1)
     n_words = mask.shape[1]
-    m = jnp.pad(mask, ((0, 0), (0, gt.shape[1] - n_words)))[:, None, :]
-    g = gt[safe] & m  # [B, R, Wp]
+    # unmatched slots (-1) read row 0; the rows past the plane's last in
+    # its last lane row are zeros
+    safe = jnp.clip(rows, 0, gt.shape[0] * pack_factor(n_words) - 1)
+    g = masked_rows(gt, safe, mask)  # [B, R, lanes]
     pcw = lambda x: jnp.sum(
         jax.lax.population_count(x), axis=-1
     ).astype(jnp.int32)
     pc_gt = pcw(g)
     if with_counts:
-        pc_call = pc_gt + pcw(gt2[safe] & m)
-        pc_tok = pcw(tok1[safe] & m) + pcw(tok2[safe] & m)
+        pc_call = pc_gt + pcw(masked_rows(gt2, safe, mask))
+        pc_tok = pcw(masked_rows(tok1, safe, mask)) + pcw(
+            masked_rows(tok2, safe, mask)
+        )
         rc = jnp.where((flags_r & FLAG.AC_INFO) != 0, ac_r, pc_call)
     else:
         pc_call = pc_gt
@@ -648,12 +652,15 @@ def _selected_batch(
     )
     bwd_any = jnp.flip((c_f - base_f) > 0, axis=1)
     or_sel = matched & ((base > 0) | fwd_any | bwd_any)
-    or_words = jax.lax.reduce(
-        jnp.where(or_sel[:, :, None], g, jnp.int32(0)),
-        np.int32(0),
-        jax.lax.bitwise_or,
-        dimensions=(1,),
-    )[:, :n_words]  # [B, W]
+    or_words = fold_parts(
+        jax.lax.reduce(
+            jnp.where(or_sel[:, :, None], g, jnp.int32(0)),
+            np.int32(0),
+            jax.lax.bitwise_or,
+            dimensions=(1,),
+        ),
+        n_words,
+    )  # [B, W]
     return agg, rows, pc_call, pc_tok, or_words
 
 

@@ -82,6 +82,10 @@ STAGES = {
     # engine
     "engine.plan": "work",  # targets, path choice, selected-sample masks
     "engine.fanout": "wait",  # parked while the scatter pool serves targets
+    # one target's wait inside engine.fanout: submitted to the scatter
+    # pool -> a pool thread starts it (beside the chain: the request's
+    # thread is in engine.fanout meanwhile)
+    "engine.pool_wait": "wait",
     "engine.materialize": "work",  # one target's response
     # micro-batcher
     # submit -> the launcher has the batch, less the entry's share of

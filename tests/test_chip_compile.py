@@ -4,7 +4,8 @@ compiles for a described v5e. Nothing runs, so these say nothing of
 results or times; they hold the per-launch programs to what they may
 touch. A plane resident ``[n, 79]`` was re-tiled whole inside every
 launch (5.12 GB of temp at 1e7 rows, refused outright at 2e7); resident
-``[n, 128]`` the gather reads it as it lies.
+``[n, 128]`` the gather reads it as it lies, and so it does the
+``[ceil(n / 4), 128]`` of a 1000-sample cohort, four rows to a lane row.
 
 All of them live in this one file: the worker that is given it loads
 the TPU's library, and keeps it.
@@ -22,6 +23,7 @@ from sbeacon_tpu.ops.plane_kernel import (
     _plane_stats,
     _write_rows,
     padded_words,
+    resident_shape,
 )
 from sbeacon_tpu.ops.scatter_kernel import (
     CHUNK_SMALL,
@@ -31,6 +33,8 @@ from sbeacon_tpu.ops.scatter_kernel import (
 
 KG1_ROWS = 10_000_000  # benchmark/configs/kg1.json
 KG1_WORDS = 79  # 2504 samples
+MDSP_ROWS = 2_999_000  # benchmark/configs/mdsp.json, one of sixteen datasets
+MDSP_WORDS = 32  # 1000 samples: four rows to a lane row
 TILE = 128
 
 
@@ -77,15 +81,15 @@ def _plane_sized_outputs(compiled, n_rows: int) -> list[str]:
     ]
 
 
-def _selected(one_chip, n_rows, cap, C, with_counts=False):
+def _selected(one_chip, n_rows, cap, C, with_counts=False, n_words=KG1_WORDS):
     n_tiles = n_rows // TILE + 1 + ScatterDeviceIndex.MAX_C
-    plane = _shape(one_chip, n_rows, padded_words(KG1_WORDS))
+    plane = _shape(one_chip, *resident_shape(n_rows, n_words))
     return _selected_batch.lower(
         _shape(one_chip, n_tiles, 8, TILE),
         plane, plane, plane, plane,
         _shape(one_chip, CHUNK_SMALL),
         _shape(one_chip, CHUNK_SMALL, 8),
-        _shape(one_chip, CHUNK_SMALL, KG1_WORDS),
+        _shape(one_chip, CHUNK_SMALL, n_words),
         T=TILE, CAP=cap, nslots=CHUNK_SMALL, C=C, exact_only=True,
         R=min(1024, cap), with_counts=with_counts, seg_k=2,
     ).compile()
@@ -96,10 +100,11 @@ def _rows_that_fit(with_counts: bool) -> int:
     return KG1_ROWS // 4 if with_counts else KG1_ROWS
 
 
-def _holds_no_plane_copy(compiled, n_rows):
-    plane_bytes = n_rows * padded_words(KG1_WORDS) * 4
+def _holds_no_plane_copy(compiled, n_rows, n_words=KG1_WORDS):
+    lane_rows, lanes = resident_shape(n_rows, n_words)
+    plane_bytes = lane_rows * lanes * 4
     assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes // 8
-    assert _plane_sized_outputs(compiled, n_rows) == []
+    assert _plane_sized_outputs(compiled, lane_rows) == []
 
 
 @pytest.mark.parametrize(
@@ -116,7 +121,7 @@ def test_selected_batch_touches_no_whole_plane(one_chip, cap, C, with_counts):
 @pytest.mark.parametrize("R,with_counts", [(128, False), (8192, True)])
 def test_plane_stats_touches_no_whole_plane(one_chip, R, with_counts):
     n_rows = _rows_that_fit(with_counts)
-    plane = _shape(one_chip, n_rows, padded_words(KG1_WORDS))
+    plane = _shape(one_chip, *resident_shape(n_rows, KG1_WORDS))
     compiled = _plane_stats.lower(
         plane, plane, plane, plane,
         _shape(one_chip, R), _shape(one_chip, R),
@@ -157,3 +162,39 @@ def test_upload_writes_its_chunk_in_place(one_chip):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == KG1_ROWS * wp * 4
     assert memory.temp_size_in_bytes <= 2 * rows * wp * 4
+
+
+@pytest.mark.parametrize(
+    "cap,C,gathered_bytes",
+    # what one launch gathers: 64 slots x R rows x 128 lanes of words
+    [(128, 1, 64 * 128 * 512), (128, None, 64 * 128 * 512),
+     (2048, None, 64 * 1024 * 512)],
+)
+def test_packed_selected_batch_at_mdsp_shapes(one_chip, cap, C, gathered_bytes):
+    """``mdsp``: 2,999,000 rows of 32 words, four to a lane row. The
+    program's temp is of the order of the rows it gathers, never of
+    the plane (384 MB)."""
+    assert resident_shape(MDSP_ROWS, MDSP_WORDS) == (749_750, 128)
+    compiled = _selected(one_chip, MDSP_ROWS, cap, C, n_words=MDSP_WORDS)
+    _holds_no_plane_copy(compiled, MDSP_ROWS, MDSP_WORDS)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.25 * gathered_bytes
+
+
+def test_packed_plane_stats_and_upload_at_mdsp_shapes(one_chip):
+    plane = _shape(one_chip, *resident_shape(MDSP_ROWS, MDSP_WORDS))
+    compiled = _plane_stats.lower(
+        plane, plane, plane, plane,
+        _shape(one_chip, 1024), _shape(one_chip, 1024),
+        _shape(one_chip, MDSP_WORDS),
+        R=1024, with_counts=False, with_or=True,
+    ).compile()
+    _holds_no_plane_copy(compiled, MDSP_ROWS, MDSP_WORDS)
+    # a 256 MiB chunk of 4m host rows crosses as the [m, 128] it fills
+    lane_rows = 256 * 1024 * 1024 // 512
+    memory = _write_rows.lower(
+        plane, _shape(one_chip, lane_rows, 128),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile().memory_analysis()
+    # the device holds whole (8, 128) tiles: 749,752 lane rows
+    assert 0 <= memory.alias_size_in_bytes - 749_750 * 512 < 8 * 512
+    assert memory.temp_size_in_bytes <= 2 * lane_rows * 512

@@ -20,6 +20,7 @@ from sbeacon_tpu.engine import (
     materialize_response_loop,
 )
 from sbeacon_tpu.ops.kernel import QuerySpec
+from sbeacon_tpu.ops.plane_kernel import resident_shape
 from sbeacon_tpu.ops.scatter_kernel import ScatterDeviceIndex
 from sbeacon_tpu.payloads import VariantQueryPayload
 from sbeacon_tpu.telemetry import flight_recorder
@@ -30,8 +31,11 @@ N_ROWS = 4000
 
 
 def _plane_bytes(shard) -> int:
-    """One plane resident: int32[n_rows, 128] (40 samples, 2 words)."""
-    return shard.n_rows * 128 * 4
+    """One plane resident: 40 samples are 2 words, 64 rows to a lane
+    row, int32[ceil(n_rows / 64), 128]."""
+    lane_rows, lanes = resident_shape(shard.n_rows, 2)
+    assert lanes == 128 and lane_rows == -(-shard.n_rows // 64)
+    return lane_rows * lanes * 4
 
 
 @pytest.fixture
@@ -86,7 +90,7 @@ def test_every_dataset_has_one_owner_and_lies_there(chips, n_chips):
             assert dindex.tiles.devices() == {dindex.device}
             for a in planes.planes():
                 assert a.devices() == {dindex.device}
-                assert a.shape == (planes.n_rows, 128)
+                assert a.shape == resident_shape(planes.n_rows, 2)
         table = eng.placement_table()
         assert [row["dataset"] for row in table] == [f"pl{d}" for d in range(4)]
         assert {row["chip"] for row in table} == {d.id for d in set(owners)}
@@ -113,7 +117,7 @@ def test_the_plane_budget_is_a_chip_s(chips, n_chips, planed):
     one chip still do not."""
     chips(n_chips)
     eng, shards = _engine(
-        use_mesh=False, plane_hbm_budget_gb=1.5 * N_ROWS * 128 * 4 / 1e9
+        use_mesh=False, plane_hbm_budget_gb=1.5 * _plane_bytes(_shard(0)) / 1e9
     )
     try:
         got = [p for _k, _s, p in eng.index_snapshot() if p is not None]

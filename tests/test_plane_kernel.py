@@ -3,6 +3,7 @@ masked popcounts / OR-reduction must keep materialize_response
 bit-identical to the loop spec, across INFO-sourced, genotype-derived,
 and ploidy>2-overflow shards (VERDICT r3 #2)."""
 
+import dataclasses
 import random
 
 import numpy as np
@@ -184,7 +185,7 @@ def test_plane_budget_gate():
     eng.close()
 
 
-# -- the resident layout: planes held [n, Wp], Wp whole 128-lane tiles -------
+# -- the resident layout: whole 128-lane rows, k narrow rows to each ----------
 
 
 def _popcount_rows(words: np.ndarray) -> np.ndarray:
@@ -201,7 +202,7 @@ def _layout_shard(n_words: int, with_counts: bool):
     # the last word is partly filled wherever the width allows it
     n_samples = 32 * n_words - (5 if n_words > 1 else 23)
     shard = synthetic_shard(
-        1500,
+        1501,
         n_samples=n_samples,
         seed=100 + n_words,
         dataset_id="lay",
@@ -216,19 +217,33 @@ def _layout_shard(n_words: int, with_counts: bool):
     return shard
 
 
+def _unpacked(resident: np.ndarray, n_rows: int, n_words: int):
+    """A resident array read back as ``[n_rows, padded_words]`` rows and
+    the zero rows that fill its last lane row."""
+    from sbeacon_tpu.ops.plane_kernel import padded_words
+
+    rows = resident.reshape(-1, padded_words(n_words))
+    return rows[:n_rows], rows[n_rows:]
+
+
 @pytest.mark.parametrize("with_counts", [False, True])
-@pytest.mark.parametrize("n_words", [1, 79, 128, 129])
+@pytest.mark.parametrize("n_words", [1, 20, 32, 33, 64, 79, 128, 129])
 def test_resident_layout_answers_as_the_host_planes(n_words, with_counts):
-    """The planes are resident zero-padded to whole 128-lane tiles; the
-    fused program and ``plane_row_stats`` still return exactly what
-    plain numpy reads from the host planes, ``or_words`` as wide as the
-    mask, through padding slots and a second chunk."""
+    """The planes are resident in whole 128-lane rows: a row of over 64
+    words zero-padded to them, k = 128 // p narrower rows sharing one
+    (p the least power of two that holds a row; the row count is no
+    multiple of k here). The fused program and ``plane_row_stats``
+    still return exactly what plain numpy reads from the host planes,
+    ``or_words`` as wide as the mask, through padding slots and a
+    second chunk, and the same words as the k = 1 layout of the same
+    planes (widened with zero words) returns."""
     from sbeacon_tpu.engine import host_match_rows
     from sbeacon_tpu.index.columnar import FLAG
     from sbeacon_tpu.ops.plane_kernel import (
         PlaneDeviceIndex,
-        padded_words,
+        pack_factor,
         plane_row_stats,
+        resident_shape,
         sample_mask_words,
     )
     from sbeacon_tpu.ops.scatter_kernel import (
@@ -238,19 +253,26 @@ def test_resident_layout_answers_as_the_host_planes(n_words, with_counts):
 
     shard = _layout_shard(n_words, with_counts)
     assert shard.gt_bits.shape[1] == n_words
+    k = pack_factor(n_words)
+    assert k == {1: 128, 20: 4, 32: 4, 33: 2, 64: 2}.get(n_words, 1)
+    assert k == 1 or shard.n_rows % k
     pindex = PlaneDeviceIndex(shard, upload_chunk_bytes=100 * n_words * 4)
     assert pindex.has_counts == with_counts
     assert pindex.n_words == n_words
     held = pindex.planes()
     assert len(held) == (4 if with_counts else 1)
+    lane_rows, lanes = resident_shape(shard.n_rows, n_words)
+    assert lanes % 128 == 0 and lane_rows == -(-shard.n_rows // k)
     for a in held:
-        assert a.shape == (shard.n_rows, padded_words(n_words))
+        assert a.shape == (lane_rows, lanes)
     assert pindex.nbytes_hbm() == sum(int(a.nbytes) for a in held)
     assert pindex.nbytes_hbm() == PlaneDeviceIndex.estimate_hbm(shard)
+    assert pindex.logical_bytes() == shard.n_rows * n_words * 4 * len(held)
+    got, beyond = _unpacked(np.asarray(pindex.gt), shard.n_rows, n_words)
     np.testing.assert_array_equal(
-        np.asarray(pindex.gt)[:, :n_words].view(np.uint32), shard.gt_bits
+        got[:, :n_words].view(np.uint32), shard.gt_bits
     )
-    assert not np.asarray(pindex.gt)[:, n_words:].any()
+    assert not got[:, n_words:].any() and not beyond.any()
 
     rng = random.Random(7 * n_words + with_counts)
     n_samples = len(shard.meta["sample_names"])
@@ -278,6 +300,25 @@ def test_resident_layout_answers_as_the_host_planes(n_words, with_counts):
         window_cap=512, record_cap=64,
     )
     assert res.or_words.shape == (len(specs), n_words)
+    if k > 1:
+        # the same planes one row to a lane row: every word as above
+        wide = 79 - n_words
+        unpacked = dataclasses.replace(shard, **{
+            name: np.pad(getattr(shard, name), ((0, 0), (0, wide)))
+            for name in ("gt_bits", "gt_bits2", "tok_bits1", "tok_bits2")
+        })
+        one_a_row = PlaneDeviceIndex(unpacked)
+        assert one_a_row.gt.shape == (shard.n_rows, 128)
+        ref = run_selected_scattered(
+            ScatterDeviceIndex(shard), one_a_row, specs,
+            np.pad(masks, ((0, 0), (0, wide))),
+            window_cap=512, record_cap=64,
+        )
+        np.testing.assert_array_equal(res.rows, ref.rows)
+        np.testing.assert_array_equal(res.pc_call, ref.pc_call)
+        np.testing.assert_array_equal(res.pc_tok, ref.pc_tok)
+        np.testing.assert_array_equal(res.or_words, ref.or_words[:, :n_words])
+        assert not ref.or_words[:, n_words:].any()
     flags, ac = shard.cols["flags"], shard.cols["ac"]
     checked = 0
     assert not res.overflow.any()

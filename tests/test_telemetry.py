@@ -175,6 +175,7 @@ GOLDEN_METRICS = [
     "batcher.fetch_ms",
     "engine.fused_searches",
     "engine.mesh_searches",
+    "engine.fanout_targets",
     "engine.materialize_ms",
     "response_cache.entries",
     "response_cache.max_entries",
@@ -249,6 +250,7 @@ GOLDEN_METRICS = [
     "device.mid_request_compiles",
     "device.fetched_bytes",
     "device.plane_resident_bytes",
+    "device.plane_fill",
     "device.donated_buffers",
     "device.fallbacks",
     "migration.started",
