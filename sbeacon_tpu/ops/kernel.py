@@ -13,14 +13,17 @@ Design notes (TPU/XLA):
   exceeds the window reports ``overflow`` and the host falls back to the
   CPU oracle for that query (two-phase execution keeps the common case
   compiled).
+- The window is contiguous, so it is read as the whole 128-lane rows
+  it lies in (``lane_rows_for``), never word by word: the chip gathers
+  lane rows some hundreds of times faster than single words.
 - The binary search is a fixed-iteration bisection (no data-dependent
   control flow), vmapped over the query batch.
 - int32 everywhere (TPU-native); no int64, no x64 mode. Chromosome
   segmentation is a 27-entry offsets table indexed by chromosome code, so
   the search key is plain ``pos``.
 - "AN once per matching record" (reference :244-250) is computed with a
-  windowed segmented first-match scan over ``rec_id`` — cumsum plus an
-  intra-window searchsorted, no scatter.
+  windowed segmented first-match scan over ``rec_id`` — a cumsum and a
+  running maximum over record starts, no scatter, no search.
 """
 
 from __future__ import annotations
@@ -178,6 +181,16 @@ _PAD_FILLS = {
 }
 
 
+#: lanes of a vector row: the unit the fused program reads a window in
+LANES = 128
+
+
+def lane_rows_for(window_cap: int) -> int:
+    """Lane rows a window of ``window_cap`` words can lie in, wherever
+    in a row it starts (``window_cap // 128 + 1`` for a multiple)."""
+    return (window_cap + 2 * LANES - 2) // LANES
+
+
 def pad_columns(
     cols: dict[str, np.ndarray], n: int, n_pad: int
 ) -> dict[str, np.ndarray]:
@@ -186,6 +199,8 @@ def pad_columns(
     per-shard and fused indexes can never drift on pad-row sentinels."""
     if n > n_pad:
         raise ValueError(f"{n} rows > pad target {n_pad}")
+    if n_pad % LANES:
+        raise ValueError(f"pad target {n_pad} is not whole {LANES}-lane rows")
     out = {}
     for name, fill in _PAD_FILLS.items():
         col = cols[name]
@@ -215,7 +230,7 @@ def window_hint_for(chrom_offsets, floor: int = 256) -> int:
     chromosome) segment — the bisection never leaves ``[seg_lo,
     seg_hi)`` — so the widest segment bounds every ``hi - lo`` the
     kernel can produce. Launching with this instead of the engine-wide
-    ``window_cap`` shrinks the per-lane gather (the launch's compute)
+    ``window_cap`` shrinks the window's lane rows (the launch's compute)
     without ever adding an overflow. Power-of-two with a floor, so the
     hint (a static program dimension) is stable across rebuilds."""
     offs = np.asarray(chrom_offsets)
@@ -271,6 +286,13 @@ class FusedDeviceIndex:
     serving micro-batcher coalesces queries for *different* datasets
     into the same launch (previously each dataset's accumulator
     launched separately).
+
+    The columns are resident 1-D (``alt_prefix`` ``[n, 4]``), as
+    ``pad_columns`` makes them, ``n_padded`` a multiple of 8192: the
+    program sees each as ``[n / 128, 128]`` (a bitcast, no copy: a
+    1024-word tile of the 1-D layout is an ``(8, 128)`` tile) and reads
+    a query's window as the 17 lane rows it lies in — see
+    ``_query_one``.
 
     Row ids come back as absolute stacked ids; ``shard_base[sid]``
     maps them back to shard-local ids for host materialisation. The
@@ -366,7 +388,7 @@ class L0DeviceIndex(FusedDeviceIndex):
         # a tail shard's candidate window can never exceed its own
         # row count, so the launch may run with a window sized to the
         # LARGEST tail shard instead of the engine-wide window_cap —
-        # the per-lane gather (the launch's compute) shrinks ~8-16x
+        # the window's lane rows (the launch's compute) shrink ~8-16x
         # for typical tails. Power-of-two with a floor, so the hint
         # (a static program dimension) is stable across builds.
         widest = max((s.n_rows for s in shards), default=1)
@@ -499,6 +521,18 @@ def _bisect(pos, target, lo0, hi0, n_iters, *, upper: bool):
 
 
 def _query_one(arrays, q, *, window_cap: int, record_cap: int, n_iters: int):
+    """One query against one index (vmapped over the batch): THE
+    predicate of every XLA index class and of the mesh programs.
+
+    Two bisections give the candidate range ``[lo, hi)``; the window
+    ``[lo, lo + window_cap)`` is then read in whole 128-lane rows, each
+    column seen as ``[n / 128, 128]``: ``lane_rows_for(window_cap)``
+    rows from row ``lo // 128``, lanes outside the window invalid. On
+    the chip a gather of single words from a 6.4e7-row column took
+    1.7 ms a column a launch, twelve columns a launch; the same window
+    as 17 lane rows is one of the row gathers the plane programs
+    already make (PERF.md 6, PR 32). The answers are those of a
+    word-by-word read, bit for bit."""
     pos = arrays["pos"]
     offsets = arrays["chrom_offsets"]
     n = pos.shape[0]
@@ -515,11 +549,21 @@ def _query_one(arrays, q, *, window_cap: int, record_cap: int, n_iters: int):
     lo = _bisect(pos, q["start_min"], seg_lo, seg_hi, n_iters, upper=False)
     hi = _bisect(pos, q["start_max"], seg_lo, seg_hi, n_iters, upper=True)
 
-    idxs = lo + jnp.arange(window_cap, dtype=jnp.int32)
-    valid = idxs < hi
-    safe = jnp.clip(idxs, 0, n - 1)
+    # rows past the column's end repeat the last one: their lanes, like
+    # those before lo, at or past hi, or past the window, are invalid
+    rows_at = lo // LANES + jnp.arange(
+        lane_rows_for(window_cap), dtype=jnp.int32
+    )
+    idxs = (
+        rows_at[:, None] * LANES + jnp.arange(LANES, dtype=jnp.int32)
+    ).reshape(-1)
+    valid = (idxs >= lo) & (idxs < hi) & (idxs - lo < window_cap)
+    safe_rows = jnp.clip(rows_at, 0, n // LANES - 1)
 
-    g = lambda name: arrays[name][safe]
+    def g(name):
+        col = arrays[name]  # [n] or, alt_prefix, [n, 4]
+        tail = col.shape[1:]
+        return col.reshape((-1, LANES) + tail)[safe_rows].reshape((-1,) + tail)
 
     rec_end = g("rec_end")
     end_ok = (q["end_min"] <= rec_end) & (rec_end <= q["end_max"])
@@ -538,7 +582,7 @@ def _query_one(arrays, q, *, window_cap: int, record_cap: int, n_iters: int):
     ref_len = g("ref_len")
 
     # symbolic-prefix match: first L bytes of alt equal '<'+variant_type
-    ap = arrays["alt_prefix"][safe]  # [W, 4] uint32
+    ap = g("alt_prefix")  # [lanes, 4] uint32
     pm = jnp.all(
         ((ap ^ q["vprefix"][None, :]) & q["vprefix_mask"][None, :]) == 0, axis=1
     )
@@ -584,16 +628,18 @@ def _query_one(arrays, q, *, window_cap: int, record_cap: int, n_iters: int):
     # AN once per record with >= 1 matched row: segmented first-match scan
     rec_w = jnp.where(valid, g("rec_id"), INT32_MAX)
     m_i = matched.astype(jnp.int32)
-    cums = jnp.cumsum(m_i)
-    seg_start = jnp.searchsorted(rec_w, rec_w, side="left").astype(jnp.int32)
-    before_all = cums - m_i  # matched strictly before row i
-    before_seg = jnp.where(seg_start > 0, cums[jnp.clip(seg_start - 1, 0)], 0)
-    first_match = matched & ((before_all - before_seg) == 0)
+    before_all = jnp.cumsum(m_i) - m_i  # matched strictly before lane i
+    # ... and strictly before the lane its record starts at: before_all
+    # never falls, so its running maximum over record starts is its
+    # value at the nearest one (a record's rows are adjacent)
+    starts = jnp.concatenate([jnp.ones(1, bool), rec_w[1:] != rec_w[:-1]])
+    before_seg = jax.lax.cummax(jnp.where(starts, before_all, 0))
+    first_match = matched & (before_all == before_seg)
     all_alleles = jnp.sum(jnp.where(first_match, g("an"), 0))
 
     # matched row ids, ascending, -1 padded, capped at record_cap
     marked = jnp.where(matched, idxs, INT32_MAX)
-    topk = jax.lax.sort(marked)[:record_cap]
+    topk = jax.lax.sort(marked)[: min(record_cap, window_cap)]
     rows = jnp.where(topk == INT32_MAX, -1, topk)
 
     return {

@@ -6,6 +6,9 @@ touch. A plane resident ``[n, 79]`` was re-tiled whole inside every
 launch (5.12 GB of temp at 1e7 rows, refused outright at 2e7); resident
 ``[n, 128]`` the gather reads it as it lies, and so it does the
 ``[ceil(n / 4), 128]`` of a 1000-sample cohort, four rows to a lane row.
+The fused program read its windows word by word, 1.7 ms a column a
+launch over ``mds``' 6.4e7 rows; it reads them as the lane rows they lie
+in, every column a bitcast of the 1-D array that is resident.
 
 All of them live in this one file: the worker that is given it loads
 the TPU's library, and keeps it.
@@ -16,9 +19,18 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from sbeacon_tpu.ops.kernel import (
+    _PAD_FILLS,
+    QuerySpec,
+    _query_batch,
+    bisect_iters,
+    encode_queries,
+    pad_columns,
+)
 from sbeacon_tpu.ops.plane_kernel import (
     _plane_stats,
     _write_rows,
@@ -198,3 +210,69 @@ def test_packed_plane_stats_and_upload_at_mdsp_shapes(one_chip):
     # the device holds whole (8, 128) tiles: 749,752 lane rows
     assert 0 <= memory.alias_size_in_bytes - 749_750 * 512 < 8 * 512
     assert memory.temp_size_in_bytes <= 2 * lane_rows * 512
+
+
+MDS_ROWS_PADDED = 63_971_328  # benchmark/configs/mds.json: 32 x 1,999,000
+
+
+def _fused(one_chip, n_padded, n_shards, batch):
+    """``_query_batch`` for an index of ``n_padded`` rows: a stack of
+    ``n_shards`` datasets, or with none of them a ``DeviceIndex``."""
+    struct = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    # resident shapes and types as ``pad_columns`` makes them
+    empty = {
+        k: np.zeros((0, 4), np.uint32) if k == "alt_prefix"
+        else np.zeros(0, np.int32)
+        for k in _PAD_FILLS
+    }
+    arrays = {
+        k: jax.ShapeDtypeStruct(
+            (n_padded,) + v.shape[1:], v.dtype, sharding=one_chip
+        )
+        for k, v in pad_columns(empty, 0, 1024).items()
+    }
+    arrays["chrom_offsets"] = _shape(
+        one_chip, *((n_shards, 27) if n_shards else (27,))
+    )
+    queries = [QuerySpec("1", 1, 2, 1, 2)] * batch
+    enc = encode_queries(queries, [0] * batch if n_shards else None)
+    return _query_batch.lower(
+        arrays,
+        {k: struct(v) for k, v in enc.items()},
+        window_cap=2048, record_cap=1024, n_iters=bisect_iters(n_padded),
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "n_padded,n_shards,batch",
+    [(MDS_ROWS_PADDED, 32, 32), (8192, 0, 8)],
+    ids=["mds_fused_stack", "device_index_8192_rows"],
+)
+def test_fused_program_reads_its_windows_in_lane_rows(
+    one_chip, n_padded, n_shards, batch
+):
+    """Every gather of more than a word a query (the bisection's
+    ``pos[mid]`` is one) takes whole 128-lane rows. Where the columns
+    are larger than a launch's windows, nothing but a parameter, or a
+    view of one, has a column's size (the compiler moves the small
+    index's 32 kB columns to faster memory whole, as it may)."""
+    compiled = _fused(one_chip, n_padded, n_shards, batch)
+    text = compiled.as_text()
+    gathers = re.findall(
+        r"= \w+\[([\d,]+)\]\S* gather\(.*?slice_sizes=\{([\d,]+)\}", text
+    )
+    windows = [
+        sizes.split(",")
+        for dims, sizes in gathers
+        if np.prod([int(d) for d in dims.split(",")]) > batch
+    ]
+    assert len(windows) >= 11  # ten int32 columns and alt_prefix
+    assert all("128" in sizes for sizes in windows), windows
+    if not n_shards:
+        return
+    column = re.compile(
+        rf"= \w+\[({n_padded}|{n_padded // 128},128)(,4)?\]\S* (\S+?)\("
+    )
+    made = {m.group(3) for m in map(column.search, text.splitlines()) if m}
+    assert made <= {"parameter", "bitcast", "get-tuple-element"}, made
+    assert compiled.memory_analysis().temp_size_in_bytes < n_padded * 4 // 8

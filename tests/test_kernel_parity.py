@@ -14,6 +14,7 @@ import pytest
 from sbeacon_tpu.index import build_index
 from sbeacon_tpu.oracle import oracle_search
 from sbeacon_tpu.ops import DeviceIndex, QuerySpec, run_queries
+from sbeacon_tpu.ops.kernel import LANES, FusedDeviceIndex, encode_queries
 from sbeacon_tpu.testing import random_records
 
 
@@ -271,3 +272,112 @@ def test_int32_max_start_max_does_not_wrap(dataset):
     assert not res.overflow[0]
     assert int(res.n_matched[0]) == len(want)
     assert len(want) > 0
+
+
+# --- the window read in lane rows, at its edges -----------------------
+
+EDGE_CAP = 256  # window_cap of the edge cases: three lane rows
+
+
+@pytest.fixture(scope="module")
+def stack():
+    """Three datasets on chromosome 1, the middle one smaller than a
+    window, none beginning at a lane row's first lane; the stack ends
+    inside its last lane row (4093 rows padded to 4096)."""
+    shards, all_recs = [], []
+    for d, n_rows in enumerate((1700, 150, 2243)):
+        rng = random.Random(40 + d)
+        recs = random_records(
+            rng, chrom="1", n=n_rows, spacing=9, n_samples=3,
+            p_multiallelic=0.0,
+        )
+        for rec in recs:  # four rows of five match any single base
+            if rng.random() < 0.8:
+                rec.alts = [rng.choice([b for b in "ACGT" if b != rec.ref])]
+        shards.append(build_index(recs, dataset_id=f"d{d}", sample_names=["a", "b", "c"]))
+        all_recs.append(recs)
+    findex = FusedDeviceIndex(shards, pad_unit=1024)
+    assert [s.n_rows for s in shards] == [1700, 150, 2243]
+    assert findex.n_padded == 4096 and findex.window_hint >= 2048
+    return all_recs, shards, findex
+
+
+def _rows_query(shard, a, count, **kw):
+    """The any-single-base query whose candidate range is rows
+    [a, a + count) of a shard with one row a position."""
+    pos = shard.cols["pos"]
+    if count == 0:  # between two adjacent rows' positions: nothing
+        gap = next(
+            r for r in range(a, shard.n_rows - 1) if pos[r + 1] - pos[r] > 2
+        )
+        lo_bp = hi_bp = int(pos[gap]) + 1
+    else:
+        lo_bp, hi_bp = int(pos[a]), int(pos[a + count - 1])
+    return QuerySpec(
+        chrom="1", start_min=lo_bp, start_max=hi_bp, end_min=1,
+        end_max=2**30, alternate_bases="N", **kw,
+    )
+
+
+# (case, dataset, first row as a function of the dataset's base row in
+# the stack, rows in the range, record_cap)
+WINDOW_EDGES = [
+    ("lo_inside_a_lane_row", 0, lambda base: 300 + 37, 90, 512),
+    ("lo_on_a_lane_row", 2, lambda base: 3 * LANES - base % LANES, 90, 512),
+    ("lo_on_a_rows_last_lane", 0, lambda base: 5 * LANES - 1, 200, 512),
+    ("first_row_of_the_stack", 0, lambda base: 0, 40, 512),
+    ("ends_in_the_stacks_last_lane_row", 2, lambda base: 2243 - 70, 70, 512),
+    ("range_equal_to_window_cap", 0, lambda base: 911, EDGE_CAP, 512),
+    ("range_one_over_window_cap", 0, lambda base: 911, EDGE_CAP + 1, 512),
+    ("range_far_over_window_cap", 2, lambda base: 77, 1500, 512),
+    ("a_whole_dataset_between_neighbours", 1, lambda base: 0, 150, 512),
+    ("ends_at_the_next_datasets_first_row", 0, lambda base: 1700 - 30, 30, 512),
+    ("begins_at_the_previous_datasets_last_row", 2, lambda base: 0, 30, 512),
+    ("empty_window", 1, lambda base: 60, 0, 512),
+    ("record_cap_under_the_matches", 2, lambda base: 1000, 120, 16),
+    ("record_cap_over_the_window", 0, lambda base: 450, 60, 1024),
+]
+
+
+@pytest.mark.parametrize(
+    "case,sid,first,count,record_cap",
+    WINDOW_EDGES,
+    ids=[c[0] for c in WINDOW_EDGES],
+)
+def test_window_read_in_lane_rows(stack, case, sid, first, count, record_cap):
+    """The fused program against the CPU oracle where a window meets a
+    lane row's, a dataset's, the stack's or its own cap's edge."""
+    from sbeacon_tpu.engine import host_match_rows
+
+    all_recs, shards, findex = stack
+    shard, base = shards[sid], int(findex.shard_base[sid])
+    a = first(base)
+    q = _rows_query(shard, a, count)
+    res = run_queries(
+        findex,
+        encode_queries([q], shard_ids=[sid]),
+        window_cap=EDGE_CAP,
+        record_cap=record_cap,
+    )
+    pos = shard.cols["pos"]
+    lo = int(np.searchsorted(pos, q.start_min, side="left"))
+    hi = int(np.searchsorted(pos, q.start_max, side="right"))
+    assert hi - lo == count and (lo == a or not count)
+    want_rows = host_match_rows(shard, q) + base
+    assert len(want_rows) > count // 2 or not count
+    in_window = want_rows[want_rows < base + lo + EDGE_CAP]
+    assert bool(res.overflow[0]) == (count > EDGE_CAP)
+    assert int(res.n_matched[0]) == len(in_window)
+    got = res.rows[0]
+    assert got.shape == (min(record_cap, EDGE_CAP),)
+    kept = in_window[: len(got)]
+    assert got[: len(kept)].tolist() == kept.tolist()
+    assert (got[len(kept):] == -1).all()
+    ac = shard.cols["ac"][in_window - base]
+    assert int(res.call_count[0]) == int(ac.sum())
+    assert int(res.n_variants[0]) == int((ac != 0).sum())
+    if count <= EDGE_CAP:
+        want = _oracle(all_recs[sid], q)
+        assert bool(res.exists[0]) == want.exists
+        assert int(res.call_count[0]) == want.call_count
+        assert int(res.all_alleles_count[0]) == want.all_alleles_count
