@@ -778,6 +778,11 @@ class VariantEngine:
         # target; engine.pool_wait times each task's wait for a thread)
         self.fanout_targets = 0
         self.mesh_searches = 0
+        # multi-dataset requests on a host with more than one device
+        # whose BASE targets did not all take the mesh stack, by reason
+        # (_note_mesh_skip); _mesh_why says why the state reads None
+        self.mesh_skips: dict[str, int] = {}
+        self._mesh_why = "unbuilt"
         # warm phases that failed and were skipped since the last
         # warmup() run started (see _warm_failed)
         self.warmup_failed_phases = 0
@@ -2536,6 +2541,15 @@ class VariantEngine:
             fn=lambda: self.mesh_searches,
         )
         registry.counter(
+            "engine.mesh_skips",
+            "multi-dataset requests on a host with more than one device "
+            "whose base targets did not all take the mesh stack, by "
+            "reason (unbuilt / warming / failed / uncovered); thread "
+            "scatter answered them",
+            label="reason",
+            fn=lambda: dict(self.mesh_skips),
+        )
+        registry.counter(
             "engine.fanout_targets",
             "targets of multi-dataset requests served one pool task "
             "each (engine.fanout parks the request meanwhile)",
@@ -2967,29 +2981,27 @@ class VariantEngine:
             )
         elif len(targets) > 1:
             state = self._mesh_ready()
-            if state is not None:
-                shard_of = state[4]
-                covered = [
-                    t
-                    for t in targets
-                    if shard_of.get((t[0], t[1])) is t[2]
-                ]
-                if covered:
-                    try:
-                        got = self._mesh_search(
-                            state, covered, spec_base, payload, sp
-                        )
-                        mesh_responses = {
-                            (t[0], t[1]): r
-                            for t, r in zip(covered, got)
-                        }
-                    except Exception:
-                        _device_fallback(
-                            "mesh_search",
-                            "mesh search failed; falling back to "
-                            "thread scatter",
-                        )
-                        mesh_responses = None
+            shard_of = state[4] if state is not None else {}
+            covered = [
+                t for t in targets if shard_of.get((t[0], t[1])) is t[2]
+            ]
+            if len(covered) < len(targets):
+                self._note_mesh_skip(state, targets, covered)
+            if covered:
+                try:
+                    got = self._mesh_search(
+                        state, covered, spec_base, payload, sp
+                    )
+                    mesh_responses = {
+                        (t[0], t[1]): r for t, r in zip(covered, got)
+                    }
+                except Exception:
+                    _device_fallback(
+                        "mesh_search",
+                        "mesh search failed; falling back to "
+                        "thread scatter",
+                    )
+                    mesh_responses = None
         if mesh_responses is not None:
             targets = [
                 t for t in targets if (t[0], t[1]) not in mesh_responses
@@ -3286,6 +3298,52 @@ class VariantEngine:
         ref = spec_base.reference_bases
         return ref is None or "N" not in ref.upper()
 
+    def _note_mesh_skip(self, state, targets, covered) -> None:
+        """Count a multi-dataset request whose BASE targets did not all
+        take the mesh stack, on a host where they should have (mesh
+        serving on, more than one device): ``engine.mesh_skips{reason}``
+        beside a ``plan_stage``, so a deployment whose stack never came
+        up cannot look like one that has it. Reasons: the state reads
+        None (``unbuilt``: no warm-up has built it yet; ``warming``: its
+        programs are compiling and thread scatter serves meanwhile;
+        ``failed``: bring-up raised, which also ticked
+        ``device.fallbacks{mesh_stack}``), or the stack stands and does
+        not hold a base shard the request targets (``uncovered``: a
+        publish the rebuild has not caught up with). The delta tail
+        rides the L0 index by design and is no skip. A skip is NOT a
+        counted fall-back: the stack is rebuilt and warmed OFF the
+        request path after every publish (a /submit, a fold), thread
+        scatter answers right meanwhile, and the smoke and the soak
+        tests hold ``device.fallbacks`` at zero across exactly those
+        windows; a cell that should never see one reads
+        ``mesh_launches_per_query``."""
+        covered_keys = {(t[0], t[1]) for t in covered}
+        missed = sum(
+            1
+            for t in targets
+            if "#d" not in t[1] and (t[0], t[1]) not in covered_keys
+        )
+        eng = self.config.engine
+        if not missed or not eng.use_mesh or not eng.use_tpu:
+            return
+        import jax
+
+        if len(jax.devices()) < 2:
+            return
+        reason = self._mesh_why if state is None else "uncovered"
+        with self._mat_lock:
+            self.mesh_skips[reason] = self.mesh_skips.get(reason, 0) + 1
+        # the plan's reasons are a registry of literals (plan.PLAN_REASONS)
+        if state is None:
+            plan_stage(
+                "mesh", decision="skipped", reason="unbuilt",
+                why=reason, targets=missed,
+            )
+        else:
+            plan_stage(
+                "mesh", decision="skipped", reason="stale", targets=missed
+            )
+
     def _mesh_ready(self):
         """(mesh, stacked, device_arrays, key->stack-position), built over
         ALL loaded shards and cached until the index set changes; None when
@@ -3299,6 +3357,7 @@ class VariantEngine:
                 return self._mesh_state
             self._mesh_state = None
             self._mesh_dirty = False
+            self._mesh_why = "unbuilt"
             gen = self._fused_gen  # bumped by every base publish
             state = None
             try:
@@ -3330,6 +3389,7 @@ class VariantEngine:
                     mesh, stacked, arrays, index_of, shard_of, planes_of
                 )
             except Exception:
+                self._mesh_why = "failed"
                 _device_fallback(
                     "mesh_stack",
                     "mesh serving unavailable; using thread scatter",
@@ -3337,6 +3397,7 @@ class VariantEngine:
             if state is None or not self._keep_warm:
                 self._mesh_state = state
                 return state
+            self._mesh_why = "warming"
         # serving: compile the stack's programs OFF the lock before any
         # request can route to it. Meanwhile the state reads clean and
         # empty, so thread scatter serves; a base publish that raced
@@ -3355,7 +3416,23 @@ class VariantEngine:
         (search_variants.py:77-118, variant_queries.py:45-59) as a single
         pjit dispatch. Per-dataset row ids come back device-sharded and
         materialise host-side with the same cumulative semantics as the
-        scatter path."""
+        scatter path.
+
+        The launch runs on the REQUEST's thread, beside the
+        micro-batcher, under the kernel stages every family passes
+        (``sharded_query``); concurrent requests launch the collective
+        program concurrently, which four v5e chips serve (PR 34: 800
+        launches a window from four clients, every answer exact; only
+        XLA:CPU needs ``mesh._collective_guard``). The materialisations,
+        one a covered target under ``engine.materialize``, then run on
+        this thread where the request reads no planes (booleans and
+        counts: the stack's traffic), and otherwise on the scatter pool
+        exactly as the per-target fan-out's do (this thread parked in
+        ``engine.fanout``, each task's wait in ``engine.pool_wait``,
+        ``engine.fanout_targets`` ticked). The device's own ``agg`` is
+        computed and only noted:
+        answering booleans and counts from it, without the per-dataset
+        responses, is the next step (PERF.md 7) and not taken here."""
         from .parallel.mesh import sharded_query
 
         mesh, stacked, arrays, index_of, shard_of, planes_of = state
@@ -3374,9 +3451,20 @@ class VariantEngine:
             n_iters=stacked.n_iters,
             window_cap=eng.window_cap,
             record_cap=eng.record_cap,
+            n_datasets=stacked.n_datasets,
         )
+        req_ctx = current_context()
 
         def _one(target):
+            # the stage covers the whole of a target's response, the
+            # pick of its rows from the launch's leaves included: built
+            # on the request's thread, 128 such picks between stages
+            # were a fifth of a request that no stage named
+            # (span_coverage 76.9 %, PERF.md 6, PR 34)
+            with stage("engine.materialize"):
+                return _response(target)
+
+        def _response(target):
             ds, vcf, _shard, _dindex, _planes, native = target
             # state-consistent shard: rows from the stacked arrays must
             # materialise against the shard the stack was built from (a
@@ -3411,11 +3499,38 @@ class VariantEngine:
                 plane_index=planes_of.get((ds, vcf)),
             )
 
-        if len(targets) == 1:
-            responses = [_one(targets[0])]
+        def _pooled(target):
+            # as the per-target fan-out of _search_targets: the
+            # request's thread is parked in ``engine.fanout`` while the
+            # pool serves it, so the pool's stages add nothing to the
+            # chain's req_ms; first the task's wait for a pool thread
+            tracer.observe(
+                "engine.pool_wait", (time.perf_counter() - t_pool) * 1e3, 0
+            )
+            with tracer.serving(0), request_context(req_ctx):
+                return _one(target)
+
+        if len(targets) == 1 or not self._wants_planes(payload):
+            # the rows are in hand and a boolean's or a count's
+            # response is microseconds of pure host work: inline, as
+            # the L0 leg's targets are. A pool task a target was 128
+            # thread hand-overs a request in ``mds4.fanout`` (39 ms
+            # parked in engine.fanout for 1.3 ms of materialising), and
+            # put the cell's tail past the server's own 250 ms
+            # objective: the brownout ladder shed the window (PERF.md
+            # 6, PR 34)
+            responses = [_one(t) for t in targets]
         else:
-            responses = list(self._scatter.map(_one, targets))
-        self.mesh_searches += 1
+            # plane-reading materialisations read the host's planes in
+            # numpy, which gives the interpreter lock up: the pool
+            # overlaps them, as the per-target fan-out's
+            with stage("engine.fanout"):
+                t_pool = time.perf_counter()
+                responses = list(self._scatter.map(_pooled, targets))
+            with self._mat_lock:  # unlocked += drops concurrent counts
+                self.fanout_targets += len(targets)
+        with self._mat_lock:
+            self.mesh_searches += 1
         annotate(dispatch="mesh")
         sp.note(
             targets=len(targets),

@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..index.columnar import N_CHROM_CODES, VariantIndexShard
 from ..ops.kernel import (
+    LANES,
     DeviceIndex,
     QueryResults,
     _donate_uploads,
@@ -208,15 +209,40 @@ class StackedIndex:
         # whole on the default device first would stand there beside
         # what that chip already owns
         return {
-            k: jax.device_put(np.asarray(v), sharding)
+            k: jax.device_put(lane_rows(k, np.asarray(v)), sharding)
             for k, v in self.arrays.items()
         }
+
+
+def lane_rows(name: str, stacked: np.ndarray) -> np.ndarray:
+    """A stacked column ``[D, n, ...]`` as it is resident: ``[D, n / 128,
+    128, ...]``, a view. ``_query_one`` reads a dataset's window as the
+    128-lane rows it lies in; resident ``[D, n]`` the device tiles eight
+    DATASETS to a tile, no dataset's column lies in lane rows, and the
+    compiler re-tiled all ten row columns whole inside every launch
+    (ten copies of ``s32[4,8,15680,128]``, 256 MB each, at ``mds4``'s 32
+    datasets x 2e6 rows a chip: sandbox compile, PERF.md 6, PR 34).
+    Resident in lane rows a dataset's column is what the program reads
+    (``pad_columns`` pads to whole lane rows only). The segment table
+    stays as it is."""
+    if name == "chrom_offsets":
+        return stacked
+    d, n = stacked.shape[:2]
+    return stacked.reshape((d, n // LANES, LANES) + stacked.shape[2:])
 
 
 def _local_query(arrays_local, enc, *, window_cap, record_cap, n_iters, axis):
     """Body run per device: vmap datasets × vmap queries, psum fan-in."""
 
     def one_dataset(arrays_one):
+        # a column resident in lane rows (lane_rows) is the 1-D column
+        # _query_one takes, seen flat: it views it in lane rows again
+        arrays_one = {
+            k: v
+            if k == "chrom_offsets"
+            else v.reshape((-1,) + v.shape[2:])
+            for k, v in arrays_one.items()
+        }
         fn = partial(
             _query_one,
             arrays_one,
@@ -368,8 +394,13 @@ _FN_CACHE: dict = {}
 #: a shared intra-process thread pool; TWO collective programs in
 #: flight from different request threads can interleave their
 #: per-device rendezvous and deadlock (the forced-host CI mesh, and any
-#: CPU fallback deployment). Real accelerator runtimes order launches
-#: on streams, so the guard is CPU-only and free elsewhere.
+#: CPU fallback deployment). On the chip no guard is taken, and that
+#: was run, not assumed (four v5e chips, PR 34): four threads x 30
+#: launches of the engine's mesh program at once all returned (1.5 s),
+#: and ``mds4.fanout``'s four closed-loop clients launched it
+#: concurrently some 800 times a 40 s window with every sampled answer
+#: exact and no request failed, on the parent and on the change. So the
+#: guard stays CPU-only and free elsewhere.
 _CPU_COLLECTIVE_LOCK = threading.Lock()
 
 
@@ -411,6 +442,7 @@ def sharded_query(
     window_cap: int = 2048,
     record_cap: int = 1024,
     aggregates_only: bool = False,
+    n_datasets: int | None = None,
 ):
     """Run a query batch against a mesh-sharded dataset stack.
 
@@ -423,21 +455,67 @@ def sharded_query(
     can only device_get fully-addressable arrays: the psum aggregates
     are replicated (addressable everywhere) while per-dataset results
     live on their owning hosts.
+
+    The launch passes the stages every family's does (``kernel.encode``,
+    ``kernel.dispatch``: the uploads and the jitted call until it
+    returns, ``kernel.readback``: both ``device_get``s, ``kernel.unpack``)
+    and is ONE record of the flight recorder under the family ``mesh``:
+    ``n_datasets`` real of the stack's padded dataset slots, times the
+    batch, are its real and padded pairs, and the bytes both
+    ``device_get``s brought back are its ``device.fetched_bytes``.
     """
-    enc = (
-        encode_queries(queries) if isinstance(queries, list) else queries
-    )
-    enc_dev = {k: jnp.asarray(v) for k, v in enc.items()}
-    fn = _build_sharded_fn(mesh, axis, window_cap, record_cap, n_iters)
+    from ..telemetry import note_device_stage, record_device_launch
+    from ..utils.trace import stage
+
+    with stage("kernel.encode"):
+        enc = (
+            encode_queries(queries) if isinstance(queries, list) else queries
+        )
+        b = int(enc["chrom"].shape[0])
+        fn = _build_sharded_fn(mesh, axis, window_cap, record_cap, n_iters)
+    d_pad = int(stacked_arrays["chrom_offsets"].shape[0])
     with _collective_guard():
-        per_ds, agg = fn(stacked_arrays, enc_dev)
-        agg = jax.device_get(agg)
-        if aggregates_only:
-            per_out: dict = {}
-        else:
-            per_ds = jax.device_get(per_ds)
-            per_out = {k: np.asarray(v) for k, v in per_ds.items()}
-    return per_out, {k: np.asarray(v) for k, v in agg.items()}
+        with stage("kernel.dispatch") as dispatched:
+            enc_dev = {k: jnp.asarray(v) for k, v in enc.items()}
+            per_ds, agg = fn(stacked_arrays, enc_dev)
+        # ONE flight-recorder seam per launch, as every other family's:
+        # the engine's mesh program is the family ``mesh`` (the pod
+        # tier's run_mesh_queries keeps mesh_replicated / mesh_sliced).
+        # A pair is one (dataset slot, query); the padding datasets that
+        # round the stack up to the mesh are evaluated like the others
+        seq = record_device_launch(
+            "mesh",
+            seam="mesh",
+            tier=b,
+            specs_real=int(n_datasets or d_pad) * b,
+            specs_padded=d_pad * b,
+            evaluated_pairs=d_pad * b,
+            launch_ms=dispatched.ms,
+            program_key=(
+                "mesh_stack",
+                int(mesh.devices.size),
+                d_pad,
+                tuple(stacked_arrays["pos"].shape[1:]),
+                n_iters,
+                b,
+                window_cap,
+                record_cap,
+            ),
+        )
+        with stage("kernel.readback") as read_back:
+            agg = jax.device_get(agg)
+            per_ds = {} if aggregates_only else jax.device_get(per_ds)
+    with stage("kernel.unpack"):
+        per_out = {k: np.asarray(v) for k, v in per_ds.items()}
+        agg_out = {k: np.asarray(v) for k, v in agg.items()}
+        note_device_stage(
+            seq,
+            fetch_ms=read_back.ms,
+            fetch_bytes=sum(
+                v.nbytes for v in (*per_out.values(), *agg_out.values())
+            ),
+        )
+    return per_out, agg_out
 
 
 class MeshPendingResults:

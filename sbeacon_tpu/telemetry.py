@@ -999,13 +999,17 @@ def publish_event(kind: str, **data) -> int | None:
 #: scatter tile kernels, the XLA gather kernel (single-shard and fused
 #: stacked alike — one program family), the delta-tail L0 mini-index
 #: (same kernel, its own family so tail serving is attributable), the
-#: mesh shard_map program in its replicated and sliced batch layouts,
-#: and the genotype-plane program. Every launch record names exactly
-#: one of these.
+#: engine's mesh-stack program (``mesh``: ``parallel/mesh.sharded_query``,
+#: one launch a multi-dataset request over the dataset-sharded stack),
+#: the pod tier's shard_map program in its replicated and sliced batch
+#: layouts (``run_mesh_queries``; kept apart from ``mesh``: another
+#: program, other operands), and the genotype-plane program. Every
+#: launch record names exactly one of these.
 DEVICE_FAMILIES = (
     "scatter",
     "fused",
     "fused_l0",
+    "mesh",
     "mesh_replicated",
     "mesh_sliced",
     "plane",
@@ -1022,6 +1026,7 @@ DEVICE_PROGRAMS = {
     "plane": ("_selected_batch",),
     "fused": ("_query_batch_impl",),
     "fused_l0": ("_query_batch_impl",),
+    "mesh": ("_local_query",),
 }
 
 
@@ -1547,7 +1552,7 @@ def register_device_metrics(registry) -> None:
     registry.counter(
         "device.launches",
         "compiled device-program launches by family (scatter / fused "
-        "/ fused_l0 / mesh_replicated / mesh_sliced / plane)",
+        "/ fused_l0 / mesh / mesh_replicated / mesh_sliced / plane)",
         label="family",
         fn=lambda: flight_recorder.launches_by_family(),
     )

@@ -276,3 +276,75 @@ def test_fused_program_reads_its_windows_in_lane_rows(
     made = {m.group(3) for m in map(column.search, text.splitlines()) if m}
     assert made <= {"parameter", "bitcast", "get-tuple-element"}, made
     assert compiled.memory_analysis().temp_size_in_bytes < n_padded * 4 // 8
+
+
+MDS4_DATASETS = 128  # benchmark/configs/mds4.json: 32 a chip on a 2x2 host
+MDS4_ROWS = 1_999_000  # a dataset; synthetic_shard adds a few rows to it
+
+
+def test_mesh_program_compiles_at_mds4_shapes(chips):
+    """The engine's mesh program (``parallel/mesh._local_query`` under
+    ``shard_map``, one launch a multi-dataset request) for the four
+    described chips at ``mds4``'s shapes: the stack ``[128, n]`` with 32
+    datasets a chip, one query replicated. A chip's arguments are its
+    slice of the eleven columns, the psums are all-reduces the compiler
+    keeps, every window is read in lane rows as the fused program reads
+    them. Resident ``[D, n]`` the compiler re-tiled all ten row columns
+    whole inside every launch (ten copies of ``s32[4,8,15680,128]``,
+    256 MB each); resident in lane rows ONE such copy is left, of
+    ``pos`` for the bisection's word probes (PERF.md 7). A compile,
+    never a time."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from sbeacon_tpu.ops.kernel import DeviceIndex, padded_rows
+    from sbeacon_tpu.parallel.mesh import AXIS, _build_sharded_fn
+
+    mesh = Mesh(np.array([next(iter(c.device_set)) for c in chips]), (AXIS,))
+    assert mesh.devices.size == 4
+    n_padded = padded_rows(MDS4_ROWS + 64, DeviceIndex.PAD_UNIT)
+    sliced, whole = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+    empty = {
+        k: np.zeros((0, 4), np.uint32) if k == "alt_prefix"
+        else np.zeros(0, np.int32)
+        for k in _PAD_FILLS
+    }
+    # resident as parallel/mesh.lane_rows lays them: [D, n / 128, 128]
+    arrays = {
+        k: jax.ShapeDtypeStruct(
+            (MDS4_DATASETS, n_padded // 128, 128) + v.shape[1:], v.dtype,
+            sharding=sliced,
+        )
+        for k, v in pad_columns(empty, 0, 1024).items()
+    }
+    arrays["chrom_offsets"] = jax.ShapeDtypeStruct(
+        (MDS4_DATASETS, 27), jnp.int32, sharding=sliced
+    )
+    enc = {
+        k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=whole)
+        for k, v in encode_queries([QuerySpec("1", 1, 2, 1, 2)]).items()
+    }
+    fn = _build_sharded_fn(mesh, AXIS, 2048, 1024, bisect_iters(n_padded))
+    compiled = fn.lower(arrays, enc).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    gathers = re.findall(
+        r"= \w+\[([\d,]+)\]\S* gather\(.*?slice_sizes=\{([\d,]+)\}", text
+    )
+    local = MDS4_DATASETS // 4
+    windows = [
+        sizes.split(",")
+        for dims, sizes in gathers
+        if np.prod([int(d) for d in dims.split(",")]) > local
+    ]
+    assert len(windows) >= 11 and all("128" in w for w in windows), windows
+    memory = compiled.memory_analysis()
+    columns = sum(
+        int(np.prod(a.shape[1:])) * a.dtype.itemsize for a in arrays.values()
+    )
+    # 32 datasets' columns a chip: 3.9 GB of its 16.9
+    assert 0 <= memory.argument_size_in_bytes - local * columns < (1 << 20)
+    assert 3.5e9 < local * columns < 4.2e9
+    column = local * n_padded * 4
+    whole = re.findall(rf"= s32\[4,8,{n_padded // 128},128\]\S* copy\(", text)
+    assert len(whole) <= 1, whole
+    assert memory.temp_size_in_bytes < column + (8 << 20)

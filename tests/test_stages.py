@@ -564,11 +564,12 @@ def test_roofline_patterns_match_the_declared_programs():
     instead of silently dropping ``<family>_kernel_ms``."""
     import sbeacon_tpu.ops.kernel as kernel
     import sbeacon_tpu.ops.scatter_kernel as scatter
+    import sbeacon_tpu.parallel.mesh as mesh
 
     assert set(tel.DEVICE_PROGRAMS) <= set(tel.DEVICE_FAMILIES)
     for family, names in tel.DEVICE_PROGRAMS.items():
         for fn in names:
-            assert hasattr(kernel, fn) or hasattr(scatter, fn), (family, fn)
+            assert any(hasattr(m, fn) for m in (kernel, scatter, mesh)), (family, fn)
     for path in sorted((BENCH / "rooflines").glob("*.py")):
         family = path.stem
         jitted = [f"jit_{fn}" for fn in tel.DEVICE_PROGRAMS[family]]
