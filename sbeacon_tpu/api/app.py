@@ -21,6 +21,7 @@ from __future__ import annotations
 import hmac
 import json
 import math
+import os
 import time
 from pathlib import Path
 
@@ -372,6 +373,10 @@ class BeaconApp:
         # process; an operator's on-demand device profile (the stage
         # annotations sit beside the device lines in it)
         trace_mod.install_gc_stage()
+        # ... and the interpreter lock's turn as ``runtime.lock_turn``,
+        # from one daemon thread that ends with close()
+        self.lock_probe = trace_mod.LockTurnProbe(self)
+        self.lock_probe.start()
         if obs.profiler_port:
             _start_profiler_server(obs.profiler_port)
         self._register_metrics()
@@ -395,6 +400,7 @@ class BeaconApp:
         self.query_runner.close()
         self.query_jobs.close()
         self.canary.close()
+        self.lock_probe.close()
         shaper_close = getattr(self.shaping, "close", None)
         if shaper_close is not None:
             shaper_close()
@@ -457,6 +463,47 @@ class BeaconApp:
             "runtime.gc_pause_ms",
             "milliseconds every thread stood still for a collection",
             fn=lambda: tracer.stage_counts("gc")[1],
+        )
+        # what the process's Python threads cost and how often they gave
+        # their processor up, by role (utils/trace.THREAD_ROLES), beside
+        # the whole process's CPU and the cores it may use: read from
+        # /proc when a snapshot is served, one scan for the four
+        threads = trace_mod.thread_clock.read
+        reg.counter(
+            "runtime.thread_cpu_ms",
+            "CPU milliseconds of the process's Python threads",
+            label="role",
+            fn=lambda: threads()["cpu_ms"],
+        )
+        reg.counter(
+            "runtime.thread_yields",
+            "voluntary context switches of the Python threads: lock "
+            "hand-overs waited for, parked waits, blocking calls",
+            label="role",
+            fn=lambda: threads()["yields"],
+        )
+        reg.counter(
+            "runtime.thread_preempted",
+            "involuntary context switches of the Python threads",
+            label="role",
+            fn=lambda: threads()["preempted"],
+        )
+        reg.counter(
+            "runtime.process_cpu_ms",
+            "CPU milliseconds of the whole process, the runtime's own "
+            "threads included",
+            fn=lambda: threads()["process_cpu_ms"],
+        )
+        reg.gauge(
+            "runtime.host_cpus",
+            "processors the process may run on",
+            fn=lambda: len(os.sched_getaffinity(0)),
+        )
+        reg.gauge(
+            "runtime.stage_cpu_every",
+            "scopes of a stage to one that reads its thread's CPU clock "
+            "(1: every scope; more where the clock is dear)",
+            fn=lambda: tracer.cpu_every,
         )
         self.canary.register_metrics(reg)
         register_plan_metrics(reg, self.plans)

@@ -14,7 +14,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
-from ..utils.trace import stage
+from ..utils.trace import stage, thread_clock
 from .app import BeaconApp
 
 
@@ -24,6 +24,20 @@ def _make_handler(app: BeaconApp):
 
         def log_message(self, *args):  # quiet by default
             pass
+
+        def setup(self):
+            # a connection's thread, by name the ``request`` role of
+            # runtime.thread_cpu_ms (utils/trace.THREAD_ROLES)
+            threading.current_thread().name = "request"
+            super().setup()
+
+        def finish(self):
+            # the connection is over and its thread ends: the thread's
+            # last reading, for the role's sums
+            try:
+                super().finish()
+            finally:
+                thread_clock.leave()
 
         def parse_request(self):
             # the request line has just been read: ``http.read`` runs

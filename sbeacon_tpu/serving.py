@@ -61,7 +61,7 @@ from .telemetry import (
     note_device_stage,
     request_context,
 )
-from .utils.trace import span, tracer
+from .utils.trace import span, thread_clock, tracer
 
 
 @dataclass(eq=False)  # an entry is only ever itself (acc.items.remove)
@@ -744,6 +744,9 @@ class MicroBatcher:
             self._serve(acc, dindex, window_cap, record_cap, None, NO_DEADLINE)
         except BaseException as e:  # pragma: no cover - failsafe
             self._fail_queued(acc, e)
+        finally:
+            # the thread ends here: its reading for its role's sums
+            thread_clock.leave()
 
     def _timeout_error(self, req_deadline) -> BaseException:
         """Bounded-wait expiry, one classification for leader and

@@ -24,12 +24,19 @@ Stages (ISSUE 24) are the part that is never off. ``stage(name)`` times
 one boundary of the serving path, named in the literal registry
 :data:`STAGES`, with ONE pair of clock reads that feeds every sink: the
 stage's monotone ``count`` / ``sum_ms`` / ``req_ms`` and its bounded
-ring (always), a ``jax.profiler.TraceAnnotation`` named
+ring (always), beside them the thread's own CPU clock into ``cpu_ms``
+(ISSUE 36: what the scope cost the processor, where ``sum_ms`` also
+holds every wait for the interpreter lock, a semaphore, a socket or
+the device), a ``jax.profiler.TraceAnnotation`` named
 ``beacon.<stage>`` for ``work`` stages (always; free while nobody
 captures a profile, and on the device trace's clock when somebody
 does), and a :class:`Span` in the tree while tracing is enabled.
 ``/debug/status`` serves :meth:`Tracer.stage_summary`; a reader takes
-the difference of two snapshots.
+the difference of two snapshots. Beside the stages, read only when a
+snapshot is served: :class:`ThreadClock`, the CPU and context switches
+of the process's Python threads by role (:data:`THREAD_ROLES`), and
+:class:`LockTurnProbe`, which measures how long a thread that gave the
+interpreter lock up waits to run again (stage ``runtime.lock_turn``).
 """
 
 from __future__ import annotations
@@ -38,8 +45,11 @@ import collections
 import functools
 import gc
 import os
+import resource
 import threading
 import time
+import timeit
+import weakref
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
@@ -103,6 +113,10 @@ STAGES = {
     "kernel.unpack": "work",  # host arrays -> each query's rows and counts
     # runtime
     "gc": "work",  # one collection, annotated beacon.gc.gen<n>
+    # how late LockTurnProbe's thread ran again after a 50 ms wait: the
+    # timer's slack plus its turn at the interpreter lock (beside the
+    # chain, twenty samples a second a running app)
+    "runtime.lock_turn": "wait",
     # the batcher's composite intervals behind /debug/status's old keys
     # (queue_wait_ms, encode_ms, launch_ms, fetch_ms, exec_ms): same
     # clock reads as the stages above, same measuring points as before
@@ -127,10 +141,49 @@ CHAIN = (
     "handoff.back", "engine.fanout", "engine.materialize", "api.envelope",
 )
 
+#: thread-name prefix -> role of ``runtime.thread_cpu_ms{role}`` and its
+#: siblings (:class:`ThreadClock`); a thread no prefix names is ``other``
+#: (the canary's, the compactor's and the probe's own among them)
+THREAD_ROLES = {
+    "request": "request",  # the HTTP server's handler threads
+    "query-runner": "query-runner",
+    "engine-scatter": "engine-scatter",
+    "kernel-launch": "kernel-launch",
+    "kernel-fetch": "kernel-fetch",
+    "batch-drain": "batch-drain",
+    "query-jobs-writer": "query-jobs-writer",
+    "canary-prober": "canary-prober",
+    "MainThread": "main",
+}
+ROLES = (*THREAD_ROLES.values(), "other")
+
 #: samples kept per stage for p50/p95/p99
 STAGE_RING = 16384
 #: samples a stage holds unfolded before a writer folds them itself
 FOLD_AT = 1024
+
+# a scope's clocks, bound once
+_wall = time.perf_counter
+_cpu_ns = time.thread_time_ns
+
+
+def cpu_clock_stride() -> int:
+    """Scopes of a stage to ONE that reads its thread's CPU clock: 1
+    where the clock is a plain system call (0.3 us on Linux), six per
+    microsecond of its cost where it is not, so that the two reads add
+    a third of a microsecond to the mean scope whatever they cost.
+    Under a sandboxed kernel (gVisor, which the chip's machines run)
+    every system call is 6 us and more under load, read from every
+    scope that took a tenth of ``kg1.unique``'s rate (PERF.md 6, PR
+    36), and the clock there moves in 10 ms ticks, so a reading is a
+    sample either way. Odd, so that a stage a request passes twice
+    is read at both places. Measured once a tracer: the best of five
+    rounds."""
+    cost_us = min(
+        timeit.timeit(_cpu_ns, number=8) for _ in range(5)
+    ) / 8 * 1e6
+    return 1 if cost_us < 1.0 else round(6 * cost_us) | 1
+
 
 # jax.profiler, resolved at the first ``work`` stage (False where JAX
 # cannot be imported); TraceAnnotation is looked up per call
@@ -161,24 +214,34 @@ class StageStats:
 
     The sinks: ``count`` samples, their ``sum_ms``, ``req_ms`` (the sum
     of duration x requests served by the sample, so that coverage still
-    adds up once a launch serves several) and the bounded ring behind
-    the quantiles.
+    adds up once a launch serves several), ``cpu_ms`` (what the scopes
+    cost their threads' processors, by ``time.thread_time_ns``: a
+    thread parked on the interpreter lock, a semaphore, a socket or the
+    device accrues none; 0 for a sample that was observed, which has no
+    thread) and the bounded ring behind the quantiles.
 
     ``with stage(name):`` enters this very object: the open reading is
     kept by thread, so a stage must not nest inside itself on one
     thread, and a sample costs its writer two dictionary operations and
-    one ``deque.append`` of a float, whether it served one request or
-    (on a fan-out's pool thread) none. Nothing the collector tracks is
-    allocated and no lock is taken: sixteen request threads pass some
-    twenty stages each, a fan-out thirty-two more on its pool, and both
-    showed on the chip (PERF.md 6, PR 24).
+    one ``deque.append`` of a float for each of its two clocks, whether
+    it served one request or (on a fan-out's pool thread) none. Nothing
+    the collector tracks is allocated and no lock is taken: sixteen
+    request threads pass some twenty stages each, a fan-out thirty-two
+    more on its pool, and both showed on the chip (PERF.md 6, PR 24).
+    The CPU clock is read inside the wall clock's two reads, and a
+    sample's wall is written before its CPU and folded after it, so
+    ``cpu_ms`` never passes ``sum_ms`` where every scope reads it.
+    Where the clock is dear only every ``_every``-th scope of the stage
+    does (:func:`cpu_clock_stride`) and counts ``_every`` times: an
+    estimate that is right over many scopes, not scope by scope.
     Readers, and a writer that finds ``FOLD_AT`` samples waiting, fold
     them into the sums under the lock, so the sums are monotone and
     exact whenever they are read."""
 
     __slots__ = ("name", "kind", "label", "tracer", "count", "sum_ms",
-                 "req_ms", "_ring", "_pending", "_beside", "_lock", "_open",
-                 "_live", "_last")
+                 "req_ms", "cpu_ms", "_ring", "_pending", "_beside", "_cpu",
+                 "_lock", "_open", "_open_cpu", "_live", "_last", "_every",
+                 "_due")
 
     def __init__(self, name: str, kind: str, tracer: "Tracer"):
         self.name = name
@@ -188,18 +251,25 @@ class StageStats:
         self.count = 0
         self.sum_ms = 0.0
         self.req_ms = 0.0
+        self.cpu_ms = 0.0
         self._ring: collections.deque = collections.deque(maxlen=STAGE_RING)
         # durations of samples that served one request, not yet folded
         self._pending: collections.deque = collections.deque()
         # ... and of samples that served none (a fan-out's pool threads)
         self._beside: collections.deque = collections.deque()
+        # ... and the CPU of either kind's scopes, each after its wall
+        self._cpu: collections.deque = collections.deque()
         # re-entrant: a collection can start inside a reader of the
         # ``gc`` stage, on the reader's own thread
         self._lock = threading.RLock()
-        # by thread: the open scope's start, its annotation and span
-        # (only while a profile is captured or the span tree is on),
-        # and the last duration (``ms``)
+        # by thread: the open scope's start on both clocks, its
+        # annotation and span (only while a profile is captured or the
+        # span tree is on), and the last duration (``ms``)
         self._open: dict[int, float] = {}
+        self._open_cpu: dict[int, int] = {}
+        # scopes to one that reads the CPU clock, and how many are left
+        # until the next (racing threads only shift the phase)
+        self._every = self._due = tracer.cpu_every
         self._live: dict[int, tuple] = {}
         self._last: dict[int, float] = {}
 
@@ -212,15 +282,26 @@ class StageStats:
         tree = (tracer._enabled or tracer._overrides) and tracer.is_enabled
         if ann is not None:
             ann.__enter__()
-        self._open[me] = t0 = time.perf_counter()
+        self._open[me] = t0 = _wall()
+        due = self._due - 1
+        if due > 0:
+            self._due = due
+        else:
+            self._due = self._every
+            self._open_cpu[me] = _cpu_ns()
         if tree or ann is not None:
             span = tracer._open(self.name, t0, {}) if tree else None
             self._live[me] = (ann, span)
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
         me = threading.get_ident()
+        cpu = None
+        if self._open_cpu:  # empty between the scopes of a stride
+            cpu = self._open_cpu.pop(me, None)
+            if cpu is not None:
+                cpu = _cpu_ns() - cpu
+        t1 = _wall()
         t0 = self._open.pop(me, None)
         if t0 is None:  # closed already
             return False
@@ -232,6 +313,8 @@ class StageStats:
             if span is not None:
                 self.tracer._finish(span, t1)
         self.add(ms, self.tracer._serving_n.get(me, 1))
+        if cpu:
+            self.add_cpu(cpu * 1e-6 * self._every)
         return False
 
     def close(self) -> None:
@@ -266,6 +349,10 @@ class StageStats:
             self.req_ms += ms * n
             self._ring.append(ms)
 
+    def add_cpu(self, cpu_ms: float) -> None:
+        """The CPU of a scope whose wall :meth:`add` has just taken."""
+        self._cpu.append(cpu_ms)
+
     @staticmethod
     def _drain(pending: collections.deque, ring: collections.deque):
         """Move what waits in ``pending`` to the ring; (count, sum)."""
@@ -282,11 +369,17 @@ class StageStats:
 
     def _fold(self) -> None:
         with self._lock:
+            # CPU first: a writer appends its wall before its CPU, so
+            # every sample whose CPU is summed here has its wall summed
+            # below, and cpu_ms never passes sum_ms
+            cpu = self._cpu
+            cpu_ms = sum(cpu.popleft() for _ in range(len(cpu)))
             served, served_ms = self._drain(self._pending, self._ring)
             beside, beside_ms = self._drain(self._beside, self._ring)
             self.count += served + beside
             self.sum_ms += served_ms + beside_ms
             self.req_ms += served_ms
+            self.cpu_ms += cpu_ms
 
     def _samples(self) -> list:
         for _ in range(3):
@@ -310,11 +403,13 @@ class StageStats:
     def summary(self) -> dict:
         with self._lock:
             count, sum_ms, req_ms = self.counts()
+            cpu_ms = self.cpu_ms
             xs = self._samples()
         return {
             "count": count,
             "sum_ms": round(sum_ms, 3),
             "req_ms": round(req_ms, 3),
+            "cpu_ms": round(cpu_ms, 3),
             **percentiles(xs),
         }
 
@@ -322,8 +417,9 @@ class StageStats:
         with self._lock:
             self._pending.clear()
             self._beside.clear()
+            self._cpu.clear()
             self.count = 0
-            self.sum_ms = self.req_ms = 0.0
+            self.sum_ms = self.req_ms = self.cpu_ms = 0.0
             self._ring.clear()
 
 
@@ -451,6 +547,8 @@ class Tracer:
         # re-serialising and filtering the whole ring per lookup
         # (exemplar-to-trace resolution is a per-dashboard-click path)
         self._by_trace: dict[str, list[Span]] = {}
+        #: scopes of a stage to one that reads the thread's CPU clock
+        self.cpu_every = cpu_clock_stride()
         self._stages = _Registry(
             (n, StageStats(n, k, self)) for n, k in STAGES.items()
         )
@@ -473,8 +571,9 @@ class Tracer:
 
     def observe(self, name: str, ms: float, n: float = 1) -> None:
         """Feed an interval whose two ends were read elsewhere (a
-        hand-off between threads, a composite). ``work`` stages are
-        scoped, never observed: the annotation needs the live scope."""
+        hand-off between threads, a composite): it has no thread, so
+        its ``cpu_ms`` stays 0. ``work`` stages are scoped, never
+        observed: the annotation needs the live scope."""
         acc = self._stages[name]
         if acc.label is not None:
             raise ValueError(f"work stage {name!r} must be a `with stage(...)`")
@@ -498,7 +597,7 @@ class Tracer:
         return self._stages[name].counts()
 
     def stage_summary(self) -> dict:
-        """{stage: {count, sum_ms, req_ms, p50, p95, p99}} for every
+        """{stage: {count, sum_ms, req_ms, cpu_ms, p50, p95, p99}} for every
         registered stage (no quantiles before its first sample)."""
         return {n: acc.summary() for n, acc in self._stages.items()}
 
@@ -720,14 +819,17 @@ def _gc_hook(phase: str, info: dict) -> None:
         ann = _annotation(_GC_LABELS[info["generation"]])
         if ann is not None:
             ann.__enter__()
-        _gc_open = (ann, time.perf_counter())
+        _gc_open = (ann, time.perf_counter(), time.thread_time_ns())
     elif _gc_open is not None:
-        ann, t0 = _gc_open
+        ann, t0, c0 = _gc_open
         _gc_open = None
+        cpu_ms = (time.thread_time_ns() - c0) * 1e-6
         ms = (time.perf_counter() - t0) * 1e3
         if ann is not None:
             ann.__exit__(None, None, None)
-        tracer._stages["gc"].add(ms)
+        acc = tracer._stages["gc"]
+        acc.add(ms)
+        acc.add_cpu(cpu_ms)
         gc_pauses[info["generation"]] += 1
 
 
@@ -735,6 +837,163 @@ def install_gc_stage() -> None:
     """Hook the collector once per process (the app does, at start)."""
     if _gc_hook not in gc.callbacks:
         gc.callbacks.append(_gc_hook)
+
+
+# -- the process's threads by role, and the interpreter lock's turn ------------
+
+
+def thread_role(name: str) -> str:
+    for prefix, role in THREAD_ROLES.items():
+        if name.startswith(prefix):
+            return role
+    return "other"
+
+
+_TICK_MS = 1e3 / os.sysconf("SC_CLK_TCK")
+
+
+def _task_reading(native_id: int):
+    """(CPU ms, voluntary, involuntary context switches) of one thread
+    of this process as the kernel accounts them, or None once it is
+    gone. A sandboxed kernel (gVisor) keeps no ``schedstat`` and counts
+    no switches: the CPU is then ``stat``'s ticks and the switches 0."""
+    task = f"/proc/self/task/{native_id}/"
+    try:
+        try:
+            with open(task + "schedstat") as f:
+                cpu_ms = int(f.read().split()[0]) * 1e-6
+        except FileNotFoundError:
+            with open(task + "stat") as f:
+                fields = f.read().rpartition(")")[2].split()
+            cpu_ms = (int(fields[11]) + int(fields[12])) * _TICK_MS
+        with open(task + "status") as f:
+            status = f.read()
+        at = status.find("\nvoluntary_ctxt_switches:")
+        yields, preempted = (
+            int(line.split()[1]) for line in status[at:].split("\n")[1:3]
+        ) if at >= 0 else (0, 0)
+    except (OSError, ValueError, IndexError):
+        return None
+    return cpu_ms, yields, preempted
+
+
+class ThreadClock:
+    """CPU time and context switches of the process's Python threads,
+    summed by role (:func:`thread_role`), read from ``/proc/self/task``
+    when a snapshot is served and never on a request's path.
+
+    A voluntary context switch (``yields``) is a thread giving its
+    processor up: a hand-over of the interpreter lock that it then has
+    to wait for, a parked wait, a blocking call; an involuntary one
+    (``preempted``) is the kernel taking it away. Monotone across a
+    thread's exit: its last reading stays in its role's sums. A thread
+    that ends by design (a connection's handler, a transient drainer)
+    calls :meth:`leave` last, so that reading is its final one; any
+    other is counted up to the last scan that saw it.
+    ``process_cpu_ms`` is the whole process, the runtime's own threads
+    (PJRT, XLA, sqlite) included, read after the threads so that their
+    sum cannot pass it. One scan serves every family of one rendering:
+    a reading younger than :attr:`KEEP_S` is served again."""
+
+    KEEP_S = 0.02
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        # thread -> (role, CPU ms, yields, preempted), as last read
+        self._seen: dict[threading.Thread, tuple] = {}
+        self._gone = {role: [0.0, 0, 0] for role in ROLES}
+        self._doc: dict | None = None
+        self._read_at = 0.0
+
+    def read(self) -> dict:
+        """{cpu_ms, yields, preempted: {role: sum}, process_cpu_ms}."""
+        with self._lock:
+            if self._doc is None or _wall() - self._read_at > self.KEEP_S:
+                self._doc = self._scan()
+                self._read_at = _wall()
+            return self._doc
+
+    def leave(self) -> None:
+        """The calling thread is about to end: keep its final reading,
+        which no scan could take later. One ``getrusage`` of the thread
+        itself, the kernel's same account as the files' (a transient
+        drainer passes here between two launches)."""
+        thread = threading.current_thread()
+        usage = resource.getrusage(resource.RUSAGE_THREAD)
+        with self._lock:
+            self._seen[thread] = (
+                thread_role(thread.name),
+                (usage.ru_utime + usage.ru_stime) * 1e3,
+                usage.ru_nvcsw, usage.ru_nivcsw,
+            )
+
+    def _scan(self) -> dict:
+        def add(sums: dict, role: str, *reading) -> None:
+            for i, v in enumerate(reading):
+                sums[role][i] += v
+
+        live = {}
+        for thread in threading.enumerate():
+            reading = thread.native_id and _task_reading(thread.native_id)
+            if reading:
+                live[thread] = (thread_role(thread.name), *reading)
+        for thread, last in self._seen.items():
+            now = live.get(thread)
+            # gone, or renamed into another role since
+            if now is None or now[0] != last[0]:
+                add(self._gone, *last)
+        self._seen = live
+        sums = {role: list(v) for role, v in self._gone.items()}
+        for reading in live.values():
+            add(sums, *reading)
+        return {
+            "cpu_ms": {r: round(v[0], 3) for r, v in sums.items()},
+            "yields": {r: v[1] for r, v in sums.items()},
+            "preempted": {r: v[2] for r, v in sums.items()},
+            "process_cpu_ms": round(time.process_time_ns() * 1e-6, 3),
+        }
+
+
+#: process-wide, as the threads are
+thread_clock = ThreadClock()
+
+
+class LockTurnProbe:
+    """One daemon thread that measures the interpreter lock's turn:
+    twenty times a second it waits :attr:`PERIOD_S` on an event and
+    feeds how late it ran again to the stage ``runtime.lock_turn``. On
+    an idle process that is the timer's slack (tens of microseconds);
+    where the interpreter is always held it is what a thread that gave
+    the lock up waits to have it back. Twenty acquisitions a second is
+    its whole cost. It ends with ``close()``, or by itself once its
+    owner is collected (an app that nobody closed)."""
+
+    PERIOD_S = 0.05
+
+    def __init__(self, owner):
+        self._owner = weakref.ref(owner)
+        self._stop = threading.Event()
+        self._thread = threading.Thread(
+            target=self._run, name="lock-turn-probe", daemon=True
+        )
+
+    def start(self) -> None:
+        self._thread.start()
+
+    def _run(self) -> None:
+        period, wait = self.PERIOD_S, self._stop.wait
+        turn = tracer._stages["runtime.lock_turn"]
+        while self._owner() is not None:
+            t0 = _wall()
+            if wait(period):
+                return
+            # beside the chain: the sample serves no request
+            turn.add(max(0.0, (_wall() - t0 - period) * 1e3), 0)
+
+    def close(self) -> None:
+        self._stop.set()
+        if self._thread.is_alive():
+            self._thread.join(1.0)
 
 
 def graft_launch_span(active, *, elapsed_ms: float = 0.0, **meta) -> None:

@@ -153,10 +153,17 @@ def test_cache_disabled_by_config():
     try:
         assert eng.cache_stats() is None
         pay = _bracket_payload()
-        n0 = kernel_mod.N_LAUNCHES
-        eng.search(pay)
-        eng.search(pay)
-        assert kernel_mod.N_LAUNCHES - n0 == 2  # both executed
+        # the counter is the process's: an earlier test's unclosed app
+        # may run its canary round meanwhile, so the least of a few
+        launched = []
+        for _ in range(3):
+            n0 = kernel_mod.N_LAUNCHES
+            eng.search(pay)
+            eng.search(pay)
+            launched.append(kernel_mod.N_LAUNCHES - n0)
+            if launched[-1] == 2:
+                break
+        assert min(launched) == 2, launched  # both executed
     finally:
         eng.close()
 

@@ -198,7 +198,7 @@ def test_every_registered_stage_is_seen(served):
     _st, doc = app.handle("GET", "/debug/status")
     for name in STAGES:
         assert set(doc["stages"][name]) >= {
-            "count", "sum_ms", "req_ms", "p50", "p95", "p99",
+            "count", "sum_ms", "req_ms", "cpu_ms", "p50", "p95", "p99",
         }, name
     for old in ("admission_wait_ms", "queue_wait_ms", "exec_ms", "encode_ms",
                 "launch_ms", "fetch_ms", "materialize_ms"):
@@ -314,10 +314,11 @@ def test_a_batch_counts_its_launch_once_in_sum_and_per_request_in_req():
 
 
 @obs
-def test_work_stages_are_annotated_and_wait_stages_are_not(monkeypatch):
+def test_work_stages_are_annotated_and_wait_stages_are_not(monkeypatch, request):
     import jax.profiler
 
     seen = []
+    me = threading.get_ident()
 
     class Annotation:
         def __init__(self, name):
@@ -325,13 +326,20 @@ def test_work_stages_are_annotated_and_wait_stages_are_not(monkeypatch):
 
         is_enabled = staticmethod(lambda: True)
 
+        # this thread's alone: an earlier test's unclosed app may pass
+        # its own work stages on its own threads meanwhile
         def __enter__(self):
-            seen.append(("open", self.name))
+            if threading.get_ident() == me:
+                seen.append(("open", self.name))
 
         def __exit__(self, *exc):
-            seen.append(("close", self.name))
+            if threading.get_ident() == me:
+                seen.append(("close", self.name))
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    # ... and no collection of this thread's own inside the scopes below
+    gc.disable()
+    request.addfinalizer(gc.enable)
     own = Tracer(enabled=False)
     with own.stage("kernel.dispatch") as st:
         pass
@@ -467,7 +475,10 @@ def test_the_counters_at_the_same_boundaries(served):
     assert fused(m1) - fused(m0) == 1
     assert (m1["device"]["evaluated_pairs"]
             - m0["device"]["evaluated_pairs"]) == 2
-    assert set(m2["runtime"]) == {"gc_pauses", "gc_pause_ms"}
+    assert set(m2["runtime"]) == {
+        "gc_pauses", "gc_pause_ms", "thread_cpu_ms", "thread_yields",
+        "thread_preempted", "process_cpu_ms", "host_cpus", "stage_cpu_every",
+    }
     assert set(m2["runtime"]["gc_pauses"]) == {"0", "1", "2"}
     gc.collect()
     m3 = metrics()

@@ -266,6 +266,12 @@ GOLDEN_METRICS = [
     "runner.persist_queue",
     "runtime.gc_pauses",
     "runtime.gc_pause_ms",
+    "runtime.thread_cpu_ms",
+    "runtime.thread_yields",
+    "runtime.thread_preempted",
+    "runtime.process_cpu_ms",
+    "runtime.host_cpus",
+    "runtime.stage_cpu_every",
 ]
 
 
