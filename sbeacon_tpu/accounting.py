@@ -97,10 +97,14 @@ def query_shape(route: str, granularity: str | None) -> str:
     bounded by the API layer) x requested granularity. This is the SAME
     key the DRR charge hook looks up, so learned per-shape costs apply
     to admission of the shape that incurred them."""
+    return f"{route}:{granularity_label(granularity)}"
+
+
+def granularity_label(granularity: str | None) -> str:
+    """A requested granularity as a bounded label: ``default`` where the
+    request named none (the route's own default then answers)."""
     g = str(granularity or "default").lower()
-    if g not in ("boolean", "count", "record", "default"):
-        g = "other"
-    return f"{route}:{g}"
+    return g if g in ("boolean", "count", "record", "default") else "other"
 
 
 class _Window:

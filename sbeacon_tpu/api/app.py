@@ -29,6 +29,7 @@ from ..accounting import (
     CostAccounting,
     cost_units,
     disabled_snapshot,
+    granularity_label,
     query_shape,
 )
 from ..canary import CanaryProber
@@ -74,6 +75,7 @@ from ..telemetry import (
     register_device_metrics,
     request_context,
     sanitize_trace_id,
+    stage_notes,
 )
 from ..utils import trace as trace_mod
 from ..utils.trace import span, stage, tracer
@@ -373,9 +375,13 @@ class BeaconApp:
         # process; an operator's on-demand device profile (the stage
         # annotations sit beside the device lines in it)
         trace_mod.install_gc_stage()
+        # where the slowest twentieth of a route's requests spent their
+        # time, from the stage vector each request carries (ISSUE 37)
+        self.tails = trace_mod.TailFold()
         # ... and the interpreter lock's turn as ``runtime.lock_turn``,
-        # from one daemon thread that ends with close()
-        self.lock_probe = trace_mod.LockTurnProbe(self)
+        # from one daemon thread that ends with close() and, once a
+        # second, takes the fold's thresholds anew
+        self.lock_probe = trace_mod.LockTurnProbe(self, self.tails.refresh)
         self.lock_probe.start()
         if obs.profiler_port:
             _start_profiler_server(obs.profiler_port)
@@ -433,6 +439,52 @@ class BeaconApp:
             "request.slow_queries",
             "requests recorded by the slow-query log",
             fn=lambda: self.slow_log.count(),
+        )
+        # the tail's layer map: sums of the classed requests' own stage
+        # vectors, read from the fold when a snapshot is served
+        series = self.tails.series
+        reg.counter(
+            "request.tail_count",
+            "status-200 tracked requests at or over their route's "
+            "running p95 of elapsed_ms",
+            fn=lambda: series()["tail_count"],
+        )
+        reg.counter(
+            "request.body_count",
+            "status-200 tracked requests between their route's running "
+            "p40 and p60 of elapsed_ms",
+            fn=lambda: series()["body_count"],
+        )
+        reg.counter(
+            "request.classed_total",
+            "status-200 tracked requests the fold saw, classed or not",
+            fn=lambda: series()["classed_total"],
+        )
+        reg.counter(
+            "request.tail_ms",
+            "milliseconds of the tail's requests by chain stage "
+            "(unnamed: elapsed_ms less the chain's sum)",
+            label="stage",
+            fn=lambda: series()["tail_ms"],
+        )
+        reg.counter(
+            "request.body_ms",
+            "milliseconds of the body's requests by chain stage "
+            "(unnamed: elapsed_ms less the chain's sum)",
+            label="stage",
+            fn=lambda: series()["body_ms"],
+        )
+        reg.counter(
+            "request.tail_by_granularity",
+            "the tail's requests by requested granularity",
+            label="granularity",
+            fn=lambda: series()["tail_by_granularity"],
+        )
+        reg.counter(
+            "request.classed_by_granularity",
+            "status-200 tracked requests by requested granularity",
+            label="granularity",
+            fn=lambda: series()["classed_by_granularity"],
         )
         self.slo.register_metrics(reg)
         if self.accounting is not None:
@@ -700,6 +752,14 @@ class BeaconApp:
                 units=cost_units(ctx.cost.snapshot()),
                 trace_id=ctx.trace_id,
             )
+        if tracked and status == 200:
+            # the tail's layer map: a request at or over its route's
+            # running p95, or in its middle fifth, adds its own stage
+            # vector to that side's sums
+            self.tails.fold(
+                route, elapsed_ms, ctx.stages,
+                granularity_label(ctx.notes.get("granularity")),
+            )
         notes = ctx.notes
         if ctx.cost.nonzero():
             # slow-query records carry the cost decomposition: a tail
@@ -711,6 +771,10 @@ class BeaconApp:
             # slow record says WHICH road the query took (and which it
             # was refused) without a second lookup
             notes = {**notes, "plan": plan_note(ctx)}
+        if self.slow_log.records(elapsed_ms):
+            # ... and WHERE its time went: the request's own stage
+            # vector, and what pool threads did for it beside
+            notes = {**notes, **stage_notes(ctx)}
         self.slow_log.maybe_record(
             trace_id=ctx.trace_id,
             route=route,
@@ -727,7 +791,10 @@ class BeaconApp:
                     # ?explain=1 (gated in _handle): the full bounded
                     # plan document rides the envelope — never cached,
                     # since explain forces no_response_cache
-                    meta["executionPlan"] = plan_document(ctx)
+                    # EXPLAIN with the timings: the stage vector too
+                    meta["executionPlan"] = {
+                        **plan_document(ctx), **stage_notes(ctx, "Ms"),
+                    }
                 unavailable = ctx.notes.get("unavailable_datasets")
                 if unavailable:
                     # partial-results degradation (dispatch.search):
@@ -1185,6 +1252,7 @@ class BeaconApp:
             "queues": queues,
             "ingest": ingest,
             "stages": stages,
+            "requests": self.tails.status(),
             "costs": costs,
             "canary": canary,
             "device": device,

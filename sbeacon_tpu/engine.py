@@ -3073,7 +3073,10 @@ class VariantEngine:
             # their count and sum and add nothing to the chain's req_ms.
             # First the task's own wait for one of the pool's threads
             tracer.observe(
-                "engine.pool_wait", (time.perf_counter() - t_pool) * 1e3, 0
+                "engine.pool_wait",
+                (time.perf_counter() - t_pool) * 1e3,
+                0,
+                (req_ctx,),
             )
             with tracer.serving(0):
                 return _one_target(target)
@@ -3505,7 +3508,10 @@ class VariantEngine:
             # pool serves it, so the pool's stages add nothing to the
             # chain's req_ms; first the task's wait for a pool thread
             tracer.observe(
-                "engine.pool_wait", (time.perf_counter() - t_pool) * 1e3, 0
+                "engine.pool_wait",
+                (time.perf_counter() - t_pool) * 1e3,
+                0,
+                (req_ctx,),
             )
             with tracer.serving(0), request_context(req_ctx):
                 return _one(target)

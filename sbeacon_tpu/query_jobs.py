@@ -816,8 +816,8 @@ class AsyncQueryRunner:
             "async-runner submit -> execution-start wait",
         )
 
-    def _note_queue_wait(self, wait_ms: float) -> None:
-        tracer.observe("runner.wait", wait_ms)
+    def _note_queue_wait(self, wait_ms: float, job_ctx=None) -> None:
+        tracer.observe("runner.wait", wait_ms, ctxs=(job_ctx,))
         h = self._wait_hist
         if h is not None:
             h.observe(wait_ms)
@@ -994,7 +994,7 @@ class AsyncQueryRunner:
 
         def run():
             self._note_queue_wait(
-                (time.perf_counter() - t_enqueue) * 1e3
+                (time.perf_counter() - t_enqueue) * 1e3, job_ctx
             )
             with request_context(job_ctx), span(
                 "query_jobs.run", query_id=query_id
@@ -1107,7 +1107,9 @@ class AsyncQueryRunner:
                 # result over: from its clock reading to this thread
                 # running again
                 tracer.observe(
-                    "handoff.back", (time.perf_counter() - hit[3]) * 1e3
+                    "handoff.back",
+                    (time.perf_counter() - hit[3]) * 1e3,
+                    ctxs=(current_context(),),
                 )
             if hit[2]:
                 # replay the partial marking onto THIS caller's request

@@ -409,7 +409,9 @@ class MicroBatcher:
         # ends here (one reading, also the slow-query note's)
         t_wake = time.perf_counter()
         if me.t_ready:
-            tracer.observe("handoff.back", (t_wake - me.t_ready) * 1e3)
+            tracer.observe(
+                "handoff.back", (t_wake - me.t_ready) * 1e3, ctxs=(me.ctx,)
+            )
         # per-request stage note for the slow-query log: submit ->
         # result delivery (queue wait + device execute + fetch), the
         # batcher's share of this request's latency — plus the kernel
@@ -554,6 +556,9 @@ class MicroBatcher:
                         p.slot_ms = min(
                             slot_ms, max(0.0, (t_got - p.t_submit) * 1e3)
                         )
+                        # ... and each entry's vector takes its part
+                        if p.ctx is not None:
+                            p.ctx.stages["batcher.pipeline"] += p.slot_ms
                     tracer.observe(
                         "batcher.pipeline",
                         slot_ms,
@@ -1024,7 +1029,9 @@ class MicroBatcher:
         stage_hist = self._stage_hist
         for p in batch:
             wait_ms = (t_launch - p.t_submit) * 1e3
-            tracer.observe("batcher.wait", wait_ms - p.slot_ms)
+            tracer.observe(
+                "batcher.wait", wait_ms - p.slot_ms, ctxs=(p.ctx,)
+            )
             tracer.observe("batcher.queue_wait", wait_ms)
             if stage_hist is not None:
                 stage_hist.observe(wait_ms, label_value="batch_wait")
@@ -1037,13 +1044,14 @@ class MicroBatcher:
         # records — and a mid-request device.compile journal event —
         # with the trace id of the request that paid for the launch.
         # Cost attribution stays per-submission via the explicit ctx.
-        lead_ctx = next(
-            (p.ctx for p in batch if p.ctx is not None), None
-        )
+        # ... and every entry's context takes the launch's stages into
+        # its own vector, as the stages' req_ms counts them n times
+        ctxs = [p.ctx for p in batch if p.ctx is not None]
+        lead_ctx = ctxs[0] if ctxs else None
         try:
             with request_context(lead_ctx), span(
                 "serving.microbatch"
-            ) as sp, tracer.serving(n):
+            ) as sp, tracer.serving(n, ctxs):
                 # chaos site: a raised fault takes the existing
                 # launch-failure path (every waiter gets the error)
                 fault_point("kernel.launch")
@@ -1123,10 +1131,11 @@ class MicroBatcher:
         try:
             n = len(batch)
             t_fetch = time.perf_counter()
+            ctxs = [p.ctx for p in batch if p.ctx is not None]
             tracer.observe(
-                "batcher.fetch_wait", (t_fetch - t_disp) * 1e3, n
+                "batcher.fetch_wait", (t_fetch - t_disp) * 1e3, n, ctxs
             )
-            with tracer.serving(n):
+            with tracer.serving(n, ctxs):
                 res = pending.fetch()
             t_done = time.perf_counter()
             exec_ms = (t_done - t_launch) * 1e3

@@ -617,7 +617,7 @@ def charge_cost(**fields) -> None:
     """Charge the current request's cost vector (ambient context), or
     the process-global unattributed residue when off-request. The
     no-context fast path is one thread-local read."""
-    ctx = getattr(_ambient, "ctx", None)
+    ctx = _ambient.ctx
     vec = ctx.cost if ctx is not None else UNATTRIBUTED_COST
     vec.add(**fields)
 
@@ -666,11 +666,24 @@ class RequestContext:
     fresh dict, never mutates in place), so a reader iterating its
     snapshot can never race a writer — an abandoned pool thread may
     still be annotating after the request returned. Two concurrent
-    annotates may drop one note; acceptable for observability."""
+    annotates may drop one note; acceptable for observability.
+
+    ``stages`` (ISSUE 37) is the request's own stage vector, stage name
+    -> milliseconds, fed by the stage clock (``utils/trace.py``) from
+    the very reading that feeds the stage's sums and under the rule of
+    its ``req_ms``: a scope on a thread this context is ambient on, a
+    launch's scopes for each entry it serves, an observed hand-off at
+    the site that knows whose it was. The chain's stages follow one
+    another for a request, so ``stages`` has one writer at a time.
+    ``beside`` holds what was done FOR the request while its own
+    thread was parked (a fan-out's pool threads, ``tracer.serving(0)``):
+    thread-milliseconds by stage, never part of the chain; its writers
+    are concurrent, and two adds that race may drop one, as notes do.
+    Wall only: no CPU is read per request."""
 
     __slots__ = (
         "trace_id", "route", "t_start", "notes", "cost", "plan",
-        "explain",
+        "explain", "stages", "beside",
     )
 
     def __init__(self, trace_id: str | None = None, route: str = ""):
@@ -689,26 +702,36 @@ class RequestContext:
         #: cache front bypasses the response cache for explained
         #: requests (plan.explain_active)
         self.explain = False
+        # defaultdict: ``vec[name] += ms`` is one subscript, one add and
+        # one store, with no call between them for a thread switch
+        self.stages: dict = collections.defaultdict(float)
+        self.beside: dict = collections.defaultdict(float)
 
     def elapsed_ms(self) -> float:
         return (time.perf_counter() - self.t_start) * 1e3
 
 
-_ambient = threading.local()
+class _Ambient(threading.local):
+    # a class default: a thread that never scoped a request reads None
+    # without raising inside getattr (every stage scope reads it)
+    ctx = None
+
+
+_ambient = _Ambient()
 
 
 def current_context() -> RequestContext | None:
     """The request context the API layer scoped onto this thread (or
     None). Pool workers re-install the submitting request's context via
     :func:`request_context`, exactly like ambient deadlines."""
-    return getattr(_ambient, "ctx", None)
+    return _ambient.ctx
 
 
 @contextmanager
 def request_context(ctx: RequestContext | None):
     """Install ``ctx`` as this thread's ambient request context
     (``None`` restores 'no context' — safe to pass through)."""
-    prev = getattr(_ambient, "ctx", None)
+    prev = _ambient.ctx
     _ambient.ctx = ctx
     try:
         yield ctx
@@ -754,9 +777,26 @@ def annotate(**kw) -> None:
     dict is never mutated, so concurrent readers (the slow-query log
     snapshotting a request an abandoned pool thread still annotates)
     cannot crash mid-iteration."""
-    ctx = getattr(_ambient, "ctx", None)
+    ctx = _ambient.ctx
     if ctx is not None:
         ctx.notes = {**ctx.notes, **kw}
+
+
+def stage_notes(ctx: RequestContext, suffix: str = "") -> dict:
+    """``{"stages": ..., "beside": ...}`` of one request for a record a
+    person reads (the slow-query log; ``?explain=1``, with ``suffix``
+    ``Ms`` beside the plan's own ``stages``): milliseconds rounded to
+    the microsecond, zeros dropped, an empty vector left out."""
+    out = {}
+    for key in ("stages", "beside"):
+        vec = {
+            name: ms
+            for name, v in list(getattr(ctx, key).items())
+            if (ms := round(v, 3))
+        }
+        if vec:
+            out[key + suffix] = vec
+    return out
 
 
 # -- slow-query log -----------------------------------------------------------
@@ -789,9 +829,14 @@ class SlowQueryLog:
         with self._lock:
             return list(self._ring)
 
+    def records(self, elapsed_ms: float) -> bool:
+        """Whether a request of this latency is recorded (what a caller
+        asks before it builds notes only a record needs)."""
+        return 0 <= self.threshold_ms <= elapsed_ms
+
     def maybe_record(self, *, trace_id: str, route: str, status: int,
                      elapsed_ms: float, notes: dict | None = None) -> bool:
-        if self.threshold_ms < 0 or elapsed_ms < self.threshold_ms:
+        if not self.records(elapsed_ms):
             return False
         entry = {
             "traceId": trace_id,

@@ -53,7 +53,12 @@ import weakref
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
-from ..telemetry import current_context, new_span_id, percentiles
+from ..telemetry import (
+    _ambient,  # read in every scope: the attribute, not a call
+    current_context,
+    new_span_id,
+    percentiles,
+)
 
 #: every stage of the serving path, name -> kind. ``work``: the thread
 #: computes, or drives or blocks on the device (annotated on the
@@ -140,6 +145,16 @@ CHAIN = (
     "batcher.fetch_wait", "kernel.readback", "kernel.unpack",
     "handoff.back", "engine.fanout", "engine.materialize", "api.envelope",
 )
+
+#: ``elapsed_ms`` less the chain's sum, as a label beside the chain's
+#: stages: the time between stages
+UNNAMED = "unnamed"
+#: label values of ``request.tail_ms{stage}`` / ``request.body_ms{stage}``
+FOLD_LABELS = (*CHAIN, UNNAMED)
+#: finishes a route's thresholds are taken over
+CLASS_RING = 2048
+#: ... and the fewest they are taken from: a twentieth of them is one
+CLASS_AFTER = 20
 
 #: thread-name prefix -> role of ``runtime.thread_cpu_ms{role}`` and its
 #: siblings (:class:`ThreadClock`); a thread no prefix names is ``other``
@@ -312,7 +327,17 @@ class StageStats:
                 ann.__exit__(None, None, None)
             if span is not None:
                 self.tracer._finish(span, t1)
-        self.add(ms, self.tracer._serving_n.get(me, 1))
+        serving = self.tracer._serving.get(me)
+        if serving is None:
+            # the thread's own request, if it has one: its vector takes
+            # what the stage's req_ms takes
+            self.add(ms)
+            ctx = _ambient.ctx
+            if ctx is not None:
+                ctx.stages[self.name] += ms
+        else:
+            self.add(ms, serving.n)
+            serving.credit(self.name, ms)
         if cpu:
             self.add_cpu(cpu * 1e-6 * self._every)
         return False
@@ -484,23 +509,39 @@ _NULL = _NullSpan()
 
 class _Serving:
     """``with tracer.serving(n):`` — see :meth:`Tracer.serving`. Holds
-    no state of one use, so the tracer keeps one per ``n`` and a pool
-    task allocates nothing for its scope; scopes do not nest on a
-    thread (launcher, fetcher and fan-out pools are threads of their
+    no state of one use, so the tracer keeps one per bare ``n`` and a
+    pool task allocates nothing for its scope (a launch that names its
+    entries' contexts allocates this one object); scopes do not nest on
+    a thread (launcher, fetcher and fan-out pools are threads of their
     own)."""
 
-    __slots__ = ("_by_thread", "_n")
+    __slots__ = ("_by_thread", "n", "ctxs")
 
-    def __init__(self, by_thread: dict, n):
+    def __init__(self, by_thread: dict, n, ctxs=None):
         self._by_thread = by_thread
-        self._n = n
+        self.n = n
+        self.ctxs = ctxs
 
     def __enter__(self):
-        self._by_thread[threading.get_ident()] = self._n
+        self._by_thread[threading.get_ident()] = self
 
     def __exit__(self, *exc):
         self._by_thread.pop(threading.get_ident(), None)
         return False
+
+    def credit(self, name: str, ms: float) -> None:
+        """A sample closed inside the scope, to the vectors of the
+        requests it served: each of a launch's entries, or, serving
+        none, ``beside`` of the request the pool thread works for. A
+        bare count over 0 names nobody (the writer's transaction)."""
+        ctxs = self.ctxs
+        if ctxs is not None:
+            for ctx in ctxs:
+                ctx.stages[name] += ms
+        elif not self.n:
+            ctx = _ambient.ctx
+            if ctx is not None:
+                ctx.beside[name] += ms
 
 
 class _Registry(dict):
@@ -556,9 +597,9 @@ class Tracer:
         # process-wide flag off, a stage skips the span tree on two
         # attribute reads
         self._overrides = 0
-        # thread id -> requests served by what that thread is running
-        # (serving): a launch of the batcher, a pool task of a fan-out
-        self._serving_n: dict[int, int] = {}
+        # thread id -> the serving scope that thread is inside: a launch
+        # of the batcher, a pool task of a fan-out
+        self._serving: dict[int, _Serving] = {}
         self._serving_scopes: dict[int, _Serving] = {}
 
     # -- stages: always on ----------------------------------------------------
@@ -569,23 +610,34 @@ class Tracer:
         :meth:`serving` count of requests, or 1."""
         return self._stages[name]
 
-    def observe(self, name: str, ms: float, n: float = 1) -> None:
+    def observe(self, name: str, ms: float, n: float = 1, ctxs=()) -> None:
         """Feed an interval whose two ends were read elsewhere (a
         hand-off between threads, a composite): it has no thread, so
         its ``cpu_ms`` stays 0. ``work`` stages are scoped, never
-        observed: the annotation needs the live scope."""
+        observed: the annotation needs the live scope. ``ctxs``: the
+        contexts of the requests the interval belongs to (None among
+        them skipped), each credited ``ms`` in its vector, or in
+        ``beside`` where the sample serves none."""
         acc = self._stages[name]
         if acc.label is not None:
             raise ValueError(f"work stage {name!r} must be a `with stage(...)`")
         acc.add(ms, n)
+        for ctx in ctxs:
+            if ctx is not None:
+                (ctx.stages if n else ctx.beside)[name] += ms
 
-    def serving(self, n: int) -> _Serving:
+    def serving(self, n: int, ctxs=None) -> _Serving:
         """Stages opened on this thread inside the scope serve ``n``
         requests each (a launch of the micro-batcher; 0 on a pool thread
-        whose request is parked in ``engine.fanout`` meanwhile)."""
+        whose request is parked in ``engine.fanout`` meanwhile).
+        ``ctxs``: the contexts of the ``n``, whose vectors each sample
+        is credited to; without them a scope that serves none credits
+        ``beside`` of the thread's ambient context."""
+        if ctxs is not None:
+            return _Serving(self._serving, n, ctxs)
         scope = self._serving_scopes.get(n)
         if scope is None:
-            scope = self._serving_scopes[n] = _Serving(self._serving_n, n)
+            scope = self._serving_scopes[n] = _Serving(self._serving, n)
         return scope
 
     def stage_quantiles(self, name: str) -> dict:
@@ -970,8 +1022,11 @@ class LockTurnProbe:
 
     PERIOD_S = 0.05
 
-    def __init__(self, owner):
+    def __init__(self, owner, each_second=None):
         self._owner = weakref.ref(owner)
+        # work of the owner's that wants a thread off the request's
+        # path once a second (it must not hold the owner alive)
+        self._each_second = each_second
         self._stop = threading.Event()
         self._thread = threading.Thread(
             target=self._run, name="lock-turn-probe", daemon=True
@@ -983,17 +1038,165 @@ class LockTurnProbe:
     def _run(self) -> None:
         period, wait = self.PERIOD_S, self._stop.wait
         turn = tracer._stages["runtime.lock_turn"]
+        each_second, per_second = self._each_second, round(1 / period)
+        ticks = 0
         while self._owner() is not None:
             t0 = _wall()
             if wait(period):
                 return
             # beside the chain: the sample serves no request
             turn.add(max(0.0, (_wall() - t0 - period) * 1e3), 0)
+            ticks += 1
+            if each_second is not None and ticks % per_second == 0:
+                each_second()
 
     def close(self) -> None:
         self._stop.set()
         if self._thread.is_alive():
             self._thread.join(1.0)
+
+
+class _Side:
+    """What the requests of one class (tail or body) of one route
+    spent: their count and their milliseconds by ``FOLD_LABELS``."""
+
+    __slots__ = ("count", "ms")
+
+    def __init__(self):
+        self.count = 0
+        self.ms = [0.0] * len(FOLD_LABELS)
+
+    def mean(self) -> dict:
+        n = self.count or 1
+        return {
+            name: round(v / n, 3)
+            for name, v in zip(FOLD_LABELS, self.ms) if v
+        }
+
+
+class _RouteFold:
+    __slots__ = ("recent", "cuts", "cut_at", "total", "tail", "body",
+                 "by_granularity", "tail_by_granularity")
+
+    def __init__(self):
+        # elapsed_ms of the route's latest finishes (append is atomic)
+        self.recent: collections.deque = collections.deque(maxlen=CLASS_RING)
+        # (p40, p60, p95) of ``recent``; nothing is classed before
+        self.cuts = (float("inf"), float("-inf"), float("inf"))
+        self.cut_at = 0  # ``total`` when the cuts were taken
+        self.total = 0
+        self.tail = _Side()
+        self.body = _Side()
+        self.by_granularity: dict = collections.defaultdict(int)
+        self.tail_by_granularity: dict = collections.defaultdict(int)
+
+
+class TailFold:
+    """Where the slowest twentieth of a route's requests spent their
+    time, beside its middle fifth (ISSUE 37): a stage's own p95 is over
+    that stage's samples, not over the slow requests.
+
+    :meth:`fold` takes a finished request's ``elapsed_ms`` (what
+    ``api.total`` and ``meta.elapsedTimeMs`` report) and its stage
+    vector and classes it against the route's RUNNING quantiles of
+    ``elapsed_ms``: *tail* at or over the p95, *body* between the p40
+    and the p60. A classed request (one in four) adds its chain stages
+    and ``unnamed`` (``elapsed_ms`` less their sum: the time between
+    stages) to its side's sums, so a side's labels add up to its
+    requests' ``elapsed_ms``; every request counts in ``total`` and by
+    granularity. One short lock a request. :meth:`refresh` takes the
+    three cuts anew over the route's latest ``CLASS_RING`` finishes, off
+    the request's path (the app's ``LockTurnProbe`` calls it once a
+    second). The sums are monotone and served when ``/metrics`` is
+    rendered (:meth:`series`); ``/debug/status`` serves :meth:`status`."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._routes: dict[str, _RouteFold] = {}
+
+    def fold(self, route: str, elapsed_ms: float, stages: dict,
+             granularity: str) -> None:
+        r = self._routes.get(route)
+        if r is None:
+            with self._lock:
+                r = self._routes.setdefault(route, _RouteFold())
+        r.recent.append(elapsed_ms)
+        lo, hi, tail = r.cuts
+        if elapsed_ms >= tail:
+            side = r.tail
+        elif lo <= elapsed_ms <= hi:
+            side = r.body
+        else:
+            side = None
+        if side is not None:
+            get = stages.get
+            ms = [get(name, 0.0) for name in CHAIN]
+            ms.append(elapsed_ms - sum(ms))
+        with self._lock:
+            r.total += 1
+            r.by_granularity[granularity] += 1
+            if side is None:
+                return
+            if side is r.tail:
+                r.tail_by_granularity[granularity] += 1
+            side.count += 1
+            side.ms = [a + b for a, b in zip(side.ms, ms)]
+
+    def refresh(self) -> None:
+        """The three cuts of every route that finished a request since
+        they were taken."""
+        for r in list(self._routes.values()):
+            total = r.total
+            if total == r.cut_at:
+                continue
+            try:
+                xs = sorted(r.recent)
+            except RuntimeError:  # an append landed mid-copy: next time
+                continue
+            n = len(xs)
+            if n >= CLASS_AFTER:
+                r.cuts = (xs[int(0.4 * n)], xs[int(0.6 * n)],
+                          xs[int(0.95 * n)])
+                r.cut_at = total
+
+    def series(self) -> dict:
+        """The sums over every route, for the ``request.*`` series."""
+        out = {
+            "tail_count": 0, "body_count": 0, "classed_total": 0,
+            "tail_ms": dict.fromkeys(FOLD_LABELS, 0.0),
+            "body_ms": dict.fromkeys(FOLD_LABELS, 0.0),
+            "tail_by_granularity": collections.Counter(),
+            "classed_by_granularity": collections.Counter(),
+        }
+        with self._lock:
+            for r in self._routes.values():
+                out["classed_total"] += r.total
+                out["classed_by_granularity"].update(r.by_granularity)
+                out["tail_by_granularity"].update(r.tail_by_granularity)
+                for key, side in (("tail", r.tail), ("body", r.body)):
+                    out[f"{key}_count"] += side.count
+                    sums = out[f"{key}_ms"]
+                    for name, v in zip(FOLD_LABELS, side.ms):
+                        sums[name] += v
+        return out
+
+    def status(self) -> dict:
+        """Per route: the cuts, the counts and each side's mean vector."""
+        with self._lock:
+            return {
+                route: {
+                    "thresholdsMs": dict(zip(
+                        ("p40", "p60", "p95"),
+                        (round(c, 3) for c in r.cuts),
+                    )) if r.cut_at else None,
+                    "classed": r.total,
+                    "tail": r.tail.count,
+                    "body": r.body.count,
+                    "tailMeanMs": r.tail.mean(),
+                    "bodyMeanMs": r.body.mean(),
+                }
+                for route, r in sorted(self._routes.items())
+            }
 
 
 def graft_launch_span(active, *, elapsed_ms: float = 0.0, **meta) -> None:

@@ -363,7 +363,8 @@ def test_explain_gate_404_401_403_and_identical_answers():
         )
         assert s == 200
         ep = explained["meta"]["executionPlan"]
-        assert set(ep) == EXECUTION_PLAN_KEYS
+        # the plan, and (ISSUE 37) the request's own stage vector
+        assert set(ep) - {"besideMs"} == EXECUTION_PLAN_KEYS | {"stagesMs"}
         assert ep["truncated"] is False
         assert ep["shape"] == plan_shape(ep["stages"])
         stages = {e["stage"] for e in ep["stages"]}

@@ -357,8 +357,8 @@ def test_debug_status_schema_and_diagnosis(app):
     assert status == 200
     assert set(doc) == {
         "ready", "beaconId", "slo", "breakers", "routing", "queues",
-        "ingest", "stages", "costs", "canary", "device", "events",
-        "plans", "diagnosis",
+        "ingest", "stages", "requests", "costs", "canary", "device",
+        "events", "plans", "diagnosis",
     }
     # canary rollup (ISSUE 12): the prober exists (idle) on every app
     assert doc["canary"]["registeredProbes"] == 0
