@@ -39,6 +39,7 @@ from ..ingest import IngestService
 from ..ingest.service import VcfLocationError
 from ..harness import faults
 from ..metadata import MetadataStore, OntologyStore
+from ..metadata.memo import register_memo_metrics
 from ..metadata.filters import FilterError
 from ..plan import (
     PlanStore,
@@ -558,6 +559,7 @@ class BeaconApp:
             fn=lambda: tracer.cpu_every,
         )
         self.canary.register_metrics(reg)
+        register_memo_metrics(reg, lambda: self.store.resolve_memo)
         register_plan_metrics(reg, self.plans)
         register_admission_metrics(reg, lambda: self.admission)
         self.shaping.register_metrics(reg)
@@ -1252,6 +1254,9 @@ class BeaconApp:
             "queues": queues,
             "ingest": ingest,
             "stages": stages,
+            # how often variant queries resolved from the memo of the
+            # metadata generation (metadata/memo.py)
+            "filters": {"memo": self.store.resolve_memo.stats()},
             "requests": self.tails.status(),
             "costs": costs,
             "canary": canary,

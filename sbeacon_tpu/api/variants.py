@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import base64
 import dataclasses
+import functools
 import json
 
 from ..metadata.filters import entity_search_conditions
@@ -45,35 +46,102 @@ def resolve_datasets(
         )
 
 
+#: every field of a filter that ``entity_search_conditions`` reads: the
+#: memo's key for a filter list is their canonical JSON
+_FILTER_FIELDS = (
+    "id", "scope", "includeDescendantTerms", "similarity", "operator", "value",
+)
+
+
+class KeptDocument(dict):
+    """A dataset document as ``resolve_datasets`` hands it out: the memo
+    keeps it and every later hit shares it, so it can be read and copied
+    (``dict(doc)``) and refuses to be changed. Frozen rather than copied
+    for each caller: 128 copies a request (``mds4``) are 128 allocations
+    inside the stage, and the collections they draw land in it."""
+
+    def _refuse(self, *_args, **_kw):
+        raise TypeError(
+            "a resolved dataset document is shared with later requests: "
+            "copy it before changing it"
+        )
+
+    __setitem__ = __delitem__ = __ior__ = _refuse
+    clear = pop = popitem = setdefault = update = _refuse
+
+
 def _resolve_datasets(store, ontology, assembly_id, filters, dataset_ids):
-    samples_by_dataset: dict[str, list[str]] = {}
+    """Through the store's memo (``metadata/memo.py``): the generations
+    are read FIRST, and a miss computes as it always did and is kept
+    under the generations read. What is kept is shared by every later
+    request, so each caller gets outer containers of its own over leaves
+    it cannot change: a fresh list of frozen documents, fresh sample
+    lists."""
+    if filters and ontology is not store.ontology:
+        # the memo knows one ontology's generation: its store's own
+
+        def through(key, compute):
+            return compute()
+
+    else:
+        through = functools.partial(
+            store.resolve_memo.through,
+            (
+                store.generation(),
+                None if store.ontology is None else store.ontology.generation(),
+            ),
+        )
+
+    samples_by_dataset: dict[str, tuple] = {}
     if filters:
-        conditions, params = entity_search_conditions(
-            filters, "analyses", "analyses", ontology=ontology, id_modifier="A.id"
+        canonical = json.dumps(
+            [{k: f[k] for k in _FILTER_FIELDS if k in f} for f in filters],
+            sort_keys=True,
         )
-        # one row per dataset, not one per sample: sqlite hands the
-        # interpreter lock back and forth once per row it steps, and
-        # with other requests on the host every such hand-over waits
-        # for a thread to wake (PERF.md, PR 25: 100 rows, 58 ms)
-        rows = store.query(
-            f"SELECT A._datasetid, json_group_array(A._vcfsampleid) "
-            f"FROM analyses A {conditions} GROUP BY A._datasetid",
-            params,
+        samples_by_dataset = through(
+            ("samples", canonical),
+            lambda: _filtered_samples(store, ontology, filters),
         )
-        for ds, samples in rows:
-            samples_by_dataset[ds] = [s for s in json.loads(samples) if s]
         ids = sorted(samples_by_dataset)
         if dataset_ids:
             allowed = set(dataset_ids)
             ids = [i for i in ids if i in allowed]
         if not ids:
             return [], {}
-        datasets = store.datasets_for_assembly(assembly_id, dataset_ids=ids)
-    else:
-        datasets = store.datasets_for_assembly(
-            assembly_id, dataset_ids=dataset_ids
-        )
-    return datasets, samples_by_dataset
+        dataset_ids = ids
+    datasets = through(
+        ("datasets", assembly_id.lower(), tuple(dataset_ids or ())),
+        lambda: tuple(
+            KeptDocument(d)
+            for d in store.datasets_for_assembly(
+                assembly_id, dataset_ids=dataset_ids
+            )
+        ),
+    )
+    return (
+        list(datasets),
+        {ds: list(names) for ds, names in samples_by_dataset.items()},
+    )
+
+
+def _filtered_samples(store, ontology, filters) -> dict[str, tuple]:
+    """dataset id -> the VCF sample names of the analyses the filters
+    select (reference route_g_variants.py:117-127 datasets_query)."""
+    conditions, params = entity_search_conditions(
+        filters, "analyses", "analyses", ontology=ontology, id_modifier="A.id"
+    )
+    # one row per dataset, not one per sample: sqlite hands the
+    # interpreter lock back and forth once per row it steps, and
+    # with other requests on the host every such hand-over waits
+    # for a thread to wake (PERF.md, PR 25: 100 rows, 58 ms)
+    rows = store.query(
+        f"SELECT A._datasetid, json_group_array(A._vcfsampleid) "
+        f"FROM analyses A {conditions} GROUP BY A._datasetid",
+        params,
+    )
+    return {
+        ds: tuple(s for s in json.loads(samples) if s) for ds, samples in rows
+    }
 
 
 def encode_internal_id(

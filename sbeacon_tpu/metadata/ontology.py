@@ -29,6 +29,7 @@ from pathlib import Path
 from typing import Callable, Iterable
 
 from ..utils.trace import stage
+from .memo import Generation
 
 
 class OntologyStore:
@@ -44,6 +45,10 @@ class OntologyStore:
         # JSON list), so a read is one statement and one row stepped.
         self._lock = threading.Lock()
         self.conn = sqlite3.connect(str(path), check_same_thread=False)
+        if str(path) != ":memory:":
+            # WAL: a commit by any connection shows in the WAL index,
+            # which the generation reads without a statement
+            self.conn.execute("PRAGMA journal_mode=WAL")
         self.conn.executescript(
             """
             CREATE TABLE IF NOT EXISTS ontologies (
@@ -58,7 +63,15 @@ class OntologyStore:
             """
         )
         self.conn.commit()
+        # what a closure read is valid for (metadata/memo.py has the
+        # rule): every committing path bumps it after its commit
+        self._generation = Generation(self.conn, str(path))
         self.resolver: Callable[[str], set[str]] | None = None
+
+    def generation(self):
+        """Moves with every commit to the ontology tables, from this
+        store or, file-backed, from any other connection."""
+        return self._generation.read()
 
     # -- ontology metadata (reference Ontologies table) ---------------------
 
@@ -69,6 +82,7 @@ class OntologyStore:
                 (prefix, json.dumps(data)),
             )
             self.conn.commit()
+            self._generation.committed()
 
     def get_ontology(self, prefix: str) -> dict | None:
         with self._lock:
@@ -143,6 +157,7 @@ class OntologyStore:
                     (t, json.dumps(sorted(descs))),
                 )
             self.conn.commit()
+            self._generation.committed()
 
     def _get_locked(self, table: str, term: str) -> set[str] | None:
         row = self.conn.execute(
@@ -209,4 +224,5 @@ class OntologyStore:
 
     def close(self) -> None:
         with self._lock:
+            self._generation.close()
             self.conn.close()

@@ -25,6 +25,7 @@ from pathlib import Path
 
 from .entities import ENTITY_COLUMNS, ENTITY_KINDS, extract_terms
 from .filters import entity_search_conditions
+from .memo import Generation, ResolveMemo
 from .ontology import OntologyStore
 
 
@@ -59,8 +60,10 @@ class MetadataStore:
         # per-kind row counts for the density heuristic: a COUNT(*) is
         # a full B-tree scan at 1M rows and was paid on EVERY
         # record-granularity fetch with one term filter (ADVICE r3);
-        # invalidated by upsert/delete/rebuild_indexes
-        self._kind_counts: dict[str, int] = {}
+        # kind -> (generation, rows)
+        self._kind_counts: dict[str, tuple] = {}
+        #: what variant queries resolved to at the current generation
+        self.resolve_memo = ResolveMemo()
         if self._path != ":memory:":
             # WAL: writers never block readers, so per-thread read
             # connections can serve concurrently while upserts/rebuilds
@@ -70,6 +73,10 @@ class MetadataStore:
             self.conn.execute("PRAGMA busy_timeout=10000")
         self.ontology = ontology
         self._create_tables()
+        # what everything derived from the tables is valid for
+        # (metadata/memo.py has the rule): every committing path bumps
+        # it after its commit, inside the write lock
+        self._generation = Generation(self.conn, self._path)
 
     def _create_tables(self) -> None:
         cur = self.conn.cursor()
@@ -101,6 +108,12 @@ class MetadataStore:
             """
         )
         self.conn.commit()
+
+    def generation(self):
+        """Moves with every commit to the store's tables, from this
+        store or, file-backed, from any other connection. Read it
+        BEFORE the data a derived answer is computed from."""
+        return self._generation.read()
 
     def _read(self, sql: str, params=()):  # noqa: D401
         """Thread-safe read.
@@ -136,7 +149,6 @@ class MetadataStore:
         (reference: per-entity upload_array ORC + terms-cache writes)."""
         if kind not in ENTITY_COLUMNS:
             raise ValueError(f"unknown entity kind {kind!r}")
-        self._kind_counts.pop(kind, None)
         cols = ENTITY_COLUMNS[kind]
         col_names = ", ".join(c.lower() for c in cols) + ", _doc"
         placeholders = ", ".join("?" for _ in range(len(cols) + 1))
@@ -162,9 +174,9 @@ class MetadataStore:
                     ],
                 )
             self.conn.commit()
+            self._generation.committed()
 
     def delete(self, kind: str, entity_id: str) -> None:
-        self._kind_counts.pop(kind, None)
         with self._lock:
             self._set_term_counts_clean(self.conn.cursor(), False)
             self.conn.execute(
@@ -175,6 +187,7 @@ class MetadataStore:
                 (kind, entity_id),
             )
             self.conn.commit()
+            self._generation.committed()
 
     # -- the indexer (reference lambda/indexer CTAS trio) -------------------
 
@@ -202,7 +215,6 @@ class MetadataStore:
     }
 
     def rebuild_indexes(self) -> None:
-        self._kind_counts.clear()
         with self._lock:
             cur = self.conn.cursor()
             # drop secondary indexes first: maintaining them during the
@@ -324,6 +336,7 @@ class MetadataStore:
             self._set_term_counts_clean(cur, True)
             cur.execute("ANALYZE")
             self.conn.commit()
+            self._generation.committed()
 
     # -- query surface (AthenaModel equivalents) ----------------------------
 
@@ -333,11 +346,13 @@ class MetadataStore:
         )
 
     def _row_count(self, kind: str) -> int:
-        """Cached COUNT(*) per entity table (write paths invalidate)."""
-        n = self._kind_counts.get(kind)
-        if n is None:
-            n = self._read(f"SELECT COUNT(*) FROM {kind}")[0][0]
-            self._kind_counts[kind] = n
+        """COUNT(*) per entity table, kept for one generation."""
+        generation = self.generation()
+        kept = self._kind_counts.get(kind)
+        if kept is not None and kept[0] == generation:
+            return kept[1]
+        n = self._read(f"SELECT COUNT(*) FROM {kind}")[0][0]
+        self._kind_counts[kind] = (generation, n)
         return n
 
     def _dense_single_term(self, filters, kind):
@@ -748,4 +763,5 @@ class MetadataStore:
                 except Exception:
                     pass
             self._read_conns.clear()
+        self._generation.close()
         self.conn.close()
