@@ -163,6 +163,66 @@ def encode_queries(
     return enc
 
 
+#: the packed query row, one ``int32`` row a query in this column order:
+#: the thirteen ``int32`` fields, ``ref_wild`` as 0 / 1, ``shard`` (0
+#: where the caller gave no shard ids: ``_query_one`` reads it only
+#: under a 2-D ``chrom_offsets``), then the four words each of
+#: ``vprefix`` and ``vprefix_mask`` (``uint32`` viewed as ``int32``)
+PACK_INT_FIELDS = (
+    "chrom",
+    "start_min",
+    "start_max",
+    "end_min",
+    "end_max",
+    "ref_hash",
+    "ref_len",
+    "alt_mode",
+    "alt_hash",
+    "alt_len",
+    "vt_code",
+    "min_len",
+    "max_len",
+)
+_COL_REF_WILD = len(PACK_INT_FIELDS)
+_COL_SHARD = _COL_REF_WILD + 1
+_COL_VPREFIX = _COL_SHARD + 1
+_COL_VPREFIX_MASK = _COL_VPREFIX + 4
+PACK_WIDTH = _COL_VPREFIX_MASK + 4
+
+
+def pack_queries(enc: dict[str, np.ndarray]) -> np.ndarray:
+    """An ``encode_queries`` dictionary as ONE ``int32[b, PACK_WIDTH]``
+    array (host, numpy): what ``run_queries`` and the mesh's
+    ``sharded_query`` upload, once a launch. The scatter family packs
+    its own (``ops/query_pack.pack_q8``)."""
+    b = int(enc["chrom"].shape[0])
+    packed = np.zeros((b, PACK_WIDTH), np.int32)
+    for col, name in enumerate(PACK_INT_FIELDS):
+        packed[:, col] = enc[name]
+    packed[:, _COL_REF_WILD] = enc["ref_wild"]
+    if "shard" in enc:
+        packed[:, _COL_SHARD] = enc["shard"]
+    packed[:, _COL_VPREFIX:_COL_VPREFIX_MASK] = enc["vprefix"].view(np.int32)
+    packed[:, _COL_VPREFIX_MASK:] = enc["vprefix_mask"].view(np.int32)
+    return packed
+
+
+def unpack_queries(packed) -> dict:
+    """The ``q`` dictionary ``_query_one`` takes, batch-leading, from a
+    packed array inside a program: static column slices, the prefix
+    words bit-cast back to ``uint32``, ``ref_wild`` as ``!= 0``."""
+    q = {name: packed[:, col] for col, name in enumerate(PACK_INT_FIELDS)}
+    q["ref_wild"] = packed[:, _COL_REF_WILD] != 0
+    q["shard"] = packed[:, _COL_SHARD]
+    q["vprefix"] = jax.lax.bitcast_convert_type(
+        packed[:, _COL_VPREFIX:_COL_VPREFIX_MASK], jnp.uint32
+    )
+    q["vprefix_mask"] = jax.lax.bitcast_convert_type(
+        packed[:, _COL_VPREFIX_MASK:], jnp.uint32
+    )
+    return q
+
+
 # per-column padding fill values (pos/rec_end/rec_id = INT32_MAX so no
 # searchsorted window ever selects a padding row)
 _PAD_FILLS = {
@@ -653,7 +713,7 @@ def _query_one(arrays, q, *, window_cap: int, record_cap: int, n_iters: int):
     }
 
 
-def _query_batch_impl(arrays, enc, *, window_cap, record_cap, n_iters):
+def _query_batch_impl(arrays, packed, *, window_cap, record_cap, n_iters):
     fn = partial(
         _query_one,
         arrays,
@@ -661,7 +721,7 @@ def _query_batch_impl(arrays, enc, *, window_cap, record_cap, n_iters):
         record_cap=record_cap,
         n_iters=n_iters,
     )
-    return jax.vmap(fn)(enc)
+    return jax.vmap(fn)(unpack_queries(packed))
 
 
 _JIT_STATICS = ("window_cap", "record_cap", "n_iters")
@@ -672,13 +732,13 @@ _query_batch = partial(jax.jit, static_argnames=_JIT_STATICS)(
     _query_batch_impl
 )
 
-#: same program, but the encoded query-batch buffers (positional arg 1)
-#: are DONATED: steady-state serving uploads a fresh encode dict per
-#: launch, and without donation XLA double-buffers every one of them in
-#: HBM next to its output. The index arrays (arg 0) are persistent and
-#: never donated. Leaves whose shape/dtype match no output are simply
-#: freed rather than aliased — that is still the win — so the advisory
-#: "donated buffers were not usable" warning is noise here.
+#: same program, but the packed query batch (positional arg 1) is
+#: DONATED: steady-state serving uploads a fresh one per launch, and
+#: without donation XLA double-buffers it in HBM next to its output.
+#: The index arrays (arg 0) are persistent and never donated. A buffer
+#: whose shape/dtype match no output is simply freed rather than
+#: aliased — that is still the win — so the advisory "donated buffers
+#: were not usable" warning is noise here.
 _query_batch_donated = partial(
     jax.jit, static_argnames=_JIT_STATICS, donate_argnums=(1,)
 )(_query_batch_impl)
@@ -981,6 +1041,12 @@ def run_queries(
     ``DeviceIndex`` or stacked ``FusedDeviceIndex``; fused batches must
     arrive pre-encoded with their ``shard`` ids).
 
+    The encoded batch goes up as ONE packed ``int32`` array
+    (``pack_queries``; the program unpacks it, ``unpack_queries``), one
+    host-to-device transfer a launch, counted in
+    ``device.query_uploads``; a caller's dictionary or list is packed
+    here, at the seam.
+
     The batch pads up to a fixed size tier (``BATCH_TIERS``, repeating
     query 0 — always semantically inert, outputs trimmed) so the
     compiled-program cache is keyed by a handful of shapes instead of
@@ -1008,22 +1074,22 @@ def run_queries(
         # mini-index does, so a per-tail-shard spec batch is not padded to
         # the global 64 tier; everything else pads to the process ladder
         padded = padded_batch(dindex, b)
+        packed = pack_queries(enc)
         if padded != b:
-            enc = {
-                k: np.concatenate(
-                    [v, np.repeat(v[:1], padded - b, axis=0)]
-                )
-                for k, v in enc.items()
-            }
+            packed = np.concatenate(
+                [packed, np.repeat(packed[:1], padded - b, axis=0)]
+            )
     donate = _donate_uploads()
     with span("kernel.run_queries") as sp:
         with stage("kernel.dispatch") as st:
-            enc_dev = {k: jnp.asarray(v) for k, v in enc.items()}
+            # ONE upload a launch: seventeen, key by key, were most of
+            # this stage (PERF.md 6, PR 42)
+            packed_dev = jnp.asarray(packed)
             batch_fn = _query_batch_donated if donate else _query_batch
             with _quiet_donation():
                 out = batch_fn(
                     dindex.arrays,
-                    enc_dev,
+                    packed_dev,
                     window_cap=window_cap,
                     record_cap=record_cap,
                     n_iters=dindex.n_iters,
@@ -1046,7 +1112,8 @@ def run_queries(
             # dataset) pair
             evaluated_pairs=b,
             launch_ms=launch_ms,
-            donated=len(enc_dev) if donate else 0,
+            donated=1 if donate else 0,
+            uploads=1,
             # the XLA-gather families are not placed: their arrays lie
             # on the default device
             chip=0,

@@ -44,9 +44,11 @@ from ..ops.kernel import (
     active_ladder,
     bisect_iters,
     encode_queries,
+    pack_queries,
     pad_columns,
     pad_shard_columns,
     padded_rows,
+    unpack_queries,
     window_hint_for,
 )
 
@@ -231,8 +233,13 @@ def lane_rows(name: str, stacked: np.ndarray) -> np.ndarray:
     return stacked.reshape((d, n // LANES, LANES) + stacked.shape[2:])
 
 
-def _local_query(arrays_local, enc, *, window_cap, record_cap, n_iters, axis):
-    """Body run per device: vmap datasets × vmap queries, psum fan-in."""
+def _local_query(
+    arrays_local, packed, *, window_cap, record_cap, n_iters, axis
+):
+    """Body run per device: vmap datasets × vmap queries, psum fan-in.
+    ``packed`` is the query batch as ``ops.kernel.pack_queries`` lays
+    it, replicated."""
+    enc = unpack_queries(packed)
 
     def one_dataset(arrays_one):
         # a column resident in lane rows (lane_rows) is the 1-D column
@@ -456,8 +463,13 @@ def sharded_query(
     are replicated (addressable everywhere) while per-dataset results
     live on their owning hosts.
 
+    The encoded batch goes up as ONE packed array
+    (``ops.kernel.pack_queries``), put replicated on the mesh's chips in
+    one call and counted in ``device.query_uploads{mesh}``; the program
+    unpacks it.
+
     The launch passes the stages every family's does (``kernel.encode``,
-    ``kernel.dispatch``: the uploads and the jitted call until it
+    ``kernel.dispatch``: the one upload and the jitted call until it
     returns, ``kernel.readback``: both ``device_get``s, ``kernel.unpack``)
     and is ONE record of the flight recorder under the family ``mesh``:
     ``n_datasets`` real of the stack's padded dataset slots, times the
@@ -472,12 +484,15 @@ def sharded_query(
             encode_queries(queries) if isinstance(queries, list) else queries
         )
         b = int(enc["chrom"].shape[0])
+        packed = pack_queries(enc)
         fn = _build_sharded_fn(mesh, axis, window_cap, record_cap, n_iters)
     d_pad = int(stacked_arrays["chrom_offsets"].shape[0])
     with _collective_guard():
         with stage("kernel.dispatch") as dispatched:
-            enc_dev = {k: jnp.asarray(v) for k, v in enc.items()}
-            per_ds, agg = fn(stacked_arrays, enc_dev)
+            # ONE put, replicated on every chip of the mesh: the jitted
+            # shard_map finds its P() operand where it wants it
+            packed_dev = jax.device_put(packed, NamedSharding(mesh, P()))
+            per_ds, agg = fn(stacked_arrays, packed_dev)
         # ONE flight-recorder seam per launch, as every other family's:
         # the engine's mesh program is the family ``mesh`` (the pod
         # tier's run_mesh_queries keeps mesh_replicated / mesh_sliced).
@@ -491,6 +506,7 @@ def sharded_query(
             specs_padded=d_pad * b,
             evaluated_pairs=d_pad * b,
             launch_ms=dispatched.ms,
+            uploads=1,
             program_key=(
                 "mesh_stack",
                 int(mesh.devices.size),

@@ -1135,6 +1135,9 @@ class DeviceFlightRecorder:
         # donated to their launch instead of double-buffered in HBM
         self._fetched_bytes = 0
         self._donated = 0
+        # host arrays put on the device for launches' query batches,
+        # by family
+        self._uploads: dict[str, int] = {}
         # compile tracker: first-seen (program, shape) keys
         self._compiles: dict[str, dict] = {}
         self._warmup_depth = 0
@@ -1188,6 +1191,7 @@ class DeviceFlightRecorder:
         program_key=None,
         sliced: bool = False,
         donated: int = 0,
+        uploads: int = 0,
         chip: int | None = None,
     ) -> int:
         """Record ONE device launch; returns its sequence number (the
@@ -1197,7 +1201,9 @@ class DeviceFlightRecorder:
         ``program_key`` is a hashable (program, shape) identity fed to
         the compile tracker (None skips tracking for this launch);
         ``chip`` is the device a one-chip program ran on (None for a
-        program that spans the mesh)."""
+        program that spans the mesh); ``uploads`` the host arrays put
+        on the device for this launch's query batch (the seams that
+        count them: ``fused``, ``fused_l0``, ``mesh``)."""
         specs_real = int(specs_real)
         specs_padded = max(int(specs_padded), specs_real, 1)
         rec: dict = {
@@ -1214,6 +1220,8 @@ class DeviceFlightRecorder:
             rec["sliced"] = True
         if donated:
             rec["donated"] = int(donated)
+        if uploads:
+            rec["uploads"] = int(uploads)
         ctx = current_context()
         if ctx is not None:
             rec["traceId"] = ctx.trace_id
@@ -1229,6 +1237,10 @@ class DeviceFlightRecorder:
                 self._sliced += 1
             self._pairs += int(evaluated_pairs)
             self._donated += int(donated)
+            if uploads:
+                self._uploads[family] = (
+                    self._uploads.get(family, 0) + int(uploads)
+                )
             pad = self._pad.setdefault(family, [0, 0])
             pad[0] += specs_real
             pad[1] += specs_padded
@@ -1404,6 +1416,13 @@ class DeviceFlightRecorder:
         with self._lock:
             return dict(self._chips)
 
+    def query_uploads_by_family(self) -> dict:
+        """{family: host arrays put on the device for its launches'
+        query batches}: over ``launches_by_family`` it is the
+        transfers a launch makes (1 since the packed upload)."""
+        with self._lock:
+            return dict(self._uploads)
+
     def _pad_waste_by_family_locked(self) -> dict:
         return {
             f: round(1.0 - real / padded, 4)
@@ -1485,11 +1504,13 @@ class DeviceFlightRecorder:
             by_family = dict(self._families)
             sliced = self._sliced
             pairs = self._pairs
+            uploads = dict(self._uploads)
         return {
             "total": total,
             "byFamily": by_family,
             "sliced": sliced,
             "evaluatedPairs": pairs,
+            "queryUploads": uploads,
         }
 
     def snapshot(self) -> dict:
@@ -1509,6 +1530,7 @@ class DeviceFlightRecorder:
             pairs = self._pairs
             fetched = self._fetched_bytes
             donated = self._donated
+            uploads = dict(self._uploads)
             by_family = self._pad_waste_by_family_locked()
             by_tier = {
                 f"{family}:{tier}": round(1.0 - real / padded, 4)
@@ -1527,6 +1549,7 @@ class DeviceFlightRecorder:
             "evaluatedPairs": pairs,
             "fetchedBytes": fetched,
             "donatedBuffers": donated,
+            "queryUploads": uploads,
             "ring": {"size": keep, "recorded": seq, "entries": ring},
             "padWaste": {
                 "byFamily": by_family,
@@ -1628,6 +1651,14 @@ def register_device_metrics(registry) -> None:
         "device-program compiles observed OUTSIDE a warmup phase (a "
         "novel batch shape paid its XLA compile inside a request)",
         fn=lambda: flight_recorder.mid_request_compiles(),
+    )
+    registry.counter(
+        "device.query_uploads",
+        "host arrays put on the device for a launch's query batch, by "
+        "family (fused / fused_l0 / mesh): over device.launches of the "
+        "same family, the transfers a launch makes (1: one packed array)",
+        label="family",
+        fn=lambda: flight_recorder.query_uploads_by_family(),
     )
     registry.counter(
         "device.fallbacks",

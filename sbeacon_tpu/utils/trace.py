@@ -112,8 +112,10 @@ STAGES = {
     "batcher.fetch_wait": "wait",  # dispatch returned -> fetcher starts
     "handoff.back": "wait",  # result set -> the waiting thread runs again
     # query encode, H2D and D2H, inside ops/
-    "kernel.encode": "work",  # encode_queries, pack_q8, padding
-    "kernel.dispatch": "work",  # uploads + the jitted call until it returns
+    "kernel.encode": "work",  # encode_queries, pack_queries / pack_q8, padding
+    # the upload and the jitted call until it returns: ONE packed array a
+    # launch of the fused and mesh families, two or three of the scatter's
+    "kernel.dispatch": "work",
     "kernel.readback": "work",  # device_get until host arrays exist
     "kernel.unpack": "work",  # host arrays -> each query's rows and counts
     # runtime

@@ -25,10 +25,12 @@ from jax.sharding import SingleDeviceSharding
 
 from sbeacon_tpu.ops.kernel import (
     _PAD_FILLS,
+    PACK_WIDTH,
     QuerySpec,
     _query_batch,
     bisect_iters,
     encode_queries,
+    pack_queries,
     pad_columns,
 )
 from sbeacon_tpu.ops.plane_kernel import (
@@ -236,9 +238,10 @@ def _fused(one_chip, n_padded, n_shards, batch):
     )
     queries = [QuerySpec("1", 1, 2, 1, 2)] * batch
     enc = encode_queries(queries, [0] * batch if n_shards else None)
+    # the batch as run_queries uploads it: ONE packed array
     return _query_batch.lower(
         arrays,
-        {k: struct(v) for k, v in enc.items()},
+        struct(pack_queries(enc)),
         window_cap=2048, record_cap=1024, n_iters=bisect_iters(n_padded),
     ).compile()
 
@@ -319,12 +322,15 @@ def test_mesh_program_compiles_at_mds4_shapes(chips):
     arrays["chrom_offsets"] = jax.ShapeDtypeStruct(
         (MDS4_DATASETS, 27), jnp.int32, sharding=sliced
     )
-    enc = {
-        k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=whole)
-        for k, v in encode_queries([QuerySpec("1", 1, 2, 1, 2)]).items()
-    }
+    # the one query as sharded_query puts it: ONE packed array, whole on
+    # every chip
+    packed = pack_queries(encode_queries([QuerySpec("1", 1, 2, 1, 2)]))
+    assert packed.shape == (1, PACK_WIDTH) and packed.dtype == np.int32
     fn = _build_sharded_fn(mesh, AXIS, 2048, 1024, bisect_iters(n_padded))
-    compiled = fn.lower(arrays, enc).compile()
+    compiled = fn.lower(
+        arrays,
+        jax.ShapeDtypeStruct(packed.shape, packed.dtype, sharding=whole),
+    ).compile()
     text = compiled.as_text()
     assert "all-reduce" in text
     gathers = re.findall(

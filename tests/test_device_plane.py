@@ -195,6 +195,7 @@ GOLDEN_DEVICE_KEYS = {
     "evaluatedPairs",
     "fetchedBytes",
     "donatedBuffers",
+    "queryUploads",
     "fallbacks",
     "ring",
     "padWaste",

@@ -252,6 +252,7 @@ GOLDEN_METRICS = [
     "device.plane_resident_bytes",
     "device.plane_fill",
     "device.donated_buffers",
+    "device.query_uploads",
     "device.fallbacks",
     "migration.started",
     "migration.completed",
