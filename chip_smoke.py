@@ -1475,7 +1475,7 @@ def check_plane_programs(run: Run, devices) -> None:
 
     from sbeacon_tpu.ops.plane_kernel import _plane_stats, resident_shape
     from sbeacon_tpu.ops.scatter_kernel import (
-        CHUNK_SMALL,
+        SELECTED_SLOTS,
         ScatterDeviceIndex,
         _selected_batch,
         _static_seg_k,
@@ -1498,9 +1498,9 @@ def check_plane_programs(run: Run, devices) -> None:
         plane = shape(*resident_shape(n_rows, n_words))
         return _selected_batch.lower(
             shape(n_tiles, 8, tile), plane, plane, plane, plane,
-            shape(CHUNK_SMALL), shape(CHUNK_SMALL, 8),
-            shape(CHUNK_SMALL, n_words),
-            T=tile, CAP=tile, nslots=CHUNK_SMALL, C=1,
+            shape(SELECTED_SLOTS), shape(SELECTED_SLOTS, 8),
+            shape(SELECTED_SLOTS, n_words),
+            T=tile, CAP=tile, nslots=SELECTED_SLOTS, C=1,
             exact_only=True, R=tile, with_counts=False, seg_k=seg_k,
         ).compile().memory_analysis()
 
@@ -1508,7 +1508,7 @@ def check_plane_programs(run: Run, devices) -> None:
     temps = {
         "_selected_batch": selected(planes.n_rows, _static_seg_k(sindex)),
         "_plane_stats": _plane_stats.lower(
-            plane, plane, plane, plane, shape(1024), shape(1024),
+            plane, plane, plane, plane, shape(1024), shape(), shape(1024),
             shape(n_words), R=1024, with_counts=False, with_or=True,
         ).compile().memory_analysis(),
     }

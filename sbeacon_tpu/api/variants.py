@@ -14,6 +14,7 @@ import functools
 import json
 
 from ..metadata.filters import entity_search_conditions
+from ..metadata.memo import KeptSamples
 from ..payloads import VariantQueryPayload
 from ..plan import explain_active
 from ..utils.chrom import normalize_chromosome
@@ -75,8 +76,8 @@ def _resolve_datasets(store, ontology, assembly_id, filters, dataset_ids):
     are read FIRST, and a miss computes as it always did and is kept
     under the generations read. What is kept is shared by every later
     request, so each caller gets outer containers of its own over leaves
-    it cannot change: a fresh list of frozen documents, fresh sample
-    lists."""
+    it cannot change: a fresh list of frozen documents, a fresh dict of
+    the kept tuples of sample names."""
     if filters and ontology is not store.ontology:
         # the memo knows one ontology's generation: its store's own
 
@@ -118,10 +119,11 @@ def _resolve_datasets(store, ontology, assembly_id, filters, dataset_ids):
             )
         ),
     )
-    return (
-        list(datasets),
-        {ds: list(names) for ds, names in samples_by_dataset.items()},
-    )
+    # the kept tuples of names themselves (``KeptSamples``): nobody can
+    # change them, a copy is 18,191 references a request at biobank
+    # width, and what the engine resolved from one stays with it
+    # (``VariantEngine._selection``)
+    return list(datasets), dict(samples_by_dataset)
 
 
 def _filtered_samples(store, ontology, filters) -> dict[str, tuple]:
@@ -140,7 +142,8 @@ def _filtered_samples(store, ontology, filters) -> dict[str, tuple]:
         params,
     )
     return {
-        ds: tuple(s for s in json.loads(samples) if s) for ds, samples in rows
+        ds: KeptSamples(s for s in json.loads(samples) if s)
+        for ds, samples in rows
     }
 
 
@@ -186,10 +189,16 @@ class VariantAggregation:
                 seen = self.sample_names_by_dataset.setdefault(
                     qr.dataset_id, []
                 )
-                seen_set = set(seen)
-                seen.extend(
-                    s for s in qr.sample_names if s not in seen_set
-                )
+                if seen:
+                    seen_set = set(seen)
+                    seen.extend(
+                        s for s in qr.sample_names if s not in seen_set
+                    )
+                else:
+                    # a dataset's first response (its only one, as a
+                    # rule): the carriers as they are, up to 18,191
+                    # names at biobank width, in one step
+                    seen.extend(qr.sample_names)
             if not check_all:
                 continue
             self.variants.update(qr.variants)

@@ -187,6 +187,32 @@ class VariantIndexShard:
         object.__setattr__(self, attr, out)
         return out
 
+    def sample_positions(self) -> dict[str, int]:
+        """{sample name: its position in the planes' bit order}, built
+        once a shard (``engine.add_index`` builds it on the publishing
+        thread): at 454,787 samples the map is a quarter of a second of
+        insertions, which no request may pay. A dataset published
+        again is another shard with its own map."""
+        cached = getattr(self, "_sample_positions", None)
+        if cached is None:
+            cached = {
+                s: k for k, s in enumerate(self.meta.get("sample_names") or [])
+            }
+            object.__setattr__(self, "_sample_positions", cached)
+        return cached
+
+    def sample_name_array(self) -> np.ndarray:
+        """``meta['sample_names']`` as an object array, so that a set of
+        positions picks its names in one indexing step; cached as
+        ``sample_positions`` is."""
+        cached = getattr(self, "_sample_name_array", None)
+        if cached is None:
+            names = self.meta.get("sample_names") or []
+            cached = np.empty(len(names), dtype=object)
+            cached[:] = names
+            object.__setattr__(self, "_sample_name_array", cached)
+        return cached
+
     @property
     def n_rows(self) -> int:
         return len(self.cols["pos"])

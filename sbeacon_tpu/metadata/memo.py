@@ -123,6 +123,23 @@ class Generation:
 RESOLVE_MEMO_ENTRIES = 256
 
 
+class KeptSamples(tuple):
+    """One dataset's selected sample names as the memo keeps them: the
+    tuple every request of one filter list is handed, with room for what
+    a consumer has resolved FROM it (``resolved``: the engine keeps the
+    names' positions and mask words there, by the shard that serves the
+    dataset; ``engine.VariantEngine._selection``). So a resolved
+    selection lives and dies with its entry: the memo's bound, its
+    generation rule and its lock are the only ones. At biobank width
+    (18,191 names of 454,787) the names are 145 kB an entry and a
+    resolved selection 0.35 MB a shard more."""
+
+    def __new__(cls, names=()):
+        self = super().__new__(cls, names)
+        self.resolved = {}
+        return self
+
+
 class ResolveMemo:
     """Answers of ``resolve_datasets`` for ONE generation of the stores,
     owned by a :class:`MetadataStore` and dying with it. A read under

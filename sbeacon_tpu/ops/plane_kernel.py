@@ -12,7 +12,10 @@ masked popcounts and the sample-hit OR-reduction in one jitted program:
   gather reads as it is (an ``[n, W]`` argument, W = ceil(n_samples/32)
   words, is re-tiled whole inside every program that gathers from it).
   A row of more than 64 words is zero-padded to the next multiple of
-  128: ``[n, Wp]``, 512 B/row/plane at 2504 samples. A narrower row is
+  128, as many lane rows as that takes: ``[n, Wp]``, one lane row and
+  512 B/row/plane at 2504 samples, 112 lane rows and 57,344 B at the
+  454,787 of a biobank (``4 x padded_words(ceil(n / 32))`` B: size a
+  chip by samples x rows). A narrower row is
   zero-padded to p words, p the least power of two >= W, and k = 128 // p
   rows share one lane row: ``[ceil(n / k), 128]``, 128 B/row/plane at
   1000 samples (``pack_factor``, ``resident_shape``). The count planes
@@ -20,7 +23,9 @@ masked popcounts and the sample-hit OR-reduction in one jitted program:
   shard has genotype-derived rows at all — INFO-sourced corpora (the
   common cohort-VCF case, and the bench corpus) only ever touch ``gt``
   for sample-hit extraction, so only it occupies HBM.
-- ``plane_row_stats`` gathers the matched rows' plane words, ANDs the
+- ``plane_row_stats`` gathers the matched rows' plane words eight rows
+  a step (``reduce_rows``: a launch's workspace is one block of rows at
+  any width, and it reads the real rows alone), ANDs the
   selected-sample mask, and returns per-row popcounts ``[R, 4]`` plus
   the OR of ``gt & mask`` over a caller-chosen row subset — the exact
   quantities ``materialize_response`` popcounted on host. The reference
@@ -49,7 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..index.columnar import FLAG, VariantIndexShard
-from ..telemetry import record_device_launch
+from ..telemetry import note_device_stage, record_device_launch
 
 # R padding tiers: one compiled program per (tier, flags) combination;
 # larger row sets chunk through the top tier (bounded compile cache)
@@ -119,6 +124,88 @@ def fold_parts(words, n_words: int):
             dimensions=(words.ndim - 1,),
         )
     return words[..., :n_words]
+
+
+#: rows one step of ``reduce_rows`` gathers: the sublanes of one tile
+ROW_BLOCK = 8
+
+
+def row_blocks(n_rows):
+    """Steps ``reduce_rows`` takes for ``n_rows`` rows at the front of
+    a slot (numpy or jnp, scalar or array)."""
+    return (n_rows + ROW_BLOCK - 1) // ROW_BLOCK
+
+
+def gathered_bytes(plane, n_rows, n_planes: int) -> int:
+    """Bytes ``reduce_rows`` reads from ``n_planes`` resident planes
+    shaped as ``plane`` for slots of ``n_rows`` real rows (an int or an
+    array of them): whole blocks of whole lane rows."""
+    blocks = int(np.sum(row_blocks(np.asarray(n_rows, dtype=np.int64))))
+    return blocks * ROW_BLOCK * int(plane.shape[1]) * 4 * n_planes
+
+
+def reduce_rows(planes, rows, n_rows, or_sel, mask):
+    """Masked popcounts and the carrier OR of the rows a batch matched,
+    in a workspace that does not grow with the batch or the width.
+
+    ``rows`` int32 ``[B, R]`` (R a multiple of ``ROW_BLOCK``) holds each
+    slot's ``n_rows[b]`` real rows at its front; ``mask`` int32
+    ``[B, W]``; ``or_sel`` bool ``[B, R]``, or None for no OR. One loop
+    runs over the ``ROW_BLOCK``-row blocks that hold a real row, slot
+    after slot: a step gathers ``[ROW_BLOCK, lanes]`` words of each
+    plane through ``masked_rows`` (8 x 57 kB at 454,787 samples),
+    popcounts them and ORs ``planes[0]``'s rows under ``or_sel`` into
+    its slot's accumulator. So a launch reads what its queries matched,
+    rounded up to blocks: a padding slot, a query that matched nothing
+    and the unmatched tail of a slot gather nothing, where one gather
+    of ``[B, R, lanes]`` read (and held) 64 x 1024 x 57 kB.
+
+    Returns (``[len(planes), B, R]`` int32 popcounts, zero past each
+    slot's last block; ``[B, lanes]`` int32 OR, to be folded by
+    ``fold_parts``)."""
+    n_slots, width = rows.shape
+    lanes = planes[0].shape[1]
+    blocks = row_blocks(jnp.minimum(n_rows, width)).astype(jnp.int32)
+    ends = jnp.cumsum(blocks)
+
+    def step(i, carry):
+        pcs, acc = carry
+        # the slot of block i: those before it end at or before i
+        slot = jnp.sum(ends <= i, dtype=jnp.int32)
+        at = (i - (ends[slot] - blocks[slot])) * ROW_BLOCK
+        r = jax.lax.dynamic_slice(rows, (slot, at), (1, ROW_BLOCK))[0]
+        m = jax.lax.dynamic_index_in_dim(mask, slot, keepdims=False)
+        words = [masked_rows(plane, r, m) for plane in planes]
+        pc = jnp.stack(
+            [
+                jnp.sum(jax.lax.population_count(g), axis=-1, dtype=jnp.int32)
+                for g in words
+            ]
+        )
+        pcs = jax.lax.dynamic_update_slice(pcs, pc[:, None, :], (0, slot, at))
+        if or_sel is not None:
+            take = jax.lax.dynamic_slice(or_sel, (slot, at), (1, ROW_BLOCK))[0]
+            hit = jax.lax.reduce(
+                jnp.where(take[:, None], words[0], jnp.int32(0)),
+                np.int32(0),
+                jax.lax.bitwise_or,
+                dimensions=(0,),
+            )
+            old = jax.lax.dynamic_index_in_dim(acc, slot, keepdims=False)
+            acc = jax.lax.dynamic_update_slice(
+                acc, (old | hit)[None, :], (slot, 0)
+            )
+        return pcs, acc
+
+    return jax.lax.fori_loop(
+        0,
+        ends[-1],
+        step,
+        (
+            jnp.zeros((len(planes), n_slots, width), jnp.int32),
+            jnp.zeros((n_slots, lanes), jnp.int32),
+        ),
+    )
 
 
 @partial(jax.jit, donate_argnums=0)
@@ -206,15 +293,22 @@ def sample_mask_words(
     selected_idx, n_words: int
 ) -> np.ndarray:
     """uint32[n_words] bit mask for a selected-sample index list — THE
-    wire format every plane consumer shares (bit s%32 of word s//32)."""
+    wire format every plane consumer shares (bit s%32 of word s//32).
+    One scatter of bits, whatever the cohort's width: the cost is the
+    selection's, and a sample named twice sets its bit once."""
+    idx = np.asarray(selected_idx, dtype=np.int64).reshape(-1)
     mask = np.zeros(n_words, dtype=np.uint32)
-    for si in selected_idx:
-        mask[si // 32] |= np.uint32(1 << (si % 32))
+    np.bitwise_or.at(
+        mask, idx >> 5, np.uint32(1) << (idx & 31).astype(np.uint32)
+    )
     return mask
 
 
 class PlaneDeviceIndex:
-    """Device-resident genotype planes of one shard.
+    """Device-resident genotype planes of one shard, each in its
+    resident layout (``resident_shape``): ``[n_rows, 128 j]`` for a row
+    of over 64 words, j the lane rows it takes (1 at 2504 samples, 112
+    at 454,787), ``[ceil(n_rows / k), 128]`` for a narrower one.
 
     ``gt`` is always uploaded (sample-hit extraction needs it); the
     three count planes ride along only when the shard contains
@@ -303,44 +397,28 @@ class PlaneDeviceIndex:
 
 @partial(jax.jit, static_argnames=("R", "with_counts", "with_or"))
 def _plane_stats(
-    gt, gt2, tok1, tok2, rows, or_sel, mask, *, R, with_counts, with_or
+    gt, gt2, tok1, tok2, rows, n_rows, or_sel, mask, *, R, with_counts,
+    with_or
 ):
     """[R,4] per-row masked popcounts + [W] OR of gt&mask over or_sel.
 
-    ``rows`` int32[R] (padding slots point at row 0; callers discard
-    their outputs), ``or_sel`` int32[R] 0/1, ``mask`` int32[W]: the
-    planes lie in their resident layout (``PlaneDeviceIndex``) and are
-    read through ``masked_rows``. Popcount columns:
+    ``rows`` int32[R] with the ``n_rows`` (an int32 scalar) real rows at
+    its front, ``or_sel`` int32[R] 0/1, ``mask`` int32[W]: the planes
+    lie in their resident layout (``PlaneDeviceIndex``) and are read
+    block by block through ``reduce_rows``, the real rows alone.
+    Popcount columns:
     0=gt, 1=gt2, 2=tok1, 3=tok2 (count columns zero when the plane set
-    has no count planes)."""
-    n_words = mask.shape[0]
-
-    def pc(g):
-        return jnp.sum(jax.lax.population_count(g), axis=1).astype(jnp.int32)
-
-    g = masked_rows(gt, rows, mask)  # [R, lanes]
-    pc_gt = pc(g)
-    zero = jnp.zeros_like(pc_gt)
-    if with_counts:
-        cols = [pc_gt] + [
-            pc(masked_rows(plane, rows, mask)) for plane in (gt2, tok1, tok2)
-        ]
-    else:
-        cols = [pc_gt, zero, zero, zero]
-    counts = jnp.stack(cols, axis=1)
-    if with_or:
-        or_words = fold_parts(
-            jax.lax.reduce(
-                jnp.where(or_sel[:, None] != 0, g, jnp.int32(0)),
-                np.int32(0),
-                jax.lax.bitwise_or,
-                dimensions=(0,),
-            ),
-            n_words,
-        )
-    else:
-        or_words = jnp.zeros((n_words,), jnp.int32)
-    return counts, or_words
+    has no count planes; every column zero past the last real block)."""
+    pcs, acc = reduce_rows(
+        (gt, gt2, tok1, tok2) if with_counts else (gt,),
+        rows[None, :],
+        n_rows[None],
+        (or_sel != 0)[None, :] if with_or else None,
+        mask[None, :],
+    )
+    zero = jnp.zeros((R,), jnp.int32)
+    cols = [pcs[p, 0] if p < pcs.shape[0] else zero for p in range(4)]
+    return jnp.stack(cols, axis=1), fold_parts(acc[0], mask.shape[0])
 
 
 def plane_row_stats(
@@ -402,6 +480,7 @@ def plane_row_stats(
         pindex.tok1 if with_counts else pindex.gt,
         pindex.tok2 if with_counts else pindex.gt,
         jax.device_put(rows_p, pindex.device),
+        jax.device_put(np.int32(R), pindex.device),
         jax.device_put(sel_p, pindex.device),
         jax.device_put(mask.view(np.int32), pindex.device),
         R=tier,
@@ -415,7 +494,7 @@ def plane_row_stats(
     # write then planted a REAL module attribute, permanently
     # shadowing the recorder behind a frozen snapshot for every later
     # reader in the process.
-    record_device_launch(
+    seq = record_device_launch(
         "plane",
         seam="scatter",
         tier=tier,
@@ -423,6 +502,12 @@ def plane_row_stats(
         specs_padded=tier,
         launch_ms=(time.perf_counter() - t0) * 1e3,
         chip=chip_of(pindex.device),
+    )
+    note_device_stage(
+        seq,
+        gather_bytes=gathered_bytes(
+            pindex.gt, R, 4 if with_counts else 1
+        ),
     )
     counts, or_words = jax.device_get((counts, or_words))
     return (

@@ -70,7 +70,14 @@ from ..telemetry import (
     record_device_launch,
 )
 from ..utils.trace import stage
-from .plane_kernel import chip_of, fold_parts, masked_rows, pack_factor
+from .plane_kernel import (
+    ROW_BLOCK,
+    chip_of,
+    fold_parts,
+    gathered_bytes,
+    pack_factor,
+    reduce_rows,
+)
 from .kernel import (
     MODE_ANY_BASE,
     MODE_EXACT,
@@ -118,6 +125,12 @@ _REF_LEN_CLAMP = 0x1FFF
 # fixed device-batch sizes (compiled-program reuse across logical sizes)
 CHUNK = 2048
 CHUNK_SMALL = 64
+
+#: slots of the fused match+planes program: its mask goes up and its
+#: carrier words come back a slot (57 kB each at 454,787 samples), and
+#: every launch the engine makes serves ONE query (``_fused_selected``),
+#: so the program has one slot and a batch of queries is a launch each
+SELECTED_SLOTS = 1
 
 # longest record (in SAME_PREV-chained rows minus one) the K-shift
 # first-match form handles; longer records take the segmented-scan form
@@ -560,10 +573,14 @@ def _selected_batch(
     - their gt/count planes are gathered, masked per-query
       (``mask`` int32 [nslots, W]) and popcounted. The planes are
       resident in whole 128-lane rows, a wide row zero-padded to them
-      and k narrow rows sharing one (``PlaneDeviceIndex``), so the
-      gather reads them as they lie: ``masked_rows`` gathers row r's
-      lane row and keeps its part, ``fold_parts`` brings ``or_words``
-      back to W, both on the device,
+      (one lane row at 2504 samples, 112 at 454,787) and k narrow rows
+      sharing one (``PlaneDeviceIndex``), so the gather reads them as
+      they lie, and it reads the matched rows alone, eight at a step
+      (``reduce_rows``: the workspace is one block of rows whatever the
+      slots, R and the width; a slot that matched nothing gathers
+      nothing).
+      ``masked_rows`` gathers row r's lane rows and keeps its part,
+      ``fold_parts`` brings ``or_words`` back to W, both on the device,
     - the sample-hit OR runs over the exact ``grp >= k0`` row subset
       via the same segmented scans as ``parallel.mesh._plane_reduce``
       (k0 = first record with positive cumulative rc; ploidy>2
@@ -572,7 +589,8 @@ def _selected_batch(
       the host's even though the extras themselves stay host-added).
 
     Returns (agg [nslots,8], rows [nslots,R] global row ids (-1 pad),
-    pc_call [nslots,R], pc_tok [nslots,R], or_words [nslots,W]).
+    pc_call [nslots,R], pc_tok [nslots,R] (0 at a pad lane),
+    or_words [nslots,W]).
     ``with_counts=False`` (INFO-sourced corpora) skips the three
     count-plane gathers entirely.
     """
@@ -601,23 +619,24 @@ def _selected_batch(
     rec_r = jnp.take_along_axis(seg_id, order, axis=1)
 
     n_words = mask.shape[1]
-    # unmatched slots (-1) read row 0; the rows past the plane's last in
-    # its last lane row are zeros
-    safe = jnp.clip(rows, 0, gt.shape[0] * pack_factor(n_words) - 1)
-    g = masked_rows(gt, safe, mask)  # [B, R, lanes]
-    pcw = lambda x: jnp.sum(
-        jax.lax.population_count(x), axis=-1
-    ).astype(jnp.int32)
-    pc_gt = pcw(g)
+    # the planes are read block by block, the matched rows alone
+    # (``reduce_rows``): every slot's matched lanes stand at its front,
+    # the width rounded up to whole blocks and cut again below
+    k = pack_factor(n_words)
+    pad = (-R) % ROW_BLOCK
+    safe = jnp.pad(
+        jnp.clip(rows, 0, gt.shape[0] * k - 1), ((0, 0), (0, pad))
+    )
+    n_rows = jnp.sum(matched, axis=1, dtype=jnp.int32)
+    live = lambda x: jnp.where(matched, x[:, :R], jnp.int32(0))
     if with_counts:
-        pc_call = pc_gt + pcw(masked_rows(gt2, safe, mask))
-        pc_tok = pcw(masked_rows(tok1, safe, mask)) + pcw(
-            masked_rows(tok2, safe, mask)
-        )
+        # the OR's row subset follows from the popcounts: one pass for
+        # the four planes' counts, a second over gt for the carriers
+        pcs, _ = reduce_rows((gt, gt2, tok1, tok2), safe, n_rows, None, mask)
+        pc_call = live(pcs[0] + pcs[1])
+        pc_tok = live(pcs[2] + pcs[3])
         rc = jnp.where((flags_r & FLAG.AC_INFO) != 0, ac_r, pc_call)
     else:
-        pc_call = pc_gt
-        pc_tok = jnp.zeros_like(pc_gt)
         rc = ac_r
     rc = rc * matched
 
@@ -652,15 +671,13 @@ def _selected_batch(
     )
     bwd_any = jnp.flip((c_f - base_f) > 0, axis=1)
     or_sel = matched & ((base > 0) | fwd_any | bwd_any)
-    or_words = fold_parts(
-        jax.lax.reduce(
-            jnp.where(or_sel[:, :, None], g, jnp.int32(0)),
-            np.int32(0),
-            jax.lax.bitwise_or,
-            dimensions=(1,),
-        ),
-        n_words,
-    )  # [B, W]
+    pcs, acc = reduce_rows(
+        (gt,), safe, n_rows, jnp.pad(or_sel, ((0, 0), (0, pad))), mask
+    )
+    if not with_counts:
+        pc_call = live(pcs[0])
+        pc_tok = jnp.zeros_like(pc_call)
+    or_words = fold_parts(acc, n_words)  # [B, W]
     return agg, rows, pc_call, pc_tok, or_words
 
 
@@ -754,28 +771,10 @@ def run_selected_scattered(
             sel = np.flatnonzero(in_tier & (is_exact == exact))
             if not len(sel):
                 continue
-            # chunk host-side at CHUNK_SMALL granularity: every padding
-            # slot in the fused program pays the R-row plane gather (not
-            # just the cheap tile gather), so padding 65 queries to 2048
-            # slots would cost ~30x the plane traffic — small fixed
-            # chunks bound both the waste and the compile cache
-            for a0 in range(0, len(sel), CHUNK_SMALL):
-                ss = sel[a0 : a0 + CHUNK_SMALL]
-                bb = len(ss)
-                nslots = CHUNK_SMALL
-                pad = (-bb) % nslots
-                tid = np.concatenate(
-                    [tile_ids_all[ss], np.zeros(pad, np.int32)]
-                )
-                qq = np.concatenate(
-                    [q8[ss], np.zeros((pad, 8), np.int32)]
-                )
-                mm = np.concatenate(
-                    [
-                        mask_words[ss],
-                        np.zeros((pad, W), np.uint32),
-                    ]
-                )
+            # one launch a query (SELECTED_SLOTS): no slot is padding
+            nslots = SELECTED_SLOTS
+            for ss in sel.reshape(-1, nslots):
+                tid, qq, mm = tile_ids_all[ss], q8[ss], mask_words[ss]
                 with stage("kernel.dispatch") as st:
                     a, r, pc, pt, ow = _selected_batch(
                         sindex.tiles,
@@ -799,7 +798,7 @@ def run_selected_scattered(
                     "plane",
                     seam="scatter",
                     tier=nslots,
-                    specs_real=bb,
+                    specs_real=nslots,
                     specs_padded=nslots,
                     launch_ms=st.ms,
                     program_key=_selected_program_key(
@@ -820,12 +819,19 @@ def run_selected_scattered(
                         fetch_bytes=sum(
                             np.asarray(v).nbytes for v in (a, r, pc, pt, ow)
                         ),
+                        # the blocks of matched rows the program read:
+                        # gt once, or the four count planes and gt again
+                        gather_bytes=gathered_bytes(
+                            pindex.gt,
+                            np.minimum(np.asarray(a)[:, 4], R),
+                            5 if with_counts else 1,
+                        ),
                     )
-                    agg[ss] = np.asarray(a)[:bb]
-                    rows[ss, :R] = np.asarray(r)[:bb]
-                    pc_call[ss, :R] = np.asarray(pc)[:bb]
-                    pc_tok[ss, :R] = np.asarray(pt)[:bb]
-                    or_words[ss] = np.asarray(ow)[:bb].view(np.uint32)
+                    agg[ss] = np.asarray(a)
+                    rows[ss, :R] = np.asarray(r)
+                    pc_call[ss, :R] = np.asarray(pc)
+                    pc_tok[ss, :R] = np.asarray(pt)
+                    or_words[ss] = np.asarray(ow).view(np.uint32)
 
     # a truncated row set would silently under-reduce the planes: the
     # per-tier R bound makes truncation part of the overflow contract
@@ -879,7 +885,8 @@ def warmup_index(
     caps = _tier_caps(sindex, window_cap)
     n = 0
     outs = []
-    for nslots in sorted(set(batch_shapes)):
+    selected_shapes = (SELECTED_SLOTS,) if pindex is not None else ()
+    for nslots in sorted({*batch_shapes, *selected_shapes}):
         tid = jax.device_put(np.zeros(nslots, np.int32), sindex.device)
         for ti, cap in [(-1, T)] + list(enumerate(caps)):
             C = 1 if ti == -1 else None
@@ -893,24 +900,25 @@ def warmup_index(
                     (MODE_EXACT if exact else MODE_ANY_BASE) << 1
                 )
                 qd = jax.device_put(q8, sindex.device)
-                outs.append(
-                    _scatter_batch(
-                        sindex.tiles, tid, qd,
-                        T=T, CAP=cap, nslots=nslots, C=C,
-                        exact_only=exact,
-                        seg_k=_static_seg_k(sindex),
+                if nslots in batch_shapes:
+                    outs.append(
+                        _scatter_batch(
+                            sindex.tiles, tid, qd,
+                            T=T, CAP=cap, nslots=nslots, C=C,
+                            exact_only=exact,
+                            seg_k=_static_seg_k(sindex),
+                        )
                     )
-                )
-                record_device_compile(
-                    "scatter",
-                    tier=nslots,
-                    program_key=_match_program_key(
-                        sindex, nslots, 1, cap, C, exact
-                    ),
-                )
-                n += 1
-                if pindex is not None and nslots == CHUNK_SMALL:
-                    # run_selected_scattered chunks at CHUNK_SMALL only.
+                    record_device_compile(
+                        "scatter",
+                        tier=nslots,
+                        program_key=_match_program_key(
+                            sindex, nslots, 1, cap, C, exact
+                        ),
+                    )
+                    n += 1
+                if nslots in selected_shapes:
+                    # run_selected_scattered launches SELECTED_SLOTS.
                     # A plane set WITH count planes serves two programs:
                     # restricted counting (selected samples) and plain
                     # sample extraction (with_counts=False)

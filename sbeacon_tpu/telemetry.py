@@ -1135,6 +1135,8 @@ class DeviceFlightRecorder:
         # donated to their launch instead of double-buffered in HBM
         self._fetched_bytes = 0
         self._donated = 0
+        # bytes plane-family launches read from the resident planes
+        self._gathered_bytes = 0
         # host arrays put on the device for launches' query batches,
         # by family
         self._uploads: dict[str, int] = {}
@@ -1329,17 +1331,23 @@ class DeviceFlightRecorder:
 
     def note_stage(self, seq: int, *, encode_ms: float | None = None,
                    fetch_ms: float | None = None,
-                   fetch_bytes: int | None = None) -> None:
+                   fetch_bytes: int | None = None,
+                   gather_bytes: int | None = None) -> None:
         """Attach a stage timing to a recorded launch (the encode
         happens before dispatch on the submitting thread, the fetch
         after it on the fetcher thread — neither is known at
         :meth:`record_launch` time). The per-record annotation no-ops
         once the record has rolled off the ring, but ``fetch_bytes``
         still accumulates into the lifetime counter — ring eviction
-        must not leak fetched bytes out of ``device.fetched_bytes``."""
+        must not leak fetched bytes out of ``device.fetched_bytes``.
+        ``gather_bytes`` (``plane`` family) is what the launch read
+        from the resident genotype planes: whole blocks of the rows its
+        queries matched, known once their counts are back."""
         with self._lock:
             if fetch_bytes is not None:
                 self._fetched_bytes += int(fetch_bytes)
+            if gather_bytes is not None:
+                self._gathered_bytes += int(gather_bytes)
             rec = self._by_seq.get(seq)
             if rec is None:
                 return
@@ -1349,6 +1357,8 @@ class DeviceFlightRecorder:
                 rec["fetchMs"] = round(float(fetch_ms), 3)
             if fetch_bytes is not None:
                 rec["fetchBytes"] = int(fetch_bytes)
+            if gather_bytes is not None:
+                rec["gatherBytes"] = int(gather_bytes)
 
     def record_fallback(self, site: str) -> None:
         """Count ONE device-path failure that was absorbed by a host
@@ -1398,6 +1408,14 @@ class DeviceFlightRecorder:
         owner-sharded output diet's structural evidence (ISSUE 17)."""
         with self._lock:
             return self._fetched_bytes
+
+    @property
+    def plane_gather_bytes(self) -> int:
+        """Lifetime bytes ``plane``-family launches gathered from the
+        resident genotype planes (``ops.plane_kernel.reduce_rows``):
+        over ``device.launches{plane}``, what a launch reads."""
+        with self._lock:
+            return self._gathered_bytes
 
     @property
     def donated_buffers(self) -> int:
@@ -1505,12 +1523,14 @@ class DeviceFlightRecorder:
             sliced = self._sliced
             pairs = self._pairs
             uploads = dict(self._uploads)
+            gathered = self._gathered_bytes
         return {
             "total": total,
             "byFamily": by_family,
             "sliced": sliced,
             "evaluatedPairs": pairs,
             "queryUploads": uploads,
+            "planeGatherBytes": gathered,
         }
 
     def snapshot(self) -> dict:
@@ -1529,6 +1549,7 @@ class DeviceFlightRecorder:
             sliced = self._sliced
             pairs = self._pairs
             fetched = self._fetched_bytes
+            gathered = self._gathered_bytes
             donated = self._donated
             uploads = dict(self._uploads)
             by_family = self._pad_waste_by_family_locked()
@@ -1548,6 +1569,7 @@ class DeviceFlightRecorder:
             "sliced": sliced,
             "evaluatedPairs": pairs,
             "fetchedBytes": fetched,
+            "planeGatherBytes": gathered,
             "donatedBuffers": donated,
             "queryUploads": uploads,
             "ring": {"size": keep, "recorded": seq, "entries": ring},
@@ -1673,6 +1695,14 @@ def register_device_metrics(registry) -> None:
         "bytes result fetches materialised on host across all kernel "
         "families (the owner-sharded output diet's structural metric)",
         fn=lambda: flight_recorder.fetched_bytes,
+    )
+    registry.counter(
+        "device.plane_gather_bytes",
+        "bytes plane-family launches gathered from the resident "
+        "genotype planes: blocks of eight rows, the rows their queries "
+        "matched alone, 4 x lane words a row and plane read; over "
+        "device.launches{plane}, what one launch reads",
+        fn=lambda: flight_recorder.plane_gather_bytes,
     )
     registry.counter(
         "device.donated_buffers",

@@ -96,6 +96,11 @@ STAGES = {
     "cache.lookup": "work",
     # engine
     "engine.plan": "work",  # targets, path choice, selected-sample masks
+    # a filtered request's samples resolved against one shard: names ->
+    # positions -> mask words, or the shard's kept answer; inside
+    # engine.plan (the mesh stack's responses: engine.materialize), so
+    # readers of both count the outer one only, as filters.descendants
+    "engine.select": "work",
     "engine.fanout": "wait",  # parked while the scatter pool serves targets
     # one target's wait inside engine.fanout: submitted to the scatter
     # pool -> a pool thread starts it (beside the chain: the request's

@@ -40,7 +40,7 @@ from sbeacon_tpu.ops.plane_kernel import (
     resident_shape,
 )
 from sbeacon_tpu.ops.scatter_kernel import (
-    CHUNK_SMALL,
+    SELECTED_SLOTS,
     ScatterDeviceIndex,
     _selected_batch,
 )
@@ -85,27 +85,43 @@ def _shape(one_chip, *dims):
 
 
 def _plane_sized_outputs(compiled, n_rows: int) -> list[str]:
-    """Instructions of the optimised program, parameters apart, whose
-    output has the plane's row count."""
-    made = re.compile(rf"= \w+\[{n_rows},\d+\]")
+    """Instructions of the optimised program whose output has the
+    plane's row count, parameters and views of one apart (the loop of
+    ``reduce_rows`` is handed the plane as an element of its state)."""
+    made = re.compile(rf"= \w+\[{n_rows},\d+\]\S* (\S+?)\(")
+    views = {"parameter", "get-tuple-element", "bitcast"}
     return [
         line.strip()[:160]
         for line in compiled.as_text().splitlines()
-        if made.search(line) and " parameter(" not in line
+        for m in [made.search(line)]
+        if m and m.group(1) not in views
     ]
 
 
-def _selected(one_chip, n_rows, cap, C, with_counts=False, n_words=KG1_WORDS):
+def _selected(
+    one_chip, n_rows, cap, C, with_counts=False, n_words=KG1_WORDS,
+    nslots=SELECTED_SLOTS,
+):
     n_tiles = n_rows // TILE + 1 + ScatterDeviceIndex.MAX_C
     plane = _shape(one_chip, *resident_shape(n_rows, n_words))
     return _selected_batch.lower(
         _shape(one_chip, n_tiles, 8, TILE),
         plane, plane, plane, plane,
-        _shape(one_chip, CHUNK_SMALL),
-        _shape(one_chip, CHUNK_SMALL, 8),
-        _shape(one_chip, CHUNK_SMALL, n_words),
-        T=TILE, CAP=cap, nslots=CHUNK_SMALL, C=C, exact_only=True,
+        _shape(one_chip, nslots),
+        _shape(one_chip, nslots, 8),
+        _shape(one_chip, nslots, n_words),
+        T=TILE, CAP=cap, nslots=nslots, C=C, exact_only=True,
         R=min(1024, cap), with_counts=with_counts, seg_k=2,
+    ).compile()
+
+
+def _stats(one_chip, n_rows, n_words, R, with_counts=False):
+    plane = _shape(one_chip, *resident_shape(n_rows, n_words))
+    return _plane_stats.lower(
+        plane, plane, plane, plane,
+        _shape(one_chip, R), _shape(one_chip), _shape(one_chip, R),
+        _shape(one_chip, n_words),
+        R=R, with_counts=with_counts, with_or=True,
     ).compile()
 
 
@@ -135,14 +151,9 @@ def test_selected_batch_touches_no_whole_plane(one_chip, cap, C, with_counts):
 @pytest.mark.parametrize("R,with_counts", [(128, False), (8192, True)])
 def test_plane_stats_touches_no_whole_plane(one_chip, R, with_counts):
     n_rows = _rows_that_fit(with_counts)
-    plane = _shape(one_chip, *resident_shape(n_rows, KG1_WORDS))
-    compiled = _plane_stats.lower(
-        plane, plane, plane, plane,
-        _shape(one_chip, R), _shape(one_chip, R),
-        _shape(one_chip, KG1_WORDS),
-        R=R, with_counts=with_counts, with_or=True,
-    ).compile()
-    _holds_no_plane_copy(compiled, n_rows)
+    _holds_no_plane_copy(
+        _stats(one_chip, n_rows, KG1_WORDS, R, with_counts), n_rows
+    )
 
 
 def test_selected_batch_compiles_at_twice_the_rows(one_chip):
@@ -178,30 +189,30 @@ def test_upload_writes_its_chunk_in_place(one_chip):
     assert memory.temp_size_in_bytes <= 2 * rows * wp * 4
 
 
+#: what a launch may hold beside its arguments and outputs, whatever the
+#: planes' width, the slots and R: the match's windows and scans and ONE
+#: block of eight gathered rows (``reduce_rows``). One gather of all 64
+#: slots x R rows held 4.2 / 33.6 MB at ``mdsp`` and 0.47 / 3.76 GB at
+#: ``ukb1``
+WORKSPACE_BYTES = 4 << 20
+
+
 @pytest.mark.parametrize(
-    "cap,C,gathered_bytes",
-    # what one launch gathers: 64 slots x R rows x 128 lanes of words
-    [(128, 1, 64 * 128 * 512), (128, None, 64 * 128 * 512),
-     (2048, None, 64 * 1024 * 512)],
+    "cap,C", [(128, 1), (128, None), (2048, None)],
 )
-def test_packed_selected_batch_at_mdsp_shapes(one_chip, cap, C, gathered_bytes):
+def test_packed_selected_batch_at_mdsp_shapes(one_chip, cap, C):
     """``mdsp``: 2,999,000 rows of 32 words, four to a lane row. The
-    program's temp is of the order of the rows it gathers, never of
-    the plane (384 MB)."""
+    program's temp is a block of the rows it gathers, never the rows of
+    a whole launch (33.6 MB at R = 1024) nor the plane (384 MB)."""
     assert resident_shape(MDSP_ROWS, MDSP_WORDS) == (749_750, 128)
     compiled = _selected(one_chip, MDSP_ROWS, cap, C, n_words=MDSP_WORDS)
     _holds_no_plane_copy(compiled, MDSP_ROWS, MDSP_WORDS)
-    assert compiled.memory_analysis().temp_size_in_bytes <= 1.25 * gathered_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes <= WORKSPACE_BYTES
 
 
 def test_packed_plane_stats_and_upload_at_mdsp_shapes(one_chip):
     plane = _shape(one_chip, *resident_shape(MDSP_ROWS, MDSP_WORDS))
-    compiled = _plane_stats.lower(
-        plane, plane, plane, plane,
-        _shape(one_chip, 1024), _shape(one_chip, 1024),
-        _shape(one_chip, MDSP_WORDS),
-        R=1024, with_counts=False, with_or=True,
-    ).compile()
+    compiled = _stats(one_chip, MDSP_ROWS, MDSP_WORDS, 1024)
     _holds_no_plane_copy(compiled, MDSP_ROWS, MDSP_WORDS)
     # a 256 MiB chunk of 4m host rows crosses as the [m, 128] it fills
     lane_rows = 256 * 1024 * 1024 // 512
@@ -212,6 +223,43 @@ def test_packed_plane_stats_and_upload_at_mdsp_shapes(one_chip):
     # the device holds whole (8, 128) tiles: 749,752 lane rows
     assert 0 <= memory.alias_size_in_bytes - 749_750 * 512 < 8 * 512
     assert memory.temp_size_in_bytes <= 2 * lane_rows * 512
+
+
+UKB1_ROWS = 160_000  # benchmark/configs/ukb1.json
+UKB1_WORDS = 14_213  # 454,787 samples: a row is 112 lane rows, 57,344 B
+
+
+@pytest.mark.parametrize(
+    "cap,C", [(128, 1), (128, None), (512, None), (2048, None)],
+)
+def test_selected_batch_at_ukb1_shapes(one_chip, cap, C):
+    """``ukb1``: a biobank-width plane, ``int32[160000, 14336]``, 9.175
+    GB resident, every window-cap tier (R = 128, 128, 512, 1024) of the
+    one-slot program the engine launches. The workspace is a block of eight rows (8 x 57,344 B)
+    beside the match's own, so ``warm_app`` can execute every tier
+    beside the resident plane: gathered whole, R = 1024 x 64 slots was
+    3.76 GB of temp before the AND and the OR. The gather the compiler
+    kept reads whole rows, eight at a step."""
+    assert resident_shape(UKB1_ROWS, UKB1_WORDS) == (UKB1_ROWS, 14_336)
+    compiled = _selected(one_chip, UKB1_ROWS, cap, C, n_words=UKB1_WORDS)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= UKB1_ROWS * 14_336 * 4
+    assert memory.temp_size_in_bytes <= WORKSPACE_BYTES
+    # the slot's outputs: its rows and counts, its carrier words
+    assert memory.output_size_in_bytes <= 3 * 1024 * 4 + 14_336 * 4 + 4096
+    assert _plane_sized_outputs(compiled, UKB1_ROWS) == []
+    assert re.search(
+        r"= s32\[8,14336\]\S* gather\(.*slice_sizes=\{1,14336\}",
+        compiled.as_text(),
+    )
+
+
+@pytest.mark.parametrize("R", [128, 1024, 8192])
+def test_plane_stats_at_ukb1_shapes(one_chip, R):
+    """``plane_row_stats``' program reads through the same blocks:
+    8,192 rows of 57,344 B gathered at once were 470 MB."""
+    memory = _stats(one_chip, UKB1_ROWS, UKB1_WORDS, R).memory_analysis()
+    assert memory.temp_size_in_bytes <= WORKSPACE_BYTES
 
 
 MDS_ROWS_PADDED = 63_971_328  # benchmark/configs/mds.json: 32 x 1,999,000

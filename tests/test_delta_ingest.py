@@ -912,7 +912,9 @@ def test_publish_burst_on_one_key_leaves_other_keys_l0_untouched():
         assert pre_a <= got_a
         assert _variants(eng.search(_bracket(chrom="1",
                                              datasets=["dsB"]))) == pre_b
-        assert tel.flight_recorder.mid_request_compiles() - c0 == 0
+        assert tel.flight_recorder.mid_request_compiles() - c0 == 0, (
+            tel.flight_recorder.last_mid_request_compile()
+        )
     finally:
         eng.close()
 

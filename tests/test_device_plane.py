@@ -196,6 +196,7 @@ GOLDEN_DEVICE_KEYS = {
     "fetchedBytes",
     "donatedBuffers",
     "queryUploads",
+    "planeGatherBytes",
     "fallbacks",
     "ring",
     "padWaste",

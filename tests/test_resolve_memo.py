@@ -121,7 +121,7 @@ def test_a_hit_after_a_miss_is_the_uncached_answer(stores):
         )
     datasets, samples = resolve_datasets(store, onto, "GRCh38", FEMALE)
     assert [d["id"] for d in datasets] == ["ds1", "ds2"]
-    assert samples == {"ds1": ["S1"], "ds2": ["S3"]}
+    assert samples == {"ds1": ("S1",), "ds2": ("S3",)}
     assert resolve_datasets(store, onto, "GRCh38", TREE)[1] == samples
     # a filter list that selects nothing is an answer too, and is kept
     nobody = [{"id": "HP:9", "scope": "individuals"}]
@@ -195,9 +195,9 @@ def test_a_write_invalidates(stores, write):
             }
     assert _moved(store, before)["invalidations"] == 1
     if write == "upsert_analyses":
-        assert resolve_datasets(store, onto, "GRCh38", FEMALE)[1]["ds1"] == [
-            "S1-renamed"
-        ]
+        assert resolve_datasets(store, onto, "GRCh38", FEMALE)[1]["ds1"] == (
+            "S1-renamed",
+        )
     if write == "delete":
         assert [d["id"] for d in resolve_datasets(store, onto, "GRCh38", [])[0]
                 ] == ["ds1"]
@@ -231,11 +231,11 @@ def test_a_write_through_a_second_store_on_the_file_is_seen(stores):
     before = store.resolve_memo.stats()
     other_store.upsert("analyses", [_analysis(3, "ds2", "S3-elsewhere")])
     assert resolve_datasets(store, onto, "GRCh38", FEMALE)[1] == {
-        "ds1": ["S1"], "ds2": ["S3-elsewhere"],
+        "ds1": ("S1",), "ds2": ("S3-elsewhere",),
     }
     assert _moved(store, before)["invalidations"] == 1
     # ... and one to the ontology's file
-    assert resolve_datasets(store, onto, "GRCh38", TREE)[1]["ds1"] == ["S1"]
+    assert resolve_datasets(store, onto, "GRCh38", TREE)[1]["ds1"] == ("S1",)
     before = store.resolve_memo.stats()
     other_onto.register_edges([("NCIT:C20197", "HP:1")])  # the males, too
     samples = resolve_datasets(store, onto, "GRCh38", TREE)[1]
@@ -306,7 +306,7 @@ def test_read_your_writes_under_load(stores, writer):
             high = started
             name = samples["ds1"][0]
             k = int(name[1:]) if name.startswith("W") else 0
-            if not low <= k <= high or samples["ds2"] != ["S3"]:
+            if not low <= k <= high or samples["ds2"] != ("S3",):
                 failures.append(f"{samples} between states {low} and {high}")
 
     threads = [threading.Thread(target=resolver) for _ in range(8)]
@@ -330,17 +330,17 @@ def test_read_your_writes_under_load(stores, writer):
         sys.setswitchinterval(interval)
     assert not any(t.is_alive() for t in threads)
     assert not failures, failures[:3]
-    assert resolve_datasets(store, onto, "GRCh38", FEMALE)[1]["ds1"] == [
-        f"W{states}"
-    ]
+    assert resolve_datasets(store, onto, "GRCh38", FEMALE)[1]["ds1"] == (
+        f"W{states}",
+    )
     stats = store.resolve_memo.stats()
     assert stats["invalidations"] >= states // 10 and stats["hits"] > 0
 
 
 def test_a_caller_cannot_corrupt_a_later_hit(stores):
     """Outer containers are the caller's own; the documents are shared
-    with later hits and refuse every change (copy first); sample lists
-    are fresh."""
+    with later hits and refuse every change (copy first); the sample
+    names are the kept tuples."""
     store, onto, _ = stores
     want = _uncached(store, onto, "GRCh38", FEMALE)
     changes = [
@@ -360,8 +360,10 @@ def test_a_caller_cannot_corrupt_a_later_hit(stores):
         assert json.loads(json.dumps(datasets[1])) == want[0][1]
         datasets.pop()
         datasets.reverse()
-        samples["ds1"].append("S-extra")
-        samples["ds2"].clear()
+        # a selection is the kept tuple itself (what the engine resolves
+        # from it stays with it): the names cannot be changed
+        assert isinstance(samples["ds1"], memo_mod.KeptSamples)
+        assert isinstance(samples["ds1"], tuple)
         del samples["ds2"]
         samples["ds9"] = ["S9"]
     assert store.resolve_memo.stats()["hits"] >= 4
@@ -409,7 +411,7 @@ def test_another_ontology_than_the_stores_own_is_not_memoised(stores):
         assert resolve_datasets(store, None, "GRCh38", TREE) == ([], {})
     assert _moved(store, before) == {"hits": 0, "misses": 0, "invalidations": 0}
     assert resolve_datasets(store, onto, "GRCh38", TREE)[1] == {
-        "ds1": ["S1"], "ds2": ["S3"],
+        "ds1": ("S1",), "ds2": ("S3",),
     }
     other.close()
 

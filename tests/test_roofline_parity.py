@@ -247,7 +247,8 @@ def test_adaptive_ladder_halves_worst_padding_waste_without_compiles(
                 run(b)
         finally:
             set_active_ladder(None)
-        assert rec.mid_request_compiles() == 0, ladder
+        assert rec.mid_request_compiles() == 0, (
+            ladder, rec.last_mid_request_compile())
         return rec.worst_pad_waste()["waste"]
 
     legacy = leg(_legacy_ladder())

@@ -34,7 +34,7 @@ BENCH = REPO / "benchmark"
 obs = pytest.mark.obs
 
 CELLS = ["kg1.unique", "mds.fanout", "kg1.samples", "kg4.samples",
-         "kg1.samples-desc", "mdsp.samples", "mds4.fanout"]
+         "kg1.samples-desc", "mdsp.samples", "mds4.fanout", "ukb1.samples"]
 CPU_PARTS = ["http_cpu_ms", "filters_resolve_cpu_ms", "runner_cpu_ms",
              "kernel_launch_cpu_ms", "materialize_cpu_ms"]
 NEW_LAYERS = ["host_cpu_ms_per_query", *CPU_PARTS, "python_cpu_ms_per_query",

@@ -548,15 +548,17 @@ def test_gap_names_reads_the_reduced_trace():
 def test_the_layer_files_read_the_program_s_own_names():
     """The coverage metric reads exactly the chain; every stage a layer
     file names is registered; host work is every work stage but the two
-    that wait for the device or the collector, and the one that lies
-    inside another (``filters.descendants`` in ``filters.resolve``)."""
+    that wait for the device or the collector, and the two that lie
+    inside another (``filters.descendants`` in ``filters.resolve``,
+    ``engine.select`` in ``engine.plan``)."""
     layers = {p.stem: json.loads(p.read_text())
               for p in (BENCH / "layers").glob("*.json")}
     assert layers["span_coverage"]["args"]["stages"] == list(CHAIN)
     assert layers["span_coverage"]["args"]["over"] == ["api.total"]
     work = [n for n, k in STAGES.items() if k == "work"]
     assert sorted(layers["host_work_ms_per_query"]["args"]["stages"]) == sorted(
-        set(work) - {"kernel.readback", "gc", "filters.descendants"}
+        set(work)
+        - {"kernel.readback", "gc", "filters.descendants", "engine.select"}
     )
     for name, layer in layers.items():
         if layer["reader"] != "stage_delta":

@@ -176,6 +176,7 @@ GOLDEN_METRICS = [
     "engine.fused_searches",
     "engine.mesh_searches",
     "engine.fanout_targets",
+    "engine.selected_samples",
     "engine.materialize_ms",
     "response_cache.entries",
     "response_cache.max_entries",
@@ -249,6 +250,7 @@ GOLDEN_METRICS = [
     "device.pad_waste",
     "device.mid_request_compiles",
     "device.fetched_bytes",
+    "device.plane_gather_bytes",
     "device.plane_resident_bytes",
     "device.plane_fill",
     "device.donated_buffers",
