@@ -1474,8 +1474,8 @@ def check_plane_programs(run: Run, devices) -> None:
     from jax.sharding import SingleDeviceSharding
 
     from sbeacon_tpu.ops.plane_kernel import _plane_stats, resident_shape
+    from sbeacon_tpu.ops.query_pack import N_QWORDS
     from sbeacon_tpu.ops.scatter_kernel import (
-        SELECTED_SLOTS,
         ScatterDeviceIndex,
         _selected_batch,
         _static_seg_k,
@@ -1494,14 +1494,13 @@ def check_plane_programs(run: Run, devices) -> None:
         return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=on_dev0)
 
     def selected(n_rows, seg_k):
+        # a launch group of one dataset: a slot over its own buffers
         n_tiles = n_rows // tile + 1 + ScatterDeviceIndex.MAX_C
         plane = shape(*resident_shape(n_rows, n_words))
         return _selected_batch.lower(
-            shape(n_tiles, 8, tile), plane, plane, plane, plane,
-            shape(SELECTED_SLOTS), shape(SELECTED_SLOTS, 8),
-            shape(SELECTED_SLOTS, n_words),
-            T=tile, CAP=tile, nslots=SELECTED_SLOTS, C=1,
-            exact_only=True, R=tile, with_counts=False, seg_k=seg_k,
+            (shape(n_tiles, 8, tile),), ((plane,),),
+            shape(1, 1 + N_QWORDS + n_words),
+            T=tile, CAP=tile, C=1, exact_only=True, R=tile, seg_k=seg_k,
         ).compile().memory_analysis()
 
     plane = shape(*planes.gt.shape)

@@ -853,6 +853,20 @@ class VariantEngine:
         # request that reads such a key's planes is served from the
         # host's copy and COUNTED (device.fallbacks{host_planes})
         self._planes_declined: set = set()
+        # launch groups of the plane readers: key -> (group, slot). A
+        # group is the tuple of (key, dindex, planes) of plane datasets
+        # that lie on ONE chip and are alike in tile size, mask width
+        # and count planes, in the order they were first published, at
+        # most SELECTED_SLOTS of them; the targets of one request that
+        # fall into one group ride ONE match+planes launch
+        # (_search_targets). Rebuilt copy-on-write by whoever publishes
+        # or drops a base index (_regroup_planes), its programs
+        # compiled there before the map is published when serving.
+        # _plane_gen counts the changes to what the map is built from
+        # (a base publish, a drop, planes leaving before a re-ingest):
+        # a map built from older inputs is never published.
+        self._plane_groups: dict = {}
+        self._plane_gen = 0
         # last computed HBM-ledger snapshot: /device/status reads it
         # when the publish lock is busy (a rebuild can hold _mesh_lock
         # for seconds, and a status probe must answer anyway)
@@ -1000,6 +1014,7 @@ class VariantEngine:
             if prior is not None and prior[2] is not None:
                 self._indexes[key] = (prior[0], prior[1], None)
                 self._rebuild_serving_state_locked()
+                self._ungroup_locked(key)
             prior = None  # noqa: F841
             # the owner's resident planes (the same key's were just
             # republished plane-less above, so every remaining p counts)
@@ -1114,6 +1129,7 @@ class VariantEngine:
             self._mesh_dirty = True
             self._fused_dirty = True
             self._fused_gen += 1
+            self._plane_gen += 1
             self._indexes[key] = (shard, dindex, planes)
             # epoch monotonicity survives restarts: a reloaded base
             # carries the highest epoch it folded, and new deltas must
@@ -1152,6 +1168,108 @@ class VariantEngine:
         # scoped invalidation frees them now WITHOUT dropping other
         # datasets' warm entries (wholesale clear when the knob is off)
         self._invalidate_cache(key[0], None)
+        # the chip's launch group now holds this key's new buffers:
+        # requests launch it alone (its own programs are warm) until
+        # the group's programs are compiled, here
+        self._regroup_planes()
+
+    def _regroup_planes(self) -> int:
+        """Rebuild the plane readers' launch groups (``_plane_groups``)
+        from what is published, on the publishing thread; returns the
+        programs compiled. A group whose members are the very objects
+        of a standing group IS that group (nothing compiles); a new
+        one of two or more members has every program it can be
+        launched as compiled first when the engine is serving
+        (``_keep_warm``), so no request pays for a publish. A map a
+        racing publish has outdated is dropped and built again. A
+        request holds a group only through identity checks on its
+        members, so a map that trails the published set costs launches
+        (the new key rides alone), never an answer."""
+        from .ops.plane_kernel import chip_of
+        from .ops.scatter_kernel import SELECTED_SLOTS, ScatterDeviceIndex
+
+        n = 0
+        while True:
+            with self._mesh_lock:
+                gen = self._plane_gen
+                first_seen = {k: i for i, k in enumerate(self._placement)}
+                readers = sorted(
+                    (
+                        (k, d, p)
+                        for k, (_s, d, p) in self._indexes.items()
+                        if p is not None and isinstance(d, ScatterDeviceIndex)
+                    ),
+                    key=lambda m: (first_seen.get(m[0], len(first_seen)), m[0]),
+                )
+                standing = {
+                    g for g, _slot in self._plane_groups.values()
+                }
+            alike: dict = {}
+            for m in readers:
+                _k, d, p = m
+                alike.setdefault(
+                    (
+                        chip_of(p.device), chip_of(d.device), d.tile,
+                        p.n_words, p.has_counts,
+                    ),
+                    [],
+                ).append(m)
+            groups = {}
+            for members in alike.values():
+                for i in range(0, len(members), SELECTED_SLOTS):
+                    group = tuple(members[i : i + SELECTED_SLOTS])
+                    # tuples of the same objects compare equal: the
+                    # standing group is kept, with what it compiled
+                    if (
+                        len(group) > 1
+                        and self._keep_warm
+                        and group not in standing
+                    ):
+                        n += self._warm_group(group)
+                    for slot, m in enumerate(group):
+                        groups[m[0]] = (group, slot)
+            with self._mesh_lock:
+                if self._plane_gen == gen:
+                    self._plane_groups = groups
+                    return n
+
+    def _ungroup_locked(self, key) -> None:
+        """Dissolve ``key``'s launch group (held under ``_mesh_lock``):
+        a group names every member's buffers, so while ANY of them is
+        grouped with ``key`` its planes stay on the chip. The others
+        ride alone (an index's own programs are always warm) until
+        whoever took the planes out publishes again and regroups."""
+        self._plane_gen += 1  # a map being built from them is dropped
+        held = self._plane_groups.get(key)
+        if held is not None:
+            self._plane_groups = {
+                k: v
+                for k, v in self._plane_groups.items()
+                if v[0] is not held[0]
+            }
+
+    def _warm_group(self, group) -> int:
+        """Compile every program a launch group of two or more members
+        can be launched as (a group of one is its index's own,
+        ``_warm_index``); returns how many."""
+        from .ops.scatter_kernel import warmup_selected
+
+        eng = self.config.engine
+        try:
+            fault_point("device.bringup", "warmup_group")
+            with device_warmup_phase():
+                return warmup_selected(
+                    [(d, p) for _k, d, p in group],
+                    window_cap=eng.window_cap,
+                    record_cap=eng.record_cap,
+                )
+        except Exception:
+            self._warm_failed(
+                "warmup_group",
+                "launch group warmup failed for %s",
+                [k[0] for k, _d, _p in group],
+            )
+            return 0
 
     def _invalidate_cache(self, dataset_id: str, regions) -> None:
         """Evict cache entries a publish could have answered differently:
@@ -1514,6 +1632,7 @@ class VariantEngine:
             self._mesh_dirty = True
             self._fused_dirty = True
             self._fused_gen += 1
+            self._plane_gen += 1
             self._rebuild_serving_state_locked()
         self._invalidate_cache(dataset_id, None)
         publish_event(
@@ -1522,6 +1641,8 @@ class VariantEngine:
             shards=len(base_keys),
         )
         self._rebuild_l0()
+        if base_keys:
+            self._regroup_planes()
         if base_keys and self._keep_warm:
             # serving: the smaller stacks are rebuilt and warmed here,
             # on the control-plane thread, like after a publish
@@ -2014,6 +2135,10 @@ class VariantEngine:
                 n = sum(pool.map(warm_owner, by_owner.values()))
         else:
             n = warm_owner(snapshot)
+        # ... and the launch groups the plane readers of one chip form
+        for group in {g for g, _slot in self._plane_groups.values()}:
+            if len(group) > 1:
+                n += self._warm_group(group)
         fst = self._fused_ready(wait=True)
         if fst is not None:
             n += self._warm_fused(fst[0])
@@ -3025,8 +3150,9 @@ class VariantEngine:
         if len(targets) > 1 and on_owners:
             # the planes are resident ONCE, on their datasets' owner
             # chips: a request that reads them fans out below, one
-            # match+planes launch per dataset on its owner, in parallel
-            # from the pool; the mesh stack carries no second copy
+            # match+planes launch per owner chip (its datasets a slot
+            # each), the chips in parallel from the pool; the mesh
+            # stack carries no second copy
             plan_stage(
                 "mesh",
                 decision="owner_fanout",
@@ -3094,7 +3220,12 @@ class VariantEngine:
             else None
         )
 
-        # the per-target fan-out as decided on this thread: counts per
+        # the fan-out's unit: the request's plane-reading targets that
+        # share a launch group (one chip's plane datasets) are ONE unit,
+        # one match+planes launch; every other target is a unit alone
+        units = self._launch_units(payload, spec_base, targets)
+
+        # the fan-out as decided on this thread: counts per
         # serving leg, with the overflow buckets (rows already marked
         # None) that will walk the host matcher instead of the leg
         # that pre-matched them
@@ -3115,13 +3246,36 @@ class VariantEngine:
                 if r is None and k not in l0_rows
             ),
             scatter=len(targets),
+            units=len(units),
         )
 
-        def _one_target(target):
+        def _one_unit(unit):
+            """[(key, response)] of one unit, on the calling thread."""
+            group, asked = unit
             with request_context(req_ctx):
-                return _one_target_inner(target)
+                if group is None:
+                    return [_one_target_inner(asked[0])]
+                sels = {}
+                for slot, (ds, _vcf, shard, *_rest) in asked.items():
+                    sels[slot] = None
+                    if payload.selected_samples_only:
+                        with stage("engine.plan"):
+                            sels[slot] = self._selection(shard, payload, ds)
+                # fused match+planes program: the whole selected-samples
+                # (or sample-extraction) leaf of every asked member in
+                # ONE kernel dispatch (the reference worker's single
+                # match+extract pass, search_variants.py:233-258). A
+                # member it does not answer (overflow) falls through to
+                # the split path, alone.
+                got = self._fused_selected(
+                    group, asked, spec_base, payload, sels
+                )
+                return [
+                    _one_target_inner(t, (sels[slot], got.get(slot)))
+                    for slot, t in asked.items()
+                ]
 
-        def _pooled_target(target):
+        def _pooled_unit(unit):
             # the request's own thread is parked in ``engine.fanout``
             # for as long as the pool serves it: the pool's stages keep
             # their count and sum and add nothing to the chain's req_ms.
@@ -3133,28 +3287,23 @@ class VariantEngine:
                 (req_ctx,),
             )
             with tracer.serving(0):
-                return _one_target(target)
+                return _one_unit(unit)
 
-        def _one_target_inner(target):
+        def _one_target_inner(target, launched=None):
+            """(key, response) of one target; ``launched`` is what its
+            group's launch left it: (its selection, its rows and plane
+            reductions or None for the split path)."""
             ds, vcf, shard, dindex, planes, native = target
             selected_idx = None
             fused = None
             rows = None
-            if payload.selected_samples_only:
-                with stage("engine.plan"):
-                    selected_idx = self._selection(shard, payload, ds)
-            if planes is not None and self._wants_planes(payload):
-                # fused match+planes program: the whole selected-samples
-                # (or sample-extraction) leaf in ONE kernel dispatch —
-                # the reference worker's single match+extract pass
-                # (search_variants.py:233-258). Falls through to the
-                # split path on overflow/wildcard-ref.
-                got = self._fused_selected(
-                    shard, dindex, planes, spec_base, payload,
-                    selected_idx,
-                )
+            if launched is not None:
+                selected_idx, got = launched
                 if got is not None:
                     rows, fused = got
+            elif payload.selected_samples_only:
+                with stage("engine.plan"):
+                    selected_idx = self._selection(shard, payload, ds)
             if rows is None and (ds, vcf) in l0_rows:
                 # the L0 mini-index launch already matched this tail
                 # target; None marks window/record overflow -> the
@@ -3212,7 +3361,7 @@ class VariantEngine:
                     shard, dindex, spec_base, key=(ds, vcf)
                 )
             with stage("engine.materialize"):
-                return materialize_response(
+                return (ds, vcf), materialize_response(
                     shard,
                     rows,
                     payload,
@@ -3224,38 +3373,46 @@ class VariantEngine:
                     fused=fused,
                 )
 
-        if len(targets) == 1:
-            responses = [_one_target(targets[0])]
+        if len(units) == 1:
+            # one launch group (one chip's cohorts, or one dataset), or
+            # one target: on the request's own thread, its kernel.*
+            # stages in the request's chain, then one engine.materialize
+            # a dataset here too; nothing parks
+            got = _one_unit(units[0])
         elif not l0_rows:
-            # per-dataset scatter (the reference's ThreadPoolExecutor(500)
+            # per-unit scatter (the reference's ThreadPoolExecutor(500)
             # per-dataset dispatch, search_variants.py:77-118): overlaps
-            # the per-shard device round-trips instead of serialising them
+            # the units' device round-trips (one a chip) instead of
+            # serialising them
             with stage("engine.fanout"):
                 t_pool = time.perf_counter()
-                responses = list(self._scatter.map(_pooled_target, targets))
+                got = [
+                    kr
+                    for krs in self._scatter.map(_pooled_unit, units)
+                    for kr in krs
+                ]
             with self._mat_lock:  # unlocked += drops concurrent counts
                 self.fanout_targets += len(targets)
         else:
             # L0-covered tail targets have NO device work left — their
             # rows are already in hand, materialisation is pure host —
             # so they run inline on the request thread while the
-            # scatter pool overlaps the targets that still pay a
+            # scatter pool overlaps the units that still pay a
             # device round-trip (a pool task per tiny tail shard is
             # mostly scheduling jitter on few-core hosts)
-            pooled = [t for t in targets if (t[0], t[1]) not in l0_rows]
+            inline, pooled = [], []
+            for u in units:
+                tail = u[0] is None and (u[1][0][0], u[1][0][1]) in l0_rows
+                (inline if tail else pooled).append(u)
             pooled_iter = (
-                self._scatter.map(_one_target, pooled)
+                self._scatter.map(_one_unit, pooled)
                 if len(pooled) > 1
-                else map(_one_target, pooled)
+                else map(_one_unit, pooled)
             )
-            got = {
-                (t[0], t[1]): _one_target(t)
-                for t in targets
-                if (t[0], t[1]) in l0_rows
-            }
-            for t, r in zip(pooled, pooled_iter):
-                got[(t[0], t[1])] = r
-            responses = [got[(t[0], t[1])] for t in targets]
+            got = [kr for u in inline for kr in _one_unit(u)]
+            got += [kr for krs in pooled_iter for kr in krs]
+        by_target = dict(got)
+        responses = [by_target[(t[0], t[1])] for t in targets]
         if mesh_responses is not None:
             # reassemble mesh-served base responses + scatter-served
             # tail in the original sorted target order
@@ -3280,45 +3437,90 @@ class VariantEngine:
             and payload.requested_granularity in ("record", "aggregated")
         )
 
-    def _fused_selected(
-        self, shard, dindex, planes, spec_base, payload, selected_idx
-    ):
-        """ONE-dispatch match + plane reduction via the fused kernel.
+    def _launch_units(self, payload, spec_base, targets) -> list:
+        """The units a request's targets are served in: ``[(group,
+        {slot: target})]``. The targets whose response reads planes the
+        fused match+planes program can reach (planes resident on their
+        owner chip, a scatter index, a device-exact ref) are gathered
+        by launch group (``_plane_groups``: the plane datasets of one
+        chip), a unit a group; a group only one of whose members is
+        asked is launched as that member alone, and so is a target
+        whose published buffers the map does not hold yet (a publish
+        the regrouping has not caught up with). Every other target is
+        a unit alone, ``group`` None."""
+        from .ops.scatter_kernel import ScatterDeviceIndex
 
-        Returns (rows, (pc_call, pc_tok, or_words)) for
-        materialize_response, or None when this query must take the
-        split path: non-scatter index, wildcard-ref regex semantics,
-        or window/record overflow (the uncapped host matcher then
-        answers, exactly like the match kernel's overflow contract).
+        reads_planes = self._wants_planes(payload) and self._device_ref_ok(
+            payload, spec_base
+        )
+        groups = self._plane_groups
+        units: list = []
+        of_group: dict = {}
+        for t in targets:
+            ds, vcf, _shard, dindex, planes, _native = t
+            if not (
+                reads_planes
+                and planes is not None
+                and isinstance(dindex, ScatterDeviceIndex)
+            ):
+                units.append((None, {0: t}))
+                continue
+            group, slot = groups.get((ds, vcf), (None, 0))
+            if (
+                group is None
+                or group[slot][1] is not dindex
+                or group[slot][2] is not planes
+            ):
+                units.append(((((ds, vcf), dindex, planes),), {0: t}))
+                continue
+            asked = of_group.get(id(group))
+            if asked is None:
+                asked = of_group[id(group)] = {}
+                units.append((group, asked))
+            asked[slot] = t
+        return [
+            ((group[next(iter(asked))],), {0: next(iter(asked.values()))})
+            if group is not None and len(asked) == 1 and len(group) > 1
+            else (group, asked)
+            for group, asked in units
+        ]
+
+    def _fused_selected(self, group, asked, spec_base, payload, sels):
+        """ONE-dispatch match + plane reduction for the request's
+        targets in one launch group via the fused kernel.
+
+        ``asked``: {slot: target} of the group's members the request
+        asks; ``sels``: {slot: its resolved selection or None}. Returns
+        {slot: (rows, (pc_call, pc_tok, or_words))} for
+        materialize_response. A slot left out must take the split path:
+        window/record overflow (the uncapped host matcher then answers,
+        exactly like the match kernel's overflow contract), or every
+        slot when the launch failed (counted).
         """
-        from .ops.scatter_kernel import (
-            ScatterDeviceIndex,
-            run_selected_scattered,
-        )
+        from .ops.scatter_kernel import run_selected_group
 
-        if not isinstance(dindex, ScatterDeviceIndex):
-            return None
-        if not self._device_ref_ok(payload, spec_base):
-            return None
         eng = self.config.engine
+        first = group[0][2]
         # the selection's own mask words (resolved once a request and
-        # dataset), or every sample
-        mask = (
-            selected_idx.mask
-            if selected_idx is not None
-            else np.full(planes.n_words, 0xFFFFFFFF, np.uint32)
-        )
+        # dataset), or every sample; a member nobody asks is a padding
+        # slot
+        masks = [None] * len(group)
+        for slot in asked:
+            masks[slot] = (
+                sels[slot].mask
+                if sels[slot] is not None
+                else np.full(first.n_words, 0xFFFFFFFF, np.uint32)
+            )
         try:
             fault_point("device.bringup", "fused_selected")
-            res = run_selected_scattered(
-                dindex,
-                planes,
-                [spec_base],
-                mask[None, :],
+            res = run_selected_group(
+                [(d, p) for _k, d, p in group],
+                spec_base,
+                masks,
                 window_cap=eng.window_cap,
                 record_cap=eng.record_cap,
                 with_counts=(
-                    selected_idx is not None and planes.has_counts
+                    payload.selected_samples_only and first.has_counts
                 ),
             )
         except Exception:
@@ -3326,17 +3528,21 @@ class VariantEngine:
                 "fused_selected",
                 "fused selected kernel failed; split path serves",
             )
-            return None
-        if res.overflow[0]:
-            return None
-        keep = res.rows[0] >= 0
-        rows = res.rows[0][keep].astype(np.int64)
-        fused = (
-            res.pc_call[0][keep],
-            res.pc_tok[0][keep],
-            res.or_words[0],
-        )
-        return rows, fused
+            return {}
+        out = {}
+        for slot in asked:
+            if res.overflow[slot]:
+                continue
+            keep = res.rows[slot] >= 0
+            out[slot] = (
+                res.rows[slot][keep].astype(np.int64),
+                (
+                    res.pc_call[slot][keep],
+                    res.pc_tok[slot][keep],
+                    res.or_words[slot],
+                ),
+            )
+        return out
 
     # -- mesh serving path --------------------------------------------------
 

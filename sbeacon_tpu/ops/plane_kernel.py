@@ -18,7 +18,9 @@ masked popcounts and the sample-hit OR-reduction in one jitted program:
   chip by samples x rows). A narrower row is
   zero-padded to p words, p the least power of two >= W, and k = 128 // p
   rows share one lane row: ``[ceil(n / k), 128]``, 128 B/row/plane at
-  1000 samples (``pack_factor``, ``resident_shape``). The count planes
+  1000 samples (``pack_factor``, ``resident_shape``; n counts whole
+  steps of 128 rows, so cohorts of like size share programs). The
+  count planes
   (gt2/tok1/tok2) are uploaded only when the
   shard has genotype-derived rows at all — INFO-sourced corpora (the
   common cohort-VCF case, and the bench corpus) only ever touch ``gt``
@@ -82,13 +84,25 @@ def pack_factor(n_words: int) -> int:
     return max(1, LANES // padded_words(n_words))
 
 
+#: rows a resident plane grows by: the rows of one tile of the match
+#: index (``ScatterDeviceIndex.tile``), whose tile count a row-gathering
+#: program is compiled for beside the plane's shape
+ROW_STEP = 128
+
+
 def resident_shape(n_rows: int, n_words: int) -> tuple[int, int]:
     """Shape of the resident array of an ``[n_rows, n_words]`` plane:
-    ``[ceil(n_rows / k), k * padded_words]``; row r lies in lane row
-    ``r // k`` at words ``(r % k) * padded_words`` onward (the rows past
-    the last in its lane row are zeros nobody reads)."""
+    ``[ceil(n / k), k * padded_words]``, n = ``n_rows`` rounded up to
+    whole ``ROW_STEP`` rows; row r lies in lane row ``r // k`` at words
+    ``(r % k) * padded_words`` onward (the rows past ``n_rows`` are
+    zeros nobody reads). A program is compiled for its operands'
+    shapes, and a launch group's for every member's: in whole steps,
+    cohorts of like size (the same cohort a few rows later, a
+    benchmark's next seed) share their programs instead of compiling
+    their own, at under 128 rows of padding a plane."""
     k = pack_factor(n_words)
-    return -(-n_rows // k), k * padded_words(n_words)
+    n = -(-n_rows // ROW_STEP) * ROW_STEP
+    return -(-n // k), k * padded_words(n_words)
 
 
 def masked_rows(plane, rows, mask):
@@ -306,9 +320,10 @@ def sample_mask_words(
 
 class PlaneDeviceIndex:
     """Device-resident genotype planes of one shard, each in its
-    resident layout (``resident_shape``): ``[n_rows, 128 j]`` for a row
-    of over 64 words, j the lane rows it takes (1 at 2504 samples, 112
-    at 454,787), ``[ceil(n_rows / k), 128]`` for a narrower one.
+    resident layout (``resident_shape``): ``[n, 128 j]`` for a row of
+    over 64 words, j the lane rows it takes (1 at 2504 samples, 112 at
+    454,787), ``[n / k, 128]`` for a narrower one, n the rows rounded
+    up to whole steps of 128.
 
     ``gt`` is always uploaded (sample-hit extraction needs it); the
     three count planes ride along only when the shard contains
@@ -500,6 +515,8 @@ def plane_row_stats(
         tier=tier,
         specs_real=R,
         specs_padded=tier,
+        # its specs are rows: one dataset's, for one request
+        targets=1,
         launch_ms=(time.perf_counter() - t0) * 1e3,
         chip=chip_of(pindex.device),
     )

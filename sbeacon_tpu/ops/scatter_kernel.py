@@ -57,6 +57,8 @@ unpacks row ids with one vectorised ``np.unpackbits`` per batch.
 
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 
 import jax
@@ -91,9 +93,12 @@ from .kernel import (
     encode_queries,
 )
 from .query_pack import (
+    N_QWORDS,
     PM_CNV,
     PM_DUPT,
     PM_INS,
+    Q_HI,
+    Q_LO,
     _rows_from_masks,
     _window_bounds,
     pack_q8,
@@ -126,11 +131,15 @@ _REF_LEN_CLAMP = 0x1FFF
 CHUNK = 2048
 CHUNK_SMALL = 64
 
-#: slots of the fused match+planes program: its mask goes up and its
-#: carrier words come back a slot (57 kB each at 454,787 samples), and
-#: every launch the engine makes serves ONE query (``_fused_selected``),
-#: so the program has one slot and a batch of queries is a launch each
-SELECTED_SLOTS = 1
+#: the most slots one launch of the fused match+planes program has: the
+#: datasets of one request that lie on one chip ride one launch
+#: (``run_selected_group``), a slot each. The program unrolls its tile
+#: and plane gathers over its slots, so its compile time grows with
+#: them, while a launch's fixed cost on the host (an upload, a jitted
+#: call, a read-back: 44 ms of wall in ``mdsp.samples``, PERF.md 6,
+#: PR 44) is already split sixteen ways; a chip that owns more plane
+#: datasets than this serves a request over them in several launches
+SELECTED_SLOTS = 16
 
 # longest record (in SAME_PREV-chained rows minus one) the K-shift
 # first-match form handles; longer records take the segmented-scan form
@@ -165,16 +174,22 @@ def _match_program_key(sindex, nslots, nc, cap, C, exact_only) -> tuple:
 
 
 def _selected_program_key(
-    sindex, pindex, nslots, cap, R, C, exact_only, with_counts
+    members, cap, R, C, exact_only, with_counts, seg_k
 ) -> tuple:
-    """Compile-tracker identity of one fused match+planes program (the
-    plane shapes are argument shapes too)."""
+    """Compile-tracker identity of one fused match+planes program: the
+    group's size and, slot by slot, its members' argument shapes (a
+    dataset's tile count and its planes' resident shape)."""
+    first, pfirst = members[0]
     return (
-        "scatter_selected", int(sindex.tiles.shape[0]),
-        tuple(int(d) for d in pindex.gt.shape), pindex.n_words,
-        pack_factor(pindex.n_words), nslots,
-        cap, R, C, exact_only, with_counts, _static_seg_k(sindex),
-        sindex.tile, chip_of(sindex.device),
+        "scatter_selected", len(members),
+        tuple(
+            (int(sindex.tiles.shape[0]),)
+            + tuple(int(d) for d in pindex.gt.shape)
+            for sindex, pindex in members
+        ),
+        pfirst.n_words, pack_factor(pfirst.n_words),
+        cap, R, C, exact_only, with_counts, seg_k,
+        first.tile, chip_of(pfirst.device),
     )
 
 
@@ -236,9 +251,6 @@ def pack_tiles(c, n: int, n_tiles: int, tile: int):
     SAME_PREV bit. Packed in row blocks on half the host's cores (a
     serving engine republishes beside its request threads); the result
     does not depend on how many."""
-    import os
-    from concurrent.futures import ThreadPoolExecutor
-
     tiles = np.empty((n_tiles, N_PACKED, tile), dtype=np.int32)
     same = np.zeros(n, dtype=np.int8)
     step = PACK_BLOCK_ROWS // tile * tile
@@ -304,10 +316,12 @@ class ScatterDeviceIndex:
 
 
 def _scatter_core(
-    tiles, tile_ids, qarr, *, T, CAP, C=None, exact_only=False, seg_k=None
+    gat, tile_ids, qarr, *, T, CAP, exact_only=False, seg_k=None
 ):
     """Traced core shared by the match-only and fused-selected batch
-    programs: C-tile gather + the vectorised predicate stack.
+    programs: the vectorised predicate stack over each slot's gathered
+    tiles ``gat`` (``[B, C, 8, T]``: tiles ``tile_ids[b]`` onward, of
+    whichever index slot b reads).
 
     Returns ``(agg, masks, m_i, win, gidx, lo)`` — agg/masks are the
     public results; m_i/win/gidx/lo let the fused program reduce the
@@ -325,12 +339,7 @@ def _scatter_core(
         Q_REF_HASH,
     )
 
-    if C is None:
-        C = CAP // T + 1
-    span = C * T
-    gat = tiles[
-        tile_ids[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-    ]  # [B, C, 8, T]
+    span = gat.shape[1] * T
     win = jnp.transpose(gat, (0, 2, 1, 3)).reshape(-1, N_PACKED, span)
     row = lambda r: win[:, r, :]  # [B, C*T]
     q = lambda f: qarr[:, f : f + 1]  # [B, 1]
@@ -527,8 +536,13 @@ def _scatter_batch(
     matters). Returns (agg [nslots, 8] int32,
     masks [nslots, C*T/16] int32).
     """
+    if C is None:
+        C = CAP // T + 1
+    gat = tiles[
+        tile_ids[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+    ]  # [B, C, 8, T]
     agg, masks, _m, _w, _g, _lo = _scatter_core(
-        tiles, tile_ids, qarr, T=T, CAP=CAP, C=C, exact_only=exact_only,
+        gat, tile_ids, qarr, T=T, CAP=CAP, exact_only=exact_only,
         seg_k=seg_k,
     )
     return agg, masks
@@ -536,77 +550,98 @@ def _scatter_batch(
 
 @partial(
     jax.jit,
-    static_argnames=(
-        "T", "CAP", "nslots", "C", "exact_only", "R", "with_counts", "seg_k",
-    ),
+    static_argnames=("T", "CAP", "C", "exact_only", "R", "seg_k"),
 )
 def _selected_batch(
     tiles,
-    gt,
-    gt2,
-    tok1,
-    tok2,
-    tile_ids,
-    qarr,
-    mask,
+    planes,
+    packed,
     *,
     T,
     CAP,
-    nslots,
     C=None,
     exact_only=False,
     R=64,
-    with_counts=False,
     seg_k=None,
 ):
-    """Fused match + genotype-plane reduction: ONE dispatch per batch.
+    """Fused match + genotype-plane reduction for a GROUP of datasets:
+    ONE dispatch, one operand up and one result back, whatever the
+    group's size.
+
+    A slot is a (dataset, query, mask) and slot d reads dataset d's own
+    resident buffers where ``engine._place`` / ``_build_planes``
+    committed them: ``tiles`` is a tuple of D tile arrays (each
+    ``[n_tiles_d, 8, T]``, their lengths free) and ``planes`` a tuple
+    of D tuples of resident planes, ``(gt,)`` or, for restricted
+    counting, ``(gt, gt2, tok1, tok2)``; nothing is copied, stacked or
+    re-laid. ``packed`` int32 ``[D, 1 + 8 + W]`` holds a slot's tile
+    id, its ``q8`` row and its mask words. Only what must touch a
+    dataset's own buffers is unrolled over the datasets: the window's
+    C tiles (a dynamic slice of ``tiles[d]``) and ``reduce_rows``'
+    block gathers; the predicate stack, the top-R sort and the segment
+    scans run once, batched over the D slots.
 
     Extends ``_scatter_batch`` (same predicate core, same gathered
-    window) with the selected-samples leaf the engine previously paid a
-    second kernel dispatch for (VERDICT r4 next #2 — the reference's
-    worker does match + per-sample extraction in one pass,
+    window) with the selected-samples leaf (the reference's worker does
+    match + per-sample extraction in one pass,
     performQuery/search_variants.py:233-258):
 
     - the top-R matched lanes become global row ids in ascending row
-      order (stable argsort of the match mask — the in-device
-      ``_rows_from_masks``),
-    - their gt/count planes are gathered, masked per-query
-      (``mask`` int32 [nslots, W]) and popcounted. The planes are
-      resident in whole 128-lane rows, a wide row zero-padded to them
-      (one lane row at 2504 samples, 112 at 454,787) and k narrow rows
-      sharing one (``PlaneDeviceIndex``), so the gather reads them as
-      they lie, and it reads the matched rows alone, eight at a step
-      (``reduce_rows``: the workspace is one block of rows whatever the
-      slots, R and the width; a slot that matched nothing gathers
-      nothing).
-      ``masked_rows`` gathers row r's lane rows and keeps its part,
-      ``fold_parts`` brings ``or_words`` back to W, both on the device,
+      order (a bisection of the match mask's running count, the
+      in-device ``_rows_from_masks``),
+    - their planes are masked by the slot's own mask and popcounted.
+      The planes are resident in whole 128-lane rows, a wide row
+      zero-padded to them (one lane row at 2504 samples, 112 at
+      454,787) and k narrow rows sharing one (``PlaneDeviceIndex``), so
+      the gather reads them as they lie, and it reads the matched rows
+      alone, eight at a step (``reduce_rows``: the workspace is one
+      block of rows whatever D, R and the width; a slot that matched
+      nothing, a padding slot among them, gathers nothing),
     - the sample-hit OR runs over the exact ``grp >= k0`` row subset
       via the same segmented scans as ``parallel.mesh._plane_reduce``
       (k0 = first record with positive cumulative rc; ploidy>2
-      overflow extras can never flip rc positivity — a saturated
-      2-bit plane cell popcounts >= 2 — so the device subset equals
-      the host's even though the extras themselves stay host-added).
+      overflow extras can never flip rc positivity, a saturated 2-bit
+      plane cell popcounts >= 2, so the device subset equals the
+      host's even though the extras themselves stay host-added).
 
-    Returns (agg [nslots,8], rows [nslots,R] global row ids (-1 pad),
-    pc_call [nslots,R], pc_tok [nslots,R] (0 at a pad lane),
-    or_words [nslots,W]).
-    ``with_counts=False`` (INFO-sourced corpora) skips the three
-    count-plane gathers entirely.
+    Returns int32 ``[D, 8 + 3R + W]``: ``agg`` [8], ``rows`` [R] global
+    row ids (-1 pad), ``pc_call`` [R], ``pc_tok`` [R] (0 at a pad lane)
+    and ``or_words`` [W] side by side (``split_selected``). Without
+    count planes ``pc_call`` is the gt popcount and ``pc_tok`` zero.
     """
+    n_slots = len(tiles)
+    with_counts = len(planes[0]) > 1
+    if C is None:
+        C = CAP // T + 1
+    tile_ids = packed[:, 0]
+    qarr = packed[:, 1 : 1 + N_QWORDS]
+    mask = packed[:, 1 + N_QWORDS :]
+    gat = jnp.stack(
+        [
+            jax.lax.dynamic_slice_in_dim(tiles[d], tile_ids[d], C, axis=0)
+            for d in range(n_slots)
+        ]
+    )  # [D, C, 8, T]
     agg, _masks, m_i, win, gidx, _lo = _scatter_core(
-        tiles, tile_ids, qarr, T=T, CAP=CAP, C=C, exact_only=exact_only,
+        gat, tile_ids, qarr, T=T, CAP=CAP, exact_only=exact_only,
         seg_k=seg_k,
     )
-    # top-R matched lanes, ascending (stable sort keeps lane order)
-    order = jnp.argsort(1 - m_i, axis=1, stable=True)[:, :R]
-    matched = jnp.take_along_axis(m_i, order, axis=1) != 0  # [B, R]
+    # top-R matched lanes, ascending: the j-th is the first lane at
+    # which the running count of matches reaches j + 1, found by
+    # bisection (a sort of a 2,176-lane window took the chip's compiler
+    # 7-8 s a program and was the program's longest device operation)
+    seen = jnp.cumsum(m_i, axis=1)
+    nth = jax.lax.broadcasted_iota(jnp.int32, (n_slots, R), 1)
+    matched = nth < seen[:, -1:]  # [D, R]
+    order = jnp.minimum(
+        jax.vmap(partial(jnp.searchsorted, side="left"))(seen, nth + 1),
+        m_i.shape[1] - 1,
+    ).astype(jnp.int32)
     rows = jnp.where(
         matched, jnp.take_along_axis(gidx, order, axis=1), jnp.int32(-1)
     )
     take = lambda r: jnp.take_along_axis(win[:, r, :], order, axis=1)
     ac_r = take(P_AC)
-    an_r = take(P_AN)
     flags_r = take(P_FLAGS)
     # record segments within the gathered window: cumsum of the
     # SAME_PREV chain breaks. Matched lanes of one record can never
@@ -624,15 +659,37 @@ def _selected_batch(
     # the width rounded up to whole blocks and cut again below
     k = pack_factor(n_words)
     pad = (-R) % ROW_BLOCK
-    safe = jnp.pad(
-        jnp.clip(rows, 0, gt.shape[0] * k - 1), ((0, 0), (0, pad))
-    )
     n_rows = jnp.sum(matched, axis=1, dtype=jnp.int32)
     live = lambda x: jnp.where(matched, x[:, :R], jnp.int32(0))
+
+    def reduce_each(n_planes, or_sel):
+        """``reduce_rows`` a dataset, each over its own planes (the
+        first ``n_planes`` of them) and its own slot: popcounts
+        ``[n_planes, D, R + pad]``, OR words ``[D, lanes]``."""
+        outs = []
+        for d in range(n_slots):
+            safe = jnp.pad(
+                jnp.clip(rows[d : d + 1], 0, planes[d][0].shape[0] * k - 1),
+                ((0, 0), (0, pad)),
+            )
+            outs.append(
+                reduce_rows(
+                    planes[d][:n_planes],
+                    safe,
+                    n_rows[d : d + 1],
+                    None if or_sel is None else or_sel[d : d + 1],
+                    mask[d : d + 1],
+                )
+            )
+        return (
+            jnp.concatenate([pcs for pcs, _acc in outs], axis=1),
+            jnp.concatenate([acc for _pcs, acc in outs], axis=0),
+        )
+
     if with_counts:
         # the OR's row subset follows from the popcounts: one pass for
         # the four planes' counts, a second over gt for the carriers
-        pcs, _ = reduce_rows((gt, gt2, tok1, tok2), safe, n_rows, None, mask)
+        pcs, _ = reduce_each(4, None)
         pc_call = live(pcs[0] + pcs[1])
         pc_tok = live(pcs[2] + pcs[3])
         rc = jnp.where((flags_r & FLAG.AC_INFO) != 0, ac_r, pc_call)
@@ -671,14 +728,12 @@ def _selected_batch(
     )
     bwd_any = jnp.flip((c_f - base_f) > 0, axis=1)
     or_sel = matched & ((base > 0) | fwd_any | bwd_any)
-    pcs, acc = reduce_rows(
-        (gt,), safe, n_rows, jnp.pad(or_sel, ((0, 0), (0, pad))), mask
-    )
+    pcs, acc = reduce_each(1, jnp.pad(or_sel, ((0, 0), (0, pad))))
     if not with_counts:
         pc_call = live(pcs[0])
         pc_tok = jnp.zeros_like(pc_call)
-    or_words = fold_parts(acc, n_words)  # [B, W]
-    return agg, rows, pc_call, pc_tok, or_words
+    or_words = fold_parts(acc, n_words)  # [D, W]
+    return jnp.concatenate([agg, rows, pc_call, pc_tok, or_words], axis=1)
 
 
 class SelectedResults:
@@ -703,149 +758,169 @@ class SelectedResults:
             setattr(self, k, kw[k])
 
 
-def run_selected_scattered(
-    sindex: ScatterDeviceIndex,
-    pindex,
-    queries,
-    mask_words: np.ndarray,
+def _group_seg_k(members) -> int | None:
+    """The K-shift static of a group's one batched predicate stack: the
+    longest chain among its members (a K past a shard's own longest
+    chain finds nothing more there: its chains are broken sooner), or
+    None, the scan form, as soon as one member needs it."""
+    ks = [_static_seg_k(sindex) for sindex, _pindex in members]
+    return None if None in ks else max(ks)
+
+
+def _group_operands(members, with_counts: bool):
+    """The resident buffers a group's program reads, as they lie: a
+    tuple of tile arrays and a tuple of plane tuples, in slot order."""
+    return (
+        tuple(sindex.tiles for sindex, _pindex in members),
+        tuple(
+            (p.gt, p.gt2, p.tok1, p.tok2) if with_counts else (p.gt,)
+            for _sindex, p in members
+        ),
+    )
+
+
+def run_selected_group(
+    members,
+    query,
+    masks,
     *,
     window_cap: int | None = None,
     record_cap: int = 1024,
     with_counts: bool | None = None,
 ) -> SelectedResults:
-    """Selected-samples query batch in ONE kernel dispatch per tier.
+    """ONE query against a group of datasets that lie on one chip, in
+    ONE launch: one upload, one program, one read-back, whatever the
+    group's size (``_selected_batch``).
 
-    ``pindex``: ops.plane_kernel.PlaneDeviceIndex of the SAME shard as
-    ``sindex``. ``mask_words``: uint32 [B, W] per-query selected-sample
-    masks (all-ones rows extract the full cohort). A query whose
-    matched-row count exceeds min(record_cap, its tier cap) reports
-    ``overflow`` (its plane outputs would be truncated) and must take
-    the host path, exactly like the match kernel's window overflow.
+    ``members``: ``[(ScatterDeviceIndex, PlaneDeviceIndex), ...]`` in
+    slot order, all on one device and alike in tile size, mask width
+    and count planes (``engine`` groups them so). ``query``: a
+    ``QuerySpec`` or its one-row encoding. ``masks``: for each slot its
+    selected-sample mask words (uint32 ``[W]``), or None for a padding
+    slot: a member the request does not ask, which rides the launch
+    under a query that matches nothing (``lo = hi = 0``: no lane is
+    valid, ``reduce_rows`` reads nothing) and comes back all zero.
+
+    The group launches once, at the tier of its WIDEST window (a
+    narrower window under a larger ``CAP`` reads the same rows: lanes
+    past ``hi`` are invalid); the single-tile tier is taken only when
+    every asked member's window lies in one tile. A slot whose
+    matched-row count exceeds min(record_cap, the launch's cap), or
+    whose window the device cannot answer, reports ``overflow`` (its
+    plane outputs would be truncated) and must take the host path,
+    exactly like the match kernel's window overflow; the other slots
+    keep their rows.
+
+    Returns arrays a slot (``rows`` / ``pc_*`` padded to the top
+    tier's R, as ``run_selected_scattered`` always had them).
     """
-    enc = encode_queries(queries) if isinstance(queries, list) else queries
-    T = sindex.tile
+    enc = encode_queries([query]) if not isinstance(query, dict) else query
+    first, pfirst = members[0]
+    T = first.tile
     window_cap = window_cap or T
-    b = len(enc["chrom"])
     if with_counts is None:
-        with_counts = bool(pindex.has_counts)
-    W = pindex.n_words
-    mask_words = np.ascontiguousarray(mask_words, dtype=np.uint32)
-    if mask_words.shape != (b, W):
-        raise ValueError(f"mask_words must be [{b}, {W}]")
-    if b == 0:
-        z = np.zeros(0, np.int32)
-        return SelectedResults(
-            exists=np.zeros(0, bool),
-            call_count=z,
-            n_variants=z,
-            all_alleles_count=z,
-            n_matched=z,
-            overflow=np.zeros(0, bool),
-            rows=np.zeros((0, 0), np.int32),
-            pc_call=np.zeros((0, 0), np.int32),
-            pc_tok=np.zeros((0, 0), np.int32),
-            or_words=np.zeros((0, W), np.uint32),
-        )
+        with_counts = bool(pfirst.has_counts)
+    W = pfirst.n_words
+    D = len(members)
+    asked = np.array([m is not None for m in masks], dtype=bool)
     with stage("kernel.encode"):
-        lo, hi = _window_bounds(sindex, enc)
-        q8, needs_host = pack_q8(enc, lo, hi)
-        tile_ids_all = (lo // T).astype(np.int32)
-        caps = _tier_caps(sindex, window_cap)
+        lo = np.zeros(D, np.int64)
+        hi = np.zeros(D, np.int64)
+        for d in np.flatnonzero(asked):
+            lo[d : d + 1], hi[d : d + 1] = _window_bounds(members[d][0], enc)
+        # the query is one: its row is packed once, and a slot's differs
+        # by its own window alone (a padding slot's stays all zero)
+        q8_one, needs_host = pack_q8(enc, lo[:1], hi[:1])
+        q8 = np.zeros((D, N_QWORDS), np.int32)
+        q8[asked] = q8_one
+        q8[asked, Q_LO] = lo[asked]
+        q8[asked, Q_HI] = hi[asked]
+        tile_ids = (lo // T).astype(np.int32)
+        caps = _tier_caps(first, window_cap)
         width = hi - lo
-        tier_of = np.searchsorted(np.asarray(caps), width, side="left")
-        tier_of = np.minimum(tier_of, len(caps) - 1)
-        single = (np.maximum(hi, lo + 1) - 1) // T <= tile_ids_all
-        tier_of = np.where(single & (tier_of == 0), -1, tier_of)
-
-        R_top = min(record_cap, caps[-1])
-        agg = np.zeros((b, 8), np.int32)
-        rows = np.full((b, R_top), -1, np.int32)
-        pc_call = np.zeros((b, R_top), np.int32)
-        pc_tok = np.zeros((b, R_top), np.int32)
-        or_words = np.zeros((b, W), np.uint32)
-        is_exact = enc["alt_mode"] == MODE_EXACT
-    for ti, cap in [(-1, T)] + list(enumerate(caps)):
-        in_tier = tier_of == ti
+        tier = int(
+            min(
+                np.searchsorted(np.asarray(caps), width.max(), side="left"),
+                len(caps) - 1,
+            )
+        )
+        single = bool(
+            ((np.maximum(hi, lo + 1) - 1) // T <= tile_ids).all()
+        )
+        C = 1 if single and tier == 0 else None
+        cap = caps[tier]
         R = min(record_cap, cap)
-        for exact in (True, False):
-            sel = np.flatnonzero(in_tier & (is_exact == exact))
-            if not len(sel):
-                continue
-            # one launch a query (SELECTED_SLOTS): no slot is padding
-            nslots = SELECTED_SLOTS
-            for ss in sel.reshape(-1, nslots):
-                tid, qq, mm = tile_ids_all[ss], q8[ss], mask_words[ss]
-                with stage("kernel.dispatch") as st:
-                    a, r, pc, pt, ow = _selected_batch(
-                        sindex.tiles,
-                        pindex.gt,
-                        pindex.gt2 if with_counts else pindex.gt,
-                        pindex.tok1 if with_counts else pindex.gt,
-                        pindex.tok2 if with_counts else pindex.gt,
-                        jax.device_put(tid, sindex.device),
-                        jax.device_put(qq, sindex.device),
-                        jax.device_put(mm.view(np.int32), sindex.device),
-                        T=T,
-                        CAP=cap,
-                        nslots=nslots,
-                        C=1 if ti == -1 else None,
-                        exact_only=exact,
-                        R=R,
-                        with_counts=with_counts,
-                        seg_k=_static_seg_k(sindex),
-                    )
-                seq = record_device_launch(
-                    "plane",
-                    seam="scatter",
-                    tier=nslots,
-                    specs_real=nslots,
-                    specs_padded=nslots,
-                    launch_ms=st.ms,
-                    program_key=_selected_program_key(
-                        sindex, pindex, nslots, cap, R,
-                        1 if ti == -1 else None, exact, with_counts,
-                    ),
-                    chip=chip_of(sindex.device),
-                )
-                # the host waits here for the device to run the program
-                # (behind whatever other threads launched before it)
-                # and for the copy back
-                with stage("kernel.readback") as st:
-                    a, r, pc, pt, ow = jax.device_get((a, r, pc, pt, ow))
-                with stage("kernel.unpack"):
-                    note_device_stage(
-                        seq,
-                        fetch_ms=st.ms,
-                        fetch_bytes=sum(
-                            np.asarray(v).nbytes for v in (a, r, pc, pt, ow)
-                        ),
-                        # the blocks of matched rows the program read:
-                        # gt once, or the four count planes and gt again
-                        gather_bytes=gathered_bytes(
-                            pindex.gt,
-                            np.minimum(np.asarray(a)[:, 4], R),
-                            5 if with_counts else 1,
-                        ),
-                    )
-                    agg[ss] = np.asarray(a)
-                    rows[ss, :R] = np.asarray(r)
-                    pc_call[ss, :R] = np.asarray(pc)
-                    pc_tok[ss, :R] = np.asarray(pt)
-                    or_words[ss] = np.asarray(ow).view(np.uint32)
-
-    # a truncated row set would silently under-reduce the planes: the
-    # per-tier R bound makes truncation part of the overflow contract
-    r_of = np.where(
-        tier_of == -1,
-        min(record_cap, T),
-        np.minimum(record_cap, np.asarray(caps)[np.maximum(tier_of, 0)]),
+        R_top = min(record_cap, caps[-1])
+        exact = bool(enc["alt_mode"][0] == MODE_EXACT)
+        seg_k = _group_seg_k(members)
+        packed = np.zeros((D, 1 + N_QWORDS + W), np.int32)
+        packed[:, 0] = tile_ids
+        packed[:, 1 : 1 + N_QWORDS] = q8
+        for d in np.flatnonzero(asked):
+            packed[d, 1 + N_QWORDS :] = np.asarray(
+                masks[d], dtype=np.uint32
+            ).view(np.int32)
+        tiles, planes = _group_operands(members, with_counts)
+    with stage("kernel.dispatch") as st:
+        out = _selected_batch(
+            tiles,
+            planes,
+            jax.device_put(packed, pfirst.device),
+            T=T,
+            CAP=cap,
+            C=C,
+            exact_only=exact,
+            R=R,
+            seg_k=seg_k,
+        )
+    n_asked = int(asked.sum())
+    seq = record_device_launch(
+        "plane",
+        seam="scatter",
+        tier=D,
+        specs_real=n_asked,
+        specs_padded=D,
+        launch_ms=st.ms,
+        program_key=_selected_program_key(
+            members, cap, R, C, exact, with_counts, seg_k
+        ),
+        chip=chip_of(pfirst.device),
     )
-    overflow = (
-        (agg[:, 5] > 0)
-        | (width > min(window_cap, caps[-1]))
-        | needs_host
-        | (agg[:, 4] > r_of)
-    )
+    # the host waits here for the device to run the program (behind
+    # whatever other threads launched before it) and for the copy back
+    with stage("kernel.readback") as st:
+        out = np.asarray(jax.device_get(out))
+    with stage("kernel.unpack"):
+        agg = out[:, :8]
+        rows = np.full((D, R_top), -1, np.int32)
+        pc_call = np.zeros((D, R_top), np.int32)
+        pc_tok = np.zeros((D, R_top), np.int32)
+        rows[:, :R] = out[:, 8 : 8 + R]
+        pc_call[:, :R] = out[:, 8 + R : 8 + 2 * R]
+        pc_tok[:, :R] = out[:, 8 + 2 * R : 8 + 3 * R]
+        or_words = np.ascontiguousarray(out[:, 8 + 3 * R :]).view(np.uint32)
+        note_device_stage(
+            seq,
+            fetch_ms=st.ms,
+            fetch_bytes=out.nbytes,
+            # the blocks of matched rows the program read: gt once, or
+            # the four count planes and gt again
+            # (the members' planes are alike in width)
+            gather_bytes=gathered_bytes(
+                pfirst.gt,
+                np.minimum(agg[asked, 4], R),
+                5 if with_counts else 1,
+            ),
+        )
+        # a truncated row set would silently under-reduce the planes:
+        # the launch's R bound makes truncation part of the overflow
+        # contract
+        overflow = asked & (
+            (agg[:, 5] > 0)
+            | (width > min(window_cap, caps[-1]))
+            | needs_host
+            | (agg[:, 4] > R)
+        )
     return SelectedResults(
         exists=agg[:, 0] > 0,
         call_count=agg[:, 1],
@@ -860,6 +935,111 @@ def run_selected_scattered(
     )
 
 
+def run_selected_scattered(
+    sindex: ScatterDeviceIndex,
+    pindex,
+    queries,
+    mask_words: np.ndarray,
+    *,
+    window_cap: int | None = None,
+    record_cap: int = 1024,
+    with_counts: bool | None = None,
+) -> SelectedResults:
+    """Selected-samples queries against ONE index: a group of one
+    dataset (``run_selected_group``), a launch a query.
+
+    ``pindex``: ops.plane_kernel.PlaneDeviceIndex of the SAME shard as
+    ``sindex``. ``mask_words``: uint32 [B, W] per-query selected-sample
+    masks (all-ones rows extract the full cohort). A query whose
+    matched-row count exceeds min(record_cap, its tier cap) reports
+    ``overflow`` (its plane outputs would be truncated) and must take
+    the host path, exactly like the match kernel's window overflow.
+    """
+    enc = encode_queries(queries) if isinstance(queries, list) else queries
+    b = len(enc["chrom"])
+    W = pindex.n_words
+    mask_words = np.ascontiguousarray(mask_words, dtype=np.uint32)
+    if mask_words.shape != (b, W):
+        raise ValueError(f"mask_words must be [{b}, {W}]")
+    got = [
+        run_selected_group(
+            [(sindex, pindex)],
+            {k: v[i : i + 1] for k, v in enc.items()},
+            [mask_words[i]],
+            window_cap=window_cap,
+            record_cap=record_cap,
+            with_counts=with_counts,
+        )
+        for i in range(b)
+    ]
+    if not got:
+        z = np.zeros(0, np.int32)
+        return SelectedResults(
+            exists=np.zeros(0, bool),
+            call_count=z,
+            n_variants=z,
+            all_alleles_count=z,
+            n_matched=z,
+            overflow=np.zeros(0, bool),
+            rows=np.zeros((0, 0), np.int32),
+            pc_call=np.zeros((0, 0), np.int32),
+            pc_tok=np.zeros((0, 0), np.int32),
+            or_words=np.zeros((0, W), np.uint32),
+        )
+    return SelectedResults(
+        **{
+            k: np.concatenate([getattr(r, k) for r in got])
+            for k in SelectedResults.__slots__
+        }
+    )
+
+
+def warmup_selected(
+    members, *, window_cap: int = 2048, record_cap: int = 1024
+) -> int:
+    """Pre-compile every fused match+planes program a group can be
+    launched as: (single-tile tier + each window-cap tier) x (exact /
+    non-exact) x (restricted counting where the members have count
+    planes / plain sample extraction). Returns how many; the caller
+    holds the flight recorder's warm-up phase."""
+    first, pfirst = members[0]
+    T = first.tile
+    D = len(members)
+    seg_k = _group_seg_k(members)
+    # zero queries match nothing (lo = hi = 0): only the compile matters
+    packed = jax.device_put(
+        np.zeros((D, 1 + N_QWORDS + pfirst.n_words), np.int32),
+        pfirst.device,
+    )
+    n = 0
+    outs = []
+    for with_counts in sorted({bool(pfirst.has_counts), False}):
+        tiles, planes = _group_operands(members, with_counts)
+        for ti, cap in [(-1, T)] + list(enumerate(_tier_caps(first, window_cap))):
+            C = 1 if ti == -1 else None
+            for exact in (True, False):
+                R = min(record_cap, cap)
+                outs.append(
+                    _selected_batch(
+                        tiles, planes, packed,
+                        T=T, CAP=cap, C=C, exact_only=exact, R=R,
+                        seg_k=seg_k,
+                    )
+                )
+                record_device_compile(
+                    "plane",
+                    tier=D,
+                    program_key=_selected_program_key(
+                        members, cap, R, C, exact, with_counts, seg_k
+                    ),
+                )
+                n += 1
+    # one sync for every queued compile+execute; an execution error in
+    # ANY warm program surfaces here, not in the first request
+    jax.block_until_ready(outs)
+    return n
+
+
 def warmup_index(
     sindex: ScatterDeviceIndex,
     pindex=None,
@@ -869,9 +1049,11 @@ def warmup_index(
     batch_shapes: tuple = (CHUNK_SMALL, CHUNK),
 ) -> int:
     """Pre-compile every program serving can dispatch against this
-    index: (single-tile fast tier + each window-cap tier) x
+    index alone: (single-tile fast tier + each window-cap tier) x
     (exact / non-exact) x each fixed batch shape, plus the fused
-    match+planes program when ``pindex`` planes are resident.
+    match+planes program of the group of one when ``pindex`` planes are
+    resident (``warmup_selected``; the groups an index shares a launch
+    with are the engine's to warm).
 
     A soak's tail is first-compiles, not queueing: a cold engine pays
     1-2 s per novel (tier, shape) signature mid-request. Returns the
@@ -879,82 +1061,47 @@ def warmup_index(
     (cached signatures are near-free, so calling this twice is cheap).
     VERDICT r4 next #7.
     """
-    import jax
+    from .query_pack import Q_META
 
     T = sindex.tile
     caps = _tier_caps(sindex, window_cap)
     n = 0
     outs = []
-    selected_shapes = (SELECTED_SLOTS,) if pindex is not None else ()
-    for nslots in sorted({*batch_shapes, *selected_shapes}):
+    for nslots in sorted(batch_shapes):
         tid = jax.device_put(np.zeros(nslots, np.int32), sindex.device)
         for ti, cap in [(-1, T)] + list(enumerate(caps)):
             C = 1 if ti == -1 else None
             for exact in (True, False):
                 # Q_META bits 1-2 = alt mode; zero queries match
                 # nothing (lo=hi=0) — only the compile matters
-                from .query_pack import Q_META
-
                 q8 = np.zeros((nslots, 8), np.int32)
                 q8[:, Q_META] = (
                     (MODE_EXACT if exact else MODE_ANY_BASE) << 1
                 )
-                qd = jax.device_put(q8, sindex.device)
-                if nslots in batch_shapes:
-                    outs.append(
-                        _scatter_batch(
-                            sindex.tiles, tid, qd,
-                            T=T, CAP=cap, nslots=nslots, C=C,
-                            exact_only=exact,
-                            seg_k=_static_seg_k(sindex),
-                        )
+                outs.append(
+                    _scatter_batch(
+                        sindex.tiles, tid,
+                        jax.device_put(q8, sindex.device),
+                        T=T, CAP=cap, nslots=nslots, C=C,
+                        exact_only=exact,
+                        seg_k=_static_seg_k(sindex),
                     )
-                    record_device_compile(
-                        "scatter",
-                        tier=nslots,
-                        program_key=_match_program_key(
-                            sindex, nslots, 1, cap, C, exact
-                        ),
-                    )
-                    n += 1
-                if nslots in selected_shapes:
-                    # run_selected_scattered launches SELECTED_SLOTS.
-                    # A plane set WITH count planes serves two programs:
-                    # restricted counting (selected samples) and plain
-                    # sample extraction (with_counts=False)
-                    mask = jax.device_put(
-                        np.zeros((nslots, pindex.n_words), np.int32),
-                        sindex.device,
-                    )
-                    for with_counts in sorted({bool(pindex.has_counts), False}):
-                        outs.append(
-                            _selected_batch(
-                                sindex.tiles,
-                                pindex.gt,
-                                pindex.gt2 if with_counts else pindex.gt,
-                                pindex.tok1 if with_counts else pindex.gt,
-                                pindex.tok2 if with_counts else pindex.gt,
-                                tid, qd, mask,
-                                T=T, CAP=cap, nslots=nslots, C=C,
-                                exact_only=exact,
-                                R=min(record_cap, cap),
-                                with_counts=with_counts,
-                                seg_k=_static_seg_k(sindex),
-                            )
-                        )
-                        record_device_compile(
-                            "plane",
-                            tier=nslots,
-                            program_key=_selected_program_key(
-                                sindex, pindex, nslots, cap,
-                                min(record_cap, cap), C, exact,
-                                with_counts,
-                            ),
-                        )
-                        n += 1
+                )
+                record_device_compile(
+                    "scatter",
+                    tier=nslots,
+                    program_key=_match_program_key(
+                        sindex, nslots, 1, cap, C, exact
+                    ),
+                )
+                n += 1
     # one sync for every queued compile+execute; an execution error in
     # ANY warm program surfaces here, not in the first request
     jax.block_until_ready(outs)
+    if pindex is not None:
+        n += warmup_selected(
+            [(sindex, pindex)], window_cap=window_cap, record_cap=record_cap
+        )
     return n
 
 

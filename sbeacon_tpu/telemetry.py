@@ -1121,6 +1121,9 @@ class DeviceFlightRecorder:
         # lifetime counters: per family, per seam (the module-property
         # back-compat views), sliced launches, evaluated pairs
         self._families: dict[str, int] = {}
+        # the (query, dataset) slots launches carried that a request
+        # asked, by family: over _families, the targets of one launch
+        self._targets: dict[str, int] = {}
         # launches of one-chip programs by the chip they ran on
         self._chips: dict[str, int] = {}
         self._seams: dict[str, int] = {}
@@ -1195,6 +1198,7 @@ class DeviceFlightRecorder:
         donated: int = 0,
         uploads: int = 0,
         chip: int | None = None,
+        targets: int | None = None,
     ) -> int:
         """Record ONE device launch; returns its sequence number (the
         handle :meth:`note_stage` later attaches encode/fetch timings
@@ -1205,7 +1209,9 @@ class DeviceFlightRecorder:
         ``chip`` is the device a one-chip program ran on (None for a
         program that spans the mesh); ``uploads`` the host arrays put
         on the device for this launch's query batch (the seams that
-        count them: ``fused``, ``fused_l0``, ``mesh``)."""
+        count them: ``fused``, ``fused_l0``, ``mesh``); ``targets`` the
+        (query, dataset) pairs it answers where that is not its real
+        specs (``device.launch_targets``)."""
         specs_real = int(specs_real)
         specs_padded = max(int(specs_padded), specs_real, 1)
         rec: dict = {
@@ -1232,6 +1238,9 @@ class DeviceFlightRecorder:
             self._seq += 1
             rec["seq"] = self._seq
             self._families[family] = self._families.get(family, 0) + 1
+            self._targets[family] = self._targets.get(family, 0) + int(
+                specs_real if targets is None else targets
+            )
             if chip is not None:
                 self._chips[str(chip)] = self._chips.get(str(chip), 0) + 1
             self._seams[seam] = self._seams.get(seam, 0) + 1
@@ -1430,6 +1439,14 @@ class DeviceFlightRecorder:
         with self._lock:
             return dict(self._families)
 
+    def launch_targets_by_family(self) -> dict:
+        """{family: (query, dataset) slots its launches carried for a
+        request}: over ``launches_by_family``, the targets one launch
+        answers (16 where a request over sixteen cohorts of one chip
+        rides one ``plane`` launch, 1 where each is launched alone)."""
+        with self._lock:
+            return dict(self._targets)
+
     def launches_by_chip(self) -> dict:
         with self._lock:
             return dict(self._chips)
@@ -1520,6 +1537,7 @@ class DeviceFlightRecorder:
         with self._lock:
             total = sum(self._families.values())
             by_family = dict(self._families)
+            targets = dict(self._targets)
             sliced = self._sliced
             pairs = self._pairs
             uploads = dict(self._uploads)
@@ -1527,6 +1545,7 @@ class DeviceFlightRecorder:
         return {
             "total": total,
             "byFamily": by_family,
+            "targetsByFamily": targets,
             "sliced": sliced,
             "evaluatedPairs": pairs,
             "queryUploads": uploads,
@@ -1546,6 +1565,7 @@ class DeviceFlightRecorder:
             keep = self._keep
             seq = self._seq
             families = dict(self._families)
+            targets = dict(self._targets)
             sliced = self._sliced
             pairs = self._pairs
             fetched = self._fetched_bytes
@@ -1565,6 +1585,7 @@ class DeviceFlightRecorder:
         return {
             "total": sum(families.values()),
             "byFamily": families,
+            "targetsByFamily": targets,
             "fallbacks": fallbacks,
             "sliced": sliced,
             "evaluatedPairs": pairs,
@@ -1645,6 +1666,16 @@ def register_device_metrics(registry) -> None:
         "/ fused_l0 / mesh / mesh_replicated / mesh_sliced / plane)",
         label="family",
         fn=lambda: flight_recorder.launches_by_family(),
+    )
+    registry.counter(
+        "device.launch_targets",
+        "(query, dataset) slots the launches of a family carried for "
+        "a request (padding slots not counted); over device.launches "
+        "of the same family, the targets one launch answers: a request "
+        "over sixteen plane datasets of one chip is ONE plane launch "
+        "of sixteen",
+        label="family",
+        fn=lambda: flight_recorder.launch_targets_by_family(),
     )
     registry.counter(
         "device.launches_by_chip",

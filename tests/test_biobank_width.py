@@ -45,7 +45,6 @@ from sbeacon_tpu.ops.plane_kernel import (
     sample_mask_words,
 )
 from sbeacon_tpu.ops.scatter_kernel import (
-    SELECTED_SLOTS,
     ScatterDeviceIndex,
     run_selected_scattered,
 )
@@ -275,7 +274,7 @@ def test_the_programs_answer_as_numpy_at_every_layout(n_samples, k, lane_rows):
     shard = _wide_shard(n_samples, seed=11 + n_samples)
     n_words = shard.gt_bits.shape[1]
     assert resident_shape(shard.n_rows, n_words) == (
-        -(-shard.n_rows // k), 128 * lane_rows)
+        -(-shard.n_rows // 128) * 128 // k, 128 * lane_rows)
     sindex, pindex = ScatterDeviceIndex(shard), PlaneDeviceIndex(shard)
     rng = np.random.default_rng(n_samples)
     specs = _specs(shard, rng, 66, 40)
@@ -390,8 +389,9 @@ def published_width():
     assert shard.gt_bits.shape == (shard.n_rows, UKB_WORDS)
     assert padded_words(UKB_WORDS) == 14_336 == 112 * 128
     pindex = PlaneDeviceIndex(shard)
-    assert pindex.gt.shape == (shard.n_rows, 14_336)
-    assert pindex.nbytes_hbm() == shard.n_rows * 57_344
+    assert shard.n_rows == 300  # resident in whole steps of 128 rows
+    assert pindex.gt.shape == (384, 14_336)
+    assert pindex.nbytes_hbm() == 384 * 57_344
     return shard, ScatterDeviceIndex(shard), pindex
 
 
@@ -598,7 +598,7 @@ def test_a_filtered_record_request_two_lane_rows_wide(
     # one slot, so one mask up and one row of carrier words back
     last = [e for e in flight_recorder.snapshot()["ring"]["entries"]
             if e["family"] == "plane"][-1]
-    assert (last["tier"], last["padded"]) == (SELECTED_SLOTS, 1)
+    assert (last["tier"], last["specs"], last["padded"]) == (1, 1, 1)
     n_matched = len(host_match_rows(shard, QuerySpec(
         "22", payload.start_min, payload.start_max, payload.end_min,
         payload.end_max, alternate_bases="N"), ref_wildcard=True))

@@ -39,6 +39,7 @@ from sbeacon_tpu.ops.plane_kernel import (
     padded_words,
     resident_shape,
 )
+from sbeacon_tpu.ops.query_pack import N_QWORDS
 from sbeacon_tpu.ops.scatter_kernel import (
     SELECTED_SLOTS,
     ScatterDeviceIndex,
@@ -100,18 +101,18 @@ def _plane_sized_outputs(compiled, n_rows: int) -> list[str]:
 
 def _selected(
     one_chip, n_rows, cap, C, with_counts=False, n_words=KG1_WORDS,
-    nslots=SELECTED_SLOTS,
+    group=1, exact=True,
 ):
+    """The fused program of a launch group of ``group`` datasets of
+    ``n_rows`` rows each: a slot over each dataset's own buffers."""
     n_tiles = n_rows // TILE + 1 + ScatterDeviceIndex.MAX_C
     plane = _shape(one_chip, *resident_shape(n_rows, n_words))
     return _selected_batch.lower(
-        _shape(one_chip, n_tiles, 8, TILE),
-        plane, plane, plane, plane,
-        _shape(one_chip, nslots),
-        _shape(one_chip, nslots, 8),
-        _shape(one_chip, nslots, n_words),
-        T=TILE, CAP=cap, nslots=nslots, C=C, exact_only=True,
-        R=min(1024, cap), with_counts=with_counts, seg_k=2,
+        (_shape(one_chip, n_tiles, 8, TILE),) * group,
+        ((plane,) * (4 if with_counts else 1),) * group,
+        _shape(one_chip, group, 1 + N_QWORDS + n_words),
+        T=TILE, CAP=cap, C=C, exact_only=exact,
+        R=min(1024, cap), seg_k=2,
     ).compile()
 
 
@@ -171,7 +172,8 @@ def test_selected_batch_compiles_on_an_owner_that_is_not_chip_0(chips):
     assert owner.device_set != chips[0].device_set
     compiled = _selected(owner, 2 * KG1_ROWS, 128, 1)
     _holds_no_plane_copy(compiled, 2 * KG1_ROWS)
-    assert compiled.input_shardings[0][0].device_set == owner.device_set
+    # the first operand: the tuple of the group's tile arrays
+    assert compiled.input_shardings[0][0][0].device_set == owner.device_set
 
 
 def test_upload_writes_its_chunk_in_place(one_chip):
@@ -204,10 +206,44 @@ def test_packed_selected_batch_at_mdsp_shapes(one_chip, cap, C):
     """``mdsp``: 2,999,000 rows of 32 words, four to a lane row. The
     program's temp is a block of the rows it gathers, never the rows of
     a whole launch (33.6 MB at R = 1024) nor the plane (384 MB)."""
-    assert resident_shape(MDSP_ROWS, MDSP_WORDS) == (749_750, 128)
+    # whole steps of 128 rows: 2,999,040 of them, four to a lane row
+    assert resident_shape(MDSP_ROWS, MDSP_WORDS) == (749_760, 128)
     compiled = _selected(one_chip, MDSP_ROWS, cap, C, n_words=MDSP_WORDS)
     _holds_no_plane_copy(compiled, MDSP_ROWS, MDSP_WORDS)
     assert compiled.memory_analysis().temp_size_in_bytes <= WORKSPACE_BYTES
+
+
+@pytest.mark.parametrize(
+    "cap,C,exact,with_counts",
+    [(128, 1, True, False), (2048, None, False, False), (512, None, True, True)],
+)
+def test_group_launch_at_mdsp_shapes(one_chip, cap, C, exact, with_counts):
+    """``mdsp.samples``' launch since PR 44: ALL sixteen datasets of the
+    chip in one program, a slot over each dataset's own tiles and
+    planes (2,999,000 rows of 32 words each). Its arguments are the
+    sixteen resident sets as they lie (nothing stacked: no temp the
+    size of a plane, no output with a plane's rows), its workspace is
+    still a block of gathered rows beside the match's own, now of
+    sixteen slots, and what comes back is ONE ``int32[16, 8 + 3R + W]``.
+    With their count planes four such datasets fill the chip."""
+    group = 4 if with_counts else SELECTED_SLOTS
+    compiled = _selected(
+        one_chip, MDSP_ROWS, cap, C, with_counts, n_words=MDSP_WORDS,
+        group=group, exact=exact,
+    )
+    _holds_no_plane_copy(compiled, MDSP_ROWS, MDSP_WORDS)
+    memory = compiled.memory_analysis()
+    lane_rows, lanes = resident_shape(MDSP_ROWS, MDSP_WORDS)
+    planes = 4 if with_counts else 1
+    assert memory.argument_size_in_bytes >= group * (
+        planes * lane_rows * lanes * 4 + MDSP_ROWS * 32
+    )
+    assert memory.temp_size_in_bytes <= WORKSPACE_BYTES
+    R = min(1024, cap)
+    (out,) = compiled.out_info if isinstance(
+        compiled.out_info, (list, tuple)
+    ) else (compiled.out_info,)
+    assert out.shape == (group, 8 + 3 * R + MDSP_WORDS)
 
 
 def test_packed_plane_stats_and_upload_at_mdsp_shapes(one_chip):
@@ -220,8 +256,7 @@ def test_packed_plane_stats_and_upload_at_mdsp_shapes(one_chip):
         plane, _shape(one_chip, lane_rows, 128),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
     ).compile().memory_analysis()
-    # the device holds whole (8, 128) tiles: 749,752 lane rows
-    assert 0 <= memory.alias_size_in_bytes - 749_750 * 512 < 8 * 512
+    assert memory.alias_size_in_bytes == 749_760 * 512
     assert memory.temp_size_in_bytes <= 2 * lane_rows * 512
 
 
@@ -235,7 +270,7 @@ UKB1_WORDS = 14_213  # 454,787 samples: a row is 112 lane rows, 57,344 B
 def test_selected_batch_at_ukb1_shapes(one_chip, cap, C):
     """``ukb1``: a biobank-width plane, ``int32[160000, 14336]``, 9.175
     GB resident, every window-cap tier (R = 128, 128, 512, 1024) of the
-    one-slot program the engine launches. The workspace is a block of eight rows (8 x 57,344 B)
+    program at ONE slot, the group of one the engine launches there. The workspace is a block of eight rows (8 x 57,344 B)
     beside the match's own, so ``warm_app`` can execute every tier
     beside the resident plane: gathered whole, R = 1024 x 64 slots was
     3.76 GB of temp before the AND and the OR. The gather the compiler

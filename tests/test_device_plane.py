@@ -191,6 +191,7 @@ def test_recorder_seam_counters_feed_module_properties(monkeypatch):
 GOLDEN_DEVICE_KEYS = {
     "total",
     "byFamily",
+    "targetsByFamily",
     "sliced",
     "evaluatedPairs",
     "fetchedBytes",

@@ -65,8 +65,8 @@ def scatter_family(monkeypatch):
 
 def _fill(shard) -> float:
     """Per cent of a resident 32-word plane that is the plane's own
-    words: four rows fill a lane row, all but the last one's spare."""
-    return 100.0 * shard.n_rows / (-(-shard.n_rows // 4) * 4)
+    words: four rows fill a lane row, the rows in whole steps of 128."""
+    return 100.0 * shard.n_rows / (-(-shard.n_rows // 128) * 128)
 
 
 def _host_planes() -> int:
@@ -79,7 +79,7 @@ def test_sixteen_packed_planes_pass_a_budget_that_declined_eight(scatter_family)
     declined, and a request that reads them counts no fall-back."""
     shards = [_shard(d) for d in range(N_DATASETS)]
     lane_rows, lanes = resident_shape(shards[0].n_rows, 32)
-    assert (lane_rows, lanes) == (-(-shards[0].n_rows // 4), 128)
+    assert (lane_rows, lanes) == (-(-shards[0].n_rows // 128) * 32, 128)
     unpacked = shards[0].n_rows * 128 * 4
     packed = PlaneDeviceIndex.estimate_hbm(shards[0])
     assert packed == lane_rows * 512 and packed < unpacked / 3.9
@@ -186,9 +186,10 @@ def test_a_filtered_record_request_over_sixteen_datasets(
     node, term, width, descendants
 ):
     """Each dataset answers as the per-record loop does over ITS forty
-    samples; the request is sixteen pool tasks: ``engine.fanout_targets``
-    + 16, ``engine.pool_wait`` sixteen samples, sixteen ``plane``
-    launches, no fall-back."""
+    samples; the request is ONE ``plane`` launch of sixteen targets on
+    its own thread (one launch group: the chip's sixteen datasets), no
+    pool task (``engine.fanout_targets`` and ``engine.pool_wait`` stand
+    still), no fall-back."""
     app, shards, calls = node
     c = shards[0].cols
     snv = np.flatnonzero((c["ref_len"] == 1) & (c["alt_len"] == 1) & (c["ac"] > 0))
@@ -211,16 +212,25 @@ def test_a_filtered_record_request_over_sixteen_datasets(
     targets = app.engine.fanout_targets
     waits = tracer.stage_counts("engine.pool_wait")[0]
     launches = flight_recorder.launches_by_family().get("plane", 0)
+    slots = flight_recorder.launch_targets_by_family().get("plane", 0)
     fallbacks = sum(flight_recorder.fallbacks_by_site().values())
     st, doc = app.handle("POST", "/g_variants", body=body)
     assert st == 200, doc
     assert doc["responseSummary"]["exists"] is True
-    assert app.engine.fanout_targets - targets == N_DATASETS
-    assert tracer.stage_counts("engine.pool_wait")[0] - waits == N_DATASETS
-    assert flight_recorder.launches_by_family()["plane"] - launches == N_DATASETS
+    assert app.engine.fanout_targets == targets
+    assert tracer.stage_counts("engine.pool_wait")[0] == waits
+    assert flight_recorder.launches_by_family()["plane"] - launches == 1
+    assert (
+        flight_recorder.launch_targets_by_family()["plane"] - slots
+        == N_DATASETS
+    )
     assert sum(flight_recorder.fallbacks_by_site().values()) == fallbacks
     _st, metrics = app.handle("GET", "/metrics")
     assert metrics["engine"]["fanout_targets"] == app.engine.fanout_targets
+    assert (
+        metrics["device"]["launch_targets"]["plane"]
+        == flight_recorder.launch_targets_by_family()["plane"]
+    )
     assert metrics["device"]["plane_fill"] == {
         "0": pytest.approx(_fill(shards[0]))}
 

@@ -34,7 +34,7 @@ def _plane_bytes(shard) -> int:
     """One plane resident: 40 samples are 2 words, 64 rows to a lane
     row, int32[ceil(n_rows / 64), 128]."""
     lane_rows, lanes = resident_shape(shard.n_rows, 2)
-    assert lanes == 128 and lane_rows == -(-shard.n_rows // 64)
+    assert lanes == 128 and lane_rows == -(-shard.n_rows // 128) * 2
     return lane_rows * lanes * 4
 
 
