@@ -1258,6 +1258,11 @@ class BeaconApp:
             # metadata generation (metadata/memo.py)
             "filters": {"memo": self.store.resolve_memo.stats()},
             "requests": self.tails.status(),
+            # how targets' responses came to be: skipped (answered from
+            # the launch's counts), inline, pooled
+            "engine": {
+                "materialized": dict(getattr(local, "materialized", {})),
+            },
             "costs": costs,
             "canary": canary,
             "device": device,

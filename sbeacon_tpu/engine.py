@@ -684,6 +684,41 @@ def materialize_response(
     )
 
 
+#: what a dataset the launch says matched nothing has in hand
+_NO_ROWS = np.zeros(0, dtype=np.int64)
+
+
+def _launched_rows(findex, routes, res, record_cap: int) -> dict:
+    """``{key: shard-local row ids | None}`` of ONE stacked launch
+    (the fused stack's, the L0 mini-index's) over ``routes``
+    ``[(key, shard id)]``, decided by one vector test of the launch's
+    own counts: None where it overflowed its window or matched more
+    than ``record_cap`` rows (the caller host-matches that shard
+    uncapped), the shared empty array where it matched nothing, and
+    only for the rest a slice of its rows: the first ``n_matched`` of
+    them (``_query_one`` returns the matched row ids first, ascending,
+    then -1), so the pick costs what matched, not ``record_cap``
+    words. A point query over 32 datasets hits one of them or none."""
+    n = len(routes)
+    n_matched = np.asarray(res.n_matched[:n])
+    host = np.asarray(res.overflow[:n], dtype=bool) | (n_matched > record_cap)
+    out = dict.fromkeys([key for key, _sid in routes], _NO_ROWS)
+    for i in np.flatnonzero(host | (n_matched > 0)).tolist():
+        key, sid = routes[i]
+        out[key] = (
+            None
+            if host[i]
+            else findex.to_local_rows(res.rows[i, : n_matched[i]], sid)
+        )
+    return out
+
+
+def _no_match(target) -> VariantSearchResponse:
+    """What ``materialize_response`` answers over no rows: the response
+    of a target whose launch matched nothing."""
+    return VariantSearchResponse(dataset_id=target[0], vcf_location=target[1])
+
+
 def _device_fallback(site: str, msg: str, *args) -> None:
     """A device path failed inside an ``except`` block and another
     path serves: log the traceback AND tick ``device.fallbacks{site}``
@@ -815,9 +850,12 @@ class VariantEngine:
         # belongs to (two racing builds could publish out of order)
         self._fused_gen = 0
         self.fused_searches = 0
-        # targets requests had served from the scatter pool (one task a
-        # target; engine.pool_wait times each task's wait for a thread)
-        self.fanout_targets = 0
+        # how requests' targets got their responses: ``skipped`` from
+        # the launch's own counts (it matched nothing there), ``inline``
+        # materialised on the request's thread, ``pooled`` by a task of
+        # the scatter pool (engine.pool_wait times each task's wait for
+        # a thread); the three add up to the targets asked
+        self.materialized = {"skipped": 0, "inline": 0, "pooled": 0}
         # samples requests selected, summed over their datasets
         self.selected_samples = 0
         self.mesh_searches = 0
@@ -2023,13 +2061,7 @@ class VariantEngine:
                 window_cap=win,
                 record_cap=eng.record_cap,
             )
-        out = {}
-        for i, (key, sid) in enumerate(routes):
-            if res.overflow[i] or res.n_matched[i] > eng.record_cap:
-                out[key] = None
-            else:
-                rows = res.rows[i][res.rows[i] >= 0]
-                out[key] = findex.to_local_rows(rows, sid)
+        out = _launched_rows(findex, routes, res, eng.record_cap)
         with self._mat_lock:  # unlocked += drops concurrent counts
             self.l0_searches += 1
         annotate(dispatch_l0=len(routes))
@@ -2734,6 +2766,16 @@ class VariantEngine:
             "each (engine.fanout parks the request meanwhile)",
             fn=lambda: self.fanout_targets,
         )
+        registry.counter(
+            "engine.materialized",
+            "targets by how their response came to be: skipped = "
+            "answered from the launch's counts (it matched nothing "
+            "there), inline = materialised on the request's thread, "
+            "pooled = by a scatter-pool task; they add up to the "
+            "targets asked",
+            label="how",
+            fn=lambda: dict(self.materialized),
+        )
         registry.gauge(
             "device.plane_resident_bytes",
             "HBM bytes of the genotype planes resident per dataset, in "
@@ -2775,6 +2817,18 @@ class VariantEngine:
             self._batcher.register_metrics(registry)
         register_cache_metrics(registry, lambda: self._response_cache)
         register_delta_metrics(registry, self.delta_metrics)
+
+    @property
+    def fanout_targets(self) -> int:
+        """Targets requests had served from the scatter pool."""
+        return self.materialized["pooled"]
+
+    def _count_materialized(self, **by_how) -> None:
+        """Once a request (and serving leg): its targets by how their
+        responses came to be."""
+        with self._mat_lock:  # unlocked += drops concurrent counts
+            for how, n in by_how.items():
+                self.materialized[how] += n
 
     def _materialize_timing(self) -> dict:
         """Host-materialisation quantiles alone (the
@@ -3004,7 +3058,9 @@ class VariantEngine:
         """{key: shard-local row ids | None} for every fused-covered
         target of a multi-dataset query, computed by ONE stacked-index
         launch (a None value marks window/record overflow — the caller
-        host-matches that shard uncapped, the per-shard contract).
+        host-matches that shard uncapped, the per-shard contract; an
+        empty array a dataset the launch's counts say matched nothing:
+        _launched_rows).
 
         Returns None (per-target dispatch serves) when the query needs
         host-only ref-wildcard semantics or fewer than 2 targets are
@@ -3066,13 +3122,7 @@ class VariantEngine:
                 record_cap=eng.record_cap,
             )
         with stage("engine.plan"):
-            out = {}
-            for i, (key, sid) in enumerate(routes):
-                if res.overflow[i] or res.n_matched[i] > eng.record_cap:
-                    out[key] = None
-                else:
-                    rows = res.rows[i][res.rows[i] >= 0]
-                    out[key] = findex.to_local_rows(rows, sid)
+            out = _launched_rows(findex, routes, res, eng.record_cap)
         with self._mat_lock:  # unlocked += would drop concurrent counts
             self.fused_searches += 1
         annotate(dispatch="fused")
@@ -3220,33 +3270,59 @@ class VariantEngine:
             else None
         )
 
+        # the rows a launch already made left in hand, by target (the
+        # L0 leg's before the fused stack's): None marks window/record
+        # overflow -> the uncapped host matcher, the per-shard contract;
+        # an empty array that the launch matched nothing (_launched_rows)
+        rows_of = {**(pre_rows or {}), **l0_rows}
+
         # the fan-out's unit: the request's plane-reading targets that
         # share a launch group (one chip's plane datasets) are ONE unit,
         # one match+planes launch; every other target is a unit alone
         units = self._launch_units(payload, spec_base, targets)
 
-        # the fan-out as decided on this thread: counts per
-        # serving leg, with the overflow buckets (rows already marked
-        # None) that will walk the host matcher instead of the leg
-        # that pre-matched them
+        # the fan-in's ONE rule, read from what the launches returned
+        # and what the payload asks. ``skipped``: the launch matched
+        # nothing there, answered from that. ``ready``: rows in hand
+        # and a response that reads no planes, microseconds of pure
+        # host work on this thread. ``waiting``: a device round trip or
+        # an uncapped host match still to pay (a plane group on its
+        # owner chip, _device_rows, overflow), or planes to read in
+        # numpy, which gives the interpreter lock up: for the pool to
+        # overlap when there are two or more of them
+        skipped, ready, waiting = [], [], []
+        for unit in units:
+            group, asked = unit
+            r = (
+                rows_of.get((asked[0][0], asked[0][1]))
+                if group is None
+                else None
+            )
+            if r is None:
+                waiting.append(unit)
+            elif not len(r):
+                skipped.append(asked[0])
+            elif wants_planes:
+                waiting.append(unit)
+            else:
+                ready.append(unit)
+
+        # the fan-out as decided on this thread: counts per serving
+        # leg, and the targets whose launch overflowed (rows marked
+        # None): they walk the host matcher instead
+        n_l0 = sum(1 for r in l0_rows.values() if r is not None)
+        n_in_hand = sum(1 for r in rows_of.values() if r is not None)
         plan_stage(
             "split",
             decision="fanout",
             mesh=len(mesh_responses) if mesh_responses else 0,
-            l0=sum(1 for r in l0_rows.values() if r is not None),
-            delta_tail_host=sum(1 for r in l0_rows.values() if r is None),
-            fused=sum(
-                1
-                for k, r in (pre_rows or {}).items()
-                if r is not None and k not in l0_rows
-            ),
-            fused_overflow_host=sum(
-                1
-                for k, r in (pre_rows or {}).items()
-                if r is None and k not in l0_rows
-            ),
+            l0=n_l0,
+            fused=n_in_hand - n_l0,
+            overflow_host=len(rows_of) - n_in_hand,
             scatter=len(targets),
             units=len(units),
+            ready=len(ready),
+            skipped=len(skipped),
         )
 
         def _one_unit(unit):
@@ -3304,25 +3380,11 @@ class VariantEngine:
             elif payload.selected_samples_only:
                 with stage("engine.plan"):
                     selected_idx = self._selection(shard, payload, ds)
-            if rows is None and (ds, vcf) in l0_rows:
-                # the L0 mini-index launch already matched this tail
-                # target; None marks window/record overflow -> the
-                # uncapped host matcher (already charged above)
-                r = l0_rows[(ds, vcf)]
-                rows = (
-                    r
-                    if r is not None
-                    else host_match_rows(
-                        shard,
-                        spec_base,
-                        ref_wildcard=payload.selected_samples_only,
-                    )
-                )
-            if rows is None and pre_rows is not None and (ds, vcf) in pre_rows:
-                # the fused stacked launch already matched this target;
-                # None marks window/record overflow -> uncapped host
-                # matcher, exactly like the per-shard contract
-                r = pre_rows[(ds, vcf)]
+            if rows is None and (ds, vcf) in rows_of:
+                # the L0 mini-index's or the fused stack's launch
+                # already matched this target (an L0 overflow's host
+                # walk is already charged above)
+                r = rows_of[(ds, vcf)]
                 rows = (
                     r
                     if r is not None
@@ -3373,44 +3435,34 @@ class VariantEngine:
                     fused=fused,
                 )
 
-        if len(units) == 1:
-            # one launch group (one chip's cohorts, or one dataset), or
-            # one target: on the request's own thread, its kernel.*
-            # stages in the request's chain, then one engine.materialize
-            # a dataset here too; nothing parks
-            got = _one_unit(units[0])
-        elif not l0_rows:
-            # per-unit scatter (the reference's ThreadPoolExecutor(500)
-            # per-dataset dispatch, search_variants.py:77-118): overlaps
-            # the units' device round-trips (one a chip) instead of
-            # serialising them
+        # two or more waiting units: a pool task each (the reference's
+        # ThreadPoolExecutor(500) per-dataset dispatch,
+        # search_variants.py:77-118), submitted first: their device
+        # round trips (one a chip) overlap each other and this thread's
+        # work. One runs here, its kernel.* stages in the request's chain
+        t_pool = time.perf_counter()
+        pooled = (
+            self._scatter.map(_pooled_unit, waiting)
+            if len(waiting) > 1
+            else None
+        )
+        got = []
+        if skipped:
+            with stage("engine.materialize"):
+                got += [((t[0], t[1]), _no_match(t)) for t in skipped]
+        for unit in (ready + waiting if pooled is None else ready):
+            got += _one_unit(unit)
+        n_pooled = 0
+        if pooled is not None:
             with stage("engine.fanout"):
-                t_pool = time.perf_counter()
-                got = [
-                    kr
-                    for krs in self._scatter.map(_pooled_unit, units)
-                    for kr in krs
-                ]
-            with self._mat_lock:  # unlocked += drops concurrent counts
-                self.fanout_targets += len(targets)
-        else:
-            # L0-covered tail targets have NO device work left — their
-            # rows are already in hand, materialisation is pure host —
-            # so they run inline on the request thread while the
-            # scatter pool overlaps the units that still pay a
-            # device round-trip (a pool task per tiny tail shard is
-            # mostly scheduling jitter on few-core hosts)
-            inline, pooled = [], []
-            for u in units:
-                tail = u[0] is None and (u[1][0][0], u[1][0][1]) in l0_rows
-                (inline if tail else pooled).append(u)
-            pooled_iter = (
-                self._scatter.map(_one_unit, pooled)
-                if len(pooled) > 1
-                else map(_one_unit, pooled)
-            )
-            got = [kr for u in inline for kr in _one_unit(u)]
-            got += [kr for krs in pooled_iter for kr in krs]
+                for krs in pooled:
+                    got += krs
+            n_pooled = sum(len(asked) for _group, asked in waiting)
+        self._count_materialized(
+            skipped=len(skipped),
+            inline=len(targets) - len(skipped) - n_pooled,
+            pooled=n_pooled,
+        )
         by_target = dict(got)
         responses = [by_target[(t[0], t[1])] for t in targets]
         if mesh_responses is not None:
@@ -3723,16 +3775,19 @@ class VariantEngine:
         (``sharded_query``); concurrent requests launch the collective
         program concurrently, which four v5e chips serve (PR 34: 800
         launches a window from four clients, every answer exact; only
-        XLA:CPU needs ``mesh._collective_guard``). The materialisations,
-        one a covered target under ``engine.materialize``, then run on
-        this thread where the request reads no planes (booleans and
-        counts: the stack's traffic), and otherwise on the scatter pool
-        exactly as the per-target fan-out's do (this thread parked in
+        XLA:CPU needs ``mesh._collective_guard``). The launch's own
+        counts then decide what is materialised: a target it matched
+        nothing in is answered from the count (one ``engine.materialize``
+        scope around all of them, ``engine.materialized{skipped}``);
+        the others, one ``engine.materialize`` each, run on this thread
+        where the request reads no planes (booleans and counts: the
+        stack's traffic), and otherwise on the scatter pool exactly as
+        the per-target fan-out's do (this thread parked in
         ``engine.fanout``, each task's wait in ``engine.pool_wait``,
         ``engine.fanout_targets`` ticked). The device's own ``agg`` is
-        computed and only noted:
-        answering booleans and counts from it, without the per-dataset
-        responses, is the next step (PERF.md 7) and not taken here."""
+        computed and only noted: answering booleans and counts from it
+        WITHOUT the per-dataset responses would take away what
+        ``search`` returns a dataset (PERF.md 7) and is not done."""
         from .parallel.mesh import sharded_query
 
         mesh, stacked, arrays, index_of, shard_of, planes_of = state
@@ -3754,52 +3809,60 @@ class VariantEngine:
             n_datasets=stacked.n_datasets,
         )
         req_ctx = current_context()
+        # ONE vector test over the launch's own counts, as the fused
+        # leg's (_launched_rows): ``host`` where the uncapped host
+        # matcher has to answer (window overflow, more matches than
+        # record_cap, a ref the device cannot compare exactly), ``miss``
+        # where the launch matched nothing: answered from the count, no
+        # pick of its rows, no call (a point query over 128 datasets
+        # hits one of them or none). A key the stack does not hold
+        # raises here: thread scatter then serves, counted by the caller
+        at = [index_of[(t[0], t[1])] for t in targets]
+        n_matched = per_ds["n_matched"][at, 0]
+        host = per_ds["overflow"][at, 0] | (n_matched > eng.record_cap)
+        if not device_ref_ok:
+            host[:] = True
+        miss = (~host & (n_matched == 0)).tolist()
+        host, n_matched = host.tolist(), n_matched.tolist()
 
-        def _one(target):
+        def _one(i):
+            ds, vcf, _shard, _dindex, _planes, native = targets[i]
             # the stage covers the whole of a target's response, the
             # pick of its rows from the launch's leaves included: built
             # on the request's thread, 128 such picks between stages
             # were a fifth of a request that no stage named
             # (span_coverage 76.9 %, PERF.md 6, PR 34)
             with stage("engine.materialize"):
-                return _response(target)
-
-        def _response(target):
-            ds, vcf, _shard, _dindex, _planes, native = target
-            # state-consistent shard: rows from the stacked arrays must
-            # materialise against the shard the stack was built from (a
-            # missing key means the dataset arrived after the stack was
-            # built — KeyError here falls back to thread scatter)
-            shard = shard_of[(ds, vcf)]
-            di = index_of[(ds, vcf)]
-            selected_idx = (
-                self._selection(shard, payload, ds)
-                if payload.selected_samples_only
-                else None
-            )
-            overflow = (
-                bool(per_ds["overflow"][di, 0])
-                or int(per_ds["n_matched"][di, 0]) > eng.record_cap
-            )
-            if not device_ref_ok or overflow:
-                rows = host_match_rows(
-                    shard, spec_base, ref_wildcard=ref_wild
+                # state-consistent shard: the stack's rows materialise
+                # against the shard the stack was built from
+                shard = shard_of[(ds, vcf)]
+                selected_idx = (
+                    self._selection(shard, payload, ds)
+                    if payload.selected_samples_only
+                    else None
                 )
-            else:
-                r = per_ds["rows"][di, 0]
-                rows = r[r >= 0].astype(np.int64)
-            return materialize_response(
-                shard,
-                rows,
-                payload,
-                chrom_label=native,
-                dataset_id=ds,
-                vcf_location=vcf,
-                selected_idx=selected_idx,
-                plane_index=planes_of.get((ds, vcf)),
-            )
+                if host[i]:
+                    rows = host_match_rows(
+                        shard, spec_base, ref_wildcard=ref_wild
+                    )
+                else:
+                    # the matched row ids come first (_query_one): the
+                    # pick costs what matched, not record_cap words
+                    rows = per_ds["rows"][at[i], 0, : n_matched[i]].astype(
+                        np.int64
+                    )
+                return materialize_response(
+                    shard,
+                    rows,
+                    payload,
+                    chrom_label=native,
+                    dataset_id=ds,
+                    vcf_location=vcf,
+                    selected_idx=selected_idx,
+                    plane_index=planes_of.get((ds, vcf)),
+                )
 
-        def _pooled(target):
+        def _pooled(i):
             # as the per-target fan-out of _search_targets: the
             # request's thread is parked in ``engine.fanout`` while the
             # pool serves it, so the pool's stages add nothing to the
@@ -3811,27 +3874,38 @@ class VariantEngine:
                 (req_ctx,),
             )
             with tracer.serving(0), request_context(req_ctx):
-                return _one(target)
+                return _one(i)
 
-        if len(targets) == 1 or not self._wants_planes(payload):
+        responses: list = [None] * len(targets)
+        if any(miss):
+            with stage("engine.materialize"):  # ONE scope, all the misses
+                responses = [
+                    _no_match(t) if m else None
+                    for t, m in zip(targets, miss)
+                ]
+        todo = [i for i, m in enumerate(miss) if not m]
+        how = "pooled"
+        if len(todo) < 2 or not self._wants_planes(payload):
             # the rows are in hand and a boolean's or a count's
-            # response is microseconds of pure host work: inline, as
-            # the L0 leg's targets are. A pool task a target was 128
-            # thread hand-overs a request in ``mds4.fanout`` (39 ms
-            # parked in engine.fanout for 1.3 ms of materialising), and
-            # put the cell's tail past the server's own 250 ms
-            # objective: the brownout ladder shed the window (PERF.md
-            # 6, PR 34)
-            responses = [_one(t) for t in targets]
+            # response is microseconds of pure host work: on this
+            # thread, as _search_targets' ready units. A pool task a
+            # target was 128 thread hand-overs a request in
+            # ``mds4.fanout`` (39 ms parked in engine.fanout for 1.3 ms
+            # of materialising) and shed the cell (PERF.md 6, PR 34)
+            how = "inline"
+            for i in todo:
+                responses[i] = _one(i)
         else:
             # plane-reading materialisations read the host's planes in
             # numpy, which gives the interpreter lock up: the pool
             # overlaps them, as the per-target fan-out's
             with stage("engine.fanout"):
                 t_pool = time.perf_counter()
-                responses = list(self._scatter.map(_pooled, targets))
-            with self._mat_lock:  # unlocked += drops concurrent counts
-                self.fanout_targets += len(targets)
+                for i, r in zip(todo, self._scatter.map(_pooled, todo)):
+                    responses[i] = r
+        self._count_materialized(
+            skipped=len(targets) - len(todo), **{how: len(todo)}
+        )
         with self._mat_lock:
             self.mesh_searches += 1
         annotate(dispatch="mesh")

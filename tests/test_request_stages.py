@@ -173,6 +173,7 @@ def test_the_vectors_of_the_finished_requests_are_the_stages_req_ms(
 
     monkeypatch.setattr(kernel_mod.PendingQueryResults, "fetch", held)
     launches = app.engine._batcher.occupancy
+    skipped = app.engine.materialized["skipped"]
     # the stages are process-wide, and apps that earlier tests left open
     # probe their engines now and then: the best of a few readings
     worst = {}
@@ -191,6 +192,9 @@ def test_the_vectors_of_the_finished_requests_are_the_stages_req_ms(
         if not worst:
             break
     assert not worst, worst
+    # ... with the ONE engine.materialize scope around the datasets a
+    # fused launch matched nothing in (a count's window misses rs1)
+    assert app.engine.materialized["skipped"] > skipped
     # both paths ran, and launches served several requests at once
     seen = {n for _s, _ms, ctx in requests for n in ctx.stages}
     assert {"batcher.wait", "kernel.dispatch", "engine.fanout",

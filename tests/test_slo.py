@@ -357,8 +357,11 @@ def test_debug_status_schema_and_diagnosis(app):
     assert status == 200
     assert set(doc) == {
         "ready", "beaconId", "slo", "breakers", "routing", "queues",
-        "ingest", "stages", "filters", "requests", "costs", "canary",
-        "device", "events", "plans", "diagnosis",
+        "ingest", "stages", "filters", "requests", "engine", "costs",
+        "canary", "device", "events", "plans", "diagnosis",
+    }
+    assert set(doc["engine"]["materialized"]) == {
+        "skipped", "inline", "pooled",
     }
     assert set(doc["filters"]["memo"]) == {
         "hits", "misses", "invalidations", "entries",

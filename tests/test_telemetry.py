@@ -176,6 +176,7 @@ GOLDEN_METRICS = [
     "engine.fused_searches",
     "engine.mesh_searches",
     "engine.fanout_targets",
+    "engine.materialized",
     "engine.selected_samples",
     "engine.materialize_ms",
     "response_cache.entries",
