@@ -801,157 +801,6 @@ def expected_host_rows(check: Check, shards: dict, eng_cfg) -> int:
     return total
 
 
-# -- multi-chip: the pod tier and the ring ------------------------------------
-
-
-def ring_matches_portable(n_dev: int, on_tpu: bool) -> dict:
-    """The Pallas ring combine against the portable all_gather combine,
-    bit for bit, on a block shaped like the hit-row gather. Off the TPU
-    only the portable combine runs (Mosaic compiles the ring for the
-    chip alone; tests/test_chip_bringup.py runs its DMA schedule in the
-    Pallas interpreter)."""
-    import jax
-    import numpy as np
-    from jax.sharding import PartitionSpec as P
-
-    from sbeacon_tpu.ops.gather_kernel import gather_partials
-    from sbeacon_tpu.parallel.mesh import AXIS, make_mesh
-
-    mesh = make_mesh()
-    rng = np.random.default_rng(7)
-    shape = (n_dev, 64, 1024) if on_tpu else (n_dev, 8, 128)
-    owner = rng.integers(0, n_dev, shape[1])
-    x = rng.integers(1, 2**30, shape, dtype=np.int32)
-    x *= (owner[None, :, None] == np.arange(n_dev)[:, None, None])
-    impls = ("pallas", "portable") if on_tpu else ("portable",)
-    out = {}
-    for impl in impls:
-        fn = jax.jit(
-            jax.shard_map(
-                lambda b, impl=impl: gather_partials(
-                    b[0], AXIS, n_dev, impl=impl
-                )[None],
-                mesh=mesh,
-                in_specs=P(AXIS),
-                out_specs=P(AXIS),
-                check_vma=False,
-            )
-        )
-        out[impl] = np.asarray(jax.block_until_ready(fn(x)))
-    want = np.broadcast_to(x.sum(axis=0), shape)
-    return {
-        "ring_equals_portable": (
-            bool(np.array_equal(out["pallas"], out["portable"]))
-            if on_tpu
-            else "not run: no TPU"
-        ),
-        "portable_equals_sum": bool(np.array_equal(out["portable"], want)),
-    }
-
-
-def replicated_outputs_match(tier, checks) -> bool:
-    """One batch through the mesh index twice: owner-sharded outputs
-    (the default, nothing crosses devices) and replicated outputs,
-    whose hit rows the selected gather (the ring on TPU) combines."""
-    import numpy as np
-
-    from sbeacon_tpu.ops.kernel import encode_queries
-    from sbeacon_tpu.telemetry import device_warmup_phase
-
-    index = tier._ready()[0]
-    c = next(c for c in checks if len(c.datasets) > 1 and c.alt == "N")
-    spec = query_spec(c.payload())
-    sids = list(range(index.n_shards))
-    out = []
-    # a program of its own, compiled here on purpose
-    with device_warmup_phase():
-        for owner_outputs in (True, False):
-            out.append(
-                index.run_mesh_queries(
-                    encode_queries([spec] * len(sids), shard_ids=sids),
-                    owner_outputs=owner_outputs,
-                )
-            )
-    fields = ("exists", "call_count", "n_variants", "all_alleles_count",
-              "n_matched", "overflow", "rows")
-    return all(
-        np.array_equal(getattr(out[0], f), getattr(out[1], f))
-        for f in fields
-    ) and bool(out[0].n_matched.sum())
-
-
-def pod_tier_phase(engine, config, checks, reference, stage) -> dict:
-    """The pod tier on the same engine: DistributedEngine(local=...) ->
-    MeshDispatchTier -> MeshFusedIndex.run_mesh_queries, answers held to
-    the same reference. (The CLI only builds the coordinator when
-    --worker is given, so a worker-less host reaches this tier through
-    the engine, not through HTTP.)"""
-    from sbeacon_tpu.ops import gather_kernel
-    from sbeacon_tpu.parallel.dispatch import DistributedEngine
-    from sbeacon_tpu.telemetry import flight_recorder
-
-    dist = DistributedEngine([], local=engine, config=config)
-    try:
-        with stage("pod_warmup"):
-            n_warm = dist.mesh_tier.warmup()
-        require(n_warm > 0, "pod tier warmed no program")
-        before = flight_recorder.launches_by_family()
-        mid0 = flight_recorder.mid_request_compiles()
-        n = 0
-        with stage("pod_queries"):
-            for c in checks:
-                if len(c.datasets) < 2:
-                    continue
-                # the response cache would answer a query the plain
-                # server already served; the pod tier must do the work
-                payload = dataclasses.replace(
-                    c.payload(), no_response_cache=True
-                )
-                diff = same_answer(
-                    dist.search(payload), reference.responses(c.payload())
-                )
-                require(diff is None, f"pod tier {c.name}: {diff}")
-                n += 1
-        after = flight_recorder.launches_by_family()
-        stats = dist.dispatch_stats()
-        mesh_launches = sum(
-            after.get(f, 0) - before.get(f, 0)
-            for f in ("mesh_sliced", "mesh_replicated")
-        )
-        require(stats["mesh_dispatches"] > 0, "pod tier served no query")
-        require(mesh_launches > 0, "pod tier launched no mesh program")
-        require(stats["mesh_fallbacks"] == 0, "mesh.fallbacks != 0")
-        require(
-            flight_recorder.mid_request_compiles() == mid0,
-            "pod tier compiled a program inside a query",
-        )
-        tier = dist.mesh_tier.stats()
-        with stage("pod_gather"):
-            gather_equal = replicated_outputs_match(dist.mesh_tier, checks)
-        require(
-            gather_equal,
-            "a launch combined by the gather differs from owner-sharded",
-        )
-        return {
-            "parity": f"{n}/{n}",
-            "programs_warmed": n_warm,
-            "mesh_dispatches": stats["mesh_dispatches"],
-            "mesh_launches": mesh_launches,
-            "mesh_fallbacks": stats["mesh_fallbacks"],
-            "mesh_refusals": stats["mesh_refusals"],
-            "shards": tier["shards"],
-            "devices": tier["devices"],
-            # owner-sharded outputs (the default) combine nothing across
-            # devices; the gather below is what a replicated-output
-            # launch would trace with
-            "outputs": "owner_sharded",
-            "gather_impl_selected": gather_kernel.default_impl(),
-            "gather_launch_equals_owner_sharded": gather_equal,
-        }
-    finally:
-        dist.close()
-
-
 # -- the run ------------------------------------------------------------------
 
 
@@ -1277,7 +1126,6 @@ def check_server_surfaces(run: Run, n_dev: int, host_rows_expected: int):
     launches = metrics["device"]["launches"]
     fallbacks = {
         "device.fallbacks": metrics["device"]["fallbacks"],
-        "mesh.fallbacks": metrics["mesh"]["fallbacks"],
         "ingest.native_fallbacks": metrics["ingest"]["native_fallbacks"],
     }
     summary["launches"] = launches
@@ -1535,20 +1383,6 @@ def check_plane_programs(run: Run, devices) -> None:
     print(json.dumps({"plane_programs": report}), file=sys.stderr, flush=True)
 
 
-def check_multichip(run: Run, n_dev: int, platform: str) -> None:
-    with run.stage("ring_check"):
-        ring = ring_matches_portable(n_dev, platform == "tpu")
-    run.summary["ring"] = ring
-    require(ring["portable_equals_sum"], "the portable combine is wrong")
-    require(
-        ring["ring_equals_portable"] is not False,
-        "the Pallas ring differs from the portable combine",
-    )
-    run.summary["pod_tier"] = pod_tier_phase(
-        run.engine, run.config, run.checks, run.reference, run.stage
-    )
-
-
 def shut_down(run: Run) -> None:
     with run.stage("shutdown"):
         if run.client is not None:
@@ -1633,8 +1467,6 @@ def run_smoke(args, stage: Stages) -> dict:
         check_device_memory(run, devices, status)
         with stage("plane_programs"):
             check_plane_programs(run, devices)
-        if n_dev > 1:
-            check_multichip(run, n_dev, platform)
     finally:
         shut_down(run)
 

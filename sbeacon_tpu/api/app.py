@@ -332,8 +332,8 @@ class BeaconApp:
         # documents aggregated by (query-shape, plan-shape) and served
         # at /ops/plans, with the drift sentinel's observation window
         # tied to the canary interval — the prober's round loop rolls
-        # the window, so a dominant-shape flip (mesh quietly refusing
-        # planes, L0 coverage collapsing to tail walks) is diagnosed
+        # the window, so a dominant-shape flip (the mesh stack going
+        # stale, L0 coverage collapsing to tail walks) is diagnosed
         # within one canary round even on a coordinator with no
         # organic traffic
         self.plans = PlanStore(
@@ -1147,11 +1147,6 @@ class BeaconApp:
                 "unavailableDatasets": engine.unavailable_datasets(),
                 "workers": engine.worker_stats(),
             }
-            # which dispatch tier serves pod-local dataset groups (and
-            # how often it has fallen back to the scatter)
-            tier = getattr(engine, "mesh_tier", None)
-            if tier is not None:
-                routing["meshTier"] = tier.stats()
         batcher = getattr(local, "_batcher", None)
         occ = batcher.occupancy() if batcher is not None else {}
         queues = {
@@ -1298,8 +1293,8 @@ class BeaconApp:
         """The device-plane flight recorder's read surface (ISSUE 14):
         the launch ring summary (padding waste by family/tier,
         evaluated pairs, per-launch records), the compile cache vs the
-        warmup shape set, the HBM plane ledger, and the fused/mesh
-        stack states. Every piece is a lock-free snapshot (the
+        warmup shape set, the HBM plane ledger, and the fused
+        stack's state. Every piece is a lock-free snapshot (the
         recorder's own short lock, try-lock on the engine ledger) —
         this surface must answer DURING an in-flight stack rebuild,
         the same discipline as ``/ops/digest``."""
@@ -1323,9 +1318,6 @@ class BeaconApp:
         fused = getattr(local, "fused_stack_status", None)
         if callable(fused):
             stacks["fused"] = fused()
-        tier = getattr(engine, "mesh_tier", None)
-        if tier is not None:
-            stacks["meshTier"] = tier.stats()
         doc["stacks"] = stacks
         doc["time"] = time.time()
         return doc

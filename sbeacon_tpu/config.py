@@ -17,9 +17,9 @@ import os
 from pathlib import Path
 
 #: THE falsy spellings for boolean env knobs — ``from_env`` and every
-#: module that reads a BEACON_* flag directly (parallel/mesh.py's
-#: BEACON_MESH_SLICE default) share this one set, so an env value can
-#: never mean "off" to one reader and "on" to another
+#: module that reads a BEACON_* flag directly (telemetry.py's recorder
+#: switches) share this one set, so an env value can never mean "off"
+#: to one reader and "on" to another
 ENV_OFF = ("0", "false", "no", "off")
 
 
@@ -112,44 +112,10 @@ class EngineConfig:
     # dataset-sharded stack with psum fan-in (parallel/mesh.py) instead
     # of per-shard thread scatter; single-device falls back to scatter
     use_mesh: bool = True
-    # pod-local SPMD dispatch (parallel/dispatch.py MeshDispatchTier):
-    # a DistributedEngine with a local engine consults the tier per
-    # query — dataset groups resolvable on the local device mesh ride
-    # ONE compiled launch (mesh-sharded fused index, on-device fan-in
-    # + hit-row gather) instead of the thread/HTTP scatter.
-    # mesh_min_shards is the smallest per-query target count worth the
-    # mesh path (below it, per-shard dispatch is already one launch).
-    mesh_dispatch: bool = True
-    mesh_min_shards: int = 2
-    # per-device query-batch slicing on the mesh tier (ISSUE 13): the
-    # encoded batch is sharded by owning device (owner-sorted permute,
-    # per-device counts padded to a shared tier) so each device
-    # evaluates only the queries targeting its shards — ~1/n_dev the
-    # per-device work — instead of the full replicated batch masked by
-    # ownership. Off restores the replicated layout.
-    mesh_slice: bool = True
-    # owner-sharded mesh outputs (ISSUE 17, the output diet): under
-    # the sliced layout every query is answered by exactly ONE owning
-    # device, so the launch returns its outputs owner-sharded
-    # (out_specs P('d')) — no psum fan-in, no ring row-gather, and the
-    # fetch pulls each owner's real rows directly instead of one
-    # full-size replicated buffer (~1/n_dev the fetched bytes). Off
-    # restores the replicated-output reassembly. No effect on the
-    # replicated batch layout (mesh_slice off), which genuinely needs
-    # the cross-device combine.
-    mesh_owner_outputs: bool = True
-    # stack the genotype planes with their datasets on the mesh tier
-    # when every shard has them and the per-device slice fits the
-    # plane_hbm_budget_gb headroom: selected-samples / sample-
-    # extraction shapes then ride the same single launch (per-query
-    # sample masks reduced on the owning device) instead of falling
-    # back to per-dataset dispatch.
-    mesh_planes: bool = True
     ingest_shard_bytes: int = 64 * 1024 * 1024
     ingest_workers: int = 8
     max_response_inline_bytes: int = 300 * 1024  # performQuery spill threshold
     request_timeout_s: float = 600.0  # variantutils REQUEST_TIMEOUT
-    mesh_axis: str = "d"
     use_tpu: bool = True
     # serving micro-batcher (SURVEY.md §7): with wait=0 nobody waits
     # for company, and batches form from requests queuing behind a
@@ -620,24 +586,6 @@ class BeaconConfig:
         if "BEACON_USE_MESH" in env:
             eng_over["use_mesh"] = (
                 env["BEACON_USE_MESH"].lower() not in _off
-            )
-        if "BEACON_MESH_DISPATCH" in env:
-            eng_over["mesh_dispatch"] = (
-                env["BEACON_MESH_DISPATCH"].lower() not in _off
-            )
-        if "BEACON_MESH_MIN_SHARDS" in env:
-            eng_over["mesh_min_shards"] = int(env["BEACON_MESH_MIN_SHARDS"])
-        if "BEACON_MESH_SLICE" in env:
-            eng_over["mesh_slice"] = (
-                env["BEACON_MESH_SLICE"].lower() not in _off
-            )
-        if "BEACON_MESH_OWNER_OUTPUTS" in env:
-            eng_over["mesh_owner_outputs"] = (
-                env["BEACON_MESH_OWNER_OUTPUTS"].lower() not in _off
-            )
-        if "BEACON_MESH_PLANES" in env:
-            eng_over["mesh_planes"] = (
-                env["BEACON_MESH_PLANES"].lower() not in _off
             )
         if "BEACON_PLANE_HBM_BUDGET_GB" in env:
             eng_over["plane_hbm_budget_gb"] = float(

@@ -875,8 +875,7 @@ class VariantEngine:
         self._keep_warm = False
         # token -> (owner chip, bytes) reserved for an in-flight plane
         # upload: counts against the OWNER's plane_hbm_budget_gb until
-        # the planes are published. Owner None = an external stack that
-        # lies on every chip (register_plane_bytes) and counts on each.
+        # the planes are published.
         self._plane_reserved: dict = {}
         # placement: key -> [owner device, bytes placed there]. Every
         # published key has ONE owner among jax.local_devices(): its
@@ -1329,8 +1328,8 @@ class VariantEngine:
     def _rebuild_serving_state_locked(self) -> None:
         """Recompute the serving list + all three fingerprint views
         (held under ``_mesh_lock``): the base fingerprint (base shards
-        only — the staleness signal the fused/mesh stacks and the pod
-        dispatch tier key on, STABLE across delta publishes), the
+        only — the staleness signal the fused/mesh stacks key on,
+        STABLE across delta publishes), the
         per-dataset components (response-cache keys), and the full
         fingerprint (base + delta tail — the freshness signal async-job
         keys and worker ``/datasets`` replica grouping need). All are
@@ -2003,9 +2002,7 @@ class VariantEngine:
         returned dict (sub-threshold residue, racing republishes via
         the shard-identity check, host-only wildcard-ref semantics) or
         marked ``None`` (overflow) — charge ``delta_shards`` here, on
-        the calling request's ambient context. Both dispatch tiers
-        (``_search`` and ``MeshDispatchTier.search``) consult this one
-        seam, so the charging rule cannot diverge between them.
+        the calling request's ambient context.
 
         ``tail_targets`` is ``[((dataset, vcf_label), shard), ...]``
         with the serve-list ``vcf#d<epoch>`` labels."""
@@ -2293,37 +2290,26 @@ class VariantEngine:
         # whose FIRST rows arrived as deltas is already routable
         return sorted({ds for ds, _vcf, _t in self._serve_list})
 
-    @property
-    def batcher(self):
-        """The serving micro-batcher (None when microbatch is off) —
-        the pod dispatch tier submits through it so cross-request
-        coalescing, the launch/fetch pipeline, and deadline-bounded
-        waits apply to mesh launches exactly as to per-shard ones."""
-        return self._batcher
-
-    def shard_snapshot(self) -> list[tuple[tuple[str, str], object]]:
-        """Sorted ``[((dataset_id, vcf_location), shard), ...]`` under
-        the publish lock — the pod dispatch tier builds its mesh stack
-        from this instead of iterating ``_indexes`` mid-ingest."""
-        with self._mesh_lock:
-            return [(k, v[0]) for k, v in sorted(self._indexes.items())]
-
     def index_snapshot(
         self,
     ) -> list[tuple[tuple[str, str], object, object]]:
         """Sorted ``[((dataset_id, vcf_location), shard, plane_index),
-        ...]`` under the publish lock — :meth:`shard_snapshot` plus the
-        device plane index per key, so the pod dispatch tier's plane-
-        stacked build pairs each shard with the exact planes of the
-        same publish (never a concurrently re-ingested replacement)."""
+        ...]`` under the publish lock: each shard with the exact planes
+        of the same publish (never a concurrently re-ingested
+        replacement)."""
         with self._mesh_lock:
             return [
                 (k, v[0], v[2]) for k, v in sorted(self._indexes.items())
             ]
 
-    def _plane_bytes_by_chip_locked(self) -> tuple[dict, int]:
-        """({chip: plane bytes resident or reserved on it}, bytes an
-        external stack holds on EVERY chip), under the publish lock."""
+    def _plane_hbm_resident_locked(self, owner=None) -> int:
+        """Plane bytes resident or reserved ON ONE CHIP, under the
+        publish lock: ``owner``'s, or with None the fullest chip's
+        (what a stack that lies on every chip has to fit beside) — THE
+        one summation the budget gates share (the upload gate and
+        ``_mesh_ready``'s stack gate), so the accounting can never
+        disagree between them. The budget is a chip's: four planes of
+        10 GB pass on four chips, two on one chip do not."""
         from .ops.plane_kernel import chip_of
 
         by_chip: dict[int, int] = {}
@@ -2331,44 +2317,18 @@ class VariantEngine:
             if p is not None:
                 c = chip_of(p.device)
                 by_chip[c] = by_chip.get(c, 0) + p.nbytes_hbm()
-        everywhere = 0
-        for owner, nbytes in self._plane_reserved.values():
-            if owner is None:
-                everywhere += nbytes
-            else:
-                c = chip_of(owner)
-                by_chip[c] = by_chip.get(c, 0) + nbytes
-        return by_chip, everywhere
-
-    def _plane_hbm_resident_locked(self, owner=None) -> int:
-        """Plane bytes resident or reserved ON ONE CHIP, under the
-        publish lock: ``owner``'s, or with None the fullest chip's
-        (what a stack that lies on every chip has to fit beside) — THE
-        one summation all three budget gates share (the upload gate,
-        ``_mesh_ready``'s stack gate, and the dispatch tier via
-        :meth:`plane_hbm_resident`), so the accounting can never
-        disagree between them. The budget is a chip's: four planes of
-        10 GB pass on four chips, two on one chip do not."""
-        from .ops.plane_kernel import chip_of
-
-        by_chip, everywhere = self._plane_bytes_by_chip_locked()
+        for reserved_on, nbytes in self._plane_reserved.values():
+            c = chip_of(reserved_on)
+            by_chip[c] = by_chip.get(c, 0) + nbytes
         if owner is None:
-            return max(by_chip.values(), default=0) + everywhere
-        return by_chip.get(chip_of(owner), 0) + everywhere
-
-    def plane_hbm_resident(self) -> int:
-        """Bytes of HBM already committed to genotype planes on the
-        fullest chip (resident plane indexes + in-flight reservations)
-        — the dispatch tier's plane-stack budget gates against this,
-        the same accounting ``_mesh_ready``'s own gate applies."""
-        with self._mesh_lock:
-            return self._plane_hbm_resident_locked()
+            return max(by_chip.values(), default=0)
+        return by_chip.get(chip_of(owner), 0)
 
     def plane_ledger(self) -> dict:
         """The HBM plane-budget ledger as a LOCK-FREE snapshot (the
         ``/device/status`` surface, ISSUE 14): resident per-dataset
-        plane bytes, standing reservations (in-flight uploads + the
-        mesh tier's stacked planes) with their token count, and the
+        plane bytes, standing reservations (in-flight uploads) with
+        their token count, and the
         budget headroom. The publish lock is only TRIED — when a stack
         rebuild holds it, the last computed snapshot serves with
         ``stale: true`` (the same answer-while-rebuilding discipline
@@ -2487,42 +2447,6 @@ class VariantEngine:
             doc["ageS"] = round(time.time() - built_at, 1)
         return doc
 
-    def register_plane_bytes(self, token, nbytes: int) -> None:
-        """Account an EXTERNAL standing plane allocation (the mesh
-        dispatch tier's group-stacked planes) against the plane HBM
-        budget: it rides the same reservation ledger the per-dataset
-        upload gate sums, so a post-build dataset upload cannot
-        overcommit the device by the stack's size (the accounting is
-        bidirectional — the tier's gate reads resident+reserved via
-        :meth:`plane_hbm_resident`, and uploads see the tier's stack
-        here). ``nbytes <= 0`` releases; re-registering the same token
-        replaces (the tier's rebuild semantics)."""
-        with self._mesh_lock:
-            if nbytes > 0:
-                self._plane_reserved[token] = (None, int(nbytes))
-            else:
-                self._plane_reserved.pop(token, None)
-
-    def try_reserve_plane_bytes(
-        self, token, nbytes: int, budget: float
-    ) -> bool:
-        """Atomic check-and-reserve for an external plane allocation:
-        headroom test and ledger write under ONE publish-lock hold, the
-        same discipline the per-dataset upload gate applies — a
-        two-step read-compare-register leaves a window in which a
-        concurrent upload's gate sees neither party's bytes and both
-        overcommit. The token's own previous reservation is excluded
-        from the headroom (it is being replaced by ``nbytes``, which
-        should already include whatever of it still stands). Returns
-        False (ledger untouched) when ``nbytes`` does not fit."""
-        with self._mesh_lock:
-            prev = self._plane_reserved.get(token, (None, 0))[1]
-            used = self._plane_hbm_resident_locked() - prev
-            if used + nbytes > budget:
-                return False
-            self._plane_reserved[token] = (None, int(nbytes))
-            return True
-
     def index_fingerprint(self) -> str:
         """FULL identity of the served data set — base shards AND the
         standing delta tail. Folds into async-query job keys and the
@@ -2534,8 +2458,8 @@ class VariantEngine:
     def base_fingerprint(self) -> str:
         """Identity of the BASE shards only — stable across delta
         publishes, bumped by compaction/re-ingest. This is the
-        staleness signal the warm dispatch stacks (engine fused/mesh
-        state, ``parallel.dispatch.MeshDispatchTier``) key on: between
+        staleness signal the warm dispatch stacks (the fused and the
+        mesh state) key on: between
         compactions they keep serving base rows and only the delta
         tail pays per-shard dispatch."""
         return self._base_fingerprint

@@ -31,10 +31,6 @@ Instrumented sites:
   ``match``. ``latency`` models a slow shaper (contended dispatch),
   ``error`` fails admission outright — both hit BEFORE any slot is
   taken, so no capacity leaks.
-- ``mesh.dispatch`` — the pod-local mesh tier's single-launch path
-  (``parallel/dispatch.py MeshDispatchTier.search``); an ``error``
-  here exercises the fall-back-once-to-scatter contract
-  (``mesh.fallbacks`` counter + ``mesh.fallback`` journal event).
 - ``compaction.fold`` — the background delta compactor
   (``ingest/service.py DeltaCompactor._fold``). Hit TWICE per fold
   with ``detail`` ``"<dataset>:<vcf>:merge"`` (before the merge/

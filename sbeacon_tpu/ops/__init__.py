@@ -54,8 +54,6 @@ def run_queries_auto(
     window_cap: int = 2048,
     record_cap: int = 1024,
     async_fetch: bool = False,
-    sample_masks=None,
-    mask_counts=None,
 ):
     """Dispatch a query batch to whichever kernel the index was built
     for — one call site for the engine and the micro-batcher.
@@ -64,40 +62,12 @@ def run_queries_auto(
     QueryResults`` immediately after the launch is dispatched so the
     caller can overlap host work with device execution (the scatter
     tile kernels execute synchronously and return already-fetched
-    results behind the same contract).
-
-    ``sample_masks``/``mask_counts`` arm the mesh tier's genotype-plane
-    program (per-query sample masks reduced on the owning device) and
-    are only meaningful for a plane-stacked MeshFusedIndex — passing
-    them for any other index family is a caller bug and raises."""
+    results behind the same contract)."""
     if isinstance(index, ScatterDeviceIndex):
-        if sample_masks is not None:
-            raise ValueError(
-                "sample_masks only ride the mesh plane program"
-            )
         res = run_queries_scattered(
             index, queries, window_cap=window_cap, record_cap=record_cap
         )
         return ReadyQueryResults(res) if async_fetch else res
-    # mesh-sharded fused index (parallel.mesh.MeshFusedIndex): duck-typed
-    # on its dispatch method so ops never imports parallel (no cycle) —
-    # the micro-batcher coalesces onto it exactly like a FusedDeviceIndex
-    mesh_run = getattr(index, "run_mesh_queries", None)
-    if mesh_run is not None:
-        kwargs = {}
-        if sample_masks is not None:
-            kwargs.update(
-                sample_masks=sample_masks, mask_counts=mask_counts
-            )
-        return mesh_run(
-            queries,
-            window_cap=window_cap,
-            record_cap=record_cap,
-            async_fetch=async_fetch,
-            **kwargs,
-        )
-    if sample_masks is not None:
-        raise ValueError("sample_masks only ride the mesh plane program")
     return run_queries(
         index,
         queries,
@@ -111,8 +81,8 @@ def launch_capacity(index, n_specs: int) -> int:
     """How many query specs ride a launch whose first entry brings
     ``n_specs``, at the padded shape that entry alone already pays for:
     the scattered kernel's chunk (every tier of the batch pads to whole
-    chunks of it), else the batch-ladder rung ``run_queries`` and the
-    mesh tier pad to. The same per-family choice ``run_queries_auto``
+    chunks of it), else the batch-ladder rung ``run_queries`` pads to.
+    The same per-family choice ``run_queries_auto``
     makes, asked before the launch: the micro-batcher fills a launch
     this far and no further, so a batched launch costs the device what
     a launch of its head entry costs and runs a shape warm-up

@@ -547,13 +547,6 @@ class QueryResults:
     n_matched: np.ndarray  # int32[B]
     overflow: np.ndarray  # bool[B] — window_cap exceeded, host fallback
     rows: np.ndarray  # int32[B, record_cap] global row ids, -1 padded
-    # genotype-plane outputs (mesh plane program only; None on every
-    # match-only path): per-row masked popcounts aligned with ``rows``
-    # and the grp>=k0 sample-hit OR — the materialize_response
-    # ``fused=(pc_call, pc_tok, or_words)`` triple, per query
-    pc_call: np.ndarray | None = None  # int32[B, record_cap]
-    pc_tok: np.ndarray | None = None  # int32[B, record_cap]
-    or_words: np.ndarray | None = None  # int32[B, plane_words]
 
 
 def _bisect(pos, target, lo0, hi0, n_iters, *, upper: bool):
@@ -781,10 +774,9 @@ class TierLadder:
     the L0 mini-index proved finer rungs pay for themselves: the extra
     compiled programs are warmed off the request path and the padding
     waste collapses. This class promotes that ladder to a single
-    process-wide source of truth — ``run_queries`` batch padding, the
-    mesh tier's replicated batch padding and per-device slice tiers,
-    and the engine/dispatch warmup loops all read the SAME instance,
-    so a rung can never exist for serving without being pre-compiled
+    process-wide source of truth — ``run_queries`` batch padding and
+    the engine's warmup loops read the SAME instance, so a rung can
+    never exist for serving without being pre-compiled
     (``tools/check_launch_recording.py`` lints the parity).
 
     Rungs are fit to measured traffic: :meth:`fit` reads the
@@ -796,10 +788,6 @@ class TierLadder:
     #: the L0-proven default (PR 15): fills the 8->64 gap where the
     #: recorder saw the worst serving-tier waste
     DEFAULT_RUNGS = (8, 16, 32, 64, 512, 2048)
-    #: per-device slice rungs at or under this are pre-compiled by the
-    #: mesh tier's warmup; larger rungs are bulk shapes that compile at
-    #: first use like the legacy ladder's top tiers
-    MESH_WARM_CAP = 64
     #: a (family, tier) histogram cell wasting more than this fraction
     #: of its padded lanes earns a finer rung below it
     WASTE_SPLIT = 0.5
@@ -807,13 +795,11 @@ class TierLadder:
     #: is a compiled program per family — warmup time and program
     #: cache both scale with it)
     MAX_RUNGS = 12
-    #: families whose recorded padding carries the n_dev slice
-    #: replication factor (``specs_padded = c_slot * n_dev``) — their
-    #: waste measures batch SKEW across owning devices, which a finer
-    #: batch rung cannot fix (the slice ladder already floors at 1), so
-    #: fit() must not chase it; left unchecked it splits every warmup's
-    #: own skewed mesh launches into ever-smaller rungs
-    FIT_SKIP_FAMILIES = frozenset({"mesh_sliced", "plane"})
+    #: families whose recorded padding is not a batch rung's: a
+    #: ``plane`` launch's tier is its launch group's slot count and its
+    #: padding the members a request did not ask, which a finer batch
+    #: rung cannot fix, so fit() must not chase it
+    FIT_SKIP_FAMILIES = frozenset({"plane"})
 
     __slots__ = ("rungs", "source")
 
@@ -828,22 +814,6 @@ class TierLadder:
         """Smallest rung holding a batch of ``b``; None past the top
         rung (bulk batches run at their exact size)."""
         return next((t for t in self.rungs if b <= t), None)
-
-    @property
-    def slice_rungs(self) -> tuple:
-        """Per-device slice shape tiers: the ladder plus a 1-floor —
-        the whole point of slicing is that each device sees
-        ~batch/n_dev queries, so padding every slice back up to the
-        8-floor would erase the win for the common pod fan-out."""
-        return self.rungs if self.rungs[0] == 1 else (1,) + self.rungs
-
-    def mesh_warm_rungs(self) -> tuple:
-        """The slice rungs MeshDispatchTier pre-compiles (all rungs <=
-        MESH_WARM_CAP; larger slices are bulk shapes outside the
-        serving path, same exposure as the legacy ladder)."""
-        return tuple(
-            t for t in self.slice_rungs if t <= self.MESH_WARM_CAP
-        )
 
     @classmethod
     def from_env(cls, env=None) -> "TierLadder":
@@ -871,11 +841,11 @@ class TierLadder:
         than ``WASTE_SPLIT`` of its padded lanes earns the half-rung
         below its tier (repeatedly halving would chase noise; one
         split per observed-bad rung per fit keeps the ladder bounded
-        and the warmup cheap). Slice-replicated families
-        (``FIT_SKIP_FAMILIES``) and splits below the ladder floor are
-        ignored, so successive fits converge — warming the fitted
-        ladder never creates cells that would re-split it. Rung count
-        is capped at MAX_RUNGS, keeping the worst offenders."""
+        and the warmup cheap). Families padded by something other
+        than a rung (``FIT_SKIP_FAMILIES``) and splits below the ladder
+        floor are ignored, so successive fits converge — warming the
+        fitted ladder never creates cells that would re-split it. Rung
+        count is capped at MAX_RUNGS, keeping the worst offenders."""
         splits = []
         for (family, tier), (real, padded) in pad_tier_hist.items():
             tier = int(tier)
@@ -913,8 +883,7 @@ _ACTIVE_LADDER: TierLadder | None = None
 
 def active_ladder() -> TierLadder:
     """The process tier ladder — THE single source every padding seam
-    (run_queries, the mesh batch/slice tiers, dispatch fan-out padding,
-    and all warmup loops) consults."""
+    (run_queries and all warmup loops) consults."""
     global _ACTIVE_LADDER
     with _LADDER_LOCK:
         if _ACTIVE_LADDER is None:
@@ -982,11 +951,6 @@ class PendingQueryResults:
             )
             self._out = None  # free the device buffers promptly
             b = self._b
-            extra = {
-                k: np.asarray(out[k])[:b]
-                for k in ("pc_call", "pc_tok", "or_words")
-                if k in out
-            }
             return QueryResults(
                 exists=np.asarray(out["exists"])[:b],
                 call_count=np.asarray(out["call_count"])[:b],
@@ -995,7 +959,6 @@ class PendingQueryResults:
                 n_matched=np.asarray(out["n_matched"])[:b],
                 overflow=np.asarray(out["overflow"])[:b],
                 rows=np.asarray(out["rows"])[:b],
-                **extra,
             )
 
 
@@ -1017,10 +980,8 @@ def padded_batch(dindex, b: int) -> int:
     the smallest rung holding it, on the index's own ladder where it
     carries one (``batch_tiers``: the L0 mini-indexes) and on the
     process ladder otherwise; past the top rung, and for an empty
-    batch, ``b`` itself. ``run_queries`` and the mesh tier's replicated
-    layout pad to it (the sliced layout pads each device's slice to a
-    slice rung, never past it), and the micro-batcher fills a launch no
-    further (``ops.launch_capacity``)."""
+    batch, ``b`` itself. ``run_queries`` pads to it, and the
+    micro-batcher fills a launch no further (``ops.launch_capacity``)."""
     if not b:
         return 0
     tiers = getattr(dindex, "batch_tiers", None)

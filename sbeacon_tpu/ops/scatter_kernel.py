@@ -598,7 +598,9 @@ def _selected_batch(
       block of rows whatever D, R and the width; a slot that matched
       nothing, a padding slot among them, gathers nothing),
     - the sample-hit OR runs over the exact ``grp >= k0`` row subset
-      via the same segmented scans as ``parallel.mesh._plane_reduce``
+      via two segmented scans over the matched lanes (a running sum
+      of rc against its value at each record's first lane, forward
+      and flipped)
       (k0 = first record with positive cumulative rc; ploidy>2
       overflow extras can never flip rc positivity, a saturated 2-bit
       plane cell popcounts >= 2, so the device subset equals the
@@ -697,8 +699,10 @@ def _selected_batch(
         rc = ac_r
     rc = rc * matched
 
-    # or_sel == (record index >= k0) for matched lanes — the segmented
-    # forward/backward scans from parallel.mesh._plane_reduce
+    # or_sel == (record index >= k0) for matched lanes: a record is
+    # selected when rc was positive before it (base > 0) or anywhere
+    # inside it, read from a cumsum less its value at the record's first
+    # lane (carried along by cummax), forward and over the flipped lanes
     rec_eff = jnp.where(matched, rec_r, jnp.int32(-2))
     first = matched & jnp.concatenate(
         [

@@ -44,8 +44,6 @@ import threading
 import time
 import urllib.error
 import concurrent.futures as futures_mod
-
-import numpy as np
 from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -80,10 +78,8 @@ from ..telemetry import (
     annotate,
     charge_cost,
     current_context,
-    device_warmup_phase,
     new_span_id,
     publish_event,
-    record_device_fallback,
     request_context,
     sanitize_trace_id,
 )
@@ -534,8 +530,7 @@ def ops_digest(engine, extras: dict | None = None) -> dict:
     stack rebuild holds the publish lock. ``extras`` lets an embedded
     coordinator add its app-tier signals (SLO breaches, slow-query
     count, top cost tenants); a bare worker host serves the engine
-    fields alone. This is also the exchange payload ROADMAP item 4's
-    cross-coordinator quota convergence will ride."""
+    fields alone."""
     base_fp = getattr(engine, "base_fingerprint", None)
     ds_fps_fn = getattr(engine, "dataset_fingerprints", None)
     delta_stats = getattr(engine, "delta_stats", None)
@@ -624,30 +619,6 @@ def register_dispatch_metrics(registry, supplier) -> None:
         "routing.rediscoveries",
         "background route-rediscovery passes run to heal dead routes",
         fn=field("rediscoveries"),
-    )
-    registry.counter(
-        "mesh.dispatches",
-        "k-shard queries answered by the pod-local single-launch tier",
-        fn=field("mesh_dispatches"),
-    )
-    registry.counter(
-        "mesh.fallbacks",
-        "mesh-tier failures that fell back to the scatter path",
-        fn=field("mesh_fallbacks"),
-    )
-    registry.counter(
-        "mesh.gather_rows",
-        "hit rows gathered on-device by the mesh tier's row gather",
-        fn=field("mesh_gather_rows"),
-    )
-    registry.counter(
-        "mesh.refusals",
-        "queries the mesh tier declined, by reason (planes = "
-        "plane-reading shape the stack cannot serve, stale = publish "
-        "outran the stack, min_shards = too few local targets, "
-        "unbuilt = no stack yet)",
-        label="reason",
-        fn=lambda: supplier().get("mesh_refusals", {}) or {},
     )
     # fleet federation (ISSUE 12): the digest-poll plane's own series
     registry.counter(
@@ -1325,724 +1296,6 @@ class WorkerError(RuntimeError):
     pass
 
 
-class MeshDispatchTier:
-    """Pod-local single-launch dispatch over a mesh-sharded fused index.
-
-    The reference answers a k-dataset query with a 500-thread Lambda
-    scatter and a DynamoDB counter fan-in; our HTTP tier mirrors that
-    shape — k RTTs — even when the k shards are chips in one pod. This
-    tier collapses that case: the local engine's shards stack into a
-    :class:`parallel.mesh.MeshFusedIndex` (dataset groups sharded over
-    ``jax.make_mesh`` with NamedSharding), and a query whose datasets
-    all live on the mesh costs ONE compiled launch — boolean OR,
-    count/allele psum, and the record-granularity hit-row gather all
-    inside the program (Pallas async-remote-copy ring on TPU,
-    all_gather elsewhere). Queries ride the local engine's
-    MicroBatcher (``submit_many``), so coalescing across concurrent
-    requests and the launch/fetch pipeline apply unchanged, and the
-    batcher's deadline-bounded waits keep the resilience contract.
-
-    The tier is an *optimisation* the :class:`DistributedEngine`
-    consults per query: dataset groups it cannot resolve (not built
-    yet, stale after an ingest, plane-reading granularities, fewer than
-    ``min_shards`` targets) keep the existing local/pooled-HTTP paths,
-    and a mesh-path failure falls back to the scatter once and trips
-    the ``mesh.fallbacks`` counter.
-    """
-
-    #: LEGACY warm tiers, kept for back-compat introspection only:
-    #: :meth:`warmup` now pre-compiles every serving rung of the
-    #: process TierLadder (``kernel.active_ladder().mesh_warm_rungs``
-    #: — ISSUE 17), so the warm set and the slice-tier padding read
-    #: the same single source and a ladder edit cannot silently
-    #: reintroduce mid-request compiles (the warmup-ladder lint in
-    #: tools/check_launch_recording.py asserts the parity)
-    WARM_TIERS = (8, 64)
-
-    def __init__(
-        self,
-        engine,
-        *,
-        min_shards: int = 2,
-        axis: str = "d",
-        devices=None,
-    ):
-        self.engine = engine
-        self.min_shards = max(1, int(min_shards))
-        self.axis = axis
-        self._devices = devices
-        self._lock = threading.Lock()
-        # (MeshFusedIndex, {key: sid}, {key: shard}, {ds: [keys]}, fp,
-        #  {key: plane_index})
-        self._state: tuple | None = None
-        self._building = False
-        # fingerprint a build pass declined (too few shards / build
-        # failure): don't spawn a rebuild thread per query for an
-        # index set that cannot produce a tier
-        self._skip_fp: str | None = None
-        self._dispatches = 0
-        self._fallbacks = 0
-        self._gather_rows = 0
-        # why queries fell off the tier, by reason — the operator's
-        # answer to "the mesh dispatch rate dropped, what happened?"
-        # (mesh.refusals{reason} series): planes = plane-reading shape
-        # the stack cannot serve (no planes stacked / wildcard-ref
-        # host semantics), stale = built but a publish outran it,
-        # min_shards = too few local targets to beat per-shard
-        # dispatch, unbuilt = no stack yet (incl. <2 devices and
-        # declined builds)
-        self._refusals: dict[str, int] = {}
-        # close() raced against an in-flight background build: the
-        # build re-checks this before publishing/registering so a dead
-        # tier can never leave a phantom plane-byte reservation (or a
-        # resurrected state) behind
-        self._tier_closed = False
-        # wall time the serving state was published (stack age on the
-        # /device/status stacks surface)
-        self._built_at: float | None = None
-
-    # -- availability / build ----------------------------------------------
-
-    def available(self) -> bool:
-        """>=2 devices visible: a 1-device 'pod' would only re-spell the
-        fused single-device stack, which the engine already serves."""
-        try:
-            import jax
-
-            devs = self._devices if self._devices is not None else jax.devices()
-        except Exception:
-            return False
-        return len(devs) >= 2
-
-    def _snapshot(self):
-        """(keys, shards, planes_of) the stack would build from, via
-        the engine's locked snapshot (never iterating ``_indexes``
-        mid-ingest). ``planes_of`` maps keys to the per-dataset device
-        plane index of the SAME publish — materialisation's host/
-        device fallback for shapes the stacked planes cannot answer
-        exactly."""
-        snap = getattr(self.engine, "index_snapshot", None)
-        if snap is not None:
-            triples = snap()
-            return (
-                [k for k, _s, _p in triples],
-                [s for _k, s, _p in triples],
-                {k: p for k, _s, p in triples},
-            )
-        snap = getattr(self.engine, "shard_snapshot", None)
-        if snap is None:
-            return [], [], {}
-        pairs = snap()
-        return (
-            [k for k, _s in pairs],
-            [s for _k, s in pairs],
-            {},
-        )
-
-    def _base_fp(self) -> str:
-        """The BASE-shard fingerprint: stable across delta publishes
-        (only compaction/re-ingest bumps it), so a delta publish does
-        NOT cold-start this tier — the stack keeps serving base rows
-        and the delta tail is served per-shard in :meth:`search`.
-        Engines without a delta registry fall back to the full
-        fingerprint (identical staleness behaviour to before)."""
-        base = getattr(self.engine, "base_fingerprint", None)
-        if base is not None:
-            return base()
-        return self.engine.index_fingerprint()
-
-    def _ready(self, wait: bool = False):
-        """The current state, or None while unbuilt/stale (the caller
-        then keeps the scatter paths — freshness beats the mesh win).
-        A stale state arms a BACKGROUND rebuild; ``wait=True`` (warmup)
-        builds inline on the caller's clock."""
-        if not self.available():
-            return None
-        fp = self._base_fp()
-        while True:
-            with self._lock:
-                if self._tier_closed:
-                    return None
-                state = self._state
-                if state is not None and state[4] == fp:
-                    return state
-                if self._skip_fp == fp and not wait:
-                    return None
-                if not self._building:
-                    self._building = True
-                    break
-                if not wait:
-                    return None
-            # wait=True with a background build in flight: JOIN it
-            # instead of racing a duplicate full stack build (transient
-            # 2x device memory, doubled journal events), then re-check
-            time.sleep(0.05)
-        if wait:
-            return self._build(fp)
-        threading.Thread(
-            target=self._build, args=(fp,), name="mesh-tier-build",
-            daemon=True,
-        ).start()
-        return None
-
-    def _build(self, fp: str):
-        try:
-            from .mesh import MeshFusedIndex, make_mesh
-
-            keys, shards, planes_of = self._snapshot()
-            if len(keys) < self.min_shards:
-                with self._lock:
-                    self._skip_fp = fp
-                return None
-            mesh = make_mesh(devices=self._devices, axis=self.axis)
-            eng_cfg = getattr(self.engine.config, "engine", None)
-            reg = getattr(self.engine, "register_plane_bytes", None)
-            # the PREVIOUS stack's registered bytes: it keeps serving
-            # until the new state publishes, so it stays accounted
-            # through the build (and is what a failed build restores)
-            with self._lock:
-                prev_bytes = (
-                    getattr(self._state[0], "plane_bytes_device", 0)
-                    if self._state is not None
-                    else 0
-                )
-            # stack the genotype planes with their datasets when the
-            # knob allows, every shard has them, and the per-device
-            # slice fits the HBM headroom left by the resident
-            # per-dataset planes (the engine's own mesh gate, applied
-            # through the index's one-source-of-truth byte math)
-            with_planes = getattr(eng_cfg, "mesh_planes", True) and all(
-                s.gt_bits is not None for s in shards
-            )
-            if with_planes:
-                per_dev = MeshFusedIndex.plane_bytes_per_device(
-                    shards, n_dev=int(mesh.devices.size)
-                )
-                budget = (
-                    getattr(eng_cfg, "plane_hbm_budget_gb", 11.0) * 1e9
-                )
-                # ATOMIC check-and-reserve BEFORE the multi-second
-                # stack build (the engine's own upload-gate
-                # discipline): the headroom test and the ledger write
-                # happen under one lock hold, and the reservation
-                # covers the old still-serving stack PLUS the build in
-                # flight — a per-dataset plane upload admitted
-                # mid-build sees these bytes, so the two gates cannot
-                # both pass on the same headroom
-                reserve = getattr(
-                    self.engine, "try_reserve_plane_bytes", None
-                )
-                if reserve is not None:
-                    with_planes = reserve(
-                        self, prev_bytes + per_dev, budget
-                    )
-                else:
-                    resident = getattr(
-                        self.engine, "plane_hbm_resident", lambda: 0
-                    )()
-                    with_planes = per_dev + resident <= budget
-                if not with_planes:
-                    log.info(
-                        "mesh tier planes skipped: %d B/device does "
-                        "not fit the %.1f GB plane budget headroom",
-                        per_dev,
-                        budget / 1e9,
-                    )
-            index = MeshFusedIndex(
-                shards,
-                mesh,
-                axis=self.axis,
-                with_planes=with_planes,
-                slice_batch=getattr(eng_cfg, "mesh_slice", None),
-                owner_outputs=getattr(
-                    eng_cfg, "mesh_owner_outputs", None
-                ),
-            )
-            sid_of = {k: i for i, k in enumerate(keys)}
-            shard_of = dict(zip(keys, shards))
-            keys_by_ds: dict[str, list] = {}
-            for k in keys:
-                keys_by_ds.setdefault(k[0], []).append(k)
-            state = (index, sid_of, shard_of, keys_by_ds, fp, planes_of)
-            with self._lock:
-                if self._tier_closed:
-                    # close() won the race: discard the build outright
-                    if reg is not None:
-                        reg(self, 0)
-                    return None
-                self._state = state
-                self._built_at = time.time()
-            # settle the bidirectional budget accounting on the NEW
-            # stack alone (keyed on the tier, so this replaces the
-            # build-window reservation — and a plane-less rebuild
-            # releases the old stack's bytes); later per-dataset
-            # uploads then cannot overcommit the device by the stack
-            if reg is not None:
-                reg(self, index.plane_bytes_device)
-                with self._lock:
-                    raced_close = self._tier_closed
-                if raced_close:
-                    # close() landed between the publish above and the
-                    # settle: its release must win, not our registration
-                    reg(self, 0)
-                    return None
-            publish_event(
-                "mesh.tier_ready",
-                shards=len(keys),
-                devices=index.n_dev,
-                planes=index.has_planes,
-            )
-            log.info(
-                "mesh dispatch tier ready: %d shards over %d devices"
-                " (planes %s)",
-                len(keys),
-                index.n_dev,
-                "stacked" if index.has_planes else "off",
-            )
-            return state
-        except Exception:
-            record_device_fallback("mesh_tier_build")
-            log.exception("mesh dispatch tier build failed; scatter serves")
-            with self._lock:
-                self._skip_fp = fp
-            # roll the build-window plane reservation back to whatever
-            # stack is actually still serving (re-derived from state, so
-            # this is correct wherever in the build the failure landed)
-            reg = getattr(self.engine, "register_plane_bytes", None)
-            if reg is not None:
-                with self._lock:
-                    prev = (
-                        getattr(self._state[0], "plane_bytes_device", 0)
-                        if self._state is not None
-                        else 0
-                    )
-                reg(self, prev)
-            return None
-        finally:
-            with self._lock:
-                self._building = False
-
-    def close(self) -> None:
-        """Drop the tier's state and release its plane-stack bytes from
-        the engine's budget ledger — a discarded tier must not keep the
-        ledger over-counting (and the ledger's strong reference would
-        otherwise pin the stack's device arrays alive). The flag is set
-        BEFORE the release so an in-flight background build observes it
-        at its publish/settle re-checks and discards itself."""
-        with self._lock:
-            self._tier_closed = True
-            self._state = None
-        reg = getattr(self.engine, "register_plane_bytes", None)
-        if reg is not None:
-            reg(self, 0)
-
-    def warmup(self) -> int:
-        """Build inline and pre-compile the tier's batch-tier programs;
-        returns the program count (0 when the tier cannot engage).
-        Runs inside a flight-recorder warmup phase so the compile
-        tracker stamps these shapes as expected (ISSUE 14)."""
-        with device_warmup_phase():
-            return self._warmup()
-
-    def _warmup(self) -> int:
-        state = self._ready(wait=True)
-        if state is None:
-            return 0
-        from ..ops.kernel import QuerySpec, active_ladder, encode_queries
-
-        index = state[0]
-        eng = self.engine.config.engine
-        n = 0
-        spec = QuerySpec("1", 1, 1, 1, 2)
-        # the sliced layout keys programs on the PER-DEVICE slice tier:
-        # a single-hot-shard batch of t slices to C=t, while the common
-        # pod fan-out (<= one query per device) slices to C=1 (the
-        # spread batch) — warm EVERY serving rung of the process
-        # ladder so no coalesced burst pays a mid-request shard_map
-        # compile (rungs past MESH_WARM_CAP are bulk shapes outside
-        # the serving path, same exposure as the legacy ladder)
-        spread = [
-            g * index.d_local
-            for g in range(index.n_dev)
-            if g * index.d_local < index.n_shards
-        ]
-        batches = [
-            [0] * t for t in active_ladder().mesh_warm_rungs() if t > 1
-        ] + [spread]
-        for sids in batches:
-            index.run_mesh_queries(
-                encode_queries([spec] * len(sids), shard_ids=sids),
-                window_cap=eng.window_cap,
-                record_cap=eng.record_cap,
-            )
-            n += 1
-        if index.has_planes:
-            # the plane program at the SAME shapes as the match warm —
-            # a selected-samples burst coalescing to any warmed tier
-            # must not pay a mid-request shard_map compile any more
-            # than a boolean one would
-            for sids in batches:
-                index.run_mesh_queries(
-                    encode_queries([spec] * len(sids), shard_ids=sids),
-                    window_cap=eng.window_cap,
-                    record_cap=eng.record_cap,
-                    sample_masks=np.zeros(
-                        (len(sids), index.plane_words), np.uint32
-                    ),
-                    mask_counts=np.zeros(len(sids), np.bool_),
-                )
-                n += 1
-        return n
-
-    # -- per-query consult ---------------------------------------------------
-
-    def _note_refusal(self, reason: str) -> None:
-        with self._lock:
-            self._refusals[reason] = self._refusals.get(reason, 0) + 1
-
-    def _is_plane_query(self, payload) -> bool:
-        """Plane-reading response shape — the predicate IS the
-        engine's (_wants_planes), not a copy that could drift."""
-        wants_planes = getattr(self.engine, "_wants_planes", None)
-        return payload.selected_samples_only or (
-            wants_planes is not None and wants_planes(payload)
-        )
-
-    def resolve(self, dataset_ids, payload) -> set:
-        """The subset of ``dataset_ids`` this tier will serve for this
-        query — empty when the tier should not engage (unbuilt/stale
-        stack, a plane-reading shape the stack cannot answer, below
-        ``min_shards``). Every refusal is reason-labeled into the
-        ``mesh.refusals`` series so operators can see why traffic
-        falls off the tier."""
-        if not dataset_ids:
-            return set()
-        state = self._ready()
-        if state is None:
-            with self._lock:
-                built = self._state is not None
-            if built:
-                self._note_refusal("stale")
-                plan_stage("mesh", decision="refused", reason="stale")
-            else:
-                self._note_refusal("unbuilt")
-                plan_stage("mesh", decision="refused", reason="unbuilt")
-            return set()
-        index = state[0]
-        if self._is_plane_query(payload):
-            # plane shapes ride the single launch when the stack
-            # carries the genotype planes AND device row-matching is
-            # exact for this query (an N-wildcard ref needs host regex
-            # semantics — the engine's own predicate decides, payload
-            # doubles as the spec arg since only reference_bases is
-            # read); otherwise they keep the per-dataset engine paths
-            ref_ok = getattr(self.engine, "_device_ref_ok", None)
-            if not index.has_planes or (
-                ref_ok is not None and not ref_ok(payload, payload)
-            ):
-                self._note_refusal("planes")
-                ledger = getattr(self.engine, "plane_ledger", None)
-                headroom = (
-                    ledger().get("headroomBytes")
-                    if callable(ledger)
-                    else None
-                )
-                plan_stage(
-                    "mesh",
-                    decision="refused",
-                    reason="planes",
-                    has_planes=bool(index.has_planes),
-                    headroom_bytes=headroom,
-                )
-                return set()
-        _index, _sid_of, _shard_of, keys_by_ds, _fp = state[:5]
-        covered = {ds for ds in dataset_ids if ds in keys_by_ds}
-        n_targets = sum(len(keys_by_ds[ds]) for ds in covered)
-        if n_targets < self.min_shards:
-            self._note_refusal("min_shards")
-            plan_stage(
-                "mesh",
-                decision="refused",
-                reason="min_shards",
-                targets=n_targets,
-                min_shards=self.min_shards,
-            )
-            return set()
-        return covered
-
-    def search(
-        self, payload: VariantQueryPayload, dataset_ids
-    ) -> list[VariantSearchResponse]:
-        """Answer ``dataset_ids`` (a :meth:`resolve` result) with one
-        mesh launch. Raises on any failure — the caller owns the
-        fall-back-once-to-scatter contract."""
-        from ..engine import host_match_rows, materialize_response
-        from ..ops.kernel import QuerySpec, encode_queries
-
-        fault_point("mesh.dispatch")
-        deadline = current_deadline()
-        deadline.check("mesh.dispatch")
-        with self._lock:
-            state = self._state
-        if state is None:
-            raise WorkerError("mesh tier state gone")
-        index, sid_of, shard_of, keys_by_ds, _fp = state[:5]
-        planes_of = state[5] if len(state) > 5 else {}
-        plane_q = self._is_plane_query(payload)
-        spec_base = QuerySpec(
-            chrom=payload.reference_name,
-            start_min=payload.start_min,
-            start_max=payload.start_max,
-            end_min=payload.end_min,
-            end_max=payload.end_max,
-            reference_bases=payload.reference_bases,
-            alternate_bases=payload.alternate_bases,
-            variant_type=payload.variant_type,
-            variant_min_length=payload.variant_min_length,
-            variant_max_length=payload.variant_max_length,
-        )
-        targets = []
-        for ds in sorted(dataset_ids):
-            for key in keys_by_ds.get(ds, ()):
-                shard = shard_of[key]
-                native = shard.meta.get("chrom_native", {}).get(
-                    payload.reference_name
-                )
-                if native is None:
-                    continue  # no matching chromosome in this VCF
-                targets.append((key, shard, native, sid_of[key]))
-        # the delta tail: shards published since the stack was built
-        # (base fingerprint unchanged, so the stack is NOT stale — the
-        # tail just isn't in it). Deltas are small and host-served, so
-        # they ride per-shard host matching next to the single mesh
-        # launch instead of cold-starting the tier per ingest.
-        delta_targets = []
-        indexes_for = getattr(self.engine, "indexes_for", None)
-        if indexes_for is not None:
-            for ds, vcf, (shard, _di, pl) in indexes_for(
-                sorted(dataset_ids)
-            ):
-                if (ds, vcf) in sid_of:
-                    continue  # base rows: the mesh launch serves them
-                native = shard.meta.get("chrom_native", {}).get(
-                    payload.reference_name
-                )
-                if native is None:
-                    continue
-                delta_targets.append(((ds, vcf), shard, native, pl))
-        if not targets and not delta_targets:
-            return []
-        eng = self.engine.config.engine
-        responses = []
-        gathered = 0
-
-        def _sel_idx(shard, ds):
-            # the engine's own name->index resolution, per shard
-            if not payload.selected_samples_only:
-                return None
-            return self.engine._selected_idx(shard, payload, ds)
-
-        if targets:
-            specs = [spec_base] * len(targets)
-            sids = [sid for _k, _s, _n, sid in targets]
-            sel_idx_of: dict = {}
-            masks = None
-            mask_counts = None
-            if plane_q:
-                # per-query sample masks, sharded WITH the batch: the
-                # owning device reduces each query's matched rows under
-                # ITS mask inside the same single launch. Selected-
-                # samples queries restrict to the named samples (and
-                # switch to genotype-derived counting when the count
-                # planes are stacked); extraction shapes take the
-                # full-cohort mask and keep the INFO-column counts —
-                # materialize only consumes their or_words.
-                from ..ops.plane_kernel import sample_mask_words
-
-                W = index.plane_words
-                masks = np.zeros((len(targets), W), np.uint32)
-                mask_counts = np.zeros(len(targets), np.bool_)
-                for i, (key, shard, _native, _sid) in enumerate(targets):
-                    if payload.selected_samples_only:
-                        sel = _sel_idx(shard, key[0])
-                        sel_idx_of[key] = sel
-                        masks[i] = sample_mask_words(sel, W)
-                        mask_counts[i] = index.has_count_planes
-                    else:
-                        masks[i] = 0xFFFFFFFF
-            batcher = getattr(self.engine, "batcher", None)
-            if batcher is not None:
-                # the serving micro-batcher coalesces concurrent pod
-                # queries into the same launch and bounds the wait by
-                # the request deadline (the mesh wait IS deadline-scoped)
-                res = batcher.submit_many(
-                    index,
-                    specs,
-                    shard_ids=sids,
-                    window_cap=eng.window_cap,
-                    record_cap=eng.record_cap,
-                    sample_masks=masks,
-                    mask_counts=mask_counts,
-                )
-            else:
-                fault_point("kernel.launch")
-                res = index.run_mesh_queries(
-                    encode_queries(specs, shard_ids=sids),
-                    window_cap=eng.window_cap,
-                    record_cap=eng.record_cap,
-                    sample_masks=masks,
-                    mask_counts=mask_counts,
-                )
-            for i, (key, shard, native, _sid) in enumerate(targets):
-                sel_idx = sel_idx_of.get(key)
-                fused = None
-                if res.overflow[i] or res.n_matched[i] > eng.record_cap:
-                    # window/record overflow: uncapped host matcher,
-                    # the same contract as every device kernel path
-                    rows = host_match_rows(
-                        shard,
-                        spec_base,
-                        ref_wildcard=payload.selected_samples_only,
-                    )
-                else:
-                    keep = res.rows[i] >= 0
-                    rows = res.rows[i][keep]
-                    gathered += int(rows.size)
-                    # the fused triple is only exact for this shard
-                    # when its count-plane availability matches the
-                    # stack-wide static (a shard WITH count planes in
-                    # a stack that ran has_counts=False was counted
-                    # full-cohort on device) — extraction shapes only
-                    # read or_words, which is count-plane-invariant
-                    if (
-                        plane_q
-                        and res.or_words is not None
-                        and (
-                            not payload.selected_samples_only
-                            or index.has_count_planes
-                            or not shard.has_count_planes
-                        )
-                    ):
-                        # or_words come back stack-wide (plane_words =
-                        # the widest shard); materialise in this
-                        # shard's own width (tail words are zero by
-                        # construction)
-                        w_shard = shard.gt_bits.shape[1]
-                        fused = (
-                            res.pc_call[i][keep],
-                            res.pc_tok[i][keep],
-                            np.asarray(res.or_words[i])
-                            .view(np.uint32)[:w_shard],
-                        )
-                responses.append(
-                    materialize_response(
-                        shard,
-                        rows,
-                        payload,
-                        chrom_label=native,
-                        dataset_id=key[0],
-                        vcf_location=key[1],
-                        selected_idx=sel_idx,
-                        plane_index=(
-                            planes_of.get(key) if plane_q else None
-                        ),
-                        fused=fused,
-                    )
-                )
-        # the delta tail: the engine's L0 mini-index is consulted
-        # FIRST — a past-threshold tail rides one batched fused_l0
-        # launch and only the residue it does not cover (or overflow,
-        # marked None) host-scans. l0_pre_rows owns the delta_shards
-        # charging rule (only host-walked shards charge), so this
-        # tier and the engine's own tail leg cannot diverge on it.
-        l0_rows: dict = {}
-        l0_fn = getattr(self.engine, "l0_pre_rows", None)
-        if delta_targets and l0_fn is not None:
-            l0_rows = l0_fn(
-                [(key, shard) for key, shard, _n, _p in delta_targets],
-                spec_base,
-                payload,
-            )
-        elif delta_targets:
-            # engines without an L0 registry: every tail shard below
-            # host-walks and charges
-            charge_cost(delta_shards=len(delta_targets))
-        # how much of the tail rode the device launch vs host-walked:
-        # with per-key L0 blocks (ISSUE 20) a key mid-restack simply
-        # falls out of coverage for a beat, and this split is the
-        # per-request signal that shows it
-        l0_covered = sum(1 for v in l0_rows.values() if v is not None)
-        for key, shard, native, pl in delta_targets:
-            rows = l0_rows.get(key)
-            if rows is None:
-                rows = host_match_rows(
-                    shard,
-                    spec_base,
-                    ref_wildcard=payload.selected_samples_only,
-                )
-            responses.append(
-                materialize_response(
-                    shard,
-                    rows,
-                    payload,
-                    chrom_label=native,
-                    dataset_id=key[0],
-                    vcf_location=key[1],
-                    selected_idx=_sel_idx(shard, key[0]),
-                    plane_index=pl if plane_q else None,
-                )
-            )
-        with self._lock:
-            self._dispatches += 1
-            self._gather_rows += gathered
-        # the dispatch_tier note belongs to DistributedEngine.search —
-        # it knows whether this query was mesh-only or "mixed" with a
-        # scatter leg; writing it here would overwrite that label
-        annotate(
-            mesh_shards=len(targets),
-            mesh_delta_tail=len(delta_targets),
-            mesh_tail_l0=l0_covered,
-            mesh_planes=plane_q,
-        )
-        plan_stage(
-            "mesh",
-            decision="served",
-            shards=len(targets),
-            delta_tail=len(delta_targets),
-            tail_l0=l0_covered,
-            planes=plane_q,
-        )
-        return responses
-
-    def note_fallback(self) -> None:
-        with self._lock:
-            self._fallbacks += 1
-
-    def stats(self) -> dict:
-        with self._lock:
-            state = self._state
-            built_at = self._built_at
-            out = {
-                "dispatches": self._dispatches,
-                "fallbacks": self._fallbacks,
-                "gather_rows": self._gather_rows,
-                "refusals": dict(self._refusals),
-            }
-        out["ready"] = state is not None
-        out["shards"] = len(state[1]) if state is not None else 0
-        out["devices"] = state[0].n_dev if state is not None else 0
-        out["planes"] = bool(state[0].has_planes) if state else False
-        # stack identity + age (the /device/status stacks surface):
-        # which publish this stack serves and how long it has stood
-        out["fingerprint"] = state[4] if state is not None else ""
-        out["ageS"] = (
-            round(time.time() - built_at, 1)
-            if state is not None and built_at is not None
-            else None
-        )
-        return out
-
-
 class FleetView:
     """Fleet-wide telemetry federation (ISSUE 12): the coordinator's
     collected view of every worker's ``/ops/digest``, served at
@@ -2060,8 +1313,8 @@ class FleetView:
     the deepest standing delta tail), the **hottest worker** (highest
     median RTT from the router's own measurements), the **divergent
     datasets** (replicas advertising different copies), and the
-    unreachable workers — the federated signal layer ROADMAP items 4
-    (quota convergence) and 5 (live migration) ride on.
+    unreachable workers — the federated signal layer live migration
+    (``migration.py``) rides on.
     """
 
     #: per-digest GET budget: a digest is a small control message and
@@ -2409,19 +1662,6 @@ class DistributedEngine:
         self._pool = ThreadPoolExecutor(
             max_workers=max_threads, thread_name_prefix="dispatch"
         )
-        # pod-local mesh dispatch (consulted per query in search()):
-        # dataset groups resolvable on the local device mesh ride ONE
-        # compiled launch instead of the thread/HTTP scatter. Cheap to
-        # construct — device probing and the stack build are deferred
-        # to first use / warmup.
-        self.mesh_tier: MeshDispatchTier | None = None
-        eng_cfg = getattr(self.config, "engine", None)
-        if local is not None and getattr(eng_cfg, "mesh_dispatch", True):
-            self.mesh_tier = MeshDispatchTier(
-                local,
-                min_shards=getattr(eng_cfg, "mesh_min_shards", 2),
-                axis=getattr(eng_cfg, "mesh_axis", "d"),
-            )
         # fleet telemetry federation (ISSUE 12): worker /ops/digest
         # collection + the /fleet/status rollup. Construction is free —
         # digests are only polled when the view is read (lazily, at
@@ -2478,10 +1718,7 @@ class DistributedEngine:
         program count — the coordinator deployment must not be the one
         shape the soak-tail fix skips."""
         warm = getattr(self.local, "warmup", None)
-        n = warm() if warm else 0
-        if self.mesh_tier is not None:
-            n += self.mesh_tier.warmup()
-        return n
+        return warm() if warm else 0
 
     def register_metrics(self, registry) -> None:
         """Coordinator telemetry: per-worker breaker series, the data
@@ -2506,9 +1743,6 @@ class DistributedEngine:
         """The fan-out counters behind the ``dispatch.*`` / ``routing.*``
         series (register_dispatch_metrics reads through this so a
         swapped engine stays observable)."""
-        mesh = (
-            self.mesh_tier.stats() if self.mesh_tier is not None else {}
-        )
         fleet = self.fleet.stats()
         mig = self.migrations.counters()
         with self._sc_lock:
@@ -2518,10 +1752,6 @@ class DistributedEngine:
                 "partial_responses": self._partials,
                 "rediscoveries": self._rediscoveries,
                 "replicas": self.router.replica_count(),
-                "mesh_dispatches": mesh.get("dispatches", 0),
-                "mesh_fallbacks": mesh.get("fallbacks", 0),
-                "mesh_gather_rows": mesh.get("gather_rows", 0),
-                "mesh_refusals": mesh.get("refusals", {}),
                 "fleet_polls": fleet.get("polls", 0),
                 "fleet_reachable": fleet.get("reachable", 0),
                 "fleet_divergent": fleet.get("divergent", 0),
@@ -2572,8 +1802,6 @@ class DistributedEngine:
         call this when rebuilding one on config/route changes)."""
         self._closed.set()
         self.migrations.close()
-        if self.mesh_tier is not None:
-            self.mesh_tier.close()
         self._pool.shutdown(wait=False, cancel_futures=True)
         # under _sc_lock, paired with _hedge_pool's closed check: a
         # hedge executor created concurrently with close() must not
@@ -2598,7 +1826,7 @@ class DistributedEngine:
     def _group_replicas(ds: str, entries: list[tuple[str, str]]) -> tuple:
         """The replica urls for one dataset, grouped by per-dataset
         fingerprint: identical shard copies are interchangeable, and so
-        are **tail-superset** copies (ROADMAP 4a): same base artifacts,
+        are **tail-superset** copies: same base artifacts,
         delta tails forming a subset chain — a replica mid-rolling-
         ingest (deeper tail) is a FRESHER copy of the same dataset,
         not a divergence loser, and the migration dual-serve window
@@ -3199,25 +2427,9 @@ class DistributedEngine:
                 # it as unknown (a stale skip would be indistinguishable
                 # from 'no variants found')
                 table = self.replica_table(refresh=True)
-            # pod-local mesh consult: dataset groups resolvable on the
-            # local device mesh ride ONE compiled launch (below, on
-            # this thread, concurrent with the worker scatter) instead
-            # of the thread/HTTP scatter
-            mesh_ds: set = set()
-            tier = self.mesh_tier
-            if tier is not None:
-                try:
-                    mesh_ds = tier.resolve(
-                        [ds for ds in wanted if ds in local_ds], payload
-                    )
-                except Exception:
-                    log.exception("mesh tier resolve failed")
-                    mesh_ds = set()
             by_worker: dict[str, list[str]] = {}
             local_wanted: list[str] = []
             for ds in wanted:
-                if ds in mesh_ds:
-                    continue
                 if ds in local_ds:
                     local_wanted.append(ds)
                 elif ds in table:
@@ -3261,18 +2473,7 @@ class DistributedEngine:
                 }
             # which tier is serving this query (the slow-query log's
             # dispatch attribution)
-            if mesh_ds:
-                tier_label = (
-                    "mesh" if not (tasks or local_wanted) else "mixed"
-                )
-                annotate(dispatch_tier=tier_label)
-                plan_stage(
-                    "tier",
-                    decision=tier_label,
-                    mesh_datasets=len(mesh_ds),
-                    worker_groups=len(tasks),
-                )
-            elif tasks:
+            if tasks:
                 annotate(dispatch_tier="http")
                 plan_stage(
                     "tier", decision="http", worker_groups=len(tasks)
@@ -3280,61 +2481,11 @@ class DistributedEngine:
             elif local_wanted:
                 annotate(dispatch_tier="local")
                 plan_stage("tier", decision="local")
-            # the POD-LOCAL mesh leg runs on this thread concurrently
-            # with the worker scatter: one compiled launch answers the
-            # whole local dataset group. A mesh failure falls back ONCE
-            # to the scatter planes (pooled HTTP where a worker route
-            # exists, the local engine's own dispatch otherwise) and
-            # trips mesh.fallbacks; a deadline expiry is the REQUEST's
-            # fault and never falls back (no time left to re-run).
-            first_err: BaseException | None = None
-            if mesh_ds:
-                try:
-                    responses.extend(tier.search(payload, mesh_ds))
-                except DeadlineExceeded as e:
-                    first_err = e
-                except Exception as e:
-                    tier.note_fallback()
-                    annotate(mesh_fallback=True)
-                    plan_stage(
-                        "fallback",
-                        decision="scatter",
-                        reason="mesh_error",
-                        datasets=len(mesh_ds),
-                    )
-                    publish_event(
-                        "mesh.fallback",
-                        datasets=len(mesh_ds),
-                        error=type(e).__name__,
-                    )
-                    log.warning(
-                        "mesh tier failed for %d dataset(s); falling "
-                        "back to the scatter path (%s)",
-                        len(mesh_ds),
-                        e,
-                    )
-                    fb_by_worker: dict[str, list[str]] = {}
-                    for ds in sorted(mesh_ds):
-                        if ds in table:
-                            primary = self.router.pick(ds)
-                            if primary is not None:
-                                fb_by_worker.setdefault(
-                                    primary, []
-                                ).append(ds)
-                                continue
-                        if ds in local_ds:
-                            local_wanted.append(ds)
-                    for url, ds_list in sorted(fb_by_worker.items()):
-                        futures[
-                            self._pool.submit(
-                                self._search_group, url, ds_list,
-                                payload, deadline, ctx,
-                            )
-                        ] = url
             # the LOCAL shard search runs on this thread CONCURRENTLY
             # with the worker fan-out (it used to wait for the full
             # drain) — the coordinator's own datasets no longer sit
             # behind the slowest worker's RTT
+            first_err: BaseException | None = None
             if local_wanted:
                 try:
                     responses.extend(

@@ -15,15 +15,15 @@ tree:
   mesh refusals, worker legs, serving.py batch exit) appends ONE
   bounded stage entry to the ambient request's plan: the stage, the
   decision taken, and — when a path was *refused* — the alternative
-  not taken and why (``mesh refused: planes`` with the measured HBM
-  headroom). A no-op off-request, exactly like ``annotate``.
+  not taken and why (``mesh skipped: stale`` with the targets the
+  stack missed). A no-op off-request, exactly like ``annotate``.
 - ``PLAN_STAGES`` / ``PLAN_REASONS`` — the literal registries of every
   stage and refusal-reason string producers may record. The static
   lint ``tools/check_plan_stages.py`` (tier-1 via tests/test_plan.py)
   enforces two-way parity with the call sites, exactly like
   ``ANNOTATION_KEYS`` and the metric catalogue.
 - :func:`plan_shape` — the ordered stage/decision fingerprint
-  (``cache=miss>tier=mesh>mesh=served``): volatile counts and details
+  (``cache=miss>tier=local>mesh=skipped``): volatile counts and details
   are excluded, so two requests served the same WAY share one shape.
 - :class:`PlanStore` — the sampled aggregate served at ``/ops/plans``:
   per ``(query-shape, plan-shape)`` counts, cost-unit means from the
@@ -84,12 +84,12 @@ _DETAIL_STR_CAP = 120
 PLAN_STAGES = frozenset({
     "admission",  # tenant + priority lane classification (api/app.py)
     "cache",      # response-cache outcome + scope (engine.search)
-    "tier",       # dispatch tier chosen: mesh/mixed/http/local
-    "mesh",       # mesh-tier consult: served, or refused with reason
+    "tier",       # dispatch tier chosen: http/local
+    "mesh",       # the engine's mesh-stack consult: why it was passed by
     "split",      # per-target split counts across device paths
     "batch",      # microbatch exit: the launch family that served
     "worker",     # one worker leg: hedge/failover/breaker flags
-    "fallback",   # a path abandoned mid-request (mesh error, partial)
+    "fallback",   # a path abandoned mid-request (partial results)
 })
 
 #: the literal registry of every refusal/fallback reason — each names
@@ -98,11 +98,8 @@ PLAN_STAGES = frozenset({
 PLAN_REASONS = frozenset({
     "stale",          # mesh stack predates the live index fingerprint
     "unbuilt",        # mesh stack not built yet (pre-warmup)
-    "planes",         # plane-reading shape the mesh stack cannot serve
-    "min_shards",     # query spans too few shards to pay the launch
     "planes_budget",  # planes not on their owner chip (budget, upload)
     "planes_on_owners",  # planes resident once, on owner chips: fan-out
-    "mesh_error",     # mesh launch failed; fell back to the scatter
     "breaker_open",   # worker leg fast-failed on an open circuit
     "no_replica",     # every replica unreachable: partial results
 })

@@ -41,8 +41,6 @@ import time
 import weakref
 from dataclasses import dataclass
 
-import numpy as np
-
 from .harness.faults import fault_point
 from .ops import launch_capacity, run_queries_auto
 from .ops.kernel import QueryResults, encode_queries
@@ -75,13 +73,6 @@ class _Pending:
     #: indexes (all submissions in one accumulator share the index, so
     #: they either all carry ids or none do)
     shard_ids: list | None = None
-    #: per-spec genotype-plane sample masks (uint32 [k, plane_words])
-    #: for the mesh tier's plane program; plane submissions ride their
-    #: own accumulator (the caps key carries the flag), so a batch is
-    #: uniformly masked or uniformly not
-    sample_masks: object = None
-    #: per-spec restricted-counting switch riding with sample_masks
-    mask_counts: object = None
     result: object = None
     error: BaseException | None = None
     t_submit: float = 0.0
@@ -320,32 +311,14 @@ class MicroBatcher:
         record_cap: int,
         timeout_s: float | None = None,
         shard_ids: list | None = None,
-        sample_masks=None,
-        mask_counts=None,
     ):
         """One fused submission of several specs (a k-dataset query
         against a FusedDeviceIndex): ALL of them ride in the same
         batch and therefore the same kernel launch, and the returned
         QueryResults carries one row per spec in order. Waiting/expiry
         semantics are exactly :meth:`submit`'s — the submission is one
-        queue entry.
-
-        ``sample_masks`` (+ ``mask_counts``) target the mesh tier's
-        genotype-plane program; masked submissions accumulate
-        separately from match-only ones (the caps key carries the
-        flag) so plane-shape and match-shape queries each coalesce
-        with their own kind — a match-only batch never pays the plane
-        reduction."""
-        # plane submissions ride their own accumulator (a match-only
-        # batch must never pay the plane program); the unmasked key
-        # stays the bare caps tuple so existing callers/tests that
-        # address an accumulator by (window_cap, record_cap) still do
-        caps = (
-            (window_cap, record_cap)
-            if sample_masks is None
-            else (window_cap, record_cap, "planes")
-        )
-        acc = self._accum(dindex, caps)
+        queue entry."""
+        acc = self._accum(dindex, (window_cap, record_cap))
         req_deadline = current_deadline()
         deadline = req_deadline.combine(
             timeout_s if timeout_s is not None else self.default_timeout_s
@@ -357,8 +330,6 @@ class MicroBatcher:
         me = _Pending(
             specs=list(specs),
             shard_ids=None if shard_ids is None else list(shard_ids),
-            sample_masks=sample_masks,
-            mask_counts=mask_counts,
             event=threading.Event(),
             t_submit=time.perf_counter(),
             deadline=deadline,
@@ -416,9 +387,8 @@ class MicroBatcher:
         # result delivery (queue wait + device execute + fetch), the
         # batcher's share of this request's latency — plus the kernel
         # family that served it (DeviceIndex / FusedDeviceIndex /
-        # ScatterDeviceIndex / MeshFusedIndex), so a tail is
-        # attributable to a dispatch tier without cross-referencing
-        # counters
+        # ScatterDeviceIndex), so a tail is attributable to a dispatch
+        # tier without cross-referencing counters
         batch_ms = round((t_wake - me.t_submit) * 1e3, 2)
         annotate(batch_ms=batch_ms, batch_index=type(dindex).__name__)
         plan_stage(
@@ -997,25 +967,6 @@ class MicroBatcher:
         shard_ids = None
         if batch and batch[0].shard_ids is not None:
             shard_ids = [s for p in batch for s in p.shard_ids]
-        # plane-program inputs (mesh tier): the accumulator key keeps
-        # masked and unmasked submissions apart, so presence on the
-        # first entry means presence on all
-        sample_masks = None
-        mask_counts = None
-        if batch and batch[0].sample_masks is not None:
-            sample_masks = np.concatenate(
-                [np.asarray(p.sample_masks) for p in batch]
-            )
-            mask_counts = np.concatenate(
-                [
-                    np.asarray(
-                        p.mask_counts
-                        if p.mask_counts is not None
-                        else np.zeros(len(p.specs), np.bool_)
-                    )
-                    for p in batch
-                ]
-            )
         # one clock reading per boundary from here on; each feeds the
         # chain stage that ends there, the composite behind the old
         # /debug/status key, the histogram and the cost vector alike
@@ -1062,21 +1013,12 @@ class MicroBatcher:
                 # copy and turned pad rows into extra scatter dispatches
                 enc = encode_queries(specs, shard_ids=shard_ids)
                 t_enc = time.perf_counter()
-                mask_kwargs = (
-                    dict(
-                        sample_masks=sample_masks,
-                        mask_counts=mask_counts,
-                    )
-                    if sample_masks is not None
-                    else {}
-                )
                 pending = run_queries_auto(
                     dindex,
                     enc,
                     window_cap=window_cap,
                     record_cap=record_cap,
                     async_fetch=True,
-                    **mask_kwargs,
                 )
                 t_disp = time.perf_counter()
                 sp.note(batch=len(specs))
@@ -1157,15 +1099,6 @@ class MicroBatcher:
             n_specs = sum(len(p.specs) for p in batch) or 1
             for p, off in zip(batch, offsets):
                 sl = slice(off, off + len(p.specs))
-                extra = (
-                    dict(
-                        pc_call=res.pc_call[sl],
-                        pc_tok=res.pc_tok[sl],
-                        or_words=res.or_words[sl],
-                    )
-                    if res.pc_call is not None
-                    else {}
-                )
                 p.result = QueryResults(
                     exists=res.exists[sl],
                     call_count=res.call_count[sl],
@@ -1174,7 +1107,6 @@ class MicroBatcher:
                     n_matched=res.n_matched[sl],
                     overflow=res.overflow[sl],
                     rows=res.rows[sl],
-                    **extra,
                 )
                 charge_cost_to(
                     p.ctx,

@@ -517,7 +517,7 @@ class CostVector:
       (``engine.host_match_rows`` — per-shard fallbacks, overflow
       paths, and the delta tail);
     - ``delta_shards`` — delta-tail shards walked for this query
-      (engine / mesh-tier per-shard host dispatch);
+      (the engine's per-shard host dispatch);
     - ``worker_rtt_ms`` — coordinator->worker round-trip time on
       successful ``/search`` legs (a worker was occupied that long on
       this request's behalf);
@@ -756,11 +756,6 @@ ANNOTATION_KEYS = frozenset({
     "failover",
     "granularity",
     "lane",
-    "mesh_delta_tail",
-    "mesh_fallback",
-    "mesh_tail_l0",
-    "mesh_planes",
-    "mesh_shards",
     "query_job",
     "replica_hedge",
     "response_cache",
@@ -871,8 +866,7 @@ class EventJournal:
     """Bounded structured journal of control-plane transitions — the
     flight recorder. Breaker opens/closes, replica failovers, hedges,
     rediscovery passes, route-table publishes, cache invalidations,
-    fused-stack rebuilds, mesh-tier bring-up/fallbacks
-    (``mesh.tier_ready`` / ``mesh.fallback``) and admission sheds each
+    fused-stack rebuilds and admission sheds each
     publish ONE small event here, stamped with monotonic time (ordering
     survives wall
     clock jumps), wall time (human correlation) and the ambient trace
@@ -1046,17 +1040,13 @@ def publish_event(kind: str, **data) -> int | None:
 #: (same kernel, its own family so tail serving is attributable), the
 #: engine's mesh-stack program (``mesh``: ``parallel/mesh.sharded_query``,
 #: one launch a multi-dataset request over the dataset-sharded stack),
-#: the pod tier's shard_map program in its replicated and sliced batch
-#: layouts (``run_mesh_queries``; kept apart from ``mesh``: another
-#: program, other operands), and the genotype-plane program. Every
-#: launch record names exactly one of these.
+#: and the genotype-plane program. Every launch record names exactly
+#: one of these.
 DEVICE_FAMILIES = (
     "scatter",
     "fused",
     "fused_l0",
     "mesh",
-    "mesh_replicated",
-    "mesh_sliced",
     "plane",
 )
 
@@ -1083,7 +1073,7 @@ class DeviceFlightRecorder:
     The reference gets per-invocation visibility for free (every
     Lambda in its scatter-gather is individually metered by
     CloudWatch); our replacement for that fan-out — the micro-batcher's
-    compiled launches, the fused stack, the pod-local mesh tier — used
+    compiled launches, the fused stack, the mesh program — used
     to count launches in UNLOCKED module globals (``mesh.N_LAUNCHES``
     ``+= 1`` raced across request threads on real accelerators, where
     no ``_CPU_COLLECTIVE_LOCK`` serialises launches) and recorded
@@ -1119,7 +1109,7 @@ class DeviceFlightRecorder:
         self._seq = 0
         self.compile_tracking = bool(compile_tracking)
         # lifetime counters: per family, per seam (the module-property
-        # back-compat views), sliced launches, evaluated pairs
+        # back-compat views), evaluated pairs
         self._families: dict[str, int] = {}
         # the (query, dataset) slots launches carried that a request
         # asked, by family: over _families, the targets of one launch
@@ -1127,7 +1117,6 @@ class DeviceFlightRecorder:
         # launches of one-chip programs by the chip they ran on
         self._chips: dict[str, int] = {}
         self._seams: dict[str, int] = {}
-        self._sliced = 0
         self._pairs = 0
         # padding accounting: family -> [real, padded] spec slots, and
         # (family, tier) -> [real, padded] for the tier-boundary view
@@ -1168,8 +1157,8 @@ class DeviceFlightRecorder:
 
     @contextmanager
     def warmup_phase(self):
-        """Mark compiles as EXPECTED while a warmup runs (engine /
-        mesh-tier program pre-compilation). A process-wide depth
+        """Mark compiles as EXPECTED while a warmup runs (the engine's
+        program pre-compilation). A process-wide depth
         counter, not a thread-local flag: warmup launches ride the
         batcher's pool threads, so the compiling thread is not the
         thread that entered warmup."""
@@ -1194,7 +1183,6 @@ class DeviceFlightRecorder:
         evaluated_pairs: int = 0,
         launch_ms: float = 0.0,
         program_key=None,
-        sliced: bool = False,
         donated: int = 0,
         uploads: int = 0,
         chip: int | None = None,
@@ -1224,8 +1212,6 @@ class DeviceFlightRecorder:
             "launchMs": round(float(launch_ms), 3),
             "time": time.time(),
         }
-        if sliced:
-            rec["sliced"] = True
         if donated:
             rec["donated"] = int(donated)
         if uploads:
@@ -1244,8 +1230,6 @@ class DeviceFlightRecorder:
             if chip is not None:
                 self._chips[str(chip)] = self._chips.get(str(chip), 0) + 1
             self._seams[seam] = self._seams.get(seam, 0) + 1
-            if sliced:
-                self._sliced += 1
             self._pairs += int(evaluated_pairs)
             self._donated += int(donated)
             if uploads:
@@ -1402,11 +1386,6 @@ class DeviceFlightRecorder:
             return self._seams.get("scatter", 0)
 
     @property
-    def sliced_launches(self) -> int:
-        with self._lock:
-            return self._sliced
-
-    @property
     def evaluated_pairs(self) -> int:
         with self._lock:
             return self._pairs
@@ -1467,8 +1446,7 @@ class DeviceFlightRecorder:
 
     def pad_waste_by_family(self) -> dict:
         """{family: lifetime padding-waste ratio} — wasted pad slots
-        over total padded slots, the structural metric for the
-        ROADMAP item 1 owner-sharded-output follow-up."""
+        over total padded slots."""
         with self._lock:
             return self._pad_waste_by_family_locked()
 
@@ -1538,7 +1516,6 @@ class DeviceFlightRecorder:
             total = sum(self._families.values())
             by_family = dict(self._families)
             targets = dict(self._targets)
-            sliced = self._sliced
             pairs = self._pairs
             uploads = dict(self._uploads)
             gathered = self._gathered_bytes
@@ -1546,7 +1523,6 @@ class DeviceFlightRecorder:
             "total": total,
             "byFamily": by_family,
             "targetsByFamily": targets,
-            "sliced": sliced,
             "evaluatedPairs": pairs,
             "queryUploads": uploads,
             "planeGatherBytes": gathered,
@@ -1566,7 +1542,6 @@ class DeviceFlightRecorder:
             seq = self._seq
             families = dict(self._families)
             targets = dict(self._targets)
-            sliced = self._sliced
             pairs = self._pairs
             fetched = self._fetched_bytes
             gathered = self._gathered_bytes
@@ -1587,7 +1562,6 @@ class DeviceFlightRecorder:
             "byFamily": families,
             "targetsByFamily": targets,
             "fallbacks": fallbacks,
-            "sliced": sliced,
             "evaluatedPairs": pairs,
             "fetchedBytes": fetched,
             "planeGatherBytes": gathered,
@@ -1663,7 +1637,7 @@ def register_device_metrics(registry) -> None:
     registry.counter(
         "device.launches",
         "compiled device-program launches by family (scatter / fused "
-        "/ fused_l0 / mesh / mesh_replicated / mesh_sliced / plane)",
+        "/ fused_l0 / mesh / plane)",
         label="family",
         fn=lambda: flight_recorder.launches_by_family(),
     )

@@ -1,12 +1,13 @@
-"""Subprocess body for the pod-dispatch single-launch contract.
+"""Subprocess body for the coordinator's single-launch contract.
 
 A pristine process (own XLA_FLAGS-forced device count, zero prior
-launches) builds a 4-shard local engine plus one HTTP worker, drives a
-k-shard boolean query through the mesh tier, and reports the contract
-observations as JSON: exactly ONE kernel launch across every kernel
-family, ZERO coordinator->worker HTTP calls (the pooled transport's
-process-wide stats unchanged), per-response parity with a plain
-engine, and the seeded-fault fallback path. The parent test
+launches) builds a 4-dataset local engine on its defaults plus one HTTP
+worker, drives an all-local boolean query through the coordinator, and
+reports the contract observations as JSON: exactly ONE kernel launch
+across every kernel family, of the family ``mesh`` (the engine's own
+mesh program), ZERO coordinator->worker HTTP calls (the pooled
+transport's process-wide stats unchanged), and per-response parity
+with a plain engine that has no mesh stack. The parent test
 (``test_mesh_dispatch.py::test_pod_contract_in_subprocess``) asserts
 the JSON.
 """
@@ -35,14 +36,17 @@ def main() -> None:
     import sbeacon_tpu.ops.kernel as kernel_mod
     from sbeacon_tpu.config import BeaconConfig, EngineConfig
     from sbeacon_tpu.engine import VariantEngine
-    from sbeacon_tpu.harness import faults
     from sbeacon_tpu.index.columnar import build_index
     from sbeacon_tpu.ops import scatter_kernel
     from sbeacon_tpu.parallel import mesh as mesh_mod
     from sbeacon_tpu.parallel import transport as transport_mod
     from sbeacon_tpu.parallel.dispatch import DistributedEngine, WorkerServer
     from sbeacon_tpu.payloads import VariantQueryPayload
+    from sbeacon_tpu.telemetry import flight_recorder
     from sbeacon_tpu.testing import random_records
+
+    def _dicts(responses) -> list:
+        return [dataclasses.asdict(r) for r in responses]
 
     def launches() -> int:
         return (
@@ -62,23 +66,27 @@ def main() -> None:
 
     def engine(shards, **over):
         eng = VariantEngine(
-            BeaconConfig(engine=EngineConfig(use_mesh=False, **over))
+            BeaconConfig(
+                engine=EngineConfig(response_cache=False, **over)
+            )
         )
         for s in shards:
             eng.add_index(s)
         return eng
 
     n_shards = 4
+    # the local engine on its defaults: with several devices visible
+    # its own mesh stack answers a multi-dataset boolean in one launch
     eng = engine([shard(d) for d in range(n_shards)], microbatch_wait_ms=0.0)
-    # one real HTTP worker in the fleet: the contract is that the mesh
-    # query never touches it (its dataset is not in the query)
-    weng = engine([shard(9)], microbatch=False, mesh_dispatch=False)
+    # one real HTTP worker in the fleet: the contract is that an
+    # all-local query never touches it (its dataset is not in the query)
+    weng = engine([shard(9)], use_mesh=False, microbatch=False)
     worker = WorkerServer(weng).start_background()
     dist = DistributedEngine([worker.address], local=eng)
     ref = engine(
         [shard(d) for d in range(n_shards)],
+        use_mesh=False,
         microbatch=False,
-        mesh_dispatch=False,
     )
 
     def payload(gran="boolean", include="NONE"):
@@ -107,44 +115,32 @@ def main() -> None:
         t0 = transport_snapshot()
         n0 = launches()
         m0 = mesh_mod.N_LAUNCHES
+        f0 = flight_recorder.launches_by_family()
+        s0 = eng.mesh_searches
         got = dist.search(payload())
         doc["total_launches"] = launches() - n0
         doc["mesh_launches"] = mesh_mod.N_LAUNCHES - m0
+        f1 = flight_recorder.launches_by_family()
+        doc["launches_by_family"] = {
+            f: f1[f] - f0.get(f, 0) for f in f1 if f1[f] != f0.get(f, 0)
+        }
         t1 = transport_snapshot()
         doc["transport_stats_unchanged"] = t0 == t1
         doc["worker_http_calls"] = (t1["opened"] + t1["reused"]) - (
             t0["opened"] + t0["reused"]
         )
-        st = dist.mesh_tier.stats()
-        doc["mesh_dispatches"] = st["dispatches"]
-        doc["exists"] = bool(got[0].exists) if got else None
+        doc["mesh_searches"] = eng.mesh_searches - s0
+        doc["exists"] = any(r.exists for r in got)
 
-        # parity: count + record shapes against a plain engine
-        parity = True
-        for gran, include in [("count", "HIT"), ("record", "HIT")]:
-            a = [dataclasses.asdict(r) for r in dist.search(payload(gran, include))]
-            b = [dataclasses.asdict(r) for r in ref.search(payload(gran, include))]
-            parity = parity and a == b
+        # parity: every shape against a plain engine with no mesh stack
+        parity = _dicts(got) == _dicts(ref.search(payload()))
+        for gran, include in [
+            ("count", "HIT"), ("record", "HIT"), ("aggregated", "ALL"),
+        ]:
+            parity = parity and _dicts(
+                dist.search(payload(gran, include))
+            ) == _dicts(ref.search(payload(gran, include)))
         doc["parity_ok"] = parity
-
-        # seeded fault: the mesh leg fails, the scatter answers, the
-        # fallback counter ticks once
-        faults.install(
-            {
-                "seed": 3,
-                "rules": [
-                    {"site": "mesh.dispatch", "kind": "error", "rate": 1.0}
-                ],
-            }
-        )
-        try:
-            got_fb = dist.search(payload("count", "HIT"))
-        finally:
-            faults.uninstall()
-        doc["fallback_ok"] = (
-            len(got_fb) == n_shards
-            and dist.mesh_tier.stats()["fallbacks"] == 1
-        )
     finally:
         dist.close()
         worker.shutdown()

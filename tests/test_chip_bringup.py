@@ -1,10 +1,8 @@
 """What the chip cannot be asked twice (ISSUE 21): where the compile
 cache lives, that a failed device path is counted and still answers
-right, that the TPU-only code names things the installed JAX has, and
-that ``chip_smoke.py`` rehearses to its end on the CPU and refuses to
-run at full size without a chip."""
+right, and that ``chip_smoke.py`` rehearses to its end on the CPU and
+refuses to run at full size without a chip."""
 
-import functools
 import json
 import os
 import random
@@ -15,7 +13,6 @@ import warnings
 from pathlib import Path
 
 import jax
-import numpy as np
 import pytest
 
 import sbeacon_tpu.config as config_mod
@@ -404,60 +401,6 @@ def test_native_library_is_named_by_its_sources():
     stale.unlink(missing_ok=True)
 
 
-# -- (c) the names the TPU-only code touches exist ----------------------------
-
-
-def test_ring_step_traces_with_the_installed_pallas():
-    from sbeacon_tpu.ops import gather_kernel
-
-    gather_kernel._ring_step_fn("d", (8, 128), "int32")
-    assert gather_kernel.default_impl() == "portable"  # tests run on CPU
-
-
-def test_ring_equals_portable_combine_in_the_interpreter(monkeypatch):
-    """The DMA schedule of the ring against the all_gather combine —
-    the check the smoke runs on four chips. Mosaic compiles the ring
-    for the chip alone, so here Pallas' TPU interpreter runs it on the
-    virtual devices; the switch lives in this test, not in the kernel."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    from jax.sharding import PartitionSpec as P
-
-    from sbeacon_tpu.ops import gather_kernel
-    from sbeacon_tpu.parallel.mesh import AXIS, make_mesh
-
-    monkeypatch.setattr(
-        pl,
-        "pallas_call",
-        functools.partial(pl.pallas_call, interpret=pltpu.InterpretParams()),
-    )
-    gather_kernel._ring_step_fn.cache_clear()
-    n_dev = jax.device_count()
-    rng = np.random.default_rng(7)
-    owner = rng.integers(0, n_dev, 8)
-    x = rng.integers(1, 2**30, (n_dev, 8, 128), dtype=np.int32)
-    x *= owner[None, :, None] == np.arange(n_dev)[:, None, None]
-    out = {}
-    try:
-        for impl in ("pallas", "portable"):
-            fn = jax.jit(
-                jax.shard_map(
-                    lambda b, impl=impl: gather_kernel.gather_partials(
-                        b[0], AXIS, n_dev, impl=impl
-                    )[None],
-                    mesh=make_mesh(),
-                    in_specs=P(AXIS),
-                    out_specs=P(AXIS),
-                    check_vma=False,
-                )
-            )
-            out[impl] = np.asarray(fn(x))
-    finally:
-        gather_kernel._ring_step_fn.cache_clear()
-    assert np.array_equal(out["pallas"], out["portable"])
-    assert np.array_equal(out["portable"][0], x.sum(axis=0))
-
-
 # -- (d) the smoke itself -----------------------------------------------------
 
 
@@ -508,7 +451,6 @@ def test_chip_smoke_rehearsal_runs_to_its_end_on_the_cpu(tmp_path):
     assert doc["mid_request_compiles"] == 0
     assert doc["fallbacks"] == {
         "device.fallbacks": {},
-        "mesh.fallbacks": 0,
         "ingest.native_fallbacks": 0,
     }
     for family in ("scatter", "plane", "fused", "fused_l0"):

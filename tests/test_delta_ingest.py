@@ -6,8 +6,8 @@ The write-path contract under test:
 - a submitted variant is queryable the moment its slice/delta publishes
   (read-your-writes before any compaction),
 - a delta publish does NOT demolish the query plane: the base
-  fingerprint (and therefore the fused/mesh stacks and the pod
-  dispatch tier) stays warm, and only cache entries whose dataset AND
+  fingerprint (and therefore the fused/mesh stacks) stays warm, and
+  only cache entries whose dataset AND
   region overlap the new rows are evicted — a cached negative for an
   overlapping bracket is the critical kill,
 - base + delta serving is bit-equal (at the aggregate level each
@@ -539,58 +539,6 @@ def test_fused_stack_stays_clean_across_delta_publish():
         eng.close()
 
 
-@pytest.mark.skipif(
-    len(jax.devices()) < 2,
-    reason="mesh tier needs >=2 devices (forced-host CI mesh)",
-)
-def test_mesh_dispatch_tier_warm_across_delta_then_stale_after_fold(
-    tmp_path,
-):
-    from sbeacon_tpu.parallel.dispatch import MeshDispatchTier
-
-    shards = [
-        _shard(random_records(random.Random(30 + i), chrom="1", n=150,
-                              n_samples=2),
-               ds=f"d{i}", vcf=f"v{i}")
-        for i in range(3)
-    ]
-    eng = _engine(*shards)
-    tier = MeshDispatchTier(eng, min_shards=2)
-    try:
-        assert tier.warmup() > 0
-        pay = _bracket(chrom="1", datasets=["d0", "d1", "d2"])
-        assert tier.resolve(["d0", "d1", "d2"], pay) == {
-            "d0", "d1", "d2"
-        }
-        before = tier.stats()["dispatches"]
-        # delta publish: tier must stay READY (no cold rebuild)...
-        eng.add_delta(_shard([_rec("1", 424_242)], ds="d0", vcf="v0"))
-        assert tier.resolve(["d0", "d1", "d2"], pay) == {
-            "d0", "d1", "d2"
-        }, "delta publish cold-started the mesh tier"
-        got = tier.search(pay, {"d0", "d1", "d2"})
-        assert tier.stats()["dispatches"] == before + 1
-        # ...and the delta tail rides along, host-served
-        assert any("424242" in v for v in _variants(got))
-        # a FOLD (base publish) is the staleness event: the tier goes
-        # cold once and background-rebuilds against the new base
-        comp = _compactor(eng, tmp_path)
-        folded = comp.run_once()
-        assert ("d0", "v0") in folded
-        deadline = time.monotonic() + 20
-        while time.monotonic() < deadline:
-            if tier.resolve(["d0", "d1", "d2"], pay):
-                break
-            time.sleep(0.1)
-        assert tier.resolve(["d0", "d1", "d2"], pay), (
-            "tier never rebuilt after compaction"
-        )
-        got = tier.search(pay, {"d0", "d1", "d2"})
-        assert any("424242" in v for v in _variants(got))
-    finally:
-        eng.close()
-
-
 # -- slice temp-disk ----------------------------------------------------------
 
 
@@ -812,15 +760,20 @@ def test_delta_shard_charges_match_shards_actually_host_walked():
 
 @pytest.mark.skipif(
     len(jax.devices()) < 2,
-    reason="mesh tier needs >=2 devices (forced-host CI mesh)",
+    reason="the mesh stack needs >=2 devices (forced-host CI mesh)",
 )
-def test_mesh_tier_delta_tail_rides_l0():
-    """The pod dispatch tier's delta-tail leg consults the L0 stack
-    before falling to host_match_rows: a deep tail next to the mesh
-    launch is L0-served (zero delta_shards charges) and the answers
-    include the tail rows."""
-    from sbeacon_tpu.parallel.dispatch import MeshDispatchTier
-    from sbeacon_tpu.telemetry import RequestContext, request_context
+def test_coordinator_delta_tail_rides_l0_beside_the_mesh_launch():
+    """Through a coordinator the local engine's own routes hold: the
+    base rows of a multi-dataset count are ONE mesh launch, and a deep
+    delta tail next to it is L0-served before anything falls to
+    host_match_rows (zero delta_shards charges), the tail rows in the
+    answers."""
+    from sbeacon_tpu.parallel.dispatch import DistributedEngine
+    from sbeacon_tpu.telemetry import (
+        RequestContext,
+        flight_recorder,
+        request_context,
+    )
 
     shards = [
         _shard(random_records(random.Random(64 + i), chrom="1", n=150,
@@ -828,25 +781,33 @@ def test_mesh_tier_delta_tail_rides_l0():
                ds=f"d{i}", vcf=f"v{i}")
         for i in range(3)
     ]
-    eng = _engine(*shards, l0_min_shards=3, response_cache=False)
-    tier = MeshDispatchTier(eng, min_shards=2)
+    eng = _engine(
+        *shards, use_mesh=True, l0_min_shards=3, response_cache=False
+    )
+    dist = DistributedEngine([], local=eng)
     try:
-        assert tier.warmup() > 0
+        assert dist.warmup() > 0
         for i in range(4):
             eng.add_delta(
                 _shard([_rec("1", 800_000 + i)], ds="d0", vcf="v0")
             )
         assert eng.l0_status()["built"]
         pay = _bracket(chrom="1", datasets=["d0", "d1", "d2"])
-        assert tier.resolve(["d0", "d1", "d2"], pay)
-        served0 = eng.l0_searches
+        served0, mesh0 = eng.l0_searches, eng.mesh_searches
+        launches0 = flight_recorder.launches_by_family().get("mesh", 0)
         ctx = RequestContext(route="test")
         with request_context(ctx):
-            got = tier.search(pay, {"d0", "d1", "d2"})
+            got = dist.search(pay)
         assert ctx.cost.delta_shards == 0
         assert eng.l0_searches > served0
+        assert eng.mesh_searches == mesh0 + 1
+        assert (
+            flight_recorder.launches_by_family().get("mesh", 0)
+            == launches0 + 1
+        )
         assert any("800003" in v for v in _variants(got))
     finally:
+        dist.close()
         eng.close()
 
 

@@ -12,7 +12,7 @@ import pytest
 from sbeacon_tpu.config import BeaconConfig, EngineConfig
 from sbeacon_tpu.engine import VariantEngine
 from sbeacon_tpu.index.columnar import build_index
-from sbeacon_tpu.ops.kernel import DeviceIndex, QuerySpec, encode_queries
+from sbeacon_tpu.ops.kernel import DeviceIndex, QuerySpec
 from sbeacon_tpu.payloads import VariantQueryPayload
 from sbeacon_tpu.telemetry import (
     DeviceFlightRecorder,
@@ -28,9 +28,10 @@ N_SHARDS = 2
 
 
 def _build_engine():
-    cfg = BeaconConfig(
-        engine=EngineConfig(use_mesh=False, microbatch_wait_ms=0.0)
-    )
+    # the mesh stack on, as a host of several chips serves (the
+    # conftest's eight virtual devices): a multi-dataset count is the
+    # ``mesh`` family, a one-dataset one ``fused``
+    cfg = BeaconConfig(engine=EngineConfig(microbatch_wait_ms=0.0))
     eng = VariantEngine(cfg)
     for d in range(N_SHARDS):
         rng = random.Random(40 + d)
@@ -65,11 +66,10 @@ def _payload(**over):
 def warm_stack():
     """One warmed serving stack under a FRESH flight recorder (the
     process global accumulates across the whole pytest run otherwise):
-    engine + fused stack + mesh dispatch tier, all warmed INSIDE
+    the engine with its fused and mesh stacks, all warmed INSIDE
     warmup phases, plus the app serving /device/status."""
     import sbeacon_tpu.telemetry as tel
     from sbeacon_tpu.api import BeaconApp
-    from sbeacon_tpu.parallel.dispatch import MeshDispatchTier
 
     # one swap point: every seam (kernels, app, debug status) resolves
     # telemetry.flight_recorder at call time
@@ -78,16 +78,11 @@ def warm_stack():
     tel.flight_recorder = rec
     eng = _build_engine()
     eng.warmup()
-    tier = MeshDispatchTier(eng)
-    tier.warmup()
-    # surface the tier on the engine so /device/status shows its stack
-    eng.mesh_tier = tier
     app = BeaconApp(engine=eng)
     try:
-        yield app, eng, tier, rec
+        yield app, eng, rec
     finally:
         app.close()
-        tier.close()
         eng.close()
         tel.flight_recorder = old
 
@@ -136,19 +131,18 @@ def test_padding_waste_math_at_tier_boundaries():
     by_tier = rec.snapshot()["padWaste"]["byTier"]
     assert by_tier["fused:64"] == pytest.approx(1 - 73 / 128, abs=1e-3)
     assert rec.pad_waste_by_family()["fused"] == by_tier["fused:64"]
-    # a sliced mesh launch: 4 real queries over 8 device slots of
-    # tier 1 -> half the evaluated slots were inert fillers
+    # a mesh launch: one query over 4 real datasets of a stack padded
+    # to 8 dataset slots -> half the evaluated slots were inert fillers
     rec.record_launch(
-        "mesh_sliced",
+        "mesh",
         seam="mesh",
         tier=1,
         specs_real=4,
         specs_padded=8,
         evaluated_pairs=8,
-        sliced=True,
     )
-    assert rec.pad_waste_by_family()["mesh_sliced"] == 0.5
-    assert rec.sliced_launches == 1
+    assert rec.pad_waste_by_family()["mesh"] == 0.5
+    assert rec.mesh_launches == 1
     assert rec.evaluated_pairs == 8
 
 
@@ -172,15 +166,13 @@ def test_recorder_seam_counters_feed_module_properties(monkeypatch):
         specs_real=2,
         specs_padded=8,
         evaluated_pairs=64,
-        sliced=True,
     )
     rec.record_launch(
         "scatter", seam="scatter", tier=64, specs_real=3, specs_padded=64
     )
     assert kernel_mod.N_LAUNCHES == 1
     assert mesh_mod.N_LAUNCHES == 1
-    assert mesh_mod.N_SLICED_LAUNCHES == 1
-    assert mesh_mod.N_EVALUATED_PAIRS == 64
+    assert rec.evaluated_pairs == 64
     assert scatter_mod.N_DISPATCHES == 1
     with pytest.raises(AttributeError):
         mesh_mod.N_NO_SUCH_COUNTER
@@ -192,7 +184,6 @@ GOLDEN_DEVICE_KEYS = {
     "total",
     "byFamily",
     "targetsByFamily",
-    "sliced",
     "evaluatedPairs",
     "fetchedBytes",
     "donatedBuffers",
@@ -232,12 +223,16 @@ GOLDEN_HBM_KEYS = {
 
 @obs
 def test_device_status_golden_schema_and_reconciliation(warm_stack):
-    app, eng, tier, rec = warm_stack
-    eng.search(_payload())  # at least one serving-path launch recorded
+    app, eng, rec = warm_stack
+    # at least one serving-path launch of each stack recorded: the two
+    # datasets in one mesh launch, one dataset through the batcher
+    eng.search(_payload())
+    eng.search(_payload(dataset_ids=["d0"]))
     status, doc = app.handle("GET", "/device/status")
     assert status == 200
     assert set(doc) == GOLDEN_DEVICE_KEYS
-    assert doc["total"] >= 1 and doc["byFamily"].get("fused", 0) >= 1
+    assert doc["total"] >= 2 and doc["byFamily"].get("fused", 0) >= 1
+    assert doc["byFamily"].get("mesh", 0) >= 1
     entries = doc["ring"]["entries"]
     assert entries and all(
         GOLDEN_RING_ENTRY_KEYS <= set(e) for e in entries
@@ -256,17 +251,24 @@ def test_device_status_golden_schema_and_reconciliation(warm_stack):
     assert set(doc["hbm"]) == GOLDEN_HBM_KEYS
     # the HBM numbers reconcile with the engine's own ledger: the
     # budget is a chip's, and the gates read the fullest chip
-    assert doc["hbm"]["fullestChipBytes"] == eng.plane_hbm_resident()
+    by_chip: dict = {}
+    for _k, _s, p in eng.index_snapshot():
+        if p is not None:
+            by_chip[str(p.device)] = (
+                by_chip.get(str(p.device), 0) + p.nbytes_hbm()
+            )
+    assert doc["hbm"]["fullestChipBytes"] == max(by_chip.values()) > 0
     assert (
         doc["hbm"]["headroomBytes"]
         == doc["hbm"]["budgetBytes"] - doc["hbm"]["fullestChipBytes"]
     )
-    # stack states: fused stack + mesh tier, with identity and age
+    # stack states: the fused stack with identity and age, and no
+    # stack beside the engine's own
+    assert set(doc["stacks"]) == {"fused"}
     assert doc["stacks"]["fused"]["built"] is True
     assert doc["stacks"]["fused"]["fingerprint"]
-    mesh = doc["stacks"]["meshTier"]
-    assert mesh["ready"] is True and mesh["fingerprint"]
-    assert mesh["ageS"] is not None and "refusals" in mesh
+    # ... and the mesh stack's slices, by chip, beside tiles and planes
+    assert any(kind == "stack" for _chip, kind in eng.resident_bytes())
     # compile cache vs warmup shape set: everything so far was warmed
     assert doc["compiles"]["enabled"] is True
     assert doc["compiles"]["warmupShapes"]
@@ -290,7 +292,7 @@ def test_device_status_answers_during_stack_rebuild(warm_stack):
     """Acceptance: /device/status must answer while a publish/rebuild
     holds the engine's publish lock — the HBM ledger serves its last
     snapshot flagged stale instead of queueing behind the lock."""
-    app, eng, _tier, _rec = warm_stack
+    app, eng, _rec = warm_stack
     app.handle("GET", "/device/status")  # prime the ledger cache
     assert eng._mesh_lock.acquire(timeout=5)
     try:
@@ -321,38 +323,36 @@ def test_device_status_answers_during_stack_rebuild(warm_stack):
 @obs
 def test_warm_paths_record_zero_compile_events(warm_stack):
     """The perf-smoke warm paths — cached repeat, fused serving, the
-    mesh tier's sliced layout, the plane shapes — must record ZERO
+    engine's mesh launch, the plane shapes — must record ZERO
     device.compile events end-to-end after warmup: every program they
     dispatch was stamped during a warmup phase."""
-    app, eng, tier, rec = warm_stack
-    eng_cfg = eng.config.engine
+    _app, eng, rec = warm_stack
     seq0 = journal.last_seq()
     c0 = rec.mid_request_compiles()
-    # fused serving path + the cached repeat
+    f0 = rec.launches_by_family()
+    # the mesh launch (two datasets) + the cached repeat
+    eng.search(_payload(no_response_cache=True))
     eng.search(_payload())
     eng.search(_payload())
-    # mesh tier at a warmed slice shape (one query per owning device)
-    state = tier._ready(wait=True)
-    assert state is not None
-    index = state[0]
-    spec = QuerySpec("1", 1, 1, 1, 2)
-    index.run_mesh_queries(
-        encode_queries([spec] * N_SHARDS, shard_ids=[0, 1]),
-        window_cap=eng_cfg.window_cap,
-        record_cap=eng_cfg.record_cap,
-    )
-    if index.has_planes:
-        import numpy as np
-
-        index.run_mesh_queries(
-            encode_queries([spec] * N_SHARDS, shard_ids=[0, 1]),
-            window_cap=eng_cfg.window_cap,
-            record_cap=eng_cfg.record_cap,
-            sample_masks=np.zeros(
-                (N_SHARDS, index.plane_words), np.uint32
-            ),
-            mask_counts=np.zeros(N_SHARDS, np.bool_),
+    # the fused serving path (one dataset, through the batcher)
+    eng.search(_payload(dataset_ids=["d1"], no_response_cache=True))
+    # the plane shapes: carriers' names, and counts over a selection
+    names = {f"d{d}": ["S1"] for d in range(N_SHARDS)}
+    for kw in (
+        dict(include_samples=True),
+        dict(selected_samples_only=True, sample_names=names),
+    ):
+        eng.search(
+            _payload(
+                requested_granularity="record",
+                include_datasets="ALL",
+                no_response_cache=True,
+                **kw,
+            )
         )
+    f1 = rec.launches_by_family()
+    for family in ("mesh", "fused", "plane"):
+        assert f1.get(family, 0) > f0.get(family, 0), family
     assert rec.mid_request_compiles() - c0 == 0
     assert journal.events(since=seq0, kind="device.compile") == []
 
@@ -360,10 +360,10 @@ def test_warm_paths_record_zero_compile_events(warm_stack):
 @obs
 def test_warmup_ladder_parity_lint_green_on_warm_stack(warm_stack):
     """ISSUE 17 satellite: after warmup, EVERY rung of the active
-    TierLadder is covered by a warmup-phase compile — the fused host
-    ladder at every serving rung, the mesh tier at every slice rung up
-    to MESH_WARM_CAP, and the plane program at the same mesh shapes.
-    An uncovered cell is a batch shape that would pay a mid-request
+    TierLadder is covered by a warmup-phase compile for the fused
+    family (the batcher pads its launches to a rung), and the engine's
+    mesh program at the one batch it serves (a request a launch). An
+    uncovered cell is a batch shape that would pay a mid-request
     compile, which test_warm_paths_record_zero_compile_events would
     only catch for the specific shapes it happens to dispatch."""
     import sys
@@ -381,17 +381,9 @@ def test_warmup_ladder_parity_lint_green_on_warm_stack(warm_stack):
         sys.path.pop(0)
     from sbeacon_tpu.ops.kernel import active_ladder
 
-    _app, _eng, tier, rec = warm_stack
-    state = tier._ready(wait=True)
-    assert state is not None
-    mesh_fams = (
-        ("mesh_sliced", "plane")
-        if state[0].has_planes
-        else ("mesh_sliced",)
-    )
-    expected = expected_warm_rungs(
-        active_ladder(), families=("fused",), mesh_families=mesh_fams
-    )
+    _app, _eng, rec = warm_stack
+    expected = expected_warm_rungs(active_ladder(), families=("fused",))
+    expected["mesh"] = (1,)
     errs = lint_warmup_ladder(rec.compile_snapshot(), expected)
     assert errs == [], errs
 
@@ -404,7 +396,7 @@ def test_unwarmed_shape_is_one_named_mid_request_compile(warm_stack):
     /debug/status diagnosis must name it."""
     from sbeacon_tpu.ops.kernel import run_queries
 
-    app, eng, _tier, rec = warm_stack
+    app, eng, rec = warm_stack
     seq0 = journal.last_seq()
     c0 = rec.mid_request_compiles()
     shard = eng._indexes[sorted(eng._indexes)[0]][0]
@@ -439,31 +431,46 @@ def test_unwarmed_shape_is_one_named_mid_request_compile(warm_stack):
 
 
 @obs
-def test_hbm_ledger_tokens_visible_and_released_on_tier_close():
-    """External plane reservations (the mesh tier's stacked planes)
-    appear in the ledger snapshot and vanish when the tier closes —
-    the /device/status view of engine.register_plane_bytes."""
-    from sbeacon_tpu.parallel.dispatch import MeshDispatchTier
+def test_hbm_ledger_shows_an_upload_in_flight_and_its_release(monkeypatch):
+    """A plane upload holds a reservation against its owner chip from
+    the budget gate until the planes are published: in flight the
+    ledger shows one token and the bytes the gate reserved, published
+    they count as resident and the token is gone."""
+    import sbeacon_tpu.ops.plane_kernel as plane_mod
 
+    rng = random.Random(5)
+    shard = build_index(
+        random_records(rng, chrom="1", n=120, n_samples=2),
+        dataset_id="up",
+        vcf_location="up.vcf.gz",
+        sample_names=["S0", "S1"],
+    )
     eng = VariantEngine(BeaconConfig())
+    in_flight = {}
+
+    class Uploading(plane_mod.PlaneDeviceIndex):
+        def __init__(self, *args, **kw):
+            # the ledger as a /device/status poll would see it mid-upload
+            in_flight.update(eng.plane_ledger())
+            super().__init__(*args, **kw)
+
+    monkeypatch.setattr(plane_mod, "PlaneDeviceIndex", Uploading)
     try:
         led = eng.plane_ledger()
         assert led["reservedTokens"] == 0 and led["reservedBytes"] == 0
         assert led["stale"] is False
-        token = object()
-        eng.register_plane_bytes(token, 1_000_000)
-        tier = MeshDispatchTier(eng)
-        eng.register_plane_bytes(tier, 2_000_000)  # the stack's bytes
+        eng.add_index(shard)
+        assert in_flight["reservedTokens"] == 1
+        assert in_flight["reservedBytes"] > 0
+        assert in_flight["residentBytes"] == 0
+        assert in_flight["fullestChipBytes"] == in_flight["reservedBytes"]
+        assert (
+            in_flight["headroomBytes"]
+            == in_flight["budgetBytes"] - in_flight["reservedBytes"]
+        )
         led = eng.plane_ledger()
-        assert led["reservedTokens"] == 2
-        assert led["reservedBytes"] == 3_000_000
-        assert led["headroomBytes"] == led["budgetBytes"] - 3_000_000
-        tier.close()  # must release exactly the tier's reservation
-        led = eng.plane_ledger()
-        assert led["reservedTokens"] == 1
-        assert led["reservedBytes"] == 1_000_000
-        eng.register_plane_bytes(token, 0)
-        assert eng.plane_ledger()["reservedBytes"] == 0
+        assert led["reservedTokens"] == 0 and led["reservedBytes"] == 0
+        assert led["residentBytes"] == led["fullestChipBytes"] > 0
     finally:
         eng.close()
 

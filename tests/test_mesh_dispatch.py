@@ -1,16 +1,21 @@
-"""Pod-local SPMD dispatch (ISSUE 9): the mesh-sharded fused index and
-the MeshDispatchTier.
+"""A coordinator's local datasets are answered by the engine's own
+search (ISSUE 47): ``DistributedEngine(workers, local=engine)`` hands
+every local dataset to ``engine.search`` on the request's thread,
+beside the worker fan-out, and holds no stack, program or route of its
+own. Whatever the granularity and shape, its responses are the
+engine's, response for response, and the plain reference's
+(``sbeacon_tpu/oracle/cpu_oracle.py``).
 
-The conftest forces an 8-virtual-CPU-device mesh, so the shard_map
-program runs in-process here exactly as the driver's dryrun does; every
-mesh test still skips cleanly when only one device is visible (running
-a file standalone without the conftest flags must not fail). The
-pristine-process single-launch contract additionally runs in a
-subprocess (``mesh_tier_worker.py``, the multihost_worker pattern) so
-its launch counters cannot be polluted by sibling tests.
+The conftest forces eight virtual CPU devices, so a multi-dataset
+boolean or count is ONE launch of the engine's mesh program here, as on
+a host of several chips; the mesh tests skip where only one device is
+visible. The one-launch, zero-worker-calls contract also runs in a
+pristine subprocess (``mesh_tier_worker.py``) whose counters no sibling
+test can have moved.
 """
 
 import dataclasses
+import gc
 import json
 import os
 import random
@@ -20,55 +25,59 @@ import time
 from pathlib import Path
 
 import jax
-import numpy as np
 import pytest
 
+import sbeacon_tpu.engine as engine_mod
+import sbeacon_tpu.telemetry as tel
 from sbeacon_tpu.config import BeaconConfig, EngineConfig
 from sbeacon_tpu.engine import VariantEngine
-from sbeacon_tpu.harness import faults
 from sbeacon_tpu.index.columnar import build_index
-from sbeacon_tpu.parallel import mesh as mesh_mod
-from sbeacon_tpu.parallel.dispatch import (
-    DistributedEngine,
-    MeshDispatchTier,
-    WorkerServer,
-)
-from sbeacon_tpu.parallel.mesh import MeshFusedIndex, make_mesh
+from sbeacon_tpu.ops.scatter_kernel import ScatterDeviceIndex
+from sbeacon_tpu.oracle import oracle_search
+from sbeacon_tpu.parallel.dispatch import DistributedEngine, WorkerServer
+from sbeacon_tpu.parallel.mesh import make_mesh
 from sbeacon_tpu.payloads import VariantQueryPayload
-from sbeacon_tpu.resilience import Deadline, DeadlineExceeded, deadline_scope
+from sbeacon_tpu.telemetry import MetricsRegistry
 from sbeacon_tpu.testing import random_records
 
 multi_device = pytest.mark.skipif(
     len(jax.devices()) < 2,
-    reason="mesh dispatch needs >=2 devices (forced-host CI mesh)",
+    reason="the engine's mesh stack needs >=2 devices (forced-host CI mesh)",
 )
 
 N_SHARDS = 4
+SAMPLES = ["S0", "S1"]
+LOCAL = [f"d{d}" for d in range(N_SHARDS)]
 
 
-def _shards(n=N_SHARDS, chrom="1", rows=250):
-    out = []
-    for d in range(n):
-        rng = random.Random(40 + d)
-        recs = random_records(rng, chrom=chrom, n=rows, n_samples=2)
-        out.append(
-            build_index(
-                recs,
-                dataset_id=f"d{d}",
-                vcf_location=f"v{d}",
-                sample_names=["S0", "S1"],
-            )
-        )
-    return out
+def _records(d: int, rows: int = 250) -> list:
+    return random_records(
+        random.Random(40 + d), chrom="1", n=rows, n_samples=len(SAMPLES)
+    )
+
+
+def _shard(ds: str, vcf: str, records):
+    return build_index(
+        records, dataset_id=ds, vcf_location=vcf, sample_names=SAMPLES
+    )
 
 
 def _engine(shards, **over):
-    eng = VariantEngine(
-        BeaconConfig(engine=EngineConfig(use_mesh=False, **over))
-    )
+    over.setdefault("response_cache", False)
+    eng = VariantEngine(BeaconConfig(engine=EngineConfig(**over)))
     for s in shards:
         eng.add_index(s)
     return eng
+
+
+def _local_engine(**over):
+    """Four datasets on pure defaults (the mesh stack on, as a host of
+    several chips serves them), the micro-batcher not waiting."""
+    return _engine(
+        [_shard(f"d{d}", f"v{d}", _records(d)) for d in range(N_SHARDS)],
+        microbatch_wait_ms=0.0,
+        **over,
+    )
 
 
 def _payload(datasets, gran="count", include="HIT", **kw):
@@ -86,7 +95,15 @@ def _payload(datasets, gran="count", include="HIT", **kw):
     )
 
 
-# -- make_mesh device selection (satellite bugfix) ----------------------------
+def _dicts(responses) -> list:
+    return [dataclasses.asdict(r) for r in responses]
+
+
+def _launches() -> dict:
+    return tel.flight_recorder.launches_by_family()
+
+
+# -- make_mesh device selection -----------------------------------------------
 
 
 def test_make_mesh_explicit_devices():
@@ -109,326 +126,258 @@ def test_make_mesh_too_many_devices_is_loud():
         make_mesh(n_devices=len(jax.devices()) + 1)
 
 
-# -- MeshFusedIndex: layout + single-launch program parity --------------------
+# -- the local leg is the engine's own search ---------------------------------
+
+#: the request shapes a coordinator is held to, each as the keywords of
+#: its payload (``selected`` restricts the counts to one sample and asks
+#: for the carriers' names, so it reads the genotype planes)
+SHAPES = {
+    "boolean": dict(gran="boolean", include="NONE"),
+    "count": dict(gran="count", include="HIT"),
+    "record": dict(gran="record", include="HIT"),
+    "record-selected": dict(
+        gran="record",
+        include="ALL",
+        include_samples=True,
+        selected_samples_only=True,
+    ),
+    "aggregated": dict(gran="aggregated", include="ALL"),
+}
+TOPOLOGIES = ("all-local", "beside-a-worker", "one-local")
+TAILS = ("base", "delta-tail")
 
 
-@multi_device
-def test_mesh_fused_index_parity_per_pair():
-    """Every (shard, query) pair answered by the sharded program must
-    match the single-shard kernel — including an uneven dataset count
-    (empty device groups) and dataset-LOCAL row ids."""
-    from sbeacon_tpu.ops.kernel import (
-        DeviceIndex,
-        QuerySpec,
-        encode_queries,
-        run_queries,
+@pytest.fixture(scope="module", params=TAILS)
+def fleet(request):
+    """A local engine of four datasets (with, under ``delta-tail``, a
+    delta standing on d0), one HTTP worker serving a fifth, a
+    coordinator over the local engine alone and one over both; and the
+    records behind every dataset, for the plain reference."""
+    records = {f"d{d}": _records(d) for d in range(N_SHARDS)}
+    records["w0"] = random_records(
+        random.Random(7), chrom="1", n=150, n_samples=len(SAMPLES)
     )
-
-    shards = _shards(5, chrom="7")
-    mesh = make_mesh()
-    mfi = MeshFusedIndex(shards, mesh)
-    specs = [
-        QuerySpec("7", 1, 1 << 30, 1, 1 << 30, alternate_bases="N"),
-        QuerySpec("7", 1500, 2500, 1, 1 << 30, alternate_bases="N"),
-    ]
-    pairs = [(sp, sid) for sp in specs for sid in range(5)]
-    enc = encode_queries(
-        [sp for sp, _ in pairs], shard_ids=[sid for _, sid in pairs]
+    eng = _local_engine()
+    weng = _engine(
+        [_shard("w0", "w0.vcf.gz", records["w0"])],
+        use_mesh=False,
+        microbatch=False,
     )
-    res = mfi.run_mesh_queries(enc, window_cap=2048, record_cap=64)
-    for i, (spec, sid) in enumerate(pairs):
-        ref = run_queries(
-            DeviceIndex(shards[sid]), [spec], window_cap=2048, record_cap=64
-        )
-        assert res.exists[i] == ref.exists[0]
-        assert res.call_count[i] == ref.call_count[0]
-        assert res.all_alleles_count[i] == ref.all_alleles_count[0]
-        assert res.n_matched[i] == ref.n_matched[0]
-        assert res.overflow[i] == ref.overflow[0]
-        assert np.array_equal(
-            res.rows[i][res.rows[i] >= 0], ref.rows[0][ref.rows[0] >= 0]
-        )
-
-
-@multi_device
-def test_mesh_fused_index_requires_shard_ids():
-    shards = _shards(2)
-    mfi = MeshFusedIndex(shards, make_mesh())
-    enc = {"chrom": np.zeros(1, np.int32)}  # encoded without shard_ids
-    with pytest.raises(ValueError, match="shard ids"):
-        mfi.run_mesh_queries(enc, window_cap=2048, record_cap=64)
-
-
-@multi_device
-def test_run_mesh_queries_bare_list_is_loud():
-    """Satellite bugfix (ISSUE 13): a bare spec list used to silently
-    encode ``shard_ids=[0]*n`` — every query answered against shard
-    0's row span, wrong for any other target. Now a loud error."""
-    from sbeacon_tpu.ops.kernel import QuerySpec
-
-    shards = _shards(2)
-    mfi = MeshFusedIndex(shards, make_mesh())
-    with pytest.raises(ValueError, match="explicit shard ids"):
-        mfi.run_mesh_queries(
-            [QuerySpec("1", 1, 10, 1, 20)], window_cap=2048, record_cap=64
-        )
-
-
-@multi_device
-def test_sliced_layout_parity_and_eval_pair_scaling():
-    """The per-device sliced batch layout must answer every (shard,
-    query) pair byte-identically to the replicated layout AND the
-    single-shard kernel, while evaluating ~1/n_dev the per-device
-    pairs (the structural FLOP proxy, not wall-clock — forced-host
-    virtual devices share cores)."""
-    from sbeacon_tpu.ops.kernel import (
-        DeviceIndex,
-        QuerySpec,
-        encode_queries,
-        run_queries,
-    )
-
-    shards = _shards(5, chrom="7")
-    mfi = MeshFusedIndex(shards, make_mesh())
-    specs = [
-        QuerySpec("7", 1, 1 << 30, 1, 1 << 30, alternate_bases="N"),
-        QuerySpec("7", 1500, 2500, 1, 1 << 30, alternate_bases="N"),
-        QuerySpec("7", 900, 1600, 1, 1 << 30, alternate_bases="N"),
-    ]
-    pairs = [(sp, sid) for sp in specs for sid in range(5)]
-    enc = encode_queries(
-        [sp for sp, _ in pairs], shard_ids=[sid for _, sid in pairs]
-    )
-    e0 = mesh_mod.N_EVALUATED_PAIRS
-    res_s = mfi.run_mesh_queries(
-        dict(enc), window_cap=2048, record_cap=64, slice_batch=True
-    )
-    sliced_pairs = mesh_mod.N_EVALUATED_PAIRS - e0
-    e0 = mesh_mod.N_EVALUATED_PAIRS
-    res_r = mfi.run_mesh_queries(
-        dict(enc), window_cap=2048, record_cap=64, slice_batch=False
-    )
-    repl_pairs = mesh_mod.N_EVALUATED_PAIRS - e0
-    for name in (
-        "exists",
-        "call_count",
-        "n_variants",
-        "all_alleles_count",
-        "n_matched",
-        "overflow",
-        "rows",
-    ):
-        assert np.array_equal(
-            getattr(res_s, name), getattr(res_r, name)
-        ), name
-    for i, (spec, sid) in enumerate(pairs):
-        ref = run_queries(
-            DeviceIndex(shards[sid]), [spec], window_cap=2048, record_cap=64
-        )
-        assert res_s.call_count[i] == ref.call_count[0]
-        assert np.array_equal(
-            res_s.rows[i][res_s.rows[i] >= 0],
-            ref.rows[0][ref.rows[0] >= 0],
-        )
-    # the structural win: replicated evaluates the full padded batch on
-    # every device; sliced evaluates each device's own slice only
-    assert sliced_pairs * 2 <= repl_pairs, (sliced_pairs, repl_pairs)
-
-
-@multi_device
-def test_tier_refusal_reasons_are_counted():
-    """mesh.refusals{reason}: operators must be able to see WHY
-    traffic falls off the tier — unbuilt, min_shards, planes (a shape
-    the stack cannot serve), stale after a base publish."""
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    dist = DistributedEngine([], local=eng)
+    worker = WorkerServer(weng).start_background()
+    alone = DistributedEngine([], local=eng)
+    beside = DistributedEngine([worker.address], local=eng)
     try:
-        tier = dist.mesh_tier
-        ds = [s.meta["dataset_id"] for s in shards]
-        assert tier.resolve(ds, _payload(ds)) == set()  # nothing built
-        assert tier.stats()["refusals"].get("unbuilt", 0) >= 1
+        assert alone.warmup() > 0
+        if request.param == "delta-tail":
+            tail = random_records(
+                random.Random(77), chrom="1", n=40, n_samples=len(SAMPLES)
+            )
+            eng.add_delta(_shard("d0", "v0", tail))
+            records["d0"] = records["d0"] + tail
+        yield {
+            "tail": request.param,
+            "engine": eng,
+            "worker_engine": weng,
+            "all-local": (alone, LOCAL),
+            "one-local": (alone, ["d0"]),
+            "beside-a-worker": (beside, LOCAL + ["w0"]),
+            "records": records,
+        }
+    finally:
+        alone.close()
+        beside.close()
+        worker.shutdown()
+        eng.close()
+        weng.close()
+
+
+def _want(records: list, payload, ds: str):
+    """The plain reference's answer for one dataset."""
+    selected = payload.selected_samples_only
+    return oracle_search(
+        records,
+        first_bp=payload.start_min,
+        last_bp=payload.start_max,
+        end_min=payload.end_min,
+        end_max=payload.end_max,
+        reference_bases=payload.reference_bases,
+        alternate_bases=payload.alternate_bases,
+        variant_type=payload.variant_type,
+        requested_granularity=payload.requested_granularity,
+        include_details=payload.include_details,
+        include_samples=payload.include_samples,
+        sample_names=payload.sample_names[ds] if selected else SAMPLES,
+        dataset_id=ds,
+        chrom_label="1",
+        selected_sample_idx=(
+            [SAMPLES.index(n) for n in payload.sample_names[ds]]
+            if selected
+            else None
+        ),
+    )
+
+
+@multi_device
+@pytest.mark.parametrize("topology", TOPOLOGIES)
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_a_coordinators_local_leg_is_the_engines_own_search(
+    fleet, shape, topology
+):
+    """Every granularity and shape, over all the local datasets, over
+    the local datasets beside a worker's and over one local dataset,
+    with and without a standing delta tail: the coordinator answers
+    what ``engine.search`` answers, response for response, and what the
+    oracle answers, dataset by dataset."""
+    dist, datasets = fleet[topology]
+    eng, weng = fleet["engine"], fleet["worker_engine"]
+    kw = dict(SHAPES[shape])
+    if kw.get("selected_samples_only"):
+        kw["sample_names"] = {ds: ["S1"] for ds in datasets}
+    payload = _payload(datasets, kw.pop("gran"), kw.pop("include"), **kw)
+    local = [ds for ds in datasets if ds != "w0"]
+    before = _launches()
+    got = dist.search(payload)
+    after = _launches()
+
+    # (1) the engine's own search, response for response
+    own = eng.search(dataclasses.replace(payload, dataset_ids=local))
+    theirs = (
+        weng.search(dataclasses.replace(payload, dataset_ids=["w0"]))
+        if "w0" in datasets
+        else []
+    )
+    want = sorted(
+        own + theirs, key=lambda r: (r.dataset_id, r.vcf_location)
+    )
+    if topology == "beside-a-worker" and shape == "boolean":
+        # a boolean with no per-dataset detail is an OR: a local hit
+        # decides it and the worker's leg may be abandoned
+        assert any(r.exists for r in own)
+        got_local = [r for r in got if r.dataset_id != "w0"]
+        assert _dicts(got_local) == _dicts(own)
+        assert _dicts(got) in (_dicts(want), _dicts(own))
+    else:
+        assert _dicts(got) == _dicts(want), (shape, topology)
+    assert {r.dataset_id for r in got} >= set(local)
+
+    # (2) the oracle, dataset by dataset (a dataset with a standing
+    # tail answers in several responses: their sum is the dataset's)
+    for ds in {r.dataset_id for r in got}:
+        mine = [r for r in got if r.dataset_id == ds]
+        ref = _want(fleet["records"][ds], payload, ds)
+        assert any(r.exists for r in mine) == ref.exists, (shape, ds)
+        if shape == "boolean":
+            continue  # the count stops at the first hit, a response each
+        assert sum(r.call_count for r in mine) == ref.call_count, (shape, ds)
+        assert (
+            sum(r.all_alleles_count for r in mine) == ref.all_alleles_count
+        ), (shape, ds)
+        assert sorted(v for r in mine for v in r.variants) == sorted(
+            ref.variants
+        ), (shape, ds)
+        if payload.include_samples:
+            assert {n for r in mine for n in r.sample_names} == set(
+                ref.sample_names
+            ), (shape, ds)
+
+    # (3) the route: several local datasets under a boolean or a count
+    # are ONE launch of the engine's mesh program over their base rows
+    if len(local) > 1 and shape in ("boolean", "count"):
+        assert after.get("mesh", 0) - before.get("mesh", 0) == 1
+    if topology == "one-local" and fleet["tail"] == "base":
+        # one shard is no fan-out: the engine asks its own index
+        assert after.get("mesh", 0) == before.get("mesh", 0)
+
+
+def _resident_by_kind(engine) -> dict:
+    """``device.resident_bytes`` as ``/metrics`` serves it, by kind."""
+    registry = MetricsRegistry()
+    engine.register_metrics(registry)
+    by_kind: dict = {}
+    by_chip = registry.render_json()["device"]["resident_bytes"]
+    for kinds in by_chip.values():
+        for kind, nbytes in kinds.items():
+            by_kind[kind] = by_kind.get(kind, 0) + nbytes
+    return by_kind
+
+
+def _live_bytes_added(build) -> tuple:
+    """(what ``build()`` returned, device bytes alive after it that were
+    not before): the least of three readings, so an array another
+    module's thread holds for a moment is not counted."""
+    gc.collect()
+    held = jax.live_arrays()  # held, so no later array takes an id of theirs
+    before = {id(a) for a in held}
+    built = build()
+    readings = []
+    for _ in range(3):
+        gc.collect()
+        readings.append(
+            sum(a.nbytes for a in jax.live_arrays() if id(a) not in before)
+        )
+        time.sleep(0.05)
+    return built, min(readings)
+
+
+@multi_device
+def test_a_coordinator_holds_no_stack_of_its_own():
+    """After ``warmup()`` a coordinator with a local engine holds on the
+    devices what the plain engine holds: ``device.resident_bytes`` by
+    kind is the engine's, and no array is alive beside the engine's."""
+
+    def plain():
+        eng = _local_engine()
+        assert eng.warmup() > 0
+        return eng
+
+    def coordinated():
+        eng = _local_engine()
+        dist = DistributedEngine([], local=eng)
         assert dist.warmup() > 0
-        assert tier.resolve(["d0"], _payload(["d0"])) == set()
-        assert tier.stats()["refusals"].get("min_shards", 0) == 1
-        # an N inside the ref needs host regex semantics for the
-        # selected-samples leaf: the plane path must refuse
-        pay = _payload(
-            ds,
-            "record",
-            "ALL",
-            selected_samples_only=True,
-            sample_names={d: ["S0"] for d in ds},
-            reference_bases="AN",
-        )
-        assert tier.resolve(ds, pay) == set()
-        assert tier.stats()["refusals"].get("planes", 0) == 1
-        # base publish: the very next consult sees a stale stack
-        eng.add_index(
-            build_index(
-                random_records(
-                    random.Random(123), chrom="1", n=80, n_samples=2
-                ),
-                dataset_id="late2",
-                vcf_location="late2.vcf.gz",
-                sample_names=["S0", "S1"],
-            )
-        )
-        assert tier.resolve(ds, _payload(ds)) == set()
-        assert tier.stats()["refusals"].get("stale", 0) >= 1
-        # the series rides dispatch_stats -> register_dispatch_metrics
-        assert dist.dispatch_stats()["mesh_refusals"].get("unbuilt", 0) >= 1
-    finally:
-        dist.close()
-        eng.close()
+        return eng, dist
 
-
-@multi_device
-def test_tier_plane_stack_counts_against_engine_budget():
-    """Bidirectional HBM accounting: the tier's standing plane stack
-    registers in the engine's plane reservation ledger, so a
-    post-build per-dataset upload gate sees it and cannot overcommit
-    the device by the stack's size."""
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    dist = DistributedEngine([], local=eng)
+    eng, plain_bytes = _live_bytes_added(plain)
     try:
-        before = eng.plane_hbm_resident()
-        dist.warmup()
-        tier = dist.mesh_tier
-        assert tier.stats()["planes"] is True
-        stack_bytes = tier._state[0].plane_bytes_device
-        assert stack_bytes > 0
-        assert eng.plane_hbm_resident() >= before + stack_bytes
+        plain_kinds = _resident_by_kind(eng)
     finally:
-        dist.close()
         eng.close()
-
-
-@multi_device
-def test_tier_plane_parity_suite():
-    """Per-granularity parity of the tier's with_planes single-launch
-    path against the per-dataset VariantEngine answers, across
-    selected-samples and sample-extraction shapes."""
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    eng_ref = _engine(_shards(), microbatch=False, mesh_dispatch=False)
-    dist = DistributedEngine([], local=eng)
+        del eng
+    (eng, dist), coordinated_bytes = _live_bytes_added(coordinated)
     try:
-        dist.warmup()
-        assert dist.mesh_tier.stats()["planes"] is True
-        ds = [s.meta["dataset_id"] for s in shards]
-        for gran in ("boolean", "count", "record"):
-            for mode in ("selected", "extract"):
-                kw = (
-                    dict(
-                        selected_samples_only=True,
-                        sample_names={d: ["S1"] for d in ds},
-                    )
-                    if mode == "selected"
-                    else dict(include_samples=True)
-                )
-                pay = _payload(ds, gran, "ALL", **kw)
-                got = dist.search(pay)
-                ref = eng_ref.search(pay)
-                assert [dataclasses.asdict(r) for r in got] == [
-                    dataclasses.asdict(r) for r in ref
-                ], (gran, mode)
-        # every selected-samples query (and the record/aggregated
-        # extraction) rode the tier, not the per-dataset engine path
-        assert dist.mesh_tier.stats()["dispatches"] >= 4
+        assert _resident_by_kind(dist) == plain_kinds
+        assert set(plain_kinds) >= {"stack"} and plain_kinds["stack"] > 0
+        assert coordinated_bytes == plain_bytes > 0
+        assert dist.search(_payload(LOCAL))  # and it serves
     finally:
         dist.close()
         eng.close()
-        eng_ref.close()
+
+
+# -- parity, route by route ---------------------------------------------------
 
 
 @multi_device
-def test_tier_planes_stay_warm_across_delta_publish():
-    """A delta publish must NOT cold-start the plane-stacked tier: the
-    mesh launch keeps serving base rows, the delta tail host-matches
-    next to it (with the selected-samples mask applied), and a later
-    base publish rebuilds with planes stacked again."""
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    eng_ref = _engine(_shards(), microbatch=False, mesh_dispatch=False)
-    dist = DistributedEngine([], local=eng)
-    try:
-        dist.warmup()
-        tier = dist.mesh_tier
-
-        def delta():
-            return build_index(
-                random_records(
-                    random.Random(77), chrom="1", n=40, n_samples=2
-                ),
-                dataset_id="d0",
-                vcf_location="v0",
-                sample_names=["S0", "S1"],
-            )
-
-        eng.add_delta(delta())
-        eng_ref.add_delta(delta())
-        ds = [s.meta["dataset_id"] for s in shards]
-        pay = _payload(
-            ds,
-            "record",
-            "ALL",
-            selected_samples_only=True,
-            sample_names={d: ["S0"] for d in ds},
-        )
-        got = dist.search(pay)
-        ref = eng_ref.search(pay)
-        assert [dataclasses.asdict(r) for r in got] == [
-            dataclasses.asdict(r) for r in ref
-        ]
-        st = tier.stats()
-        assert st["dispatches"] == 1 and st["ready"] and st["planes"]
-        # base publish -> stale -> inline rebuild stacks planes again
-        eng.add_index(
-            build_index(
-                random_records(
-                    random.Random(5), chrom="1", n=60, n_samples=2
-                ),
-                dataset_id="late3",
-                vcf_location="late3.vcf.gz",
-                sample_names=["S0", "S1"],
-            )
-        )
-        assert tier.warmup() > 0
-        assert tier.stats()["planes"] is True
-        assert tier.stats()["shards"] == N_SHARDS + 1
-    finally:
-        dist.close()
-        eng.close()
-        eng_ref.close()
-
-
-# -- MeshDispatchTier through DistributedEngine -------------------------------
-
-
-@multi_device
-def test_tier_parity_across_granularities():
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    eng_ref = _engine(_shards(), microbatch=False, mesh_dispatch=False)
+def test_local_leg_parity_across_granularities():
+    """The coordinator over an engine on its mesh stack against a plain
+    engine with the mesh off and no batcher: another route, the same
+    responses."""
+    eng = _local_engine()
+    eng_ref = _local_engine(use_mesh=False, microbatch=False)
     dist = DistributedEngine([], local=eng)
     try:
         assert dist.warmup() > 0
-        assert dist.mesh_tier is not None and dist.mesh_tier.stats()["ready"]
+        searches = eng.mesh_searches
         for gran, include in [
             ("boolean", "NONE"),
             ("count", "HIT"),
             ("record", "HIT"),
             ("aggregated", "ALL"),
         ]:
-            pay = _payload([s.meta["dataset_id"] for s in shards], gran, include)
-            got = dist.search(pay)
-            ref = eng_ref.search(pay)
-            assert [dataclasses.asdict(r) for r in got] == [
-                dataclasses.asdict(r) for r in ref
-            ], (gran, include)
-        assert dist.mesh_tier.stats()["dispatches"] >= 3
+            pay = _payload(LOCAL, gran, include)
+            assert _dicts(dist.search(pay)) == _dicts(eng_ref.search(pay)), (
+                gran, include,
+            )
+        assert eng.mesh_searches >= searches + 2
     finally:
         dist.close()
         eng.close()
@@ -436,50 +385,66 @@ def test_tier_parity_across_granularities():
 
 
 @multi_device
-def test_tier_rides_microbatcher():
-    """The mesh launch goes through serving's MicroBatcher: a 4-target
-    query lands as one 4-spec submit_many entry (fused_hist key 4), so
-    coalescing/pipelining semantics apply to pod dispatch unchanged."""
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
+def test_local_leg_plane_parity_suite():
+    """Selected-samples and sample-extraction shapes at every
+    granularity: the coordinator's answers are the plain engine's."""
+    eng = _local_engine()
+    eng_ref = _local_engine(use_mesh=False, microbatch=False)
     dist = DistributedEngine([], local=eng)
     try:
         dist.warmup()
-        dist.search(_payload([s.meta["dataset_id"] for s in shards]))
-        occ = eng.batcher.occupancy()
-        assert 4 in occ["fused_hist"] or "4" in occ["fused_hist"]
-        assert dist.mesh_tier.stats()["dispatches"] == 1
+        for gran in ("boolean", "count", "record"):
+            for mode in ("selected", "extract"):
+                kw = (
+                    dict(
+                        selected_samples_only=True,
+                        sample_names={d: ["S1"] for d in LOCAL},
+                    )
+                    if mode == "selected"
+                    else dict(include_samples=True)
+                )
+                pay = _payload(LOCAL, gran, "ALL", **kw)
+                assert _dicts(dist.search(pay)) == _dicts(
+                    eng_ref.search(pay)
+                ), (gran, mode)
     finally:
         dist.close()
         eng.close()
+        eng_ref.close()
 
 
 @multi_device
-def test_tier_plane_shapes_ride_the_single_launch():
-    """Selected-samples / sample-extraction shapes now ride the tier's
-    plane-stacked single launch (ISSUE 13) instead of refusing to
-    per-dataset dispatch — with answers identical to the engine path."""
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    eng_ref = _engine(_shards(), microbatch=False, mesh_dispatch=False)
+def test_plane_shapes_ride_one_launch_an_owner_chip(monkeypatch):
+    """A sample-extraction request over cohorts on several chips is one
+    match+planes launch an OWNER chip (``device.launches{plane}``; the
+    chip's index family forced on the CPU, where placement gives every
+    dataset a chip of its own), with the answers of the plain engine."""
+    eng_ref = _local_engine(use_mesh=False, microbatch=False)
+    monkeypatch.setattr(
+        engine_mod,
+        "make_device_index",
+        lambda shard, **kw: ScatterDeviceIndex(shard, device=kw.get("device")),
+    )
+    rec = tel.DeviceFlightRecorder()
+    monkeypatch.setattr(tel, "flight_recorder", rec)
+    eng = _local_engine()
     dist = DistributedEngine([], local=eng)
     try:
         dist.warmup()
-        assert dist.mesh_tier.stats()["planes"] is True
-        pay = _payload(
-            [s.meta["dataset_id"] for s in shards],
-            "record",
-            "ALL",
-            include_samples=True,
-        )
+        owners = {row["chip"] for row in eng.placement_table()}
+        pay = _payload(LOCAL, "record", "ALL", include_samples=True)
+        want = eng_ref.search(pay)
+        before = rec.launches_by_family().get("plane", 0)
         got = dist.search(pay)
-        ref = eng_ref.search(pay)
+        assert (
+            rec.launches_by_family().get("plane", 0) - before
+            == len(owners)
+            > 1
+        )
         assert len(got) == N_SHARDS
         assert all(r.sample_names for r in got if r.exists)
-        assert [dataclasses.asdict(r) for r in got] == [
-            dataclasses.asdict(r) for r in ref
-        ]
-        assert dist.mesh_tier.stats()["dispatches"] == 1
+        assert _dicts(got) == _dicts(want)
+        assert rec.fallbacks_by_site() == {}
     finally:
         dist.close()
         eng.close()
@@ -487,146 +452,32 @@ def test_tier_plane_shapes_ride_the_single_launch():
 
 
 @multi_device
-def test_tier_goes_cold_on_ingest_then_rebuilds():
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    dist = DistributedEngine([], local=eng)
-    try:
-        dist.warmup()
-        tier = dist.mesh_tier
-        pay = _payload([s.meta["dataset_id"] for s in shards])
-        dist.search(pay)
-        assert tier.stats()["dispatches"] == 1
-        # a publish bumps the fingerprint: the tier refuses to serve a
-        # stale stack (scatter answers) until the rebuild completes
-        extra = build_index(
-            random_records(random.Random(99), chrom="1", n=100, n_samples=2),
-            dataset_id="late",
-            vcf_location="late.vcf.gz",
-            sample_names=["S0", "S1"],
-        )
-        eng.add_index(extra)
-        got = dist.search(pay)  # stale stack refused; scatter answers
-        assert len(got) == N_SHARDS
-        assert tier.warmup() > 0  # inline rebuild picks up the new shard
-        assert tier.stats()["shards"] == N_SHARDS + 1
-        dist.search(_payload(["d0", "d1", "late"]))
-        # >= 2, not == 2: the background rebuild may have finished fast
-        # enough to serve the intermediate query too
-        assert tier.stats()["dispatches"] >= 2
-    finally:
-        dist.close()
-        eng.close()
-
-
-@multi_device
-@pytest.mark.resilience
-def test_tier_fallback_on_seeded_fault():
-    """A seeded mesh.dispatch fault must fall back ONCE to the scatter
-    path: the query still answers, mesh.fallbacks ticks, and the
-    flight recorder carries the mesh.fallback event."""
-    from sbeacon_tpu.telemetry import journal
-
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    dist = DistributedEngine([], local=eng)
-    try:
-        dist.warmup()
-        seq0 = journal.last_seq()
-        faults.install(
-            {
-                "seed": 3,
-                "rules": [
-                    {"site": "mesh.dispatch", "kind": "error", "rate": 1.0}
-                ],
-            }
-        )
-        try:
-            got = dist.search(_payload([s.meta["dataset_id"] for s in shards]))
-        finally:
-            faults.uninstall()
-        assert len(got) == N_SHARDS and all(r.exists for r in got)
-        st = dist.mesh_tier.stats()
-        assert st["fallbacks"] == 1 and st["dispatches"] == 0
-        kinds = [e["kind"] for e in journal.events(since=seq0)]
-        assert "mesh.fallback" in kinds
-        # the fallback is once-per-query, not a latch: the next query
-        # rides the mesh tier again
-        got2 = dist.search(_payload([s.meta["dataset_id"] for s in shards]))
-        assert len(got2) == N_SHARDS
-        assert dist.mesh_tier.stats()["dispatches"] == 1
-    finally:
-        dist.close()
-        eng.close()
-
-
-@multi_device
-@pytest.mark.resilience
-def test_tier_deadline_expiry_never_falls_back():
-    """DeadlineExceeded is the REQUEST's fault: re-running the query on
-    the scatter would only burn more of nobody's time budget."""
-    shards = _shards()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
-    dist = DistributedEngine([], local=eng)
-    try:
-        dist.warmup()
-        with deadline_scope(Deadline.after(0.001)):
-            time.sleep(0.01)  # the deadline is certainly lapsed
-            with pytest.raises(DeadlineExceeded):
-                dist.search(_payload([s.meta["dataset_id"] for s in shards]))
-        assert dist.mesh_tier.stats()["fallbacks"] == 0
-    finally:
-        dist.close()
-        eng.close()
-
-
-@multi_device
-def test_tier_mixed_query_splits_mesh_and_http():
-    """Datasets on the local mesh ride the single launch; a dataset only
-    a worker serves keeps the pooled-HTTP scatter — one query, both
-    tiers, one merged response set."""
-    shards = _shards()
+def test_a_query_over_local_and_worker_datasets_runs_both_legs():
+    """Local datasets ride the engine's one mesh launch on the request's
+    thread; a dataset only a worker serves keeps the pooled-HTTP
+    scatter: one query, both legs, one merged response set."""
     weng = _engine(
-        [
-            build_index(
-                random_records(random.Random(7), chrom="1", n=150, n_samples=2),
-                dataset_id="w0",
-                vcf_location="w0.vcf.gz",
-                sample_names=["S0", "S1"],
-            )
-        ],
+        [_shard("w0", "w0.vcf.gz", random_records(
+            random.Random(7), chrom="1", n=150, n_samples=2
+        ))],
+        use_mesh=False,
         microbatch=False,
-        mesh_dispatch=False,
     )
     worker = WorkerServer(weng).start_background()
-    eng = _engine(shards, microbatch_wait_ms=0.0)
+    eng = _local_engine()
     dist = DistributedEngine([worker.address], local=eng)
     try:
         dist.warmup()
-        got = dist.search(
-            _payload([s.meta["dataset_id"] for s in shards] + ["w0"])
-        )
+        searches, mesh = eng.mesh_searches, _launches().get("mesh", 0)
+        got = dist.search(_payload(LOCAL + ["w0"]))
         assert [r.dataset_id for r in got] == ["d0", "d1", "d2", "d3", "w0"]
-        assert dist.mesh_tier.stats()["dispatches"] == 1
+        assert eng.mesh_searches == searches + 1
+        assert _launches().get("mesh", 0) == mesh + 1
     finally:
         dist.close()
         worker.shutdown()
         eng.close()
         weng.close()
-
-
-def test_tier_unavailable_on_single_device():
-    """With one visible device the tier must report unavailable and
-    resolve nothing — the engine's own paths already serve that case."""
-    shards = _shards(2)
-    eng = _engine(shards, microbatch=False)
-    try:
-        tier = MeshDispatchTier(eng, devices=jax.devices()[:1])
-        assert not tier.available()
-        assert tier.resolve(["d0", "d1"], _payload(["d0", "d1"])) == set()
-        assert tier.warmup() == 0
-    finally:
-        eng.close()
 
 
 # -- pristine-process single-launch contract (subprocess) ---------------------
@@ -636,9 +487,10 @@ WORKER = Path(__file__).with_name("mesh_tier_worker.py")
 
 @pytest.mark.timeout(600)
 def test_pod_contract_in_subprocess(tmp_path):
-    """The satellite CPU-testability drive: a fresh process with
-    XLA_FLAGS-forced devices runs the full pod contract (1 launch, 0
-    worker HTTP calls, parity, fallback) with unpolluted counters."""
+    """A fresh process with XLA_FLAGS-forced devices runs the whole
+    contract with counters nothing else has moved: an all-local boolean
+    is ONE launch, of the ``mesh`` family, with zero coordinator-to-
+    worker calls, and the answers are a plain engine's."""
     out = tmp_path / "out.json"
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
@@ -658,8 +510,9 @@ def test_pod_contract_in_subprocess(tmp_path):
     assert doc["devices"] >= 2
     assert doc["mesh_launches"] == 1
     assert doc["total_launches"] == 1
+    assert doc["launches_by_family"] == {"mesh": 1}
     assert doc["worker_http_calls"] == 0
     assert doc["transport_stats_unchanged"] is True
-    assert doc["mesh_dispatches"] == 1
+    assert doc["mesh_searches"] == 1
+    assert doc["exists"] is True
     assert doc["parity_ok"] is True
-    assert doc["fallback_ok"] is True

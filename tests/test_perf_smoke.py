@@ -22,8 +22,9 @@ N_SHARDS = 4
 
 
 def _engine(**eng_over):
+    eng_over.setdefault("use_mesh", False)
     cfg = BeaconConfig(
-        engine=EngineConfig(use_mesh=False, microbatch_wait_ms=0.0, **eng_over)
+        engine=EngineConfig(microbatch_wait_ms=0.0, **eng_over)
     )
     eng = VariantEngine(cfg)
     shards = []
@@ -57,7 +58,7 @@ def _payload():
 
 def _launches() -> int:
     # every kernel family counts: XLA gather (CPU tier-1), scatter
-    # tiles, and the pod-local mesh programs
+    # tiles, and the engine's mesh program
     from sbeacon_tpu.ops import scatter_kernel
     from sbeacon_tpu.parallel import mesh as mesh_mod
 
@@ -305,28 +306,14 @@ def test_hedged_scan_not_gated_by_slow_worker():
         pool.close()
 
 
-# -- pod-local mesh dispatch (ISSUE 9) ----------------------------------------
+# -- a coordinator's local leg (ISSUE 47) -------------------------------------
 
 
-@pytest.mark.perf_smoke
-def test_mesh_tier_boolean_query_is_one_launch_zero_http():
-    """A 4-shard boolean query served by the pod-local mesh tier must
-    cost exactly ONE kernel launch and ZERO coordinator->worker HTTP
-    calls (the pooled transport's process-wide stats unchanged across
-    the query) — the reference shape was k Lambda RTTs plus a DynamoDB
-    counter poll."""
-    import jax
-
-    from sbeacon_tpu.parallel import transport as transport_mod
+def _worker_beside(eng):
+    """A live worker in the fleet (so "zero HTTP" is the local leg's
+    doing, not an empty topology) and the coordinator over both."""
     from sbeacon_tpu.parallel.dispatch import DistributedEngine, WorkerServer
-    from sbeacon_tpu.index.columnar import build_index
-    from sbeacon_tpu.testing import random_records
 
-    if len(jax.devices()) < 2:
-        pytest.skip("mesh tier needs >=2 devices (forced-host CI mesh)")
-    eng, _shards = _engine()
-    # a live worker in the fleet proves "zero HTTP" is the tier's doing,
-    # not an empty topology
     weng = VariantEngine(
         BeaconConfig(engine=EngineConfig(microbatch=False, use_mesh=False))
     )
@@ -339,25 +326,45 @@ def test_mesh_tier_boolean_query_is_one_launch_zero_http():
         )
     )
     worker = WorkerServer(weng).start_background()
-    dist = DistributedEngine([worker.address], local=eng)
+    return weng, worker, DistributedEngine([worker.address], local=eng)
 
-    def transport_snapshot() -> dict:
-        keys = ("opened", "reused", "evicted", "retried", "gzip_bodies")
-        return {k: transport_mod._STATS.get(k) for k in keys}
 
+def _transport_snapshot() -> dict:
+    from sbeacon_tpu.parallel import transport as transport_mod
+
+    keys = ("opened", "reused", "evicted", "retried", "gzip_bodies")
+    return {k: transport_mod._STATS.get(k) for k in keys}
+
+
+@pytest.mark.perf_smoke
+def test_coordinator_boolean_query_is_one_launch_zero_http():
+    """A 4-dataset boolean query over a coordinator's LOCAL datasets
+    must cost exactly ONE kernel launch (the engine's own mesh program)
+    and ZERO coordinator->worker HTTP calls (the pooled transport's
+    process-wide stats unchanged across the query) — the reference
+    shape was k Lambda RTTs plus a DynamoDB counter poll."""
+    import jax
+
+    from sbeacon_tpu.telemetry import flight_recorder
+
+    if len(jax.devices()) < 2:
+        pytest.skip("the mesh stack needs >=2 devices (forced-host CI mesh)")
+    eng, _shards = _engine(use_mesh=True)
+    weng, worker, dist = _worker_beside(eng)
     try:
         dist.replica_table()  # discovery rides HTTP, OUTSIDE the probe
         dist.warmup()  # compiles outside the measured window
-        t0 = transport_snapshot()
+        t0 = _transport_snapshot()
         n0 = _launches()
+        m0 = flight_recorder.launches_by_family().get("mesh", 0)
         got = dist.search(
             _worker_payload(datasets=[f"d{d}" for d in range(N_SHARDS)])
         )
         assert _launches() - n0 == 1, "expected exactly one mesh launch"
-        assert transport_snapshot() == t0, "mesh query touched the transport"
-        assert any(r.exists for r in got) or got == []
-        st = dist.mesh_tier.stats()
-        assert st["dispatches"] == 1 and st["fallbacks"] == 0
+        assert flight_recorder.launches_by_family().get("mesh", 0) == m0 + 1
+        assert _transport_snapshot() == t0, "local query touched the transport"
+        assert any(r.exists for r in got)
+        assert eng.mesh_searches == 1
     finally:
         dist.close()
         worker.shutdown()
@@ -366,44 +373,34 @@ def test_mesh_tier_boolean_query_is_one_launch_zero_http():
 
 
 @pytest.mark.perf_smoke
-def test_mesh_tier_selected_query_is_one_launch_zero_http():
-    """ISSUE 13 acceptance: a selected-samples query over >=2
-    local-device datasets executes as ONE mesh launch (the
-    plane-stacked program — per-query masks reduced on the owning
-    device, zero per-dataset plane dispatches) with ZERO
-    coordinator->worker HTTP calls, byte-identical to the per-dataset
-    path."""
+def test_coordinator_selected_query_is_one_launch_an_owner_zero_http(
+    monkeypatch,
+):
+    """A selected-samples query over a coordinator's local datasets is
+    one match+planes launch an OWNER chip and nothing else (the chip's
+    index family forced on the CPU, where every dataset has a chip of
+    its own), with ZERO coordinator->worker HTTP calls, byte-identical
+    to the per-dataset path."""
     import dataclasses
 
     import jax
 
-    from sbeacon_tpu.parallel import transport as transport_mod
-    from sbeacon_tpu.parallel.dispatch import DistributedEngine, WorkerServer
-    from sbeacon_tpu.index.columnar import build_index
-    from sbeacon_tpu.testing import random_records
+    import sbeacon_tpu.engine as engine_mod
+    import sbeacon_tpu.telemetry as tel
+    from sbeacon_tpu.ops.scatter_kernel import ScatterDeviceIndex
 
     if len(jax.devices()) < 2:
-        pytest.skip("mesh tier needs >=2 devices (forced-host CI mesh)")
-    eng, _shards = _engine()
-    ref_eng, _ = _engine(mesh_dispatch=False, microbatch=False)
-    weng = VariantEngine(
-        BeaconConfig(engine=EngineConfig(microbatch=False, use_mesh=False))
+        pytest.skip("several owner chips need >=2 devices")
+    ref_eng, _ = _engine(microbatch=False)
+    monkeypatch.setattr(
+        engine_mod,
+        "make_device_index",
+        lambda shard, **kw: ScatterDeviceIndex(shard, device=kw.get("device")),
     )
-    weng.add_index(
-        build_index(
-            random_records(random.Random(9), chrom="1", n=120, n_samples=2),
-            dataset_id="wrk",
-            vcf_location="wrk.vcf.gz",
-            sample_names=["S0", "S1"],
-        )
-    )
-    worker = WorkerServer(weng).start_background()
-    dist = DistributedEngine([worker.address], local=eng)
-
-    def transport_snapshot() -> dict:
-        keys = ("opened", "reused", "evicted", "retried", "gzip_bodies")
-        return {k: transport_mod._STATS.get(k) for k in keys}
-
+    rec = tel.DeviceFlightRecorder()
+    monkeypatch.setattr(tel, "flight_recorder", rec)
+    eng, _shards = _engine(use_mesh=True)
+    weng, worker, dist = _worker_beside(eng)
     datasets = [f"d{d}" for d in range(N_SHARDS)]
     pay = dataclasses.replace(
         _worker_payload(granularity="record", include="ALL",
@@ -414,15 +411,18 @@ def test_mesh_tier_selected_query_is_one_launch_zero_http():
     try:
         dist.replica_table()  # discovery rides HTTP, OUTSIDE the probe
         dist.warmup()  # compiles outside the measured window
-        assert dist.mesh_tier.stats()["planes"] is True
-        t0 = transport_snapshot()
-        n0 = _launches()
-        got = dist.search(pay)
-        assert _launches() - n0 == 1, "expected exactly one mesh launch"
-        assert transport_snapshot() == t0, "plane query touched the transport"
-        st = dist.mesh_tier.stats()
-        assert st["dispatches"] == 1 and st["fallbacks"] == 0
         ref = ref_eng.search(pay)
+        owners = {row["chip"] for row in eng.placement_table()}
+        t0 = _transport_snapshot()
+        f0 = rec.launches_by_family()
+        got = dist.search(pay)
+        f1 = rec.launches_by_family()
+        assert {
+            f: f1[f] - f0.get(f, 0) for f in f1 if f1[f] != f0.get(f, 0)
+        } == {"plane": len(owners)}
+        assert len(owners) > 1
+        assert _transport_snapshot() == t0, "plane query touched the transport"
+        assert rec.fallbacks_by_site() == {}
         assert [dataclasses.asdict(r) for r in got] == [
             dataclasses.asdict(r) for r in ref
         ]

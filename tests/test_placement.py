@@ -7,12 +7,14 @@ family forced (``make_device_index`` picks it only on a TPU).
 
 import dataclasses
 import random
+import threading
 
 import jax
 import numpy as np
 import pytest
 
 import sbeacon_tpu.engine as engine_mod
+import sbeacon_tpu.ops.plane_kernel as plane_mod
 from sbeacon_tpu.config import BeaconConfig, EngineConfig
 from sbeacon_tpu.engine import (
     VariantEngine,
@@ -129,6 +131,100 @@ def test_the_plane_budget_is_a_chip_s(chips, n_chips, planed):
             _plane_bytes(s) for s in shards[:planed])
         assert ledger["reservedBytes"] == 0
     finally:
+        eng.close()
+
+
+def _site(name: str) -> int:
+    return flight_recorder.fallbacks_by_site().get(name, 0)
+
+
+@pytest.mark.parametrize(
+    "case", ["one", "second-same-chip", "second-other-chip", "same-key-race"]
+)
+def test_the_upload_gate_holds_a_chip_to_its_budget(chips, monkeypatch, case):
+    """The gate ``add_index`` holds a plane to, with a budget a little
+    over one plane's bytes: one plane is admitted and resident and its
+    requests count no fall-back; a second on the same chip is declined
+    (read from the host, counted), a second on another chip admitted;
+    and of two concurrent uploads of one key only one passes, the first
+    one's reservation standing in ``plane_ledger()`` while its bytes are
+    in flight and gone once they are published."""
+    one = _plane_bytes(_shard(0))
+    budget = 1.2 * one / 1e9
+    chips(2 if case == "second-other-chip" else 1)
+    n = 1 if case in ("one", "same-key-race") else 2
+    if case != "same-key-race":
+        eng, shards = _engine(
+            n_datasets=n, use_mesh=False, plane_hbm_budget_gb=budget
+        )
+        try:
+            planed = [
+                k for k, _s, p in eng.index_snapshot() if p is not None
+            ]
+            want = 1 if case == "second-same-chip" else n
+            assert planed == [_key(s) for s in shards[:want]]
+            assert eng._planes_declined == {_key(s) for s in shards[want:]}
+            ledger = eng.plane_ledger()
+            assert ledger["residentBytes"] == want * one
+            assert ledger["fullestChipBytes"] == one
+            assert ledger["reservedBytes"] == ledger["reservedTokens"] == 0
+            assert ledger["headroomBytes"] == int(budget * 1e9) - one
+            before = _site("host_planes")
+            for k, shard in enumerate(shards):
+                pay = _payload(
+                    shard, [shard.meta["dataset_id"]], selected=True
+                )
+                got, ref = eng.search(pay), _reference(shards, pay)
+                assert [r.sample_indices for r in got] == [
+                    r.sample_indices for r in ref]
+                assert _site("host_planes") == before + max(0, k + 1 - want)
+        finally:
+            eng.close()
+        return
+
+    # two uploads of ONE key at once: the first is held inside its
+    # upload, its reservation standing; the second meets the gate
+    real = plane_mod.PlaneDeviceIndex
+    entered, go = threading.Event(), threading.Event()
+    uploads = []
+
+    class Held(real):
+        def __init__(self, shard, **kw):
+            uploads.append(shard)
+            if len(uploads) == 1:
+                entered.set()
+                assert go.wait(60)
+            super().__init__(shard, **kw)
+
+    monkeypatch.setattr(plane_mod, "PlaneDeviceIndex", Held)
+    eng = VariantEngine(BeaconConfig(engine=EngineConfig(
+        use_mesh=False, plane_hbm_budget_gb=budget)))
+    shard = _shard(0)
+    first = threading.Thread(target=eng.add_index, args=(shard,))
+    try:
+        first.start()
+        assert entered.wait(60)
+        ledger = eng.plane_ledger()
+        assert ledger["reservedBytes"] == one
+        assert ledger["reservedTokens"] == 1
+        assert ledger["residentBytes"] == 0
+        assert ledger["fullestChipBytes"] == one
+        eng.add_index(shard)  # the second upload: declined at the gate
+        assert len(uploads) == 1
+        assert eng._planes_declined == {_key(shard)}
+        assert all(p is None for _k, _s, p in eng.index_snapshot())
+        go.set()
+        first.join(60)
+        assert not first.is_alive()
+        ledger = eng.plane_ledger()
+        assert ledger["reservedBytes"] == ledger["reservedTokens"] == 0
+        assert ledger["residentBytes"] == ledger["fullestChipBytes"] == one
+        assert len(uploads) == 1
+        (_k, _s, planes), = eng.index_snapshot()
+        assert planes is not None
+    finally:
+        go.set()
+        first.join(60)
         eng.close()
 
 

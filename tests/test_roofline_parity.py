@@ -1,19 +1,16 @@
-"""Roofline-campaign parity (ISSUE 17): the adaptive tier ladder and
-the owner-sharded mesh output layout are PURE perf changes — every
-answer must stay byte-identical (``dataclasses.asdict``) to the legacy
-``BATCH_TIERS`` ladder and the replicated output layout, across
+"""Roofline-campaign parity (ISSUE 17): the adaptive tier ladder is
+a PURE perf change — every answer must stay byte-identical
+(``dataclasses.asdict``) to the legacy ``BATCH_TIERS`` ladder, across
 boolean/count/record x selected-samples x delta-tail (L0) shapes.
 
 The ladder tests flip the process-global active ladder around the
 SAME index objects, so any divergence is the ladder's padding and
-nothing else; the mesh tests flip only ``owner_outputs`` on one
-``MeshFusedIndex``. Tier-1 safe (8 forced host devices via conftest).
+nothing else. Tier-1 safe (8 forced host devices via conftest).
 """
 
 import dataclasses
 import random
 
-import jax
 import numpy as np
 import pytest
 
@@ -33,11 +30,6 @@ from sbeacon_tpu.ops.kernel import (
 )
 from sbeacon_tpu.payloads import VariantQueryPayload
 from sbeacon_tpu.testing import random_records
-
-multi_device = pytest.mark.skipif(
-    len(jax.devices()) < 2,
-    reason="mesh parity needs >=2 devices (forced-host CI mesh)",
-)
 
 SAMPLES = ["S0", "S1"]
 
@@ -84,16 +76,17 @@ def test_ladder_fit_skips_skew_and_floor_and_converges():
     """fit() must never chase waste it cannot fix: the bottom rung's
     padding is the floor's known cost (a sub-floor rung would leak
     process-wide — every 3-query batch padding to 4 instead of 8), and
-    slice-replicated families record padded = c_slot * n_dev, so their
-    waste measures owner SKEW. Both classes of cell must be ignored, and
+    a ``plane`` launch's tier is its launch group's slot count, its
+    padding the members a request did not ask: no batch rung fixes
+    that. Both classes of cell must be ignored, and
     re-fitting on the same histogram must be a fixed point — otherwise
     each engine warmup() refit grows the ladder again."""
     ladder = TierLadder(TierLadder.DEFAULT_RUNGS)
     # sub-floor: 8 is the bottom rung, so an 87%-waste cell at 8 stays
     assert ladder.fit({("fused", 8): (10, 80)}) is ladder
-    # slice-replicated families: pure skew, never a split
-    assert ladder.fit({("mesh_sliced", 16): (16, 1280)}) is ladder
+    # a family padded by something other than a rung: never a split
     assert ladder.fit({("plane", 16): (16, 1280)}) is ladder
+    assert ladder.fit({("plane", 512): (650, 5120)}) is ladder
     # a genuinely wasteful serving rung splits once...
     fitted = ladder.fit({("fused", 512): (650, 5120)})
     assert 256 in fitted.rungs and fitted.source == "fit"
@@ -313,95 +306,3 @@ def test_ladder_parity_delta_tail():
         assert legacy == adaptive
     finally:
         eng.close()
-
-
-# -- owner-sharded vs replicated mesh outputs ---------------------------------
-
-
-@multi_device
-def test_owner_sharded_parity_byte_identical():
-    """The owner-sharded output layout (out_specs P('d'), per-owner
-    slice fetch) must answer byte-identically to the replicated layout
-    across match and plane (selected-samples) programs, balanced and
-    skewed batches — while fetching FEWER bytes off the device."""
-    import sbeacon_tpu.telemetry as tel
-    from sbeacon_tpu.parallel.mesh import MeshFusedIndex, make_mesh
-
-    shards = _shards(5, chrom="7", rows=250)
-    mfi = MeshFusedIndex(shards, make_mesh(), with_planes=True)
-    specs = [
-        QuerySpec("7", 1, 1 << 29, 1, 1 << 30, alternate_bases="N"),
-        QuerySpec("7", 900, 1600, 1, 1 << 30, alternate_bases="N"),
-    ]
-    balanced = [(sp, sid) for sp in specs for sid in range(5)]
-    skewed = [(sp, 0) for sp in specs for _ in range(4)]
-    rec = tel.flight_recorder
-    for name, pairs in (("balanced", balanced), ("skewed", skewed)):
-        enc = encode_queries(
-            [sp for sp, _ in pairs], shard_ids=[sid for _, sid in pairs]
-        )
-
-        def fewest_fetched(owner_outputs):
-            # the recorder is process-wide: a prober thread an earlier
-            # test left running can only ADD to a reading, so the
-            # smallest of a few is the call's own
-            seen = []
-            for _ in range(3):
-                f0 = rec.fetched_bytes
-                res = mfi.run_mesh_queries(
-                    dict(enc),
-                    window_cap=2048,
-                    record_cap=64,
-                    owner_outputs=owner_outputs,
-                )
-                seen.append(rec.fetched_bytes - f0)
-            return res, min(seen)
-
-        own, owner_bytes = fewest_fetched(True)
-        repl, repl_bytes = fewest_fetched(False)
-        _assert_results_byte_identical(own, repl, label=name)
-        # the output-diet claim: the owner fetch trims each device's
-        # block to its real count instead of pulling a full replica,
-        # at most half the bytes
-        assert owner_bytes * 2 <= repl_bytes, (name, owner_bytes, repl_bytes)
-        # plane program at the same shapes
-        masks = np.full(
-            (len(pairs), mfi.plane_words), 0xFFFFFFFF, np.uint32
-        )
-        mc = np.zeros(len(pairs), np.bool_)
-        own_p = mfi.run_mesh_queries(
-            dict(enc),
-            window_cap=2048,
-            record_cap=64,
-            sample_masks=masks,
-            mask_counts=mc,
-            owner_outputs=True,
-        )
-        repl_p = mfi.run_mesh_queries(
-            dict(enc),
-            window_cap=2048,
-            record_cap=64,
-            sample_masks=masks,
-            mask_counts=mc,
-            owner_outputs=False,
-        )
-        _assert_results_byte_identical(own_p, repl_p, label=f"{name}-plane")
-
-
-@multi_device
-def test_owner_sharded_fetch_never_materializes_replicas():
-    """Satellite bugfix guard: the owner fetch path slices each
-    device's OWN block (shape [c_slot, ...]) — a full-size replica
-    arriving at the host would defeat the output diet. The fetch
-    asserts per-shard shape internally; this exercises it on a batch
-    where c_slot x n_dev is much larger than the real batch."""
-    from sbeacon_tpu.parallel.mesh import MeshFusedIndex, make_mesh
-
-    shards = _shards(2, chrom="7", rows=120)
-    mfi = MeshFusedIndex(shards, make_mesh())
-    spec = QuerySpec("7", 1, 1 << 29, 1, 1 << 30, alternate_bases="N")
-    enc = encode_queries([spec] * 6, shard_ids=[0] * 6)
-    res = mfi.run_mesh_queries(
-        dict(enc), window_cap=2048, record_cap=64, owner_outputs=True
-    )
-    assert res.exists.shape == (6,)

@@ -215,10 +215,6 @@ GOLDEN_METRICS = [
     "dispatch.partial_responses",
     "routing.replicas",
     "routing.rediscoveries",
-    "mesh.dispatches",
-    "mesh.fallbacks",
-    "mesh.gather_rows",
-    "mesh.refusals",
     "breaker.state",
     "breaker.consecutive_failures",
     "breaker.opens",
@@ -819,8 +815,8 @@ def test_native_seam_lint_catches_violations():
 def test_warmup_ladder_lint_catches_violations():
     """ISSUE 17 satellite: the warmup-ladder parity lint over a
     compile snapshot — an active-ladder rung with no warmup-phase
-    compile, or a plane-capable family warming only one of its two
-    programs per rung, must fail."""
+    compile must fail, for the batcher's families and for the engine's
+    mesh program (warmed at the one batch it serves) alike."""
     sys.path.insert(0, str(REPO / "tools"))
     try:
         from check_launch_recording import (
@@ -844,11 +840,10 @@ def test_warmup_ladder_lint_catches_violations():
         "entries": [
             entry("fused", 8, "f:8"),
             entry("fused", 64, "f:64"),
-            entry("mesh_sliced", 1, "m:1:match"),
-            entry("mesh_sliced", 1, "m:1:plane"),
+            entry("mesh", 1, "m:1"),
         ]
     }
-    expected = {"fused": (8, 64), "mesh_sliced": (1,)}
+    expected = {"fused": (8, 64), "mesh": (1,)}
     assert lint_warmup_ladder(snap, expected) == []
     assert lint_warmup_ladder(snap["entries"], expected) == []
     # an uncovered rung fails, naming family and tier
@@ -859,30 +854,18 @@ def test_warmup_ladder_lint_catches_violations():
         [entry("fused", 8, "f:8", warmup=False)], {"fused": (8,)}
     )
     assert len(errs) == 1 and "warmup" in errs[0]
-    # a plane-capable family needs BOTH programs per rung
+    # a family's coverage is its own: another family's warm cell at
+    # the same tier does not count
     errs = lint_warmup_ladder(
-        [entry("mesh_sliced", 1, "m:1:match")],
-        {"mesh_sliced": (1,)},
-        plane_families=("mesh_sliced",),
+        [entry("fused", 1, "f:1")], {"mesh": (1,)}
     )
-    assert len(errs) == 1 and "plane" in errs[0]
-    assert (
-        lint_warmup_ladder(
-            snap,
-            {"mesh_sliced": (1,)},
-            plane_families=("mesh_sliced",),
-        )
-        == []
-    )
-    # the expected-map helper mirrors the warmup loops: host families
-    # warm every serving rung, mesh families the capped slice rungs
+    assert len(errs) == 1 and "mesh" in errs[0]
+    # the expected-map helper mirrors the warmup loops: every family
+    # the batcher pads warms every serving rung
     lad = TierLadder((8, 16, 32, 64, 512, 2048))
-    exp = expected_warm_rungs(
-        lad, families=("fused",), mesh_families=("mesh_sliced", "plane")
-    )
+    exp = expected_warm_rungs(lad, families=("fused", "fused_l0"))
     assert exp["fused"] == (8, 16, 32, 64, 512, 2048)
-    assert exp["mesh_sliced"] == (1, 8, 16, 32, 64)
-    assert exp["plane"] == exp["mesh_sliced"]
+    assert exp["fused_l0"] == exp["fused"]
 
 
 # -- annotation-key lint (ISSUE 11 satellite) ----------------------------------

@@ -13,10 +13,10 @@ launch ring and the compile tracker), and the old names are module
 This lint keeps it that way:
 
 - NO module under ``sbeacon_tpu/`` may assign or augment a
-  launch-counter name (``N_LAUNCHES`` / ``N_SLICED_LAUNCHES`` /
-  ``N_EVALUATED_PAIRS`` / ``N_DISPATCHES``) — at module scope, inside
-  a function, or via a ``global`` declaration. A reintroduced direct
-  increment is exactly the racy bypass this lint exists to stop;
+  launch-counter name (``N_LAUNCHES`` / ``N_DISPATCHES``) — at module
+  scope, inside a function, or via a ``global`` declaration. A
+  reintroduced direct increment is exactly the racy bypass this lint
+  exists to stop;
 - every module that dispatches compiled device programs (the three
   kernel seams) must keep its module ``__getattr__`` back-compat
   property AND call the recorder seam (``record_device_launch`` /
@@ -34,8 +34,7 @@ This lint keeps it that way:
 It also carries the RUNTIME warmup-ladder parity check
 (``lint_warmup_ladder``, ISSUE 17 satellite): given a flight-recorder
 compile snapshot and the rungs the active ``TierLadder`` serves, every
-(family, rung) cell must hold a warmup-stamped compile — and for
-plane-capable families both the match AND the plane program — so
+(family, rung) cell must hold a warmup-stamped compile, so
 ``device.mid_request_compiles`` stays zero for any batch the ladder
 can emit. The static ``main()`` pass cannot observe compiles, so this
 check runs from ``tests/test_telemetry.py`` against a warmed engine.
@@ -55,8 +54,6 @@ PKG = Path(__file__).resolve().parent.parent / "sbeacon_tpu"
 #: the launch-counter names whose direct mutation is forbidden
 COUNTER_NAMES = frozenset({
     "N_LAUNCHES",
-    "N_SLICED_LAUNCHES",
-    "N_EVALUATED_PAIRS",
     "N_DISPATCHES",
 })
 
@@ -160,11 +157,7 @@ def lint_jit_bypass(rel: str, src: str) -> list[str]:
     return errors
 
 
-def lint_warmup_ladder(
-    snapshot,
-    expected,
-    plane_families=(),
-) -> list[str]:
+def lint_warmup_ladder(snapshot, expected) -> list[str]:
     """Warmup-ladder parity (ISSUE 17 satellite).
 
     ``snapshot`` is a flight-recorder compile snapshot
@@ -175,56 +168,33 @@ def lint_warmup_ladder(
     Every (family, rung) cell must be covered by a compile stamped
     inside a ``device_warmup_phase`` — an uncovered rung is exactly a
     batch shape that would pay a mid-request compile the first time
-    traffic coalesces to it. Families in ``plane_families`` dispatch a
-    SECOND compiled program for selected-samples planes at the same
-    rungs, so their cells need at least two distinct warm program
-    keys (match + plane).
+    traffic coalesces to it.
     """
     entries = (
         snapshot.get("entries", [])
         if isinstance(snapshot, dict)
         else list(snapshot)
     )
-    warm: dict = {}
-    for e in entries:
-        if not e.get("warmup"):
-            continue
-        cell = (e.get("family"), int(e.get("tier", -1)))
-        warm.setdefault(cell, set()).add(e.get("key"))
-    errors: list[str] = []
-    for family in sorted(expected):
-        need = 2 if family in plane_families else 1
-        for t in sorted({int(r) for r in expected[family]}):
-            keys = warm.get((family, t), set())
-            if not keys:
-                errors.append(
-                    f"{family}: ladder rung {t} has no warmup-phase "
-                    "compile — the first request batch padded to this "
-                    "tier pays a mid-request compile"
-                )
-            elif len(keys) < need:
-                errors.append(
-                    f"{family}: ladder rung {t} warmed only "
-                    f"{len(keys)} program(s) — the match AND plane "
-                    "programs must both be covered"
-                )
-    return errors
+    warm = {
+        (e.get("family"), int(e.get("tier", -1)))
+        for e in entries
+        if e.get("warmup")
+    }
+    return [
+        f"{family}: ladder rung {t} has no warmup-phase "
+        "compile — the first request batch padded to this "
+        "tier pays a mid-request compile"
+        for family in sorted(expected)
+        for t in sorted({int(r) for r in expected[family]})
+        if (family, t) not in warm
+    ]
 
 
-def expected_warm_rungs(
-    ladder,
-    families=("fused",),
-    mesh_families=(),
-) -> dict:
+def expected_warm_rungs(ladder, families=("fused",)) -> dict:
     """The (family → rungs) map ``lint_warmup_ladder`` checks, derived
-    from one ``TierLadder``. Host-padded families warm every serving
-    rung (``ladder.rungs``); mesh families key programs on the
-    PER-DEVICE slice tier, so they warm ``ladder.mesh_warm_rungs()``
-    (slice rungs at or under ``MESH_WARM_CAP`` — larger rungs are bulk
-    shapes outside the serving path)."""
-    exp = {f: tuple(ladder.rungs) for f in families}
-    exp.update({f: tuple(ladder.mesh_warm_rungs()) for f in mesh_families})
-    return exp
+    from one ``TierLadder``: every family pads a request's batch to a
+    serving rung (``ladder.rungs``) and warms them all."""
+    return {f: tuple(ladder.rungs) for f in families}
 
 
 def lint_l0_family(kernel_src: str, telemetry_src: str) -> list[str]:
